@@ -6,11 +6,20 @@ initializes its backends, hence the env mutation at import time.
 """
 
 # Force the CPU backend with 8 virtual devices so multi-chip paths run
-# without hardware (see tpu_olap.utils.platform for why env vars alone
-# are not enough in this sandbox).
+# without hardware. tpu_olap.utils.platform is the one home for the
+# env/config mutation (shared with bench.py's CPU rehearsal and tools).
 from tpu_olap.utils.platform import force_cpu_devices  # noqa: E402
 
 force_cpu_devices(8)  # raises if a backend beat us to initialization
+
+import jax  # noqa: E402
+
+# Engine.__init__ places the persistent compile cache at
+# <checkout>/.jax_cache (platform.configure_compile_cache). A test session
+# must not fill it with XLA:CPU entries that then travel with every chip
+# call (the chip tool copies the tree as it stands): switch the cache off
+# for the session — this sets no directory.
+jax.config.update("jax_enable_compilation_cache", False)
 
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402

@@ -1,0 +1,208 @@
+"""Compile the serving path's device programs for a DESCRIBED TPU v5e.
+
+Interpret mode never lowers through Mosaic, so every other Pallas test
+in this suite passes on kernels the chip's compiler refuses
+(docs/TPU_NOTES.md, pitfalls #1-#5). The TPU compiler is installed in
+the sandbox and compiles for a topology that is described, not attached;
+nothing runs, so these cases guard lowering only — never results or
+times. This is the only file that describes the chip: the topology call
+lives in a fixture (one xdist worker loads libtpu, the others never do),
+never at import, in a skipif, in parametrize arguments, or in conftest.
+"""
+
+import math
+import os
+import re
+
+import pytest
+
+from tpu_olap import Engine
+from tpu_olap.bench import QUERIES
+from tpu_olap.bench.ssb import generate_tables, register_ssb
+from tpu_olap.executor import EngineConfig
+
+ROWS = 120_000
+ROWS_75M = 75_000_000
+
+MINMAX_SQL = """
+    SELECT d_year, min(lo_revenue) AS lo, max(lo_supplycost) AS hi,
+           sum(lo_revenue) AS revenue
+    FROM lineorder GROUP BY d_year"""
+# min/max disables the lane factorization, so K ~ 7 x 1000 tiles over
+# ceil(K / pallas_k_per_block) K-blocks
+KTILED_SQL = """
+    SELECT d_year, p_brand1, max(lo_revenue) AS hi,
+           sum(lo_revenue) AS revenue
+    FROM lineorder GROUP BY d_year, p_brand1"""
+
+# sketch finals are float64 computed ON the device (packing.device_finalize)
+HLL_SQL = """
+    SELECT s_region, approx_count_distinct(lo_custkey) AS u
+    FROM lineorder JOIN supplier ON lo_suppkey = s_suppkey
+    GROUP BY s_region"""
+
+# (id, sql, rows the segment axis is scaled to, expect the Pallas kernel).
+# min/max and sketches finalize to float64, which the TPU cannot bitcast
+# into the packed int32 buffer: those slabs travel as an f32 pair
+# (packing.PackLayout.f64_as_pair) and the program stays single-fetch.
+CASES = [
+    ("q1.1-generic", QUERIES["q1.1"], ROWS, False),
+    ("q2.1", QUERIES["q2.1"], ROWS, True),
+    ("q2.2-factorized", QUERIES["q2.2"], ROWS, True),
+    ("minmax", MINMAX_SQL, ROWS, True),
+    ("k-tiled", KTILED_SQL, ROWS, True),
+    ("hll", HLL_SQL, ROWS, False),
+    ("q4.3-chunked-75M", QUERIES["q4.3"], ROWS_75M, True),
+]
+
+
+@pytest.fixture(scope="module")
+def topo(tmp_path_factory):
+    from jax.experimental import topologies
+    # libtpu otherwise logs under the fixed /tmp/tpu_logs, outside the
+    # checkout and TMPDIR, where every run of this file would meet
+    # ("disabled" only moves the files to the top of TMPDIR or /tmp)
+    os.environ.setdefault("TPU_LOG_DIR",
+                          str(tmp_path_factory.mktemp("tpu_logs")))
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "no compiler"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip (the next one warns and
+    recompiles) — keep the cache off around these compiles."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def ssb_tables():
+    return generate_tables(ROWS, seed=7)
+
+
+def _engine(ssb_tables, **cfg):
+    eng = Engine(EngineConfig(fallback_on_device_failure=False, **cfg))
+    register_ssb(eng, tables=ssb_tables)
+    return eng
+
+
+def _as_tpu(monkeypatch):
+    """Code that asks jax.default_backend() sees the CPU here; steer the
+    one seam the lowering reads so `use_pallas="auto"` builds the real
+    (non-interpret) kernel, exactly as it would on the chip."""
+    from tpu_olap.executor import lowering
+    monkeypatch.setattr(lowering, "_default_backend", lambda: "tpu")
+
+
+def _physical(eng, sql):
+    plan = eng.planner.plan(sql)
+    assert plan.rewritten, plan.fallback_reason
+    return eng.runner._lower_cached(plan.query, plan.entry.segments)
+
+
+def _scaled(tree, seg_factor, sharding):
+    """ShapeDtypeStructs of `tree` on the described device, leading
+    (segment) axis of every >=1-D leaf multiplied by `seg_factor`."""
+    import jax
+
+    def leaf(x):
+        shape = tuple(x.shape)
+        if seg_factor != 1 and shape:
+            shape = (shape[0] * seg_factor,) + shape[1:]
+        return jax.ShapeDtypeStruct(shape, x.dtype, sharding=sharding)
+
+    return jax.tree_util.tree_map(leaf, tree)
+
+
+def compile_dispatch(eng, phys, sharding, rows=None):
+    """Lower + compile, from shapes alone, the packed single-fetch
+    program (runner._packed_jit) the runner would dispatch for `phys`
+    on `sharding`'s device."""
+    r = eng.runner
+    env, valid, seg_mask = r._prepare(phys, {})
+    n_seg = len(seg_mask)
+    factor = 1 if rows is None else max(
+        1, math.ceil(rows / (n_seg * phys.table.block_rows)))
+    consts_dev, seg_arg = r._args_for(phys, seg_mask, None)
+    cap = min(eng.config.result_group_cap, phys.total_groups)
+    jitted, layout, _ = r._packed_jit(phys, cap, None)
+    data = _scaled((env, valid, seg_arg), factor, sharding)
+    consts = _scaled(consts_dev, 1, sharding)
+    return jitted.lower(*data, consts).compile(), factor * n_seg, layout
+
+
+@pytest.mark.parametrize("case,sql,rows,want_pallas", CASES,
+                         ids=[c[0] for c in CASES])
+def test_dispatch_program_compiles_for_v5e(topo, no_persistent_cache,
+                                           ssb_tables, monkeypatch, case,
+                                           sql, rows, want_pallas):
+    from jax.sharding import SingleDeviceSharding
+    _as_tpu(monkeypatch)
+    eng = _engine(ssb_tables)
+    phys = _physical(eng, sql)
+    if want_pallas:
+        assert phys.pallas_reason is None, phys.pallas_reason
+    compiled, n_seg, layout = compile_dispatch(
+        eng, phys, SingleDeviceSharding(topo.devices[0]),
+        rows if rows != ROWS else None)
+    assert layout.f64_as_pair
+    assert n_seg * phys.table.block_rows >= rows
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == want_pallas
+    if case == "k-tiled":
+        assert phys.total_groups > eng.config.pallas_k_per_block
+    if rows == ROWS_75M:
+        # enough grid steps that the int32 accumulator chunks: the
+        # kernel's [n_chunks, K_pad, W] output has n_chunks > 1, so the
+        # f64 chunk recombination is part of the compiled program
+        call = next(ln for ln in text.splitlines()
+                    if 'custom_call_target="tpu_custom_call"' in ln)
+        assert int(re.search(r"= s32\[(\d+),\d+,\d+\]", call).group(1)) > 1
+
+
+def test_sharded_program_compiles_for_four_chips(topo, no_persistent_cache,
+                                                 ssb_tables, monkeypatch):
+    """The dense mesh program (sharding.mesh_agg_kernel, "historicals")
+    on a four-device Mesh built from the described topology."""
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from tpu_olap.executor import sharding as sh
+    _as_tpu(monkeypatch)
+    eng = _engine(ssb_tables)
+    phys = _physical(eng, QUERIES["q2.1"])
+    assert phys.pallas_reason is None, phys.pallas_reason
+    mesh = Mesh(np.asarray(topo.devices[:4]), (sh.AXIS,))
+    env, valid, seg_mask = eng.runner._prepare(phys, {})
+    n_seg = sh.pad_segments(len(seg_mask), 4)
+
+    def struct(x, spec):
+        shape = tuple(x.shape)
+        if spec != P():
+            shape = (n_seg,) + shape[1:]
+        return jax.ShapeDtypeStruct(shape, x.dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    seg = P(sh.AXIS)
+    args = (jax.tree_util.tree_map(lambda x: struct(x, seg), env),
+            struct(valid, seg), struct(seg_mask, seg),
+            {k: struct(v, P()) for k, v in phys.pool.consts.items()})
+    fn = sh.mesh_agg_kernel(phys, mesh, n_seg // 4, "historicals")
+    text = fn.lower(*args).compile().as_text()
+    # The plan is Pallas-eligible, but the mesh program runs the plan's
+    # generic key_fn + group_reduce on every chip (mesh_agg_kernel's
+    # docstring): the record's `pallas` flag is plan-level under a mesh.
+    # Pinned here so a later per-chip Pallas mesh path updates it.
+    assert "tpu_custom_call" not in text
+    assert "scatter" in text

@@ -6,7 +6,7 @@ rides in silently as long as the worst-case metric holds. This tool is
 the gate CI (and future PRs) call:
 
     python tools/bench_compare.py BASELINE.json NEW.json
-    python tools/bench_compare.py BENCH_r05.json BENCH_r06.json \
+    python tools/bench_compare.py BENCH_r04.json BENCH_r06.json \
         --threshold 0.10
     python tools/bench_compare.py BENCH_CACHE_old.json BENCH_CACHE.json
 

@@ -34,6 +34,7 @@ from tpu_olap.resilience.faults import maybe_inject
 from tpu_olap.segments.ingest import (DEFAULT_BLOCK_ROWS, ingest_arrow,
                                       ingest_pandas, ingest_parquet,
                                       ingest_parquet_stream)
+from tpu_olap.utils.platform import configure_compile_cache
 
 _UNSUPPORTED = (UnsupportedAggregation, UnsupportedFilter,
                 UnsupportedGranularity, UnsupportedDimension)
@@ -66,6 +67,7 @@ def _failure_status(e: BaseException) -> int:
 class Engine:
     def __init__(self, config: EngineConfig | None = None):
         self.config = config or EngineConfig()
+        configure_compile_cache()  # before the first jit
         self.catalog = Catalog()
         self.runner = QueryRunner(self.config)
         self.planner = DruidPlanner(self.catalog, self.config)

@@ -88,9 +88,9 @@ def generate_tables(lineorder_rows: int = 60_000, seed: int = 0,
     parts)."""
     rng = np.random.default_rng(seed)
     n = lineorder_rows
-    n_cust = customers or max(200, n // 200)
-    n_supp = suppliers or max(150, n // 3000)
-    n_part = parts or max(500, n // 30)
+    d_cust, d_supp, d_part = _ssb_sizes(n)
+    n_cust, n_supp, n_part = (customers or d_cust, suppliers or d_supp,
+                              parts or d_part)
     dims = _gen_dimensions(rng, n_cust, n_supp, n_part)
     dims["lineorder"] = _gen_lineorder(
         rng, n, n_cust, n_supp, n_part,
@@ -160,44 +160,76 @@ def _gen_lineorder(rng, n: int, n_cust: int, n_supp: int, n_part: int,
     })
 
 
-def write_ssb_parquet(out_dir: str, lineorder_rows: int, seed: int = 0,
-                      chunk_rows: int = 2_000_000,
-                      row_group_rows: int = 1 << 18) -> tuple[list, dict]:
-    """Generate the denormalized SSB fact as a multi-file parquet dataset
-    in bounded-memory chunks (the SF10/SF100 generation path — a whole
-    SF10 denormalized frame would not be polite to host RAM, and the
-    row-group structure is what ingest_parquet_stream streams over).
+def _ssb_sizes(n: int) -> tuple[int, int, int]:
+    """(customers, suppliers, parts) for an n-row lineorder (SF ratios)."""
+    return max(200, n // 200), max(150, n // 3000), max(500, n // 30)
 
-    Returns (fact parquet paths, dimension tables dict)."""
+
+def _dim_path(out_dir: str, table: str) -> str:
+    import os
+    return os.path.join(out_dir, f"dim-{table}.parquet")
+
+
+def _write_fact_chunk(task) -> str:
+    """One fact file of write_ssb_parquet. Top-level and fed plain
+    values so a spawn worker can run it: dimension frames are re-read
+    from the parquet files the parent wrote beside the fact."""
     import os
 
     import pyarrow as pa
     import pyarrow.parquet as pq
 
-    n = lineorder_rows
-    n_cust = max(200, n // 200)
-    n_supp = max(150, n // 3000)
-    n_part = max(500, n // 30)
-    rng = np.random.default_rng(seed)
-    dims = _gen_dimensions(rng, n_cust, n_supp, n_part)
-    datekeys = dims["date"]["d_datekey"].to_numpy()
+    out_dir, seed, chunk_idx, start, m, row_group_rows, dims = task
+    if dims is None:
+        dims = {t: pd.read_parquet(_dim_path(out_dir, t))
+                for t in _DENORM_COLS}
+    crng = np.random.default_rng((seed, 7919, chunk_idx))
+    fact = _gen_lineorder(
+        crng, m, len(dims["customer"]), len(dims["supplier"]),
+        len(dims["part"]), dims["date"]["d_datekey"].to_numpy(),
+        start_key=start)
+    chunk = denormalize({"lineorder": fact, **dims})
+    path = os.path.join(out_dir, f"lineorder-{chunk_idx:05d}.parquet")
+    pq.write_table(pa.Table.from_pandas(chunk, preserve_index=False),
+                   path, row_group_size=row_group_rows)
+    return path
 
+
+def write_ssb_parquet(out_dir: str, lineorder_rows: int, seed: int = 0,
+                      chunk_rows: int = 2_000_000,
+                      row_group_rows: int = 1 << 18,
+                      workers: int = 1) -> tuple[list, dict]:
+    """Generate the denormalized SSB fact as a multi-file parquet dataset
+    in bounded-memory chunks (the SF10/SF100 generation path — a whole
+    SF10 denormalized frame would not be polite to host RAM, and the
+    row-group structure is what ingest_parquet_stream streams over).
+
+    Every chunk draws from its own (seed, chunk) stream, so the files
+    are identical for any `workers`; > 1 writes chunks from that many
+    spawned processes (pandas merges hold the GIL — threads gain
+    little). Workers only run numpy/pandas/pyarrow: they never touch a
+    JAX device, so the parent may own the chip.
+
+    Returns (fact parquet paths, dimension tables dict)."""
+    import os
+
+    n = lineorder_rows
+    rng = np.random.default_rng(seed)
+    dims = _gen_dimensions(rng, *_ssb_sizes(n))
     os.makedirs(out_dir, exist_ok=True)
-    paths = []
-    start = 1
-    chunk_idx = 0
-    while start <= n:
-        m = min(chunk_rows, n - start + 1)
-        crng = np.random.default_rng((seed, 7919, chunk_idx))
-        fact = _gen_lineorder(crng, m, n_cust, n_supp, n_part, datekeys,
-                              start_key=start)
-        chunk = denormalize({"lineorder": fact, **dims})
-        path = os.path.join(out_dir, f"lineorder-{chunk_idx:05d}.parquet")
-        pq.write_table(pa.Table.from_pandas(chunk, preserve_index=False),
-                       path, row_group_size=row_group_rows)
-        paths.append(path)
-        start += m
-        chunk_idx += 1
+    starts = range(1, n + 1, chunk_rows)
+    tasks = [(out_dir, seed, i, s, min(chunk_rows, n - s + 1),
+              row_group_rows, None if workers > 1 else dims)
+             for i, s in enumerate(starts)]
+    if workers <= 1:
+        return [_write_fact_chunk(t) for t in tasks], dims
+    import multiprocessing
+
+    for t in _DENORM_COLS:
+        dims[t].to_parquet(_dim_path(out_dir, t), index=False)
+    with multiprocessing.get_context("spawn").Pool(
+            min(workers, len(tasks))) as pool:
+        paths = pool.map(_write_fact_chunk, tasks, chunksize=1)
     return paths, dims
 
 

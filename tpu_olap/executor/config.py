@@ -367,7 +367,7 @@ class EngineConfig:
     # count good, others (and failures/sheds) bad; the burn-rate gauge
     # is bad_fraction over slo_window_s divided by the error budget
     # (1 - slo_target). Defaults mirror the bench north star
-    # (BASELINE.md: every SSB query < 500 ms).
+    # (BASELINE.json: every SSB query < 500 ms).
     slo_latency_ms: float = 500.0
     slo_target: float = 0.99
     slo_window_s: float = 3600.0

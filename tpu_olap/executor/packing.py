@@ -38,13 +38,23 @@ _WORD = np.dtype(np.int32)  # buffer word: everything bitcasts to int32
 @dataclass(frozen=True)
 class PackLayout:
     """Static buffer layout: [count:int32][idx:int32[cap]] then one
-    [cap]-slot slab per field, each bitcast to int32 words."""
+    [cap]-slot slab per field, each bitcast to int32 words.
+
+    `f64_as_pair`: a float64 slab travels as [hi:f32[cap]][lo:f32[cap]]
+    (value = hi + lo) instead of bitcast words — same width. The TPU
+    emulates f64 as exactly such an f32 pair and XLA's x64 rewriter
+    refuses every f64<->integer bitcast-convert there (UNIMPLEMENTED at
+    compile time, found compiling a min/max plan for a described v5e),
+    so the split carries all the device holds; elsewhere the bitcast is
+    exact and stays."""
     cap: int
     total: int
     fields: tuple  # ((name, np.dtype), ...) in buffer order
+    f64_as_pair: bool = False
 
 
-def make_layout(plan, config, cap: int | None = None) -> PackLayout:
+def make_layout(plan, config, cap: int | None = None,
+                backend: str | None = None) -> PackLayout:
     cap = min(cap if cap is not None else config.result_group_cap,
               plan.total_groups)
     fdt = np.dtype(np.float64 if config.enable_x64 else np.float32)
@@ -54,7 +64,8 @@ def make_layout(plan, config, cap: int | None = None) -> PackLayout:
             fields.append((p.name, np.dtype(p.acc_dtype)))
         else:  # min | max | hll | theta -> finalized float
             fields.append((p.name, fdt))
-    return PackLayout(cap, plan.total_groups, tuple(fields))
+    return PackLayout(cap, plan.total_groups, tuple(fields),
+                      f64_as_pair=backend == "tpu")
 
 
 def device_finalize(out: dict, agg_plans, layout: PackLayout, xp) -> dict:
@@ -97,18 +108,26 @@ def build_packer(inner, plan, layout: PackLayout):
             .astype(jnp.int32)
         parts = [count.reshape(1), idx]
         for name, dt in layout.fields:
-            parts.append(_as_words(fin[name][idx].astype(dt)))
+            parts.append(_as_words(fin[name][idx].astype(dt),
+                                   layout.f64_as_pair))
         return jnp.concatenate(parts)
 
     return fn
 
 
-def _as_words(x):
+def _as_words(x, f64_as_pair: bool):
     import jax
     import jax.numpy as jnp
 
     if x.dtype == jnp.int32:
         return x.reshape(-1)
+    if f64_as_pair and x.dtype == jnp.float64:
+        hi = x.astype(jnp.float32)
+        # nan/inf ride in hi alone (inf - inf would turn them to nan)
+        lo = jnp.where(jnp.isfinite(hi), x - hi.astype(jnp.float64),
+                       0.0).astype(jnp.float32)
+        return jax.lax.bitcast_convert_type(
+            jnp.concatenate([hi, lo]), jnp.int32)
     return jax.lax.bitcast_convert_type(x, jnp.int32).reshape(-1)
 
 
@@ -127,7 +146,12 @@ def unpack(buf, layout: PackLayout):
         w = dt.itemsize // _WORD.itemsize
         slab = words[pos:pos + cap * w]
         pos += cap * w
-        arrays[name] = np.ascontiguousarray(slab).view(dt)[:n]
+        slab = np.ascontiguousarray(slab)
+        if layout.f64_as_pair and dt == np.float64:
+            hi, lo = slab.view(np.float32).reshape(2, cap)[:, :n]
+            arrays[name] = hi.astype(np.float64) + lo
+        else:
+            arrays[name] = slab.view(dt)[:n]
     return count, idx, arrays
 
 

@@ -250,11 +250,18 @@ class StagePool:
                 self._gauges()
             if self._sched._m_wait is not None:
                 self._sched._m_wait.observe(waited_ms, stage=self.name)
+            # the task's token IS its slot: a section() of this stage
+            # inside the task must re-enter it, not wait for a second
+            # one — with fan-out >= max_workers + 1 (sparse fetch on an
+            # 8-chip mesh, 4 workers) every worker held a slot while
+            # waiting for another and the query hung forever
+            self._local.held = 1
             try:
                 fut._finish(res=ctx.run(fn))
             except BaseException as e:  # noqa: BLE001 - relayed via future
                 fut._finish(err=e)
             finally:
+                self._local.held = 0
                 self._release(token)
 
     def stop(self):

@@ -640,7 +640,8 @@ def build_kernel(plan, table, config, filter_fn, interpret: bool,
             # directly in K-major orientation so every op stays 2-D. Under
             # factorization the row axis indexes k1 = key >> s; garbage
             # keys on masked-out rows shift to negative k1 and never match
-            kk = jax.lax.broadcasted_iota(jnp.int32, (KB, rb), 0) + kb * KB
+            kk = jax.lax.broadcasted_iota(jnp.int32, (KB, rb), 0) \
+                + kb * np.int32(KB)
             if fact is not None:
                 k1 = jnp.right_shift(key, jnp.int32(fact.shift))
                 k2v = jnp.bitwise_and(key, jnp.int32(fact.k2 - 1))
@@ -710,7 +711,10 @@ def build_kernel(plan, table, config, filter_fn, interpret: bool,
                 onehot, vals, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32).astype(jnp.int32)
 
-            @pl.when(step % spc == 0)  # first step of this chunk
+            # np.int32, not the Python int: under x64 a weak int scalar
+            # enters the remainder as i64 and Mosaic's i64->i32 scalar
+            # conversion recurses (same hazard as the typed zero above)
+            @pl.when(step % np.int32(spc) == 0)  # first step of this chunk
             def _():
                 out_ref[0, :, :] = jnp.zeros((KB, W), jnp.int32)
             out_ref[0, :, :] += partial
@@ -723,7 +727,7 @@ def build_kernel(plan, table, config, filter_fn, interpret: bool,
                                           jnp.int32))
                 upd = jnp.concatenate(cols2, axis=1)
 
-                @pl.when(step == 0)
+                @pl.when(step == np.int32(0))
                 def _():
                     mm_ref[:, :] = jnp.full((KB, MM_pad),
                                             jnp.int32(MAX_VALUE),

@@ -16,7 +16,7 @@ first-class metric instead of something recomputed from bench artifacts:
   `GET /status`.
 
 Knobs (EngineConfig): `slo_latency_ms` (objective; default 500 matching
-BASELINE.md), `slo_target` (good fraction; default 0.99),
+BASELINE.json), `slo_target` (good fraction; default 0.99),
 `slo_window_s` (burn-rate window; default 3600).
 
 The window is a deque of per-second [second, events, bad] buckets

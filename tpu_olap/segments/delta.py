@@ -688,6 +688,12 @@ class IngestManager:
         df = pd.DataFrame(canon_rows)
         if TIME_COLUMN in df.columns:
             ts = pd.to_datetime(df[TIME_COLUMN], unit="ms")
+            # bounds check only (result discarded): pandas 3 keeps the
+            # ms unit here and accepts any epoch-ms, but catalog.frame's
+            # concat coerces to the base frame's unit — ns at the
+            # finest — and would raise AFTER the WAL ack. Whatever fits
+            # ns fits every unit the base can carry.
+            ts.astype("datetime64[ns]")
             df = df.drop(columns=[TIME_COLUMN])
             df[entry.time_column or TIME_COLUMN] = ts
         return df
