@@ -1,0 +1,13 @@
+"""The benchmark's self-tests run on the CPU, in seconds:
+
+    JAX_PLATFORMS=cpu python -m pytest perfbench/tests -q
+"""
+
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
