@@ -20,7 +20,9 @@ Phases, one chip (default):
   size     75,000,000 rows (SF100 on a v5e-8 / 8 chips) written as parquet,
            stream-ingested, then a QueryServer answers all 13 over POST /sql
            twice each; totals vs pyarrow, HTTP frame sha256 == Engine.sql's,
-           and every record served by the device on the planned path
+           and every record served by the device on the planned path; then
+           POST /debug/profile?ms=N under load: the capture holds the
+           program's spans and no Python-tracer events
 With --chips 4, only: one-device engine vs num_shards=4 engine on 24,000,000
 rows — sha256 parity, per-chip window, sparse fan-out, sys.devices, bytes
 resident on every chip.
@@ -160,6 +162,66 @@ def post_sql(conn, sql: str):
             resp.getheader("X-Query-Id"), ms)
 
 
+def check_device_capture(server, conn, sql: str, ms: int = 1500):
+    """POST /debug/profile?ms=N while this thread keeps querying: the
+    capture must hold the program's spans beside the device's operations
+    (host events named by the span, the device call named by the query id)
+    and nothing of the profiler's Python tracer (events named "$file:line
+    function"), which `obs.profile.start_capture` leaves off."""
+    import glob
+    import threading
+
+    import jax
+    from jax.profiler import ProfileData
+
+    out = {}
+
+    def capture():
+        c = http.client.HTTPConnection(server.host, server.port, timeout=120)
+        try:
+            c.request("POST", f"/debug/profile?ms={ms}", "")
+            out.update(json.loads(c.getresponse().read()))
+        finally:
+            c.close()
+
+    t = threading.Thread(target=capture)
+    t.start()
+    qids, deadline = [], time.perf_counter() + 60
+    while t.is_alive() and time.perf_counter() < deadline:
+        qids.append(post_sql(conn, sql)[1])
+    t.join(timeout=120)
+    check(out.get("ok") is True, f"POST /debug/profile: {out}")
+    paths = glob.glob(os.path.join(out["trace_dir"], "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    check(len(paths) == 1, f"capture files under {out['trace_dir']}: {paths}")
+    host, device_ops = {}, 0
+    for plane in ProfileData.from_file(paths[0]).planes:
+        for line in plane.lines:
+            if plane.name.startswith("/host:"):
+                for e in line.events:
+                    host[e.name] = host.get(e.name, 0) + 1
+            elif plane.name.startswith("/device:") and line.name == "XLA Ops":
+                device_ops += len(list(line.events))
+    shutil.rmtree(out["trace_dir"], ignore_errors=True)
+    spans = ("sql", "http-read", "parse", "plan", "execute", "device-call",
+             "prepare", "record", "render", "serialize", "http-write")
+    missing = [n for n in spans if n not in host]
+    python_events = sum(n for name, n in host.items()
+                        if name.startswith("$"))
+    annotated = sum(1 for q in qids if q in host)
+    check(not missing, f"capture lacks the spans {missing}")
+    check(python_events == 0,
+          f"capture holds {python_events} Python-tracer events")
+    check(annotated > 0, "no device call of the window is annotated")
+    check(device_ops > 0 or jax.devices()[0].platform != "tpu",
+          "capture holds no device operation")
+    say(f"capture: POST /debug/profile?ms={ms} -> {sum(host.values())} host "
+        f"events under {len(host)} names, {python_events} of the Python "
+        f"tracer; {len(qids)} queries sent, {annotated} device calls "
+        f"annotated, serialize x{host['serialize']}, device-call "
+        f"x{host['device-call']}; {device_ops} device operations")
+
+
 def phase_size(rows: int, seed: int, use_pallas: str, data_dir: str,
                workers: int):
     import jax
@@ -248,6 +310,7 @@ def phase_size(rows: int, seed: int, use_pallas: str, data_dir: str,
                 f"warm_compile_ms={warm.get('compile_ms', 0):.1f} "
                 f"path={warm.get('path')} groups={len(direct)} "
                 f"sha256={digest(direct)[:12]}")
+        check_device_capture(server, conn, QUERIES["q2.1"])
         hbm = eng.runner.device_snapshot()[0]
         stats = jax.devices()[0].memory_stats() or {}
         say(f"size: {served} HTTP records served by the device, none "

@@ -181,20 +181,3 @@ def test_concurrent_fallback_not_wedged_behind_device_query(engine):
         t.join(timeout=30)
         engine.config.fault_injector = None
         srv.stop()
-
-
-def test_profiler_hook(tmp_path):
-    rng = np.random.default_rng(3)
-    df = pd.DataFrame({
-        "ts": pd.to_datetime("2021-01-01")
-        + pd.to_timedelta(rng.integers(0, 86400, 256), unit="s"),
-        "v": rng.integers(0, 9, 256).astype(np.int64),
-    })
-    from tpu_olap.executor import EngineConfig
-    eng = Engine(EngineConfig(profile_dir=str(tmp_path)))
-    eng.register_table("t", df, time_column="ts")
-    eng.sql("SELECT sum(v) AS s FROM t")
-    rec = eng.history[-1]
-    assert rec["profile_trace"].startswith(str(tmp_path))
-    import os
-    assert os.path.isdir(rec["profile_trace"])

@@ -6,6 +6,7 @@ QUERY), and CLEAR DRUID CACHE.
 
 from __future__ import annotations
 
+import contextlib
 import time
 
 import numpy as np
@@ -16,8 +17,9 @@ from tpu_olap.catalog import (Catalog, StarSchema, SysTableProvider,
 from tpu_olap.obs.workload import (fingerprint_sql,
                                    introspection_execution)
 from tpu_olap.executor import EngineConfig, QueryRunner
-from tpu_olap.obs.trace import (Trace, current_query_id,
-                                in_nested_execution, nested_execution,
+from tpu_olap.obs.trace import (Trace, adopt_root, current_query_id,
+                                detached_trace, in_nested_execution,
+                                nested_execution,
                                 parse_traceparent, span as _span,
                                 use_query_id, use_traceparent)
 from tpu_olap.executor.dimplan import UnsupportedDimension
@@ -351,10 +353,33 @@ class Engine:
         with use_traceparent(tp["traceparent"] if tp else None):
             return self._sql_traced_inner(query, tp)
 
+    @contextlib.contextmanager
+    def _root(self, name: str):
+        """The statement's root trace: the one the HTTP edge opened for
+        this call (obs.trace.adopt_root — the edge closes it, after the
+        last byte is written), else one of this call's own."""
+        root = adopt_root(name)
+        if root is not None:
+            yield root
+            return
+        with self.tracer.trace(name) as root:
+            yield root
+
+    def _untraced(self, run):
+        """Run a statement that leaves no trace (a statement verb,
+        sys.* introspection): a root the HTTP edge opened for it is
+        discarded and out of sight while it runs."""
+        root = adopt_root("sql")
+        if root is None:
+            return run()
+        root.discard()
+        with detached_trace():
+            return run()
+
     def _sql_traced_inner(self, query: str, tp: dict | None = None):
         verb = _match_verb(query)
         if verb is not None:
-            return verb(self), None
+            return self._untraced(lambda: verb(self)), None
         from tpu_olap.planner.sqlparse import parse_sql
         pre_stmt = None
         if _SYS_HINT_RE.search(query):
@@ -371,8 +396,9 @@ class Engine:
                 pre_stmt = None
             if pre_stmt is not None \
                     and stmt_uses_sys(pre_stmt, self.catalog):
-                return self._execute_sys_stmt(pre_stmt), None
-        with self.tracer.trace("sql") as root:
+                return self._untraced(
+                    lambda: self._execute_sys_stmt(pre_stmt)), None
+        with self._root("sql") as root:
             root.set(sql=query)
             if tp is not None:
                 root.set(traceparent=tp["traceparent"],
@@ -643,7 +669,7 @@ class Engine:
         # one query_id per logical statement, minted up front so the
         # fused batch legs' records stay attributable (obs.trace)
         qids = [self.tracer.new_query_id() for _ in queries]
-        with self.tracer.trace("sql_batch") as root:
+        with self._root("sql_batch") as root:
             root.set(statements=len(queries))
             if tp is not None:
                 root.set(traceparent=tp["traceparent"],
@@ -767,7 +793,6 @@ class Engine:
         the planner's normalization passes, so aliases, windows over
         groups, and expression simplification behave exactly like any
         other fallback statement."""
-        from tpu_olap.obs.trace import detached_trace
         from tpu_olap.planner.exprutil import simplify_stmt
         from tpu_olap.planner.plan import _apply_windows_over_groups
         from tpu_olap.planner.sqlparse import UnionStmt

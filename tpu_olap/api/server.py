@@ -130,6 +130,12 @@ def _int_param(qs: dict, names, cap: int | None = None,
     return default
 
 
+def _encode(payload) -> bytes:
+    """A response body: strict JSON of the sanitized payload."""
+    return json.dumps(_jsonable(payload), default=str,
+                      allow_nan=False).encode()
+
+
 def _jsonable(x):
     """Strict-JSON sanitizer: NaN/inf and SQL nulls that surface as pandas
     scalars (NaT, pd.NA) -> JSON null; BI clients reject bare NaN/Infinity
@@ -173,8 +179,9 @@ class QueryServer:
                 pass
 
             def _send(self, code: int, payload, headers=()):
-                body = json.dumps(_jsonable(payload), default=str,
-                                  allow_nan=False).encode()
+                self._write(code, _encode(payload), headers)
+
+            def _write(self, code: int, body: bytes, headers=()):
                 self.send_response(code)
                 self.send_header("Content-Type", "application/json")
                 self.send_header("Content-Length", str(len(body)))
@@ -182,6 +189,39 @@ class QueryServer:
                     self.send_header(k, v)
                 self.end_headers()
                 self.wfile.write(body)
+
+            def _serve_sql(self):
+                """POST /sql and /sql/batch under one root trace, socket
+                to socket: the edge opens the statement's root before it
+                reads the body and closes it after the last byte is
+                written, and the engine entry point adopts it
+                (obs.trace.adopt_root). The edge's own children:
+                `http-read` (body read + JSON decode), then the
+                engine's spans, then `serialize` (frames -> rows ->
+                JSON bytes) and `http-write` (status line, headers,
+                body). An error answer is written after the root has
+                closed with the error on it."""
+                batch = self.path == "/sql/batch"
+                with server.engine.tracer.trace(
+                        "sql_batch" if batch else "sql",
+                        adoptable=True) as root:
+                    with root.span("http-read") as sp:
+                        raw = self.rfile.read(
+                            int(self.headers.get("Content-Length", 0)))
+                        req = json.loads(raw)
+                        sp.set(bytes=len(raw))
+                    frames, headers = server._sql(
+                        batch, req, self.headers.get("traceparent"))
+                    with root.span("serialize") as sp:
+                        results = [{"columns": list(f.columns),
+                                    "rows": f.to_dict("records")}
+                                   for f in frames]
+                        body = _encode({"results": results} if batch
+                                       else results[0])
+                        sp.set(rows=sum(len(f) for f in frames),
+                               bytes=len(body))
+                    with root.span("http-write"):
+                        self._write(200, body, headers)
 
             def _send_query_error(self, e: QueryError):
                 """Structured taxonomy mapping: status from the error,
@@ -237,10 +277,13 @@ class QueryServer:
             def do_POST(self):
                 server._enter()
                 try:
-                    payload, headers = server._post(
-                        self.path, self._body(),
-                        traceparent=self.headers.get("traceparent"))
-                    self._send(200, payload, headers)
+                    if self.path in ("/sql", "/sql/batch"):
+                        self._serve_sql()
+                    else:
+                        payload, headers = server._post(
+                            self.path, self._body(),
+                            traceparent=self.headers.get("traceparent"))
+                        self._send(200, payload, headers)
                 except QueryError as e:
                     # taxonomy first: UserError IS a ValueError and
                     # FallbackError maps to 400 through http_status, so
@@ -460,36 +503,39 @@ class QueryServer:
         m.gauge("slo_burn_rate").set(eng.runner.slo.burn_rate())
         return m.render()
 
-    def _post(self, path: str, body: str, traceparent: str | None = None):
-        """(payload, headers) for a POST. /sql and /sql/batch answer
-        with an X-Query-Id header (ISSUE 11 satellite) so a client can
-        correlate a response with /debug/queries, SELECT ... FROM
-        sys.queries, and Perfetto traces. A valid W3C `traceparent`
-        request header (ISSUE 17) joins the query records and span
-        trees to the caller's distributed trace and is echoed back on
-        the response; an invalid one is ignored, never an error."""
+    @staticmethod
+    def _traceparent_headers(traceparent: str | None) -> list:
+        """A valid W3C `traceparent` request header (ISSUE 17) joins the
+        query records and span trees to the caller's distributed trace
+        and is echoed back on the response; an invalid one is ignored,
+        never an error."""
         from tpu_olap.obs.trace import parse_traceparent
         tp = parse_traceparent(traceparent)
-        tp_headers = [("traceparent", tp["traceparent"])] if tp else []
-        if path == "/sql":
-            req = json.loads(body)
+        return [("traceparent", tp["traceparent"])] if tp else []
+
+    def _sql(self, batch: bool, req: dict, traceparent: str | None = None):
+        """(result frames, headers) of POST /sql (one frame) or
+        /sql/batch. Both answer with an X-Query-Id header (ISSUE 11
+        satellite) so a client can correlate a response with
+        /debug/queries, SELECT ... FROM sys.queries, and Perfetto
+        traces; /sql's is the id of the root trace the handler opened."""
+        tp_headers = self._traceparent_headers(traceparent)
+        if not batch:
             frame, trace = self.engine._sql_traced(
                 req["query"], traceparent=traceparent)
             headers = [("X-Query-Id", trace.query_id)] \
                 if trace is not None else []
-            return {"columns": list(frame.columns),
-                    "rows": frame.to_dict("records")}, \
-                headers + tp_headers
-        if path == "/sql/batch":
-            # explicit batch submission: one POST, N statements, shared
-            # scans where compatible (Engine.sql_batch / executor.batch)
-            req = json.loads(body)
-            frames, qids = self.engine.sql_batch_ids(
-                req["queries"], traceparent=traceparent)
-            return {"results": [{"columns": list(f.columns),
-                                 "rows": f.to_dict("records")}
-                                for f in frames]}, \
-                [("X-Query-Id", ",".join(qids))] + tp_headers
+            return [frame], headers + tp_headers
+        # explicit batch submission: one POST, N statements, shared
+        # scans where compatible (Engine.sql_batch / executor.batch)
+        frames, qids = self.engine.sql_batch_ids(
+            req["queries"], traceparent=traceparent)
+        return frames, [("X-Query-Id", ",".join(qids))] + tp_headers
+
+    def _post(self, path: str, body: str, traceparent: str | None = None):
+        """(payload, headers) for a POST other than /sql and /sql/batch
+        (Handler._serve_sql)."""
+        tp_headers = self._traceparent_headers(traceparent)
         if path in ("/druid/v2", "/druid/v2/"):
             spec = json.loads(body)
             res = self.engine.execute_ir(spec)

@@ -335,14 +335,8 @@ class EngineConfig:
     breaker_failure_threshold: int = 5
     breaker_open_cooldown_s: float = 5.0
 
-    # tracing (SURVEY.md §6): when set, each query dispatch runs under a
-    # jax.profiler trace written beneath this directory; the history record
-    # gets a "profile_trace" pointer. Opt-in — per-query profiler start/stop
-    # costs milliseconds.
-    profile_dir: str | None = None
-
     # observability (tpu_olap.obs): per-query span-tree tracing (obs.trace)
-    # — on by default; the cost is two perf_counter() calls per stage.
+    # — on by default; what a span costs is in docs/OBSERVABILITY.md.
     # trace_history_limit bounds the recent-trace ring served by
     # GET /debug/queries; traces slower than slow_query_ms also land in the
     # slow-query ring (slow_log_limit entries).

@@ -25,7 +25,7 @@ from tpu_olap.kernels.exprs import materialize_virtuals
 from tpu_olap.kernels.filtereval import ConstPool, compile_filter
 from tpu_olap.kernels.groupby import (UnsupportedAggregation,
                                       build_group_key, compile_aggregations,
-                                      group_reduce)
+                                      group_reduce, stage_scope)
 from tpu_olap.kernels.timebucket import compile_granularity
 from tpu_olap.executor.dimplan import compile_dimension
 from tpu_olap.segments.segment import ColumnType, TIME_COLUMN
@@ -495,26 +495,28 @@ def _lower_agg(query, table, config) -> PhysicalPlan:
         nulls = {c: a.reshape(-1) for c, a in env["nulls"].items()}
         materialize_virtuals(vexprs, flat, nulls, xp)
         fenv = {"cols": flat, "nulls": nulls}
-        mask = (valid & seg_mask[:, None]).reshape(-1)
-        if filter_fn is not None:
-            mask = mask & filter_fn(fenv, consts)
-        if imask_fn is not None:
-            mask = mask & imask_fn(fenv, consts)
+        with stage_scope("filter", xp):
+            mask = (valid & seg_mask[:, None]).reshape(-1)
+            if filter_fn is not None:
+                mask = mask & filter_fn(fenv, consts)
+            if imask_fn is not None:
+                mask = mask & imask_fn(fenv, consts)
         ids, radix = [], []
-        if bucket_plan.kind != "all":
-            cached = flat.get(bucket_plan.derived_name) \
-                if bucket_plan.cache_token else None
-            ids.append(bucket_plan.ids_from_cached(cached, consts, xp)
-                       if cached is not None
-                       else bucket_plan.ids(flat[TIME_COLUMN], consts))
-            radix.append(sizes[0])
-        for dp, size in zip(dim_plans, sizes[1:]):
-            ids.append(dp.ids(fenv, consts, xp))
-            radix.append(size)
-        if ids:
-            key, _ = key_builder(ids, radix, xp)
-        else:
-            key = xp.zeros(mask.shape, xp.int32)
+        with stage_scope("key", xp):
+            if bucket_plan.kind != "all":
+                cached = flat.get(bucket_plan.derived_name) \
+                    if bucket_plan.cache_token else None
+                ids.append(bucket_plan.ids_from_cached(cached, consts, xp)
+                           if cached is not None
+                           else bucket_plan.ids(flat[TIME_COLUMN], consts))
+                radix.append(sizes[0])
+            for dp, size in zip(dim_plans, sizes[1:]):
+                ids.append(dp.ids(fenv, consts, xp))
+                radix.append(size)
+            if ids:
+                key, _ = key_builder(ids, radix, xp)
+            else:
+                key = xp.zeros(mask.shape, xp.int32)
         return fenv, mask, key
 
     def kernel(env, valid, seg_mask, consts):
@@ -684,11 +686,12 @@ def _lower_mask(query, table, config) -> PhysicalPlan:
         nulls = {c: a.reshape(-1) for c, a in env["nulls"].items()}
         materialize_virtuals(vexprs, flat, nulls, xp)
         fenv = {"cols": flat, "nulls": nulls}
-        mask = (valid & seg_mask[:, None]).reshape(-1)
-        if filter_fn is not None:
-            mask = mask & filter_fn(fenv, consts)
-        if imask_fn is not None:
-            mask = mask & imask_fn(fenv, consts)
+        with stage_scope("filter", xp):
+            mask = (valid & seg_mask[:, None]).reshape(-1)
+            if filter_fn is not None:
+                mask = mask & filter_fn(fenv, consts)
+            if imask_fn is not None:
+                mask = mask & imask_fn(fenv, consts)
         return {"mask": mask}
 
     statics = ("mask", filter_fn is not None, imask_fn is not None)

@@ -95,24 +95,26 @@ def device_finalize(out: dict, agg_plans, layout: PackLayout, xp) -> dict:
 def build_packer(inner, plan, layout: PackLayout):
     """Wrap a partials kernel (single-chip or sharded+merged) so the jitted
     program returns the single packed int32 buffer."""
+    import jax
     import jax.numpy as jnp
 
     agg_plans = plan.agg_plans
 
-    def fn(env, valid, seg_mask, consts):
+    def packed_agg(env, valid, seg_mask, consts):
         out = inner(env, valid, seg_mask, consts)
-        fin = device_finalize(out, agg_plans, layout, jnp)
-        present = fin["_rows"] > 0
-        count = present.sum(dtype=jnp.int32)
-        idx = jnp.nonzero(present, size=layout.cap, fill_value=0)[0] \
-            .astype(jnp.int32)
-        parts = [count.reshape(1), idx]
-        for name, dt in layout.fields:
-            parts.append(_as_words(fin[name][idx].astype(dt),
-                                   layout.f64_as_pair))
-        return jnp.concatenate(parts)
+        with jax.named_scope("pack"):
+            fin = device_finalize(out, agg_plans, layout, jnp)
+            present = fin["_rows"] > 0
+            count = present.sum(dtype=jnp.int32)
+            idx = jnp.nonzero(present, size=layout.cap, fill_value=0)[0] \
+                .astype(jnp.int32)
+            parts = [count.reshape(1), idx]
+            for name, dt in layout.fields:
+                parts.append(_as_words(fin[name][idx].astype(dt),
+                                       layout.f64_as_pair))
+            return jnp.concatenate(parts)
 
-    return fn
+    return packed_agg
 
 
 def _as_words(x, f64_as_pair: bool):

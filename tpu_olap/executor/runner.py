@@ -223,7 +223,6 @@ class QueryRunner:
                               self.config.slo_window_s,
                               metrics=self.metrics)
         self._totals_lock = threading.Lock()
-        self._profile_seq = 0  # profiler trace dirs outlive ring eviction
         self._totals = {"queries": 0, "rows_scanned": 0,
                         "segments_scanned": 0, "segments_pruned": 0,
                         "cache_hits": 0, "total_ms": 0.0}
@@ -579,7 +578,13 @@ class QueryRunner:
         stays exact after ring eviction) and the metrics registry, then
         append to the bounded history ring. Sanitization is IN PLACE so
         a QueryResult.metrics dict sharing this object stays the
-        consistent view."""
+        consistent view. The `record` span times all of it: the
+        workload profiler, the registry, the SLO, the event log and the
+        sentinel, per query, on the serving thread."""
+        with _span("record"):
+            return self._record(m)
+
+    def _record(self, m: dict) -> dict:
         # the transient fingerprint rides under `_wl` (obs.workload):
         # popped before sanitization so the object never stringifies
         fp = m.pop("_wl", None)
@@ -915,13 +920,17 @@ class QueryRunner:
         attempts = max(1, self.config.dispatch_retries + 1)
         for attempt in range(attempts):
             try:
-                maybe_inject(self.config, "dispatch", attempt)
                 self._attempt_local.value = attempt
-                # while an on-demand jax.profiler capture is live
-                # (obs.profile), annotate this dispatch with its
-                # query_id so the captured XLA ops nest under the query;
-                # otherwise a single module-flag probe
-                with annotate_dispatch(current_query_id()):
+                # `device-call` is the one instant both clocks see: the
+                # span (perf_counter) has exactly the extent of the
+                # annotation that a live jax.profiler capture
+                # (obs.profile) puts around this dispatch under its
+                # query_id, so the captured XLA ops nest under the query
+                # and the two axes join on it; off capture the
+                # annotation is a single module-flag probe
+                with _span("device-call", attempt=attempt), \
+                        annotate_dispatch(current_query_id()):
+                    maybe_inject(self.config, "dispatch", attempt)
                     out = call()
                 # success resets the breaker's consecutive-failure count
                 self.breaker.record_success()
@@ -1291,22 +1300,7 @@ class QueryRunner:
         t0 = time.perf_counter()
         self._last_metrics = {}
         try:
-            if self.config.profile_dir is not None:
-                import os
-                import jax
-                # monotonic, NOT len(history): the ring plateaus at
-                # history_limit and directory names would collide
-                with self._totals_lock:
-                    self._profile_seq += 1
-                    seq = self._profile_seq
-                trace_dir = os.path.join(
-                    self.config.profile_dir,
-                    f"q{seq:05d}_{query.query_type}")
-                with jax.profiler.trace(trace_dir):
-                    res = self._execute_inner(query, table)
-                res.metrics["profile_trace"] = trace_dir
-            else:
-                res = self._execute_inner(query, table)
+            res = self._execute_inner(query, table)
         except Exception:
             # failed queries still leave an observability record (with
             # retry_errors) so poisoned-device vs deterministic failures
@@ -1323,7 +1317,8 @@ class QueryRunner:
         res.metrics["total_ms"] = (time.perf_counter() - t0) * 1000
         res.metrics["query_type"] = query.query_type
         res.metrics["datasource"] = table.name
-        fp = self.fingerprint(query, table.name)
+        with _span("fingerprint"):
+            fp = self.fingerprint(query, table.name)
         res.metrics["_wl"] = fp
         if abandoned is None or not abandoned.is_set():
             self.record(res.metrics)
@@ -1580,10 +1575,22 @@ class QueryRunner:
         metrics["rows_scanned"] = int(sum(
             table.segments[i].meta.n_valid for i in plan.pruned_ids)) \
             if not plan.empty else 0
+        metrics["bytes_scanned"] = metrics["segments_scanned"] \
+            * self._segment_scan_bytes(env, valid, table)
         if self._hbm_ledger.budget is not None:
             metrics["hbm_bytes"] = self._hbm_ledger.bytes_in_use
             metrics["hbm_evictions"] = self._hbm_ledger.evictions
         return env, valid, seg_mask
+
+    @staticmethod
+    def _segment_scan_bytes(env, valid, table) -> int:
+        """Bytes one scanned segment hands the kernels — not what the
+        answer needs: every array of the env (columns, null masks,
+        derived streams) and the validity mask, at its resident width,
+        over the segment's PADDED rows."""
+        return table.block_rows * sum(
+            a.dtype.itemsize
+            for a in (*env["cols"].values(), *env["nulls"].values(), valid))
 
     def _build_derived(self, ds, plan: PhysicalPlan, dp):
         """Materialize one precomputed dim id stream [S, R] int32 on the
@@ -1682,13 +1689,16 @@ class QueryRunner:
         that keep the window size re-use the executable."""
         import jax
 
-        def fn(env, valid, seg_mask, consts, lo):
+        def windowed(env, valid, seg_mask, consts, lo):
             def sl(a):
                 return jax.lax.dynamic_slice_in_dim(a, lo, W, axis=0)
-            wenv = {"cols": {c: sl(a) for c, a in env["cols"].items()},
+            with jax.named_scope("window"):
+                wenv = {
+                    "cols": {c: sl(a) for c, a in env["cols"].items()},
                     "nulls": {c: sl(a) for c, a in env["nulls"].items()}}
-            return kernel(wenv, sl(valid), sl(seg_mask), consts)
-        return fn
+                valid, seg_mask = sl(valid), sl(seg_mask)
+            return kernel(wenv, valid, seg_mask, consts)
+        return windowed
 
     @staticmethod
     def _window_numpy(env, valid, seg_mask, win):
@@ -1990,7 +2000,8 @@ class QueryRunner:
                     # single buffer — one round trip
                     with _span("host-transfer"):
                         buf = self._fetch_tree(buf, metrics, pin)
-                        count, idx, compact = unpack(buf, layout)
+                        with _span("unpack"):
+                            count, idx, compact = unpack(buf, layout)
                     if count <= layout.cap:
                         break
                     if count > cap_limit:
@@ -2415,6 +2426,7 @@ class QueryRunner:
                 metrics["segments_total"] = len(table.segments)
                 metrics["segments_scanned"] = 0
                 metrics["rows_scanned"] = 0
+                metrics["bytes_scanned"] = 0
                 metrics["num_shards"] = 1
         metrics["cache_hit"] = bool(hits)
         if hits:
@@ -2447,6 +2459,8 @@ class QueryRunner:
             metrics["segments_scanned"] = len(compute_ids)
             metrics["rows_scanned"] = int(sum(
                 table.segments[i].meta.n_valid for i in compute_ids))
+            metrics["bytes_scanned"] = len(compute_ids) \
+                * self._segment_scan_bytes(env, valid, table)
             S = len(seg_mask)
             K = plan.total_groups
             lo, hi = min(compute_ids), max(compute_ids) + 1

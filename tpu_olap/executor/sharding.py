@@ -243,7 +243,7 @@ def mesh_agg_kernel(plan, mesh: Mesh, per_chip: int, strategy: str,
     W = win[1] if win is not None else per_chip
     historicals = strategy == "historicals"
 
-    def body(env, valid, seg_mask, consts, lo=None):
+    def mesh_agg(env, valid, seg_mask, consts, lo=None):
         if lo is not None:
             env, valid, seg_mask = _window_env(env, valid, seg_mask,
                                                D, per_chip, lo, W)
@@ -251,16 +251,13 @@ def mesh_agg_kernel(plan, mesh: Mesh, per_chip: int, strategy: str,
         if not historicals:
             return group_reduce(key, mask, fenv, plan.agg_plans, K,
                                 consts)
-        key2 = chip_extended_key(key, mask, D, W, K)
+        with jax.named_scope("key"):
+            key2 = chip_extended_key(key, mask, D, W, K)
         return group_reduce(key2, mask, fenv, plan.agg_plans, D * K,
                             consts)
 
     out = shard_spec(mesh) if historicals else replicated_spec(mesh)
-    if win is not None:
-        return jax.jit(lambda e, v, m, c, lo: body(e, v, m, c, lo),
-                       out_shardings=out)
-    return jax.jit(lambda e, v, m, c: body(e, v, m, c),
-                   out_shardings=out)
+    return jax.jit(mesh_agg, out_shardings=out)
 
 
 def mesh_mask_kernel(plan, mesh: Mesh):
@@ -288,17 +285,18 @@ def mesh_seg_partials_kernel(plan, mesh: Mesh, per_chip: int, W: int,
 
     D = mesh.devices.size
 
-    def fn(env, valid, seg_mask, consts, lo):
+    def mesh_seg_partials(env, valid, seg_mask, consts, lo):
         env, valid, seg_mask = _window_env(env, valid, seg_mask,
                                            D, per_chip, lo, W)
         fenv, mask, key = plan.key_fn(env, valid, seg_mask, consts)
-        r = mask.shape[0] // (D * W)
-        pos = jnp.repeat(jnp.arange(D * W, dtype=jnp.int32), r)
-        key2 = pos * jnp.int32(K) + key.astype(jnp.int32)
+        with jax.named_scope("key"):
+            r = mask.shape[0] // (D * W)
+            pos = jnp.repeat(jnp.arange(D * W, dtype=jnp.int32), r)
+            key2 = pos * jnp.int32(K) + key.astype(jnp.int32)
         return group_reduce(key2, mask, fenv, plan.agg_plans, D * W * K,
                             consts)
 
-    return jax.jit(fn, out_shardings=shard_spec(mesh))
+    return jax.jit(mesh_seg_partials, out_shardings=shard_spec(mesh))
 
 
 def broker_merge(out: dict, agg_plans, num_shards: int) -> dict:

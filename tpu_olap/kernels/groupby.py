@@ -10,6 +10,7 @@ an allreduce, never a hash exchange, as long as K fits the dense budget
 
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass
 
 import jax
@@ -26,6 +27,18 @@ from tpu_olap.segments.segment import ColumnType
 
 class UnsupportedAggregation(Exception):
     pass
+
+
+_NO_SCOPE = contextlib.nullcontext()  # stateless, so one serves every call
+
+
+def stage_scope(name: str, xp):
+    """`jax.named_scope(name)` while a device program is being traced
+    (xp is jax.numpy), nothing on the numpy path: every op of the jitted
+    programs carries its stage — filter, key, reduce, pack — in its
+    op_name, which the profiler records beside the compiler's own name
+    for the op."""
+    return _NO_SCOPE if xp is np else jax.named_scope(name)
 
 
 @dataclass
@@ -171,6 +184,11 @@ def group_reduce(key, mask, env, plans, num_groups, consts):
     hll max, theta re-merge).
     """
     xp = jnp if not isinstance(mask, np.ndarray) else np
+    with stage_scope("reduce", xp):
+        return _group_reduce(key, mask, env, plans, num_groups, consts, xp)
+
+
+def _group_reduce(key, mask, env, plans, num_groups, consts, xp):
     out = {}
     key = xp.where(mask, key, 0)  # masked rows: contribute zeros to group 0
     out["_rows"] = _seg_sum(mask.astype(np.int32), key, num_groups, xp)

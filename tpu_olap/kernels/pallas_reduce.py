@@ -71,6 +71,9 @@ from tpu_olap.ir.expr import BinOp, Col, Lit
 from tpu_olap.kernels.exprs import materialize_virtuals
 from tpu_olap.segments.segment import ColumnType, TIME_COLUMN
 
+# the kernel's name in the compiled program and in a device profile
+KERNEL_NAME = "onehot_group_reduce"
+
 N_PLANE_BITS = 8
 PLANE_MASK = (1 << N_PLANE_BITS) - 1
 MAX_VALUE = (1 << 31) - 1           # aggregate inputs must fit int32
@@ -746,14 +749,18 @@ def build_kernel(plan, table, config, filter_fn, interpret: bool,
     def const_spec(n):
         return pl.BlockSpec((1, n), lambda kb, i: (_z, _z))
 
-    def fn(env, valid, seg_mask, consts):
+    def pallas_agg(env, valid, seg_mask, consts):
         n_segments = valid.shape[0]
         n = n_segments * block_rows
         grid_rows = n // rb
         cset = set(col_names)
         null_names = sorted(c for c in env["nulls"]
                             if c != TIME_COLUMN and c in cset)
-        mask = (valid & seg_mask[:, None]).reshape(-1)
+        # the stages XLA runs before the kernel: `filter` is the interval
+        # mask, `key` the precomputed id streams; the per-row filter and
+        # the remaining key arithmetic run inside the kernel (`reduce`)
+        with jax.named_scope("filter"):
+            mask = (valid & seg_mask[:, None]).reshape(-1)
         pre_in = []
         if imask_fn is not None or n_pre:
             flat_env = {
@@ -761,20 +768,24 @@ def build_kernel(plan, table, config, filter_fn, interpret: bool,
                 "nulls": {c: a.reshape(-1)
                           for c, a in env["nulls"].items()}}
             if imask_fn is not None:
-                mask = mask & imask_fn(flat_env, consts)
-            if has_buckets:
-                b = flat_env["cols"].get(bucket_plan.derived_name) \
-                    if bucket_plan.cache_token else None
-                # cached uniform streams are TABLE-anchored; rebase to
-                # this plan's origin bucket (timebucket.ids_from_cached)
-                b = bucket_plan.ids(flat_env["cols"][TIME_COLUMN],
-                                    consts) if b is None else \
-                    bucket_plan.ids_from_cached(b, consts, jnp)
-                pre_in.append(b.astype(jnp.int32).reshape(1, n))
-            for dp, is_pre in zip(dim_plans, pre_dims):
-                if is_pre:
-                    ids = dp.ids(flat_env, consts, jnp)
-                    pre_in.append(ids.astype(jnp.int32).reshape(1, n))
+                with jax.named_scope("filter"):
+                    mask = mask & imask_fn(flat_env, consts)
+            with jax.named_scope("key"):
+                if has_buckets:
+                    b = flat_env["cols"].get(bucket_plan.derived_name) \
+                        if bucket_plan.cache_token else None
+                    # cached uniform streams are TABLE-anchored; rebase
+                    # to this plan's origin bucket
+                    # (timebucket.ids_from_cached)
+                    b = bucket_plan.ids(flat_env["cols"][TIME_COLUMN],
+                                        consts) if b is None else \
+                        bucket_plan.ids_from_cached(b, consts, jnp)
+                    pre_in.append(b.astype(jnp.int32).reshape(1, n))
+                for dp, is_pre in zip(dim_plans, pre_dims):
+                    if is_pre:
+                        ids = dp.ids(flat_env, consts, jnp)
+                        pre_in.append(
+                            ids.astype(jnp.int32).reshape(1, n))
         mask2 = mask.reshape(1, n)
         col_in = [_narrow(env["cols"][c].reshape(1, n), jnp)
                   for c in col_names]
@@ -794,90 +805,92 @@ def build_kernel(plan, table, config, filter_fn, interpret: bool,
                          pl.BlockSpec((KB, MM_pad), lambda kb, i: (kb, _z))]
             out_shape = [out_shape,
                          jax.ShapeDtypeStruct((K_pad, MM_pad), jnp.int32)]
-        out = pl.pallas_call(
-            make_kernel_fn(null_names),
-            grid=(n_kb, grid_rows),
-            in_specs=([row_spec() for _ in col_in]
-                      + [row_spec() for _ in pre_in]
-                      + [row_spec() for _ in null_in]
-                      + [row_spec()]
-                      + [const_spec(c.shape[1]) for c in const_in]),
-            out_specs=out_specs,
-            out_shape=out_shape,
-            interpret=interpret,
-        )(*col_in, *pre_in, *null_in, mask2, *const_in)
-        mm = None
-        if n_mm:
-            out, mm = out
-            mm = mm[:K]
-        if n_chunks > 1:
-            # exact: each chunk entry < 2^31, chunk totals < 2^53 (the
-            # MAX_ROWS eligibility bound); f64 keeps the consumer out of
-            # the fused int pipeline (see the recombination note below)
-            out = out.astype(jnp.float64).sum(axis=0)
-        else:
-            out = out[0]
-        if fact is not None:
-            # entry (k1, h*k2 + k2v) -> row k1*k2 + k2v == dense key,
-            # column h: plain XLA reshuffle outside the pallas_call
-            out = (out[:, :fact.k2 * H]
-                   .reshape(K_pad, H, fact.k2)
-                   .transpose(0, 2, 1)
-                   .reshape(K_pad * fact.k2, H))
-        out = out[:K]
-
-        res = {"_rows": out[:, layout.rows_slot].astype(jnp.int64)}
-        for p, (name, kind, start, n_planes, bias) in zip(agg_plans,
-                                                          layout.agg_slots):
-            if kind == "count":
-                res[name] = out[:, start].astype(p.acc_dtype)
-            elif kind in ("min", "max"):
-                v = mm[:, n_planes]  # n_planes doubles as the mm column
-                if kind == "max":
-                    v = -v
-                # empty groups carry the identity; finalize renders them
-                # NULL via the non-null count
-                res[name] = v.astype(p.acc_dtype)
-                res[f"_nn_{name}"] = out[:, start].astype(jnp.int32)
+        with jax.named_scope("reduce"):
+            out = pl.pallas_call(
+                make_kernel_fn(null_names),
+                grid=(n_kb, grid_rows),
+                in_specs=([row_spec() for _ in col_in]
+                          + [row_spec() for _ in pre_in]
+                          + [row_spec() for _ in null_in]
+                          + [row_spec()]
+                          + [const_spec(c.shape[1]) for c in const_in]),
+                out_specs=out_specs,
+                out_shape=out_shape,
+                interpret=interpret,
+                name=KERNEL_NAME,
+            )(*col_in, *pre_in, *null_in, mask2, *const_in)
+            mm = None
+            if n_mm:
+                out, mm = out
+                mm = mm[:K]
+            if n_chunks > 1:
+                # exact: each chunk entry < 2^31, chunk totals < 2^53 (the
+                # MAX_ROWS eligibility bound); f64 keeps the consumer out of
+                # the fused int pipeline (see the recombination note below)
+                out = out.astype(jnp.float64).sum(axis=0)
             else:
-                # Plane recombination rides f64, NOT int64 shifts: on the
-                # v5e sandbox, a jit-fused  custom_call -> convert(i64) ->
-                # shift/mul  chain miscompiles (the converted values read
-                # as ZERO for a deterministic subset of rows; eager or
-                # plain-array runs of the identical expression are
-                # correct, and multiplies instead of shifts change
-                # nothing). f64 math forces the consumer out of the fused
-                # int pipeline and is exact here: each half-sum is below
-                # 255*MAX_ROWS*(256+1) < 2^53 (the MAX_ROWS bound).
-                half = (n_planes + 1) // 2  # [0, half) and [half, n)
-                lo = jnp.zeros((K,), jnp.float64)
-                hi = jnp.zeros((K,), jnp.float64)
-                for j in range(n_planes):
-                    w = float(1 << (N_PLANE_BITS *
-                                    (j if j < half else j - half)))
-                    v = out[:, start + j].astype(jnp.float64) * w
-                    if j < half:
-                        lo = lo + v
-                    else:
-                        hi = hi + v
-                acc = lo.astype(jnp.int64) + (
-                    hi.astype(jnp.int64) << (N_PLANE_BITS * half))
-                if bias:
-                    # same split for the bias un-shift: bias*n can exceed
-                    # 2^53, so do it in 16-bit halves of |bias|. True sum
-                    # = plane sum + n_masked * bias (inputs were shifted
-                    # by -bias), so the adjustment adds for bias > 0 and
-                    # subtracts for bias < 0.
-                    n_masked = out[:, start + n_planes].astype(jnp.float64)
-                    b = abs(bias)
-                    b_lo, b_hi = b & 0xFFFF, b >> 16
-                    adj = (n_masked * float(b_lo)).astype(jnp.int64) + (
-                        (n_masked * float(b_hi)).astype(jnp.int64) << 16)
-                    acc = acc + adj if bias > 0 else acc - adj
-                res[name] = acc.astype(p.acc_dtype)
-        return res
+                out = out[0]
+            if fact is not None:
+                # entry (k1, h*k2 + k2v) -> row k1*k2 + k2v == dense key,
+                # column h: plain XLA reshuffle outside the pallas_call
+                out = (out[:, :fact.k2 * H]
+                       .reshape(K_pad, H, fact.k2)
+                       .transpose(0, 2, 1)
+                       .reshape(K_pad * fact.k2, H))
+            out = out[:K]
 
-    return fn
+            res = {"_rows": out[:, layout.rows_slot].astype(jnp.int64)}
+            for p, (name, kind, start, n_planes, bias) in zip(
+                    agg_plans, layout.agg_slots):
+                if kind == "count":
+                    res[name] = out[:, start].astype(p.acc_dtype)
+                elif kind in ("min", "max"):
+                    v = mm[:, n_planes]  # n_planes doubles as the mm column
+                    if kind == "max":
+                        v = -v
+                    # empty groups carry the identity; finalize renders them
+                    # NULL via the non-null count
+                    res[name] = v.astype(p.acc_dtype)
+                    res[f"_nn_{name}"] = out[:, start].astype(jnp.int32)
+                else:
+                    # Plane recombination rides f64, NOT int64 shifts: on the
+                    # v5e sandbox, a jit-fused  custom_call -> convert(i64) ->
+                    # shift/mul  chain miscompiles (the converted values read
+                    # as ZERO for a deterministic subset of rows; eager or
+                    # plain-array runs of the identical expression are
+                    # correct, and multiplies instead of shifts change
+                    # nothing). f64 math forces the consumer out of the fused
+                    # int pipeline and is exact here: each half-sum is below
+                    # 255*MAX_ROWS*(256+1) < 2^53 (the MAX_ROWS bound).
+                    half = (n_planes + 1) // 2  # [0, half) and [half, n)
+                    lo = jnp.zeros((K,), jnp.float64)
+                    hi = jnp.zeros((K,), jnp.float64)
+                    for j in range(n_planes):
+                        w = float(1 << (N_PLANE_BITS *
+                                        (j if j < half else j - half)))
+                        v = out[:, start + j].astype(jnp.float64) * w
+                        if j < half:
+                            lo = lo + v
+                        else:
+                            hi = hi + v
+                    acc = lo.astype(jnp.int64) + (
+                        hi.astype(jnp.int64) << (N_PLANE_BITS * half))
+                    if bias:
+                        # same split for the bias un-shift: bias*n can exceed
+                        # 2^53, so do it in 16-bit halves of |bias|. True sum
+                        # = plane sum + n_masked * bias (inputs were shifted
+                        # by -bias), so the adjustment adds for bias > 0 and
+                        # subtracts for bias < 0.
+                        n_masked = out[:, start + n_planes].astype(jnp.float64)
+                        b = abs(bias)
+                        b_lo, b_hi = b & 0xFFFF, b >> 16
+                        adj = (n_masked * float(b_lo)).astype(jnp.int64) + (
+                            (n_masked * float(b_hi)).astype(jnp.int64) << 16)
+                        acc = acc + adj if bias > 0 else acc - adj
+                    res[name] = acc.astype(p.acc_dtype)
+            return res
+
+    return pallas_agg
 
 
 def _split_refs(refs, n_cols, n_pre, n_nulls, n_consts, n_outs=1):

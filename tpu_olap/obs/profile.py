@@ -11,14 +11,20 @@ Two complementary views of where a query's time goes:
    lay out side by side on one timeline. Served by `GET /debug/profile`
    and banked by `bench.py --trace-out`.
 
-2. **XLA op-level capture** (`capture_device_profile`): an on-demand
-   `jax.profiler` trace window (`POST /debug/profile?ms=N`). While a
+2. **XLA op-level capture** (`start_capture`, and around it
+   `capture_device_profile`): an on-demand `jax.profiler` trace window
+   (`POST /debug/profile?ms=N`), started with the profiler's Python
+   tracer OFF — that tracer records every Python call of the serving
+   threads (over a million host events in five seconds) and makes the
+   host path it is meant to observe several times slower. While a
    capture is live, QueryRunner._dispatch wraps each device call in
    `jax.profiler.TraceAnnotation(query_id)` so the XLA ops in the
-   profile nest under the query that dispatched them. The annotation
-   costs one module-flag probe when no capture is active, and the whole
-   feature degrades gracefully (a structured "unavailable" result, not
-   an exception) where `jax.profiler` cannot run.
+   profile nest under the query that dispatched them, and every span
+   of obs.trace enters an annotation of its own name (`annotate_span`),
+   so the profile shows the host stages beside the ops they starve.
+   Both cost one module-flag probe when no capture is active, and the
+   whole feature degrades gracefully (a structured "unavailable"
+   result, not an exception) where `jax.profiler` cannot run.
 
 No new dependencies: the Chrome trace format is plain JSON, and the
 jax.profiler import is deferred + guarded.
@@ -123,47 +129,83 @@ def annotate_dispatch(query_id: str | None):
         return _NULL_CM
 
 
+def annotate_span(name: str, query_id: str | None):
+    """The annotation a span of obs.trace enters while a capture is
+    live: the span's name, with the query id as its argument — never a
+    query id as the NAME, which is what marks a device call."""
+    try:
+        import jax
+        if query_id is None:
+            return jax.profiler.TraceAnnotation(name)
+        return jax.profiler.TraceAnnotation(name, query_id=query_id)
+    except Exception:  # noqa: BLE001 — annotation is best-effort
+        return _NULL_CM
+
+
+def start_capture(trace_dir: str):
+    """Start a jax.profiler capture into `trace_dir` and return the
+    function that stops it — the one way this process starts the
+    profiler. The Python tracer is off (see the module docstring); the
+    capture holds `_capture_lock` from start to stop (jax.profiler runs
+    one trace at a time) and `_capture_active` is up in between. Raises
+    RuntimeError while another capture is live, and whatever
+    jax.profiler raises where it cannot start."""
+    global _capture_active
+    import jax
+    if not _capture_lock.acquire(blocking=False):
+        raise RuntimeError("capture already in progress")
+    try:
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
+    except BaseException:
+        _capture_lock.release()
+        raise
+    _capture_active = True
+
+    def stop():
+        global _capture_active
+        _capture_active = False
+        try:
+            jax.profiler.stop_trace()
+        finally:
+            _capture_lock.release()
+
+    return stop
+
+
 def capture_device_profile(ms: float, trace_dir: str | None = None) -> dict:
-    """Run a jax.profiler capture for `ms` milliseconds and return a
-    structured result:
+    """Run a capture for `ms` milliseconds (`start_capture`, a sleep,
+    stop) and return a structured result:
 
         {"ok": true, "trace_dir": ..., "ms": N}            on success
         {"ok": false, "reason": ...}                       degraded
 
     The capture is synchronous (the caller's thread sleeps out the
-    window) but the engine keeps serving — dispatches that land inside
-    the window are annotated with their query_id (annotate_dispatch).
-    Exactly one capture runs at a time; a second request while one is
-    live degrades with "capture already in progress" instead of
-    corrupting the profiler's global state."""
-    global _capture_active
+    window) but the engine keeps serving — dispatches and spans that
+    land inside the window are annotated. Exactly one capture runs at a
+    time; a second request while one is live degrades with "capture
+    already in progress" instead of corrupting the profiler's global
+    state."""
     ms = max(1.0, min(float(ms), float(CAPTURE_MS_MAX)))
-    try:
-        import jax
-        profiler = jax.profiler
-    except Exception as e:  # noqa: BLE001 — jax absent/broken: degrade
-        return {"ok": False, "reason": f"jax.profiler unavailable: {e}"}
-    if not _capture_lock.acquire(blocking=False):
+    if _capture_lock.locked():  # before a directory is made for nothing
         return {"ok": False, "reason": "capture already in progress"}
+    if trace_dir is None:
+        import tempfile
+        trace_dir = tempfile.mkdtemp(prefix="tpu_olap_profile_")
     try:
-        if trace_dir is None:
-            import tempfile
-            trace_dir = tempfile.mkdtemp(prefix="tpu_olap_profile_")
-        try:
-            profiler.start_trace(trace_dir)
-        except Exception as e:  # noqa: BLE001 — backend refused: degrade
-            return {"ok": False,
-                    "reason": f"jax.profiler.start_trace failed: {e}"}
-        _capture_active = True
-        try:
-            time.sleep(ms / 1000.0)
-        finally:
-            _capture_active = False
-            try:
-                profiler.stop_trace()
-            except Exception as e:  # noqa: BLE001 — partial capture
-                return {"ok": False, "trace_dir": trace_dir,
-                        "reason": f"jax.profiler.stop_trace failed: {e}"}
-        return {"ok": True, "trace_dir": trace_dir, "ms": ms}
+        stop = start_capture(trace_dir)
+    except RuntimeError as e:  # a capture is live
+        return {"ok": False, "reason": str(e)}
+    except Exception as e:  # noqa: BLE001 — jax absent / backend refused
+        return {"ok": False,
+                "reason": f"jax.profiler.start_trace failed: {e}"}
+    try:
+        time.sleep(ms / 1000.0)
     finally:
-        _capture_lock.release()
+        try:
+            stop()
+        except Exception as e:  # noqa: BLE001 — partial capture
+            return {"ok": False, "trace_dir": trace_dir,
+                    "reason": f"jax.profiler.stop_trace failed: {e}"}
+    return {"ok": True, "trace_dir": trace_dir, "ms": ms}

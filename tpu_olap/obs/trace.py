@@ -11,16 +11,32 @@ child spans through the context-manager API:
 
 Propagation is via `contextvars`, so nested layers (engine → runner →
 kernels) need no plumbing: `span(name)` attaches to whatever span is
-current, and returns the no-op `NULL_SPAN` when no trace is active —
-tracing costs two perf_counter() calls per stage when on, one dict probe
-when off. Cross-thread dispatch (the deadline watchdog runs the device
-call on a fresh thread, executor.runner._join_abandoning) propagates by
-running the work inside a `contextvars.copy_context()` snapshot.
+current, and returns the no-op `NULL_SPAN` when no trace is active.
+A span costs, when tracing is on: one object, a locked append to its
+parent's children, two perf_counter() calls, a contextvar set/reset
+and the capture-flag probe below (docs/OBSERVABILITY.md has the
+measured figure); one contextvar probe when off. Cross-thread dispatch
+(the deadline watchdog runs the device call on a fresh thread,
+executor.runner._join_abandoning) propagates by running the work inside
+a `contextvars.copy_context()` snapshot.
 
-Clocks are monotonic (`time.perf_counter`); wall timestamps are recorded
-once per trace root for display only. Completed traces land in the
-tracer's bounded recent-ring, and traces slower than `slow_ms` also land
-in the slow-query ring — both served by `GET /debug/queries`.
+Clocks are monotonic (`time.perf_counter`). A root also exports `t0_ns`,
+its `perf_counter_ns()` at entry: `t0_ns` + a span's `start_ms` places
+spans of DIFFERENT requests on one process-wide axis at microsecond
+grain (`started_at` is wall time, for display only). While an on-demand
+device capture is live (obs.profile.capture_active) every span also
+enters a `jax.profiler.TraceAnnotation` of its own name, so the span
+tree shows in the captured profile beside the XLA ops — and that axis
+and the profiler's differ by one offset per process. Completed traces
+land in the tracer's bounded recent-ring, and traces slower than
+`slow_ms` also land in the slow-query ring — both served by
+`GET /debug/queries`.
+
+The root of a served request belongs to the HTTP edge: it opens the
+trace before it reads the body (`Tracer.trace(name, adoptable=True)`)
+and closes it after the last byte is written; the engine entry point it
+calls takes that root for its own (`adopt_root`) instead of opening a
+second one.
 """
 
 from __future__ import annotations
@@ -30,6 +46,8 @@ import itertools
 import os
 import threading
 import time
+
+from tpu_olap.obs import profile as _profile
 
 _current_span: contextvars.ContextVar = contextvars.ContextVar(
     "tpu_olap_current_span", default=None)
@@ -83,7 +101,7 @@ class Span:
     with the owning trace's lock."""
 
     __slots__ = ("name", "attrs", "children", "t0", "start_ms",
-                 "duration_ms", "_token", "_trace")
+                 "duration_ms", "_token", "_trace", "_annotation")
 
     def __init__(self, name: str, trace: "Trace | None" = None):
         self.name = name
@@ -94,6 +112,7 @@ class Span:
         self.duration_ms: float | None = None
         self._token = None
         self._trace = trace
+        self._annotation = None
 
     # ------------------------------------------------------------- build
 
@@ -117,6 +136,12 @@ class Span:
     # --------------------------------------------------------- lifecycle
 
     def __enter__(self) -> "Span":
+        if _profile._capture_active:
+            # a device capture is live: the span shows in the captured
+            # profile under its own name, beside the XLA ops
+            self._annotation = _profile.annotate_span(
+                self.name, _current_qid.get())
+            self._annotation.__enter__()
         self.t0 = time.perf_counter()
         # start position on the trace timeline: offset from the root's
         # monotonic t0 (perf_counter is one clock across threads, so
@@ -131,6 +156,9 @@ class Span:
 
     def __exit__(self, exc_type, exc, tb):
         self.duration_ms = (time.perf_counter() - self.t0) * 1000
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         if exc is not None:
             self.set(error=exc)
         if self._token is not None:
@@ -202,6 +230,19 @@ def span(name: str, **attrs):
     if cur is None:
         return NULL_SPAN
     return cur.span(name, **attrs)
+
+
+def adopt_root(name: str) -> "Trace | None":
+    """The root the HTTP edge opened for the entry point that asks, once:
+    the current span when it is an adoptable root of that name, else
+    None (called directly — Engine.sql, EXPLAIN ANALYZE's inner
+    statement — the entry point roots its own trace). The edge, not the
+    adopter, closes it."""
+    cur = _current_span.get()
+    if isinstance(cur, Trace) and cur._adoptable and cur.name == name:
+        cur._adoptable = False
+        return cur
+    return None
 
 
 class nested_execution:
@@ -334,32 +375,49 @@ class Trace(Span):
     parent pointer walk) and hands itself to the tracer's rings on
     exit."""
 
-    __slots__ = ("query_id", "started_at", "_qid_token", "_lock",
-                 "_tracer")
+    __slots__ = ("query_id", "started_at", "t0_ns", "_qid_token", "_lock",
+                 "_tracer", "_adoptable")
 
-    def __init__(self, name: str, query_id: str, tracer: "Tracer"):
+    def __init__(self, name: str, query_id: str, tracer: "Tracer",
+                 adoptable: bool = False):
         super().__init__(name, trace=None)
         self._trace = self  # children funnel through this trace's lock
         self._lock = threading.Lock()
         self.query_id = query_id
         self.started_at = time.time()  # display only; durations are mono
+        self.t0_ns: int | None = None
         self._qid_token = None
         self._tracer = tracer
+        self._adoptable = adoptable
 
     def __enter__(self) -> "Trace":
         self._qid_token = _current_qid.set(self.query_id)
-        return super().__enter__()
+        super().__enter__()
+        # the root's place on the process-wide axis that the spans of
+        # other requests share; t0 is the same instant, so t0_ns plus a
+        # child's start_ms is that child's place on it
+        self.t0_ns = time.perf_counter_ns()
+        self.t0 = self.t0_ns / 1e9
+        return self
 
     def __exit__(self, exc_type, exc, tb):
         super().__exit__(exc_type, exc, tb)
         _current_qid.reset(self._qid_token)
-        self._tracer._finished(self)
+        if self._tracer is not None:
+            self._tracer._finished(self)
         return False
+
+    def discard(self):
+        """Keep this trace out of the tracer's rings: the edge opened it
+        for a statement that leaves no trace (a statement verb, sys.*
+        introspection)."""
+        self._tracer = None
 
     def to_json(self) -> dict:
         out = super().to_json()
         out["query_id"] = self.query_id
         out["started_at"] = round(self.started_at, 3)
+        out["t0_ns"] = self.t0_ns
         # flat per-stage summary of the graph's `stage:<name>` spans
         # (executor/stages.py), so GET /debug/queries readers get the
         # stage walk without re-walking the span tree
@@ -400,11 +458,14 @@ class Tracer:
     def new_query_id(self) -> str:
         return f"q{self._stamp}-{next(self._seq):06d}"
 
-    def trace(self, name: str, query_id: str | None = None, **attrs):
-        """Start a root span (context manager). Disabled -> NULL_SPAN."""
+    def trace(self, name: str, query_id: str | None = None,
+              adoptable: bool = False, **attrs):
+        """Start a root span (context manager). Disabled -> NULL_SPAN.
+        `adoptable`: the opener is the HTTP edge, and the engine entry
+        point it is about to call takes this root (`adopt_root`)."""
         if not self.enabled:
             return NULL_SPAN
-        t = Trace(name, query_id or self.new_query_id(), self)
+        t = Trace(name, query_id or self.new_query_id(), self, adoptable)
         if attrs:
             t.set(**attrs)
         return t
