@@ -214,8 +214,9 @@ def test_pallas_time_in_kernel_ineligible():
 
 def test_pallas_multichip_parity():
     """Pallas plans under the 8-device virtual mesh: the mesh dispatch
-    uses the generic key_fn path (a Pallas kernel is a single-chip
-    program), so forced-Pallas configs stay parity-exact sharded."""
+    maps the single-chip kernel over the chips (sharding.
+    mesh_agg_kernel), so the Pallas kernel itself runs per chip — in
+    interpret mode here — and stays parity-exact sharded."""
     plain = Engine(EngineConfig(use_pallas="never"))
     forced = Engine(EngineConfig(use_pallas="force", num_shards=8))
     df = _table()

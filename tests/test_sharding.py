@@ -15,7 +15,7 @@ pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs 8 virtual devices")
 
 
-def build(num_shards=None):
+def build(num_shards=None, **cfg):
     rng = np.random.default_rng(3)
     n = 20_000
     t0 = tu.date_to_millis(1993, 1, 1)
@@ -28,7 +28,7 @@ def build(num_shards=None):
         "price": np.round(rng.uniform(0, 100, n), 2),
         "uid": rng.integers(0, 3000, n).astype(np.int64),
     })
-    eng = Engine(EngineConfig(num_shards=num_shards))
+    eng = Engine(EngineConfig(num_shards=num_shards, **cfg))
     eng.register_table("f", df, time_column="ts", block_rows=1 << 11)
     return eng, df
 
@@ -228,3 +228,131 @@ def test_compaction_keeps_untouched_cache_shards():
     pd.testing.assert_frame_equal(warm, again)
     assert m["cache_hit"], m.get("segment_cache")
     assert m["segments_cached"] > 0 and m["segments_computed"] == 0, m
+
+
+# ---------------------------------------------------------------------------
+# shard_map of the single-chip kernel (ISSUE 26): "historicals" is
+# plan.kernel on each chip's own rows — no collective, [K] partials a chip
+
+
+def _theta_query():
+    from tpu_olap.ir import (DefaultDimensionSpec, GroupByQuerySpec,
+                             ThetaSketchAggregation)
+    return GroupByQuerySpec(
+        data_source="f", dimensions=(DefaultDimensionSpec("region"),),
+        aggregations=(ThetaSketchAggregation("u", "uid", 1 << 10),))
+
+
+SUM_SQL = """SELECT brand, sum(qty) AS s, count(*) AS n FROM f
+             WHERE region = 'ASIA' GROUP BY brand ORDER BY brand"""
+MINMAX_SQL = """SELECT region, min(qty) AS mn, max(qty) AS mx, sum(qty) AS s
+                FROM f GROUP BY region ORDER BY region"""
+HLL_SQL = """SELECT region, count(DISTINCT uid) AS u FROM f
+             GROUP BY region ORDER BY region"""
+# one month of the month-partitioned table: fewer segments than chips,
+# so some chips' windows hold no row of any group
+ONE_MONTH_SQL = ("SELECT g, sum(v) AS s, min(v) AS mn, count(*) AS n "
+                 "FROM m WHERE ts >= '1993-03-01' AND ts < '1993-04-01' "
+                 "GROUP BY g ORDER BY g")
+
+# (id, engine builder, query, use_pallas, record's `pallas`, windowed)
+PER_CHIP_CASES = [
+    ("ungrouped", build, QUERIES[0], "never", False, False),
+    ("sum-count", build, SUM_SQL, "never", False, False),
+    ("sum-count-pallas", build, SUM_SQL, "force", True, False),
+    ("minmax", build, MINMAX_SQL, "never", False, False),
+    ("minmax-pallas", build, MINMAX_SQL, "force", True, False),
+    ("hll", build, HLL_SQL, "never", False, False),
+    ("hll-pallas-ineligible", build, HLL_SQL, "force", False, False),
+    ("theta", build, _theta_query, "never", False, False),
+    ("windowed", _month_build, WINDOW_SQL, "never", False, True),
+    ("windowed-pallas", _month_build, WINDOW_SQL, "force", True, True),
+    ("one-month", _month_build, ONE_MONTH_SQL, "never", False, True),
+    ("one-month-pallas", _month_build, ONE_MONTH_SQL, "force", True, True),
+]
+COLLECTIVES = ("all-gather", "all-reduce", "all-to-all",
+               "collective-permute", "reduce-scatter")
+
+
+def _answer_sha(eng, query):
+    import hashlib
+    if isinstance(query, str):
+        body = eng.sql(query).to_csv(index=False)
+    else:
+        body = repr(eng.execute_ir(query()).rows)
+    return hashlib.sha256(body.encode()).hexdigest()
+
+
+@pytest.mark.parametrize("case,builder,query,use_pallas,want_pallas,windowed",
+                         PER_CHIP_CASES, ids=[c[0] for c in PER_CHIP_CASES])
+def test_per_chip_program(monkeypatch, case, builder, query, use_pallas,
+                          want_pallas, windowed):
+    """The "historicals" mesh program is the single-chip kernel mapped
+    over the chips: answers sha256-equal to one chip's, no collective
+    in the compiled program, a [K] partial table from every chip (the
+    identity where a chip's segments hold no row of a group), and a
+    record that says what ran."""
+    from tpu_olap.executor import sharding as sh
+    from tpu_olap.kernels.groupby import merge_partials
+
+    D = 8
+    calls = []
+    real = sh.mesh_agg_kernel
+
+    def spy(plan, mesh, per_chip, strategy, win=None):
+        jitted = real(plan, mesh, per_chip, strategy, win)
+
+        def run(*args):
+            calls.append((plan, strategy, win, jitted, args))
+            return jitted(*args)
+        return run
+
+    monkeypatch.setattr(sh, "mesh_agg_kernel", spy)
+    e1, _ = builder(use_pallas="never")
+    eD, _ = builder(num_shards=D, use_pallas=use_pallas)
+    assert _answer_sha(e1, query) == _answer_sha(eD, query)
+
+    rec = eD.runner.history[-1]
+    assert rec["num_shards"] == D
+    assert rec["mesh_program"] == "per_chip" and rec["merge"] == "broker"
+    assert bool(rec.get("pallas")) == want_pallas
+    assert rec["path"] == ("pallas" if want_pallas else "dense")
+    assert bool(rec.get("segments_window_per_chip")) == windowed
+
+    (plan, strategy, win, jitted, args), = calls
+    assert strategy == "historicals" and (win is not None) == windowed
+    assert (plan.pallas_reason is None) == want_pallas
+    text = jitted.lower(*args).compile().as_text()
+    assert not [c for c in COLLECTIVES if c in text]
+
+    K = plan.total_groups
+    out = jitted(*args)
+    for name, v in out.items():
+        assert v.shape[0] == D * K, (name, v.shape)
+        assert {s.data.shape[0] for s in v.addressable_shards} == {K}
+    fetched = jax.device_get(out)
+    merged = sh.broker_merge(fetched, plan.agg_plans, D)
+    assert all(v.shape[0] == K for v in merged.values())
+    parts = [{n: v.reshape((D, K) + v.shape[1:])[d]
+              for n, v in fetched.items()} for d in range(D)]
+    empty = [p for p in parts if not p["_rows"].any()]
+    full = [p for p in parts if p["_rows"].any()]
+    assert full
+    if case.startswith("one-month"):
+        assert empty
+    for p in empty:  # an empty chip's table is the merge's identity
+        got = merge_partials(p, full[0], plan.agg_plans)
+        for name, v in full[0].items():
+            np.testing.assert_array_equal(np.asarray(got[name]), v)
+
+
+def test_gspmd_record_says_what_ran():
+    """force_strategy="broker" keeps the GSPMD spelling: the generic
+    key_fn over global shapes, so the record carries mesh_program
+    "gspmd" and no `pallas`, whatever the plan was eligible for."""
+    e1, _ = build(use_pallas="never")
+    e8, _ = build(num_shards=8, use_pallas="force", force_strategy="broker")
+    assert _answer_sha(e1, SUM_SQL) == _answer_sha(e8, SUM_SQL)
+    rec = e8.runner.history[-1]
+    assert rec["mesh_program"] == "gspmd" and rec["merge"] == "gspmd"
+    assert not rec.get("pallas") and rec["path"] == "dense"

@@ -171,21 +171,41 @@ def test_dispatch_program_compiles_for_v5e(topo, no_persistent_cache,
         assert int(re.search(r"= s32\[(\d+),\d+,\d+\]", call).group(1)) > 1
 
 
-def test_sharded_program_compiles_for_four_chips(topo, no_persistent_cache,
-                                                 ssb_tables, monkeypatch):
+# (id, sql, per-chip window, expect the Pallas kernel, expect a scatter)
+MESH_CASES = [
+    ("q2.1", QUERIES["q2.1"], False, True, False),
+    ("q2.1-windowed", QUERIES["q2.1"], True, True, False),
+    ("q1.1-windowed-generic", QUERIES["q1.1"], True, False, False),
+    ("hll-generic", HLL_SQL, False, False, True),
+]
+COLLECTIVES = ("all-gather", "all-reduce", "all-to-all",
+               "collective-permute", "reduce-scatter")
+
+
+@pytest.mark.parametrize("case,sql,windowed,want_pallas,want_scatter",
+                         MESH_CASES, ids=[c[0] for c in MESH_CASES])
+def test_sharded_program_compiles_for_four_chips(
+        topo, no_persistent_cache, ssb_tables, monkeypatch, case, sql,
+        windowed, want_pallas, want_scatter):
     """The dense mesh program (sharding.mesh_agg_kernel, "historicals")
-    on a four-device Mesh built from the described topology."""
+    on a four-device Mesh built from the described topology: the
+    single-chip plan.kernel mapped over the chips — the Mosaic call when
+    the plan is eligible, XLA's scatter otherwise — on each chip's OWN
+    rows, with no collective anywhere in the program."""
     import jax
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from tpu_olap.executor import sharding as sh
     _as_tpu(monkeypatch)
     eng = _engine(ssb_tables)
-    phys = _physical(eng, QUERIES["q2.1"])
-    assert phys.pallas_reason is None, phys.pallas_reason
+    phys = _physical(eng, sql)
+    assert (phys.pallas_reason is None) == want_pallas, phys.pallas_reason
     mesh = Mesh(np.asarray(topo.devices[:4]), (sh.AXIS,))
     env, valid, seg_mask = eng.runner._prepare(phys, {})
-    n_seg = sh.pad_segments(len(seg_mask), 4)
+    # shapes only: the segment axis is scaled to eight blocks a chip
+    per_chip, block_rows = 8, phys.table.block_rows
+    n_seg = 4 * per_chip
+    win = (0, per_chip // 2) if windowed else None
 
     def struct(x, spec):
         shape = tuple(x.shape)
@@ -198,11 +218,24 @@ def test_sharded_program_compiles_for_four_chips(topo, no_persistent_cache,
     args = (jax.tree_util.tree_map(lambda x: struct(x, seg), env),
             struct(valid, seg), struct(seg_mask, seg),
             {k: struct(v, P()) for k, v in phys.pool.consts.items()})
-    fn = sh.mesh_agg_kernel(phys, mesh, n_seg // 4, "historicals")
+    if windowed:
+        args += (jax.ShapeDtypeStruct((), np.int32,
+                                      sharding=NamedSharding(mesh, P())),)
+    fn = sh.mesh_agg_kernel(phys, mesh, per_chip, "historicals", win)
     text = fn.lower(*args).compile().as_text()
-    # The plan is Pallas-eligible, but the mesh program runs the plan's
-    # generic key_fn + group_reduce on every chip (mesh_agg_kernel's
-    # docstring): the record's `pallas` flag is plan-level under a mesh.
-    # Pinned here so a later per-chip Pallas mesh path updates it.
-    assert "tpu_custom_call" not in text
-    assert "scatter" in text
+    assert ("tpu_custom_call" in text) == want_pallas
+    assert ("scatter" in text) == want_scatter
+    assert not [c for c in COLLECTIVES if c in text]
+    # every chip's program holds its own rows only: the resident blocks
+    # come in as [S/D, block_rows], nothing has the global row count,
+    # and the kernel's row operands are the chip's (windowed) rows
+    shapes = {tuple(int(d) for d in m.split(","))
+              for m in re.findall(r"\w\d+\[([\d,]+)\]", text)}
+    assert (per_chip, block_rows) in shapes
+    assert not [s for s in shapes if math.prod(s) == n_seg * block_rows]
+    if want_pallas:
+        call = next(ln for ln in text.splitlines()
+                    if 'custom_call_target="tpu_custom_call"' in ln)
+        assert "onehot_group_reduce" in call
+        rows = (win[1] if windowed else per_chip) * block_rows
+        assert f"[1,{rows}]" in call

@@ -423,7 +423,7 @@ def _lower_agg(query, table, config) -> PhysicalPlan:
     # sketch aggregates keep [groups × radix] state PER AGGREGATION: at
     # large K their TOTAL dominates memory long before the group COUNT
     # exceeds the dense budget (observed: a 1M-group theta query
-    # allocating >100 GB). The mesh's chip-extended partials ([D·K, k]
+    # allocating >100 GB). The mesh's per-chip partials ([D·K, k]
     # theta tables, executor/sharding.py::mesh_agg_kernel) multiply
     # that state by the mesh size — a fuzz-found sharded theta query
     # ground a host to 100 GB and an XLA rendezvous abort with

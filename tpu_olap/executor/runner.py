@@ -1810,12 +1810,13 @@ class QueryRunner:
 
     def _run_partials_mesh(self, plan: PhysicalPlan,
                            metrics: dict) -> dict:
-        """Sharded dispatch on `jax.jit` + `NamedSharding` (executor.
-        sharding; docs/TPU_NOTES.md "sharded serving"): columns sit
-        placed per chip (interleaved segment→chip assignment), the
-        per-chip LOCAL window slices each chip's pruned working set,
-        and the merge strategy follows planner.cost — "historicals"
-        brings per-chip unfinalized partials back sharded and merges
+        """Sharded dispatch (executor.sharding; docs/TPU_NOTES.md
+        "sharded serving"): columns sit placed per chip (interleaved
+        segment→chip assignment), the per-chip LOCAL window slices each
+        chip's pruned working set, and the merge strategy follows
+        planner.cost — "historicals" runs the single-chip `plan.kernel`
+        on every chip's own rows (`jax.shard_map`, no collective),
+        brings the per-chip unfinalized partials back sharded and merges
         them at the host broker with the segment-cache algebra;
         "broker" hands the whole program to GSPMD (replicated outputs,
         compiler-inserted psum/all-gather). Mask-kind plans (scan/
@@ -1838,11 +1839,6 @@ class QueryRunner:
                     with _span("cost-decision") as sp:
                         decision = cost_mod.decide(plan, self.config, D)
                         strategy = decision.strategy
-                        # chip-extended keys must fit int32; a dense
-                        # table that large defers to the partitioner
-                        if strategy == "historicals" and \
-                                D * plan.total_groups >= (1 << 31):
-                            strategy = "broker"
                         # DCN mesh: remote chips' shards are not host-
                         # addressable, so the broker merge cannot see
                         # them — GSPMD's replicated merge is the only
@@ -1892,8 +1888,13 @@ class QueryRunner:
             with _span("broker-merge", num_shards=D):
                 out = sh.broker_merge(out, plan.agg_plans, D)
             metrics["merge"] = "broker"
+            metrics["mesh_program"] = "per_chip"
         elif is_agg:
             metrics["merge"] = "gspmd"
+            metrics["mesh_program"] = "gspmd"
+            # the GSPMD spelling runs the generic key_fn, never the
+            # Mosaic call the plan was eligible for
+            metrics.pop("pallas", None)
         if plan.kind == "mask":
             # placed -> logical segment order: the scan/select/search
             # assemblers index rows by GLOBAL logical segment id

@@ -1,36 +1,40 @@
-"""Multi-chip execution on `jax.jit` + `NamedSharding` (no shard_map).
+"""Multi-chip execution: one chip's program mapped over the mesh.
 
 The TPU-native replacement for the reference's direct-historical fan-out
-(SURVEY.md §3.5 P2), rebuilt on the modern JAX API: columns are placed
-ONCE with `jax.device_put(x, NamedSharding(mesh, P(AXIS)))` over an
-INTERLEAVED segment→chip assignment (segment i → chip i mod D, the way a
-Druid coordinator balances an interval's segments across historicals),
-and group-reduce kernels compile with `jax.jit(..., out_shardings=...)`
-so XLA's GSPMD partitioner inserts the cross-chip collectives the old
-`jax.shard_map` code spelled by hand.
+(SURVEY.md §3.5 P2): columns are placed ONCE with
+`jax.device_put(x, NamedSharding(mesh, P(AXIS)))` over an INTERLEAVED
+segment→chip assignment (segment i → chip i mod D, the way a Druid
+coordinator balances an interval's segments across historicals), and the
+dense aggregate runs as the SINGLE-CHIP kernel under `jax.shard_map`:
+every chip reduces its own rows with the program one chip would run
+(the Pallas one-hot reduce included), and no collective is compiled in.
 
 Two dense merge strategies (planner.cost picks per query, same decision
 shape as the reference's broker-vs-direct-historicals choice):
 
-- "historicals": the group key is EXTENDED by the owning chip id, the
-  [D·K] partial table comes back sharded per chip (each chip's K-block
-  lives in its own HBM — zero cross-chip traffic in the reduce), and a
-  host-side **broker** step merges the D unfinalized partial tables
-  with the exact algebra the segment cache and cube folds already share
-  (kernels.groupby.merge_partials / partials_radix). One device fetch
-  pulls every chip's shard concurrently, so stage-2 transfers overlap
-  across chips.
-- "broker": the WHOLE program is handed to GSPMD — plain group keys,
-  replicated outputs, compiler-inserted psum/all-gather (the fan-out/
-  merge is opaque, like Druid's broker).
+- "historicals": `shard_map(plan.kernel)` — each chip returns its
+  unfinalized [K, ...] partial table from plain [0, K) keys, the tables
+  come back laid end to end as [D·K, ...] (each chip's K-block lives in
+  its own HBM — zero cross-chip traffic), and a host-side **broker**
+  step merges the D tables with the exact algebra the segment cache and
+  cube folds already share (kernels.groupby.merge_partials /
+  partials_radix). One device fetch pulls every chip's shard
+  concurrently, so stage-2 transfers overlap across chips.
+- "broker": the WHOLE program in global shapes is handed to GSPMD under
+  `jax.jit(..., out_shardings=...)` — plain group keys, replicated
+  outputs, compiler-inserted psum/all-gather (the fan-out/merge is
+  opaque, like Druid's broker). The only correct spelling on a
+  multi-host mesh, whose remote shards the host broker cannot see.
 
 Interleaved placement is what makes windowed dispatch prune PER-CHIP
 working sets (docs/TPU_NOTES.md): a contiguous time range of logical
 segments [lo, hi) lands on every chip as the LOCAL range
-[lo//D, ceil(hi/D)), so the kernel reshapes [S, R] → [D, S/D, R]
-(sharded on the chip axis) and dynamic-slices the local axis — each chip
-reads only its ~(hi-lo)/D pruned segments, with no cross-chip data
-movement and ONE compiled program per (template, local width).
+[lo//D, ceil(hi/D)), so inside the map each chip dynamic-slices axis 0
+of its own [S/D, R] blocks exactly as one chip slices its window — each
+chip reads only its ~(hi-lo)/D pruned segments, with no cross-chip data
+movement and ONE compiled program per (template, local width). The
+GSPMD spellings (the "broker" strategy, the per-segment cache partials)
+reshape [S, R] → [D, S/D, R] and slice the local axis instead.
 
 High-cardinality sparse group-by fans out as true per-chip programs:
 each chip's resident shard (an addressable single-device array — no
@@ -207,12 +211,11 @@ def _window_env(env, valid, seg_mask, D, per_chip, lo, W):
 
 def chip_extended_key(key, mask, D: int, blocks: int, K: int):
     """Group key extended by the owning chip (placement order: row
-    block b belongs to chip b // blocks), so the [D·K] partial table
-    shards per chip with zero cross-chip reduce traffic. THE one
-    definition shared by the single-query mesh kernel and the fused
-    batch legs — the key layout must never drift between them (a
-    drift would silently de-synchronize fused-batch results from
-    single-query mesh results)."""
+    block b belongs to chip b // blocks), so a GSPMD-partitioned
+    [D·K] partial table shards per chip: the fused batch legs'
+    (executor/batch.py) spelling of the layout broker_merge folds.
+    The single-query kernel (mesh_agg_kernel) gets the same layout
+    from shard_map's out_specs with plain keys."""
     import jax.numpy as jnp
 
     r = mask.shape[0] // (D * blocks)
@@ -225,39 +228,49 @@ def mesh_agg_kernel(plan, mesh: Mesh, per_chip: int, strategy: str,
                     win=None):
     """Jitted dense-aggregation program over the mesh.
 
-    strategy "historicals": chip-extended group keys -> [D·K] partials,
-    out_shardings=P(chips) so each chip's K-block stays in its own HBM
-    (the host broker merges). strategy "broker": plain keys ->
-    replicated [K] outputs, GSPMD inserts the cross-chip psum/
-    all-gather merges. Both run the plan's GENERIC key_fn front half
-    (the Pallas kernel is a single-chip program; under a mesh the
-    shared jnp path serves every chip identically).
+    strategy "historicals": `plan.kernel` ITSELF — the function one
+    chip jits: the Pallas one-hot reduce when the plan is eligible, the
+    generic key + group_reduce otherwise — `jax.shard_map`ped over the
+    chip axis. Each chip sees its own [S/D, block_rows] blocks (with a
+    per-chip window, the single-chip dynamic slice of axis 0), keys are
+    the plain [0, K) keys, and out_specs=P(chips) lays the D unfinalized
+    [K, ...] partial tables end to end as [D·K, ...], a chip each — the
+    layout broker_merge folds. No collective is in the program.
+
+    strategy "broker": plain keys over the GLOBAL shapes handed to
+    GSPMD -> replicated [K] outputs, compiler-inserted cross-chip
+    merges. Always the plan's generic key_fn (GSPMD cannot partition a
+    Mosaic call).
 
     Signature matches the single-device jit paths:
     fn(env, valid, seg_mask, consts[, lo_local]) with `lo_local` traced
     when a per-chip window is active."""
+    if strategy == "historicals":
+        from tpu_olap.executor.runner import QueryRunner
+
+        local = plan.kernel if win is None \
+            else QueryRunner._window_kernel(plan.kernel, win[1])
+        seg, rep = P(AXIS), P()
+        in_specs = (seg, seg, seg, rep) + ((rep,) if win is not None
+                                           else ())
+        # check_vma off: pallas_call outputs carry no varying-axis type
+        return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                                     out_specs=seg, check_vma=False))
+
     from tpu_olap.kernels.groupby import group_reduce
 
     D = mesh.devices.size
     K = plan.total_groups
     W = win[1] if win is not None else per_chip
-    historicals = strategy == "historicals"
 
     def mesh_agg(env, valid, seg_mask, consts, lo=None):
         if lo is not None:
             env, valid, seg_mask = _window_env(env, valid, seg_mask,
                                                D, per_chip, lo, W)
         fenv, mask, key = plan.key_fn(env, valid, seg_mask, consts)
-        if not historicals:
-            return group_reduce(key, mask, fenv, plan.agg_plans, K,
-                                consts)
-        with jax.named_scope("key"):
-            key2 = chip_extended_key(key, mask, D, W, K)
-        return group_reduce(key2, mask, fenv, plan.agg_plans, D * K,
-                            consts)
+        return group_reduce(key, mask, fenv, plan.agg_plans, K, consts)
 
-    out = shard_spec(mesh) if historicals else replicated_spec(mesh)
-    return jax.jit(mesh_agg, out_shardings=out)
+    return jax.jit(mesh_agg, out_shardings=replicated_spec(mesh))
 
 
 def mesh_mask_kernel(plan, mesh: Mesh):

@@ -14,12 +14,13 @@ The TPU translation keeps the same decision shape with the same inputs:
   compiler-inserted psum/all-gather (the fan-out/merge is opaque, like
   Druid's broker). The only strategy on a multi-host (DCN) mesh, where
   remote shards are not host-addressable.
-- "**historicals**" -> chip-extended group keys under
-  `jax.jit(..., out_shardings=P('chips'))`: each chip's explicit
-  partial dense group table stays SHARDED in its own HBM (zero
-  cross-chip traffic in the reduce), one fetch pulls every chip's
-  shard concurrently, and the host BROKER merges the D unfinalized
-  tables with the segment-cache algebra (the analog of per-historical
+- "**historicals**" -> the single-chip kernel (the Pallas one-hot
+  reduce included) under `jax.shard_map` over the chip axis: every
+  chip reduces its OWN rows with plain group keys, each chip's explicit
+  partial dense group table stays SHARDED in its own HBM (no
+  collective in the program), one fetch pulls every chip's shard
+  concurrently, and the host BROKER merges the D unfinalized tables
+  with the segment-cache algebra (the analog of per-historical
   partial aggregates + Spark's final merge-aggregate, SURVEY.md §3.5
   P2; executor/sharding.py).
 
