@@ -134,10 +134,26 @@ def test_mixed_types_fall_back(eng):
         run_both(eng, "SELECT count(*) AS n FROM t WHERE city = v")
 
 
-def test_ordered_string_comparison_falls_back(eng):
-    from tpu_olap.bench.parity import ParityError
-    with pytest.raises(ParityError):
-        run_both(eng, "SELECT count(*) AS n FROM t WHERE city < dest")
+@pytest.mark.parametrize("op", ["<", "<=", ">", ">="])
+def test_ordered_string_comparison_on_device(eng, op):
+    """Row-vs-row ORDER of two string columns (TPC-H Q12's `l_commitdate <
+    l_receiptdate`): ranks in the merged dictionary, served by the device
+    (a structural fallback before), NULL operands never matching."""
+    out, fb, _plan = run_both(
+        eng, f"SELECT count(*) AS n FROM t WHERE city {op} dest")
+    f = _frame()
+    both = f[f.city.notna() & f.dest.notna()]
+    exp = int(eval(f"(both.city {op} both.dest).sum()"))
+    assert 0 < exp < len(both)
+    assert int(out.iloc[0]["n"]) == int(fb.iloc[0]["n"]) == exp
+
+
+def test_ordered_serde_roundtrip():
+    f = ColumnComparisonFilter(("a", "b"), "<=")
+    assert f.to_json()["op"] == "<=" and filter_from_json(f.to_json()) == f
+    assert "op" not in ColumnComparisonFilter(("a", "b")).to_json()
+    with pytest.raises(ValueError):
+        ColumnComparisonFilter(("a", "b", "c"), "<")
 
 
 def test_derived_stream_cached(eng):

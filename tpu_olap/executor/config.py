@@ -26,10 +26,10 @@ class EngineConfig:
     # theta sketch nominal-entries cap (k × groups × 8B of HBM)
     theta_k_cap: int = 1 << 14
 
-    # host-side label-table cap per grouped NUMERIC dimension (the dense
-    # id space materializes [size] labels at lowering time; this bounds
-    # host memory, not the group space — the sparse path groups far past
-    # the dense budget through the same per-dim id spaces)
+    # host-side cap on the fine buckets a timeFormat dimension may
+    # materialize at lowering time. (A grouped NUMERIC dimension's labels
+    # are arithmetic, dimplan.NumericLabels: its domain is bounded by the
+    # group space's budgets, not by this.)
     numeric_dim_label_budget: int = 1 << 22
 
     # sort-based sparse group-by (kernels.sparse_groupby), used when the

@@ -2065,25 +2065,29 @@ class QueryRunner:
         local_limit = min(budget, plan.total_groups)
         hint = self._cap_hints.get(base_key)
         cap = min(local_limit, self.config.sparse_group_cap) \
-            if hint is None else min(local_limit, max(64, _next_pow2(2 * hint)))
+            if hint is None else _grown_cap(hint, local_limit)
 
         t0 = time.perf_counter()
         hit = False
+        attempts = 0
         if self.config.platform == "cpu":
             if win is not None:
                 env, valid, seg_mask = self._window_numpy(
                     env, np.asarray(valid), seg_mask, win)
             while True:
-                out = plan.make_sparse_kernel(cap)(
-                    env, np.asarray(valid), seg_mask, plan.pool.consts)
-                count = int(out["_count"])
+                attempts += 1
+                with _span("sparse-attempt", cap=cap) as sp:
+                    out = plan.make_sparse_kernel(cap)(
+                        env, np.asarray(valid), seg_mask, plan.pool.consts)
+                    count = int(out["_count"])
+                    sp.set(present_groups=count)
                 if count <= cap:
                     break
                 if count > cap_limit:
                     raise UnsupportedAggregation(
                         f"{count} present groups exceed sparse budget "
                         f"{cap_limit}")
-                cap = min(cap_limit, _next_pow2(count))
+                cap = _grown_cap(count, cap_limit)
             out = {k: np.asarray(v) for k, v in out.items()}
             metrics["num_shards"] = 1
         elif mesh is None:
@@ -2094,39 +2098,45 @@ class QueryRunner:
             pin = None
             try:
                 while True:
-                    with self._enqueue_lock(metrics):
-                        consts_dev, seg_arg = self._args_for(
-                            plan, seg_mask, None)
-                        key = base_key + (cap,) \
-                            + ((win[1],) if win else ())
-                        jitted = self._jit_cache.get(key)
-                        hit = jitted is not None
-                        if hit:
-                            _cache_lru_hit(self._jit_cache, key)
-                        else:
-                            kern = plan.make_sparse_kernel(cap)
-                            if win is not None:
-                                jitted = jax.jit(
-                                    self._window_kernel(kern, win[1]))
+                    attempts += 1
+                    with _span("sparse-attempt", cap=cap) as sp:
+                        with self._enqueue_lock(metrics):
+                            consts_dev, seg_arg = self._args_for(
+                                plan, seg_mask, None)
+                            key = base_key + (cap,) \
+                                + ((win[1],) if win else ())
+                            jitted = self._jit_cache.get(key)
+                            hit = jitted is not None
+                            if hit:
+                                _cache_lru_hit(self._jit_cache, key)
                             else:
-                                jitted = jax.jit(kern)
-                            self._jit_cache[key] = jitted
-                            self._note_compile("sparse", metrics)
-                        out = jitted(env, valid, seg_arg, consts_dev,
-                                     win[0]) if win is not None else \
-                            jitted(env, valid, seg_arg, consts_dev)
-                        prev, pin = pin, self._pin_inflight(out)
-                    if prev is not None:
-                        self._hbm_ledger.unpin_inflight(prev)
-                    count = int(out["_count"])
+                                kern = plan.make_sparse_kernel(cap)
+                                if win is not None:
+                                    jitted = jax.jit(
+                                        self._window_kernel(kern, win[1]))
+                                else:
+                                    jitted = jax.jit(kern)
+                                self._jit_cache[key] = jitted
+                                self._note_compile("sparse", metrics)
+                            out = jitted(env, valid, seg_arg, consts_dev,
+                                         win[0]) if win is not None else \
+                                jitted(env, valid, seg_arg, consts_dev)
+                            prev, pin = pin, self._pin_inflight(out)
+                        if prev is not None:
+                            self._hbm_ledger.unpin_inflight(prev)
+                        # the one-element sync that waits for the sort
+                        with _span("count-probe"):
+                            count = int(out["_count"])
+                        sp.set(present_groups=count, jit_cache_hit=hit)
                     if count <= cap:
                         break
                     if count > cap_limit:
                         raise UnsupportedAggregation(
                             f"{count} present groups exceed sparse "
                             f"budget {cap_limit}")
-                    cap = min(cap_limit, _next_pow2(count))
-                out = self._fetch_tree(out, metrics, pin)
+                    cap = _grown_cap(count, cap_limit)
+                with _span("host-transfer", cap=cap):
+                    out = self._fetch_tree(out, metrics, pin)
                 pin = None  # consumed (fetch unpins)
             finally:
                 if pin is not None:
@@ -2158,6 +2168,7 @@ class QueryRunner:
                 pin = None
                 try:
                     while True:
+                        attempts += 1
                         with self._enqueue_lock(metrics):
                             consts_dev, seg_arg = self._args_for(
                                 plan, seg_mask, mesh)
@@ -2185,7 +2196,7 @@ class QueryRunner:
                             raise UnsupportedAggregation(
                                 f"{count} present groups exceed sparse "
                                 f"budget {local_limit}")
-                        cap = min(local_limit, _next_pow2(count))
+                        cap = _grown_cap(count, local_limit)
                     out = self._fetch_tree(out, metrics, pin)
                     pin = None
                 finally:
@@ -2196,16 +2207,15 @@ class QueryRunner:
                 metrics["execute_ms"] = \
                     (time.perf_counter() - t0) * 1000
                 metrics["jit_cache_hit"] = hit
-                metrics["sparse"] = True
-                metrics["result_groups"] = count
-                metrics["result_cap"] = cap
+                self._note_sparse(metrics, attempts, cap, count)
                 return out, count
             lhint = self._cap_hints.get(base_key + ("local",))
             if lhint is not None:
-                cap = min(local_limit, max(64, _next_pow2(2 * lhint)))
+                cap = _grown_cap(lhint, local_limit)
             pin = None
             try:
                 while True:
+                    attempts += 1
                     with self._enqueue_lock(metrics):
                         consts_dev, seg_arg = self._args_for(
                             plan, seg_mask, mesh)
@@ -2234,7 +2244,7 @@ class QueryRunner:
                         raise UnsupportedAggregation(
                             f"{local_max} per-chip present groups "
                             f"exceed sparse budget {local_limit}")
-                    cap = min(local_limit, _next_pow2(local_max))
+                    cap = _grown_cap(local_max, local_limit)
                 parts = self._fetch_trees(outs, metrics, pin)
                 pin = None  # consumed (fetch unpins)
             finally:
@@ -2258,10 +2268,18 @@ class QueryRunner:
         self._cap_hints[base_key] = count
         metrics["execute_ms"] = (time.perf_counter() - t0) * 1000
         metrics["jit_cache_hit"] = hit
-        metrics["sparse"] = True
-        metrics["result_groups"] = count
-        metrics["result_cap"] = cap
+        self._note_sparse(metrics, attempts, cap, count)
         return out, count
+
+    @staticmethod
+    def _note_sparse(metrics: dict, attempts: int, cap: int, count: int):
+        """The sparse dispatch's counters on the record: how many cap
+        attempts ran (1 once the template's hint is warm), the compact
+        table's final cap, and the groups present in it."""
+        metrics["sparse"] = True
+        metrics["sparse_attempts"] = attempts
+        metrics["sparse_cap"] = metrics["result_cap"] = cap
+        metrics["present_groups"] = metrics["result_groups"] = count
 
     # ------------------------------------------------------------ agg paths
 
@@ -2273,6 +2291,17 @@ class QueryRunner:
         metrics["lower_ms"] = (time.perf_counter() - t0) * 1000
         if getattr(plan, "pallas_reason", "off") is None:
             metrics["pallas"] = True  # fused Pallas reduce kernel active
+        # which group-reduce implementation the plan holds, and why the
+        # Pallas kernel was turned down where it was: "reduce" is the
+        # generic kernel with nothing to group by (one masked reduce)
+        if plan.sparse:
+            metrics["reduce_path"] = "sparse"
+        elif plan.pallas_reason is None:
+            metrics["reduce_path"] = "pallas"
+        else:
+            metrics["reduce_path"] = \
+                "scatter" if plan.total_groups > 1 else "reduce"
+            metrics["pallas_reason"] = plan.pallas_reason
         specs = agg_specs_by_name(query.aggregations)
         # theta set-op post-aggs consume RAW sketch tables host-side;
         # the packed path finalizes sketches on device, so those queries
@@ -2637,7 +2666,9 @@ class QueryRunner:
         return QueryResult(query, rows, druid)
 
     def _decode_groups(self, plan, idx: np.ndarray):
-        """Present flat group ids -> (bucket ids, {dim name -> values})."""
+        """Present flat group ids -> (bucket ids, {dim name -> dense ids}).
+        A dimension's values are `dp.labels[ids]`; the caller looks up
+        only the rows it emits or sorts by."""
         sizes = plan.sizes
         rem = idx
         radix_vals = []
@@ -2646,10 +2677,9 @@ class QueryRunner:
             rem = rem // s
         radix_vals = radix_vals[::-1]  # bucket first, then dims in order
         buckets = radix_vals[0]
-        dim_vals = {}
-        for dp, ids in zip(plan.dim_plans, radix_vals[1:]):
-            dim_vals[dp.name] = dp.labels[ids]
-        return buckets, dim_vals
+        dim_ids = {dp.name: ids
+                   for dp, ids in zip(plan.dim_plans, radix_vals[1:])}
+        return buckets, dim_ids
 
     def _assemble_groupby(self, query, plan, arrays) -> QueryResult:
         names = self._out_names(query)
@@ -2661,41 +2691,35 @@ class QueryRunner:
         """present: flat group ids (any int width); sub: compact per-group
         final values. Shared tail of the dense and sparse paths."""
         names = self._out_names(query)
-        buckets, dim_vals = self._decode_groups(plan, present)
+        buckets, dim_ids = self._decode_groups(plan, present)
+        labels = {dp.name: dp.labels for dp in plan.dim_plans}
 
         if query.having is not None:
-            hmask = eval_having(query.having, sub, dim_vals)
-            present = present[hmask]
+            hmask = eval_having(
+                query.having, sub,
+                {d: labels[d][ids] for d, ids in dim_ids.items()})
             buckets = buckets[hmask]
-            dim_vals = {k: v[hmask] for k, v in dim_vals.items()}
+            dim_ids = {k: v[hmask] for k, v in dim_ids.items()}
             sub = {k: v[hmask] for k, v in sub.items()}
 
-        order = np.arange(len(present))
+        order = np.arange(len(buckets))
         ls = query.limit_spec
         if ls is not None and ls.columns:
-            keys = []
-            for c in ls.columns[::-1]:
-                if c.dimension == "timestamp":
-                    k = np.asarray(buckets, np.float64)
-                elif c.dimension in dim_vals:
-                    v = dim_vals[c.dimension]
-                    k = np.asarray([("" if x is None else str(x)) for x in v])
-                    if c.dimension_order == "numeric":
-                        k = np.asarray([float(x) if x else -np.inf for x in k])
-                else:
-                    k = np.asarray(sub[c.dimension], np.float64)
-                if c.direction == "descending":
-                    k = _invert_sort_key(k)
-                keys.append(k)
-            order = np.lexsort(keys)
+            with _span("ordered-limit", groups=len(order), limit=ls.limit):
+                order = _limit_order(ls, buckets, dim_ids, labels, sub)
         if ls is not None:
             lo = ls.offset
             hi = None if ls.limit is None else lo + ls.limit
             order = order[lo:hi]
 
+        # labels and rows of what is emitted only: a LIMIT over a sparse
+        # group-by keeps tens of rows of hundreds of thousands of groups
+        buckets = buckets[order]
+        dim_vals = {d: labels[d][ids[order]] for d, ids in dim_ids.items()}
+        sub = {n: sub[n][order] for n in names}
         rows, druid = [], []
         starts = plan.bucket_plan.starts
-        for i in order:
+        for i in range(len(order)):
             ts = iso(starts[buckets[i]])
             ev = {dp.name: render_value(dim_vals[dp.name][i])
                   for dp in plan.dim_plans}
@@ -2970,6 +2994,49 @@ class QueryRunner:
 
 def _next_pow2(n: int) -> int:
     return 1 << max(0, int(n) - 1).bit_length() if n > 1 else 1
+
+
+def _grown_cap(count: int, limit: int) -> int:
+    """The sparse compact table's cap for `count` present groups: what an
+    overflowing attempt grows to AND what the template's next run starts
+    from (its hint), so the program compiled for the retry is the one
+    every later run finds in the jit cache: each size is a compile of
+    the sort."""
+    return min(limit, max(64, _next_pow2(2 * count)))
+
+
+def _limit_order(ls, buckets, dim_ids, labels, sub) -> np.ndarray:
+    """Row order by the limit spec's columns (stable, like np.lexsort).
+    Where a LIMIT keeps few of many groups and the first column is an
+    aggregate, only the groups that can reach the limit by that column
+    (ties with the last of them included) have the other keys built and
+    sorted: a key over a dimension is built a Python value at a time."""
+    n = len(buckets)
+    cand = np.arange(n)
+    first = ls.columns[0]
+    if ls.limit is not None and first.dimension in sub \
+            and n > 4 * (ls.offset + ls.limit) + 64:
+        k = np.asarray(sub[first.dimension], np.float64)
+        if first.direction == "descending":
+            k = -k
+        if not np.isnan(k).any():
+            kth = ls.offset + ls.limit - 1
+            cand = np.flatnonzero(k <= np.partition(k, kth)[kth])
+    keys = []
+    for c in ls.columns[::-1]:
+        if c.dimension == "timestamp":
+            k = np.asarray(buckets[cand], np.float64)
+        elif c.dimension in dim_ids:
+            v = labels[c.dimension][dim_ids[c.dimension][cand]]
+            k = np.asarray([("" if x is None else str(x)) for x in v])
+            if c.dimension_order == "numeric":
+                k = np.asarray([float(x) if x else -np.inf for x in k])
+        else:
+            k = np.asarray(sub[c.dimension][cand], np.float64)
+        if c.direction == "descending":
+            k = _invert_sort_key(k)
+        keys.append(k)
+    return cand[np.lexsort(keys)]
 
 
 def _invert_sort_key(k: np.ndarray):

@@ -216,22 +216,42 @@ class ColumnComparisonFilter(FilterSpec):
     String/string pairs compare via a cross-dictionary code translation
     map built host-side and hoisted to a device-resident derived stream
     (executor/dataset.py::derived), so the device cost is one elementwise
-    int32 compare, not a per-dispatch gather."""
+    int32 compare, not a per-dispatch gather.
+
+    `op` "<" or "<=" (an extension: Druid's filter is equality only) is
+    the ORDERED comparison of exactly two columns, `a op b`, the shape of
+    TPC-H Q12's `l_commitdate < l_receiptdate`: two string columns compare
+    by their values' ranks in the merged dictionary, carried by the same
+    derived streams; the time column against a string column compares
+    epoch millis with the dictionary's values read as ISO dates (a value
+    that is no date matches nothing), as a literal beside the time column
+    is read. `>`/`>=` are the same filter with the columns swapped."""
     dimensions: tuple  # >= 2 column names
+    op: str = "=="     # "==" | "<" | "<="
+
+    def __post_init__(self):
+        if self.op not in ("==", "<", "<="):
+            raise ValueError(f"columnComparison op {self.op!r}")
+        if self.op != "==" and len(self.dimensions) != 2:
+            raise ValueError(
+                "an ordered columnComparison takes exactly 2 dimensions")
 
     def columns(self):
         return set(self.dimensions)
 
     def to_json(self):
-        return {"type": "columnComparison",
-                "dimensions": list(self.dimensions)}
+        out = {"type": "columnComparison",
+               "dimensions": list(self.dimensions)}
+        if self.op != "==":
+            out["op"] = self.op
+        return out
 
     @staticmethod
     def from_json(d):
         dims = tuple(d["dimensions"])
         if len(dims) < 2:
             raise ValueError("columnComparison needs >= 2 dimensions")
-        return ColumnComparisonFilter(dims)
+        return ColumnComparisonFilter(dims, d.get("op", "=="))
 
 
 @register("filter", "expression")

@@ -60,7 +60,7 @@ def virtual_null_mask(expr: Expr, nulls: dict, xp):
     ANY referenced input is null. Returns a bool mask or None when no
     referenced column carries nulls."""
     mask = None
-    for col in expr.columns():
+    for col in sorted(expr.columns()):
         m = nulls.get(col)
         if m is not None:
             mask = m if mask is None else (mask | m)
@@ -72,12 +72,15 @@ def widen_int_env(expr: Expr, cols: dict, xp) -> dict:
     int64: device columns may be stored int32 (executor.dataset narrow
     storage), and products/sums must not wrap. XLA fuses the widening
     into the consumer, so the HBM read stays narrow. No-op without x64
-    (int64 lanes unavailable — matches pre-narrowing behavior)."""
+    (int64 lanes unavailable — matches pre-narrowing behavior). Columns
+    are taken in sorted order: a set's order changes with the process's
+    hash seed, the traced program with it, and so would its key in the
+    persistent compile cache."""
     from tpu_olap.kernels.hashing import has_x64
     if not has_x64(xp):
         return cols
     out = None
-    for c in expr.columns():
+    for c in sorted(expr.columns()):
         v = cols.get(c)
         if v is not None and getattr(v, "dtype", None) is not None and \
                 v.dtype.kind in "iu" and v.dtype.itemsize < 8:

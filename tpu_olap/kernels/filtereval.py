@@ -117,6 +117,8 @@ def compile_filter(spec, table, pool: ConstPool, virtual_exprs=None):
             if len(s.dimensions) < 2:
                 raise UnsupportedFilter(
                     "columnComparison needs >= 2 dimensions")
+            if s.op != "==":
+                return _colcmp_ordered(*s.dimensions, s.op)
             pairs = [_colcmp_pair(a, b)
                      for a, b in zip(s.dimensions, s.dimensions[1:])]
             return lambda env, c: _fold_direct(pairs, env, c)
@@ -346,6 +348,65 @@ def compile_filter(spec, table, pool: ConstPool, virtual_exprs=None):
             ta_ids = hit if hit is not None else c[cname][env["cols"][a]]
             return ta_ids == env["cols"][b]
         colcmp_cache[(a, b)] = fn
+        return fn
+
+    def _colcmp_ordered(a, b, op):
+        """`a op b` for op "<" or "<=", row by row; a NULL operand never
+        matches. Two string columns compare by their values' ranks in the
+        merged dictionary: each side's rank stream is a derived column
+        like the equality pair's translation stream, with NULL at the end
+        of the order where it makes the comparison false. The time column
+        against a string column compares epoch millis with the
+        dictionary's values read as ISO dates."""
+        ta, tb = col_type(a), col_type(b)
+        a_str, b_str = ta is ColumnType.STRING, tb is ColumnType.STRING
+        less = (lambda x, y: x < y) if op == "<" else (lambda x, y: x <= y)
+        if a_str and b_str:
+            da, db = table.dictionaries[a], table.dictionaries[b]
+            merged = np.union1d(np.asarray(da.values, str),
+                                np.asarray(db.values, str))
+            big = np.iinfo(np.int32).max
+            sides = []
+            for col, d, null_rank in ((a, da, big), (b, db, -1)):
+                ranks = np.empty(d.size + 1, np.int32)
+                ranks[0] = null_rank
+                ranks[1:] = np.searchsorted(merged,
+                                            np.asarray(d.values, str))
+                cname = pool.add(ranks, np.int32)
+                token = _stream_token("cr", col, ranks)
+                pool.streams.append((token, col, cname))
+                pool.tag(f"cr:{token}")
+                sides.append((col, cname, "\0d:" + token))
+
+            def side(env, c, col, cname, dname):
+                hit = env["cols"].get(dname)
+                return hit if hit is not None \
+                    else c[cname][env["cols"][col]]
+
+            return lambda env, c: less(side(env, c, *sides[0]),
+                                       side(env, c, *sides[1]))
+        if TIME_COLUMN not in (a, b) or a_str == b_str:
+            raise UnsupportedFilter(
+                f"ordered columnComparison of {a!r} and {b!r}: two string "
+                "columns, or the time column and a string column")
+        from tpu_olap.utils.timeutil import parse_iso_datetime
+        scol = a if a_str else b
+        d = table.dictionaries[scol]
+        # the string side's millis; NULL and what is no date sit where
+        # the comparison is false
+        never = np.iinfo(np.int64).max if a_str else np.iinfo(np.int64).min
+        ms = np.full(d.size + 1, never, np.int64)
+        for i, v in enumerate(d.values):
+            try:
+                ms[i + 1] = parse_iso_datetime(str(v))
+            except ValueError:
+                pass
+        cname = pool.add(ms, np.int64)
+
+        def fn(env, c):
+            s_ms = c[cname][env["cols"][scol]]
+            t = env["cols"][TIME_COLUMN]
+            return less(s_ms, t) if a_str else less(t, s_ms)
         return fn
 
     def _table_filter(col, typ, make_table):

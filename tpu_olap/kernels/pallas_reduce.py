@@ -300,6 +300,8 @@ def _colcmp_nodes(spec):
 
 
 def _filter_ok(spec) -> bool:
+    if isinstance(spec, F.ColumnComparisonFilter) and spec.op != "==":
+        return False  # ordered row-vs-row: the generic kernel's
     if spec is None or isinstance(spec, _SIMPLE_FILTERS):
         return True
     if isinstance(spec, (F.AndFilter, F.OrFilter)):

@@ -837,6 +837,17 @@ class _Rewriter:
                 cb = self._check_col(right.name)
                 sa = self._col_type(ca) is ColumnType.STRING
                 sb = self._col_type(cb) is ColumnType.STRING
+                if (sa or sb) and op in ("<", "<=", ">", ">=") and (
+                        sa == sb or TIME_COLUMN in (ca, cb)):
+                    # ordered row-vs-row comparison of two string
+                    # columns (by value rank), or of the time column
+                    # with a string column of ISO dates (TPC-H Q12:
+                    # `l_commitdate < l_receiptdate`, `l_shipdate <
+                    # l_commitdate`); > and >= swap the columns
+                    if op in (">", ">="):
+                        ca, cb = cb, ca
+                    return F.ColumnComparisonFilter(
+                        (ca, cb), "<=" if "=" in op else "<")
                 if sa != sb:
                     raise RewriteError(
                         f"comparison between string and numeric columns "
@@ -852,9 +863,6 @@ class _Rewriter:
                     return F.ColumnComparisonFilter((ca, cb))
                 if op == "!=":
                     return F.NotFilter(F.ColumnComparisonFilter((ca, cb)))
-                if sa:
-                    raise RewriteError(
-                        "ordered comparison between string columns")
             if op == "!=":
                 # general `a <> b` must lower as NOT(a = b): a bare
                 # ExpressionFilter(!=) would exclude NULL operands
