@@ -316,3 +316,116 @@ def test_jitted_group_reduce_compiles_once():
     out2 = jf(env, valid, consts2)
     assert calls["n"] == 1
     assert np.allclose(np.asarray(out1["s"]), np.asarray(out2["s"]))
+
+
+# --- the compare form of the dense reduce (kernels/groupby.py) -----------
+
+def _compare_case(k, n=3000, seed=11, all_masked=False):
+    """(key, mask, env, plans) over `k` slots of which the last one no row
+    hits (for k > 1), with an int64 column past 2^31 a row, a float
+    column, a nullable column and a per-aggregate filter."""
+    from tpu_olap.kernels.groupby import AggPlan
+    rng = np.random.default_rng(seed)
+    key = rng.integers(0, max(1, k - 1), n).astype(np.int32)
+    mask = np.zeros(n, bool) if all_masked else rng.random(n) < 0.8
+    env = {"cols": {"big": rng.integers(1 << 33, 1 << 40, n),
+                    "f": rng.normal(0, 1e3, n),
+                    "nul": rng.integers(-50, 50, n),
+                    "flag": rng.integers(0, 3, n).astype(np.int32)},
+           "nulls": {"nul": rng.random(n) < 0.3}}
+
+    def flag_is_1(e, consts):
+        return e["cols"]["flag"] == 1
+
+    plans = [AggPlan("cnt", "count", (), np.int64),
+             AggPlan("fcnt", "count", (), np.int64, filter_fn=flag_is_1),
+             AggPlan("s_big", "sum", ("big",), np.int64),
+             AggPlan("s_f", "sum", ("f",), np.float64),
+             AggPlan("s_nul", "sum", ("nul",), np.int64),
+             AggPlan("fs_big", "sum", ("big",), np.int64,
+                     filter_fn=flag_is_1),
+             AggPlan("mn_big", "min", ("big",), np.int64),
+             AggPlan("mx_nul", "max", ("nul",), np.int64),
+             AggPlan("mn_f", "min", ("f",), np.float64),
+             AggPlan("mx_f", "max", ("f",), np.float64,
+                     filter_fn=flag_is_1)]
+    return key, mask, env, plans
+
+
+def _bound():
+    from tpu_olap.kernels.groupby import COMPARE_MAX_GROUPS
+    return COMPARE_MAX_GROUPS
+
+
+def _slots(k):
+    """A case's K: a number, or a place relative to the bound."""
+    return {"bound": _bound(), "bound+1": _bound() + 1}.get(k, k)
+
+
+_COMPARE_NAMES = ("_rows", "cnt", "fcnt", "s_big", "s_f", "s_nul", "fs_big",
+                  "mn_big", "mx_nul", "mn_f", "mx_f", "_nn_s_nul",
+                  "_nn_fs_big", "_nn_mx_f")
+
+
+@pytest.mark.parametrize("k,all_masked,block_rows", [
+    (2, False, None), ("bound", False, None), ("bound+1", False, None),
+    (12, True, None), (12, False, 256)],
+    ids=["k2", "k-bound", "k-bound+1", "k12-all-masked", "k12-row-blocks"])
+def test_compare_form_equals_the_numpy_reduce(monkeypatch, k, all_masked,
+                                              block_rows):
+    """The device side of group_reduce against its numpy side, every table
+    of it: bit-equal for every integer aggregate whatever the order of the
+    adds (a row's value passes 2^31; a slot no row hits holds the
+    identity; all rows masked), float sums to the tolerance the scatter
+    is held to. K at the bound is the compare form (there a loop over row
+    blocks with a tail, as in the last case, whose block is 256 rows),
+    one past it the scatter: all are the same tables."""
+    from tpu_olap.kernels import groupby
+    k = _slots(k)
+    if block_rows:
+        monkeypatch.setattr(groupby, "_CMP_BLOCK_BYTES", k * 8 * block_rows)
+    key, mask, env, plans = _compare_case(k, all_masked=all_masked)
+    want = group_reduce(key, mask, env, plans, k, {})
+    jenv = {g: {c: jnp.asarray(a) for c, a in env[g].items()} for g in env}
+    got = jax.jit(lambda key, mask, env: group_reduce(
+        key, mask, env, plans, k, {}))(jnp.asarray(key), jnp.asarray(mask),
+                                       jenv)
+    assert groupby.reduce_form(k) == \
+        ("compare" if k <= _bound() else "scatter")
+    assert sorted(got) == sorted(want) and set(_COMPARE_NAMES) <= set(want)
+    for name in want:
+        g, w = np.asarray(got[name]), want[name]
+        assert g.shape == w.shape == (k,) and g.dtype == w.dtype, name
+        if w.dtype.kind == "f":
+            assert np.allclose(g, w, rtol=1e-9, atol=1e-6), name
+        else:
+            assert np.array_equal(g, w), name
+    if not all_masked:   # a sum past int32; the slot no row hits
+        assert want["s_big"].max() > (1 << 40) and want["s_big"][-1] == 0
+
+
+@pytest.mark.parametrize("k,kinds,form", [
+    (2, ("sum", "count"), "compare"),
+    ("bound", ("min", "max", "sum"), "compare"),
+    ("bound+1", ("sum",), "scatter"),
+    (2, ("count", "hll"), "scatter"),
+    (12, ("theta",), "scatter"),
+    (12, (), "compare"),
+])
+def test_reduce_form_is_a_function_of_groups_and_kinds(k, kinds, form):
+    from tpu_olap.kernels.groupby import reduce_form
+    k = _slots(k)
+    assert reduce_form(k, kinds) == form
+
+
+@pytest.mark.parametrize("k,want_scatter", [
+    (12, False), ("bound", False), ("bound+1", True)],
+    ids=["k12", "k-bound", "k-bound+1"])
+def test_compare_form_lowers_without_a_scatter(k, want_scatter):
+    """What the jitted reduce IS, on any backend: no scatter op in the
+    lowered module at K up to the bound, XLA's scatter past it."""
+    k = _slots(k)
+    key, mask, env, plans = _compare_case(k)
+    text = jax.jit(lambda key, mask, env: group_reduce(
+        key, mask, env, plans, k, {})).lower(key, mask, env).as_text()
+    assert ("scatter" in text) == want_scatter

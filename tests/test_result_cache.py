@@ -300,3 +300,32 @@ def test_explain_analyze_shows_cache_decision():
     d = json.loads(spans["segment-cache"])
     assert d["segments_cached"] >= 1
     assert "segments_computed" in d
+
+
+@pytest.mark.parametrize("days,form", [(60, "compare"), (400, "scatter")])
+def test_segment_partials_record_the_form_of_the_extended_key(days, form):
+    """The segment-cache partials program reduces over W*K groups (the
+    key extended by the window's segment), so that number, not the plan's
+    K of 10, is what the generic kernel branches on: the record and the
+    `dispatch` span name the program that ran, and the answer is the
+    uncached one either way."""
+    from tpu_olap.kernels.groupby import COMPARE_MAX_GROUPS, reduce_form
+    df = _df(days=days)
+    eng = _engine(df, result_cache_enabled=False)
+    got = eng.sql(GROUP_SQL)
+    rec = eng.runner.history[-1]
+    W = 1 << (rec["segments_computed"] - 1).bit_length()
+    assert reduce_form(10) == "compare" and rec["reduce_path"] == "scatter"
+    assert (W * 10 > COMPARE_MAX_GROUPS) == (form == "scatter")
+    assert rec["reduce_form"] == form
+
+    def walk(t):
+        yield t
+        for c in t.get("children", []):
+            yield from walk(c)
+    assert [s["attrs"]["reduce_form"]
+            for s in walk(eng.tracer.last.to_json())
+            if s["name"] == "dispatch" and s["attrs"].get("segcache")] \
+        == [form]
+    want = df.groupby("g")["v"].sum().sort_index()
+    assert list(got["s"]) == list(want)

@@ -72,11 +72,44 @@ def test_template_equals_the_reference(eng, data, name):
     assert rec["reduce_path"] == REDUCE_PATH[name]
     assert rec.get("num_shards", 1) == 1
     if REDUCE_PATH[name] == "scatter":
+        # the generic grouped kernel, here a masked reduce a slot: Q1 has
+        # a dozen dense slots, Q12 as many as l_shipmode has values
         assert rec["pallas_reason"]
+        assert rec["reduce_form"] == "compare"
+    else:
+        assert "reduce_form" not in rec
     if REDUCE_PATH[name] == "sparse":
         assert rec["sparse_attempts"] >= 1
         assert rec["sparse_cap"] >= rec["present_groups"] \
             == data["reference"]["groups"][name]
+
+
+def test_past_the_bound_the_generic_kernel_is_the_scatter(eng):
+    """o_orderdate x l_returnflag is a dense space of ~9,600 slots, past
+    COMPARE_MAX_GROUPS, and sum_charge's input passes int32, so Pallas
+    turns the plan down: the record and the `dispatch` span both say
+    which program ran."""
+    from tpu_olap.kernels.groupby import COMPARE_MAX_GROUPS
+    sql = f"""
+        SELECT o_orderdate, l_returnflag, count(*) AS n,
+               sum(l_extendedprice * (100 - l_discount) * (100 + l_tax))
+                   AS sum_charge
+        FROM {tpch_flat.TABLE} GROUP BY o_orderdate, l_returnflag"""
+    plan = eng.planner.plan(sql)
+    phys = eng.runner._lower_cached(plan.query, plan.entry.segments)
+    assert COMPARE_MAX_GROUPS < phys.total_groups and not phys.sparse
+    _, rec = _served(eng, sql)
+    assert rec["reduce_path"] == "scatter" and rec["pallas_reason"]
+    assert rec["reduce_form"] == "scatter"
+    assert _dispatch_forms(eng) == ["scatter"]
+    _served(eng, tpch_flat.templates()["q1"])
+    assert _dispatch_forms(eng) == ["compare"]
+
+
+def _dispatch_forms(eng):
+    return [s["attrs"].get("reduce_form")
+            for s in _walk(eng.tracer.last.to_json())
+            if s["name"] == "dispatch"]
 
 
 def test_sparse_cap_grows_once_and_the_retry_is_the_warm_program(data):

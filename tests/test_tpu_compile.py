@@ -171,6 +171,66 @@ def test_dispatch_program_compiles_for_v5e(topo, no_persistent_cache,
         assert int(re.search(r"= s32\[(\d+),\d+,\d+\]", call).group(1)) > 1
 
 
+# TPC-H Q1's shape on the SSB table: a sum whose input passes int32, so
+# Pallas turns the plan down and the generic grouped kernel serves it
+CHARGE = "sum(lo_extendedprice * (100 - lo_discount) * (100 + lo_tax))"
+GENERIC_ROWS = 6_000_000
+# (id, sql, expect XLA's scatter in the program)
+GENERIC_CASES = [
+    ("q1-shaped-8-slots", f"""
+        SELECT d_year, count(*) AS n, sum(lo_quantity) AS qty,
+               sum(lo_extendedprice) AS base, {CHARGE} AS charge,
+               min(lo_extendedprice) AS lo, max(lo_supplycost) AS hi
+        FROM lineorder GROUP BY d_year""", False),
+    ("q1-shaped-1001-slots", f"""
+        SELECT p_brand1, count(*) AS n, {CHARGE} AS charge
+        FROM lineorder GROUP BY p_brand1""", False),
+    ("q1-shaped-past-the-bound", f"""
+        SELECT d_year, p_brand1, count(*) AS n, {CHARGE} AS charge
+        FROM lineorder GROUP BY d_year, p_brand1""", True),
+    ("hll-8-slots", HLL_SQL, True),
+]
+
+
+@pytest.mark.parametrize("case,sql,want_scatter", GENERIC_CASES,
+                         ids=[c[0] for c in GENERIC_CASES])
+def test_generic_grouped_program_compare_or_scatter(
+        topo, no_persistent_cache, ssb_tables, monkeypatch, case, sql,
+        want_scatter):
+    """The generic dense reduce at 6M rows, compiled for the chip: up to
+    COMPARE_MAX_GROUPS slots there is no scatter in the program and no
+    [K, N] buffer (the compare-select is the reduce's fused input; what
+    the program holds is each aggregate's input, evaluated once: a small
+    multiple of one int64 column an aggregate); past the bound, and for a
+    sketch's [K, m] state, XLA's scatter stays."""
+    from jax.sharding import SingleDeviceSharding
+    from tpu_olap.kernels.groupby import COMPARE_MAX_GROUPS, reduce_form
+    _as_tpu(monkeypatch)
+    eng = _engine(ssb_tables)
+    phys = _physical(eng, sql)
+    assert phys.pallas_reason is not None and not phys.sparse
+    form = reduce_form(phys.total_groups,
+                       [p.kind for p in phys.agg_plans])
+    assert (form == "scatter") == want_scatter
+    assert (phys.total_groups > COMPARE_MAX_GROUPS) == \
+        (case == "q1-shaped-past-the-bound")
+    compiled, n_seg, _ = compile_dispatch(
+        eng, phys, SingleDeviceSharding(topo.devices[0]), GENERIC_ROWS)
+    n = n_seg * phys.table.block_rows
+    assert n >= GENERIC_ROWS
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    # the `pack` stage compacts the [K] tables with a K-row scatter of
+    # its own; the reduce stage's ops carry its scope in their op_name
+    in_reduce = [ln for ln in text.splitlines()
+                 if " scatter(" in ln and "/reduce/" in ln]
+    assert bool(in_reduce) == want_scatter
+    if not want_scatter:
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        n_aggs = len(phys.agg_plans)
+        assert temp < n_aggs * n * 8 < phys.total_groups * n * 8, (temp, n)
+
+
 # (id, sql, per-chip window, expect the Pallas kernel, expect a scatter)
 MESH_CASES = [
     ("q2.1", QUERIES["q2.1"], False, True, False),

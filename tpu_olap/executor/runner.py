@@ -48,6 +48,7 @@ from tpu_olap.ir.query import (GroupByQuerySpec, ScanQuerySpec,
 from tpu_olap.ir.interval import ETERNITY
 from tpu_olap.ir.aggregations import CountAggregation
 from tpu_olap.ir.dimensions import DefaultDimensionSpec
+from tpu_olap.kernels.groupby import reduce_form
 from tpu_olap.segments.segment import TIME_COLUMN
 
 
@@ -1784,7 +1785,8 @@ class QueryRunner:
                     self._jit_cache[key] = jitted
                     self._note_compile("partials", metrics)
                 t0 = time.perf_counter()
-                with _span("dispatch", jit_cache_hit=hit, num_shards=1):
+                with _span("dispatch", jit_cache_hit=hit, num_shards=1,
+                           **_form_attr(metrics)):
                     consts_dev, seg_arg = self._args_for(plan, seg_mask,
                                                          None)
                     out = jitted(env, valid, seg_arg, consts_dev,
@@ -1869,7 +1871,7 @@ class QueryRunner:
                     self._note_compile("mesh", metrics)
                 t0 = time.perf_counter()
                 with _span("dispatch", jit_cache_hit=hit, num_shards=D,
-                           strategy=strategy):
+                           strategy=strategy, **_form_attr(metrics)):
                     consts_dev, seg_arg = self._args_for(plan, seg_mask,
                                                          mesh)
                     out = jitted(env, valid, seg_arg, consts_dev,
@@ -1981,7 +1983,8 @@ class QueryRunner:
                 min(cap_limit, max(64, _next_pow2(2 * hint)))
 
             t0 = time.perf_counter()
-            with _span("dispatch", packed=True) as dsp:
+            with _span("dispatch", packed=True,
+                       **_form_attr(metrics)) as dsp:
                 while True:
                     # stage 1 per attempt: jit/arg caches + the async
                     # dispatch under the lock; a cap-overflow retry
@@ -2302,6 +2305,11 @@ class QueryRunner:
             metrics["reduce_path"] = \
                 "scatter" if plan.total_groups > 1 else "reduce"
             metrics["pallas_reason"] = plan.pallas_reason
+            if plan.total_groups > 1 and self.config.platform != "cpu":
+                # "scatter" names the generic grouped kernel; which
+                # device program that is — a masked reduce a slot, or
+                # XLA's scatter — is the kernel's own function of the plan
+                _note_form(metrics, plan, plan.total_groups)
         specs = agg_specs_by_name(query.aggregations)
         # theta set-op post-aggs consume RAW sketch tables host-side;
         # the packed path finalizes sketches on device, so those queries
@@ -2539,8 +2547,10 @@ class QueryRunner:
                             plan, mesh, per_chip, W, K)
                         self._jit_cache[jkey] = jitted
                         self._note_compile("segcache", metrics)
+                    _note_form(metrics, plan, D * W * K)
                     with _span("dispatch", jit_cache_hit=hit,
-                               segcache=True, num_shards=D):
+                               segcache=True, num_shards=D,
+                               **_form_attr(metrics)):
                         consts_dev, seg_arg = self._args_for(
                             plan, seg_mask, mesh)
                         out = jitted(env, valid, seg_arg, consts_dev,
@@ -2574,8 +2584,10 @@ class QueryRunner:
                             self._seg_partials_kernel(plan, W, K))
                         self._jit_cache[jkey] = jitted
                         self._note_compile("segcache", metrics)
+                    _note_form(metrics, plan, W * K)
                     with _span("dispatch", jit_cache_hit=hit,
-                               segcache=True, num_shards=1):
+                               segcache=True, num_shards=1,
+                               **_form_attr(metrics)):
                         consts_dev, seg_arg = self._args_for(
                             plan, seg_mask, None)
                         out = jitted(env, valid, seg_arg, consts_dev,
@@ -2990,6 +3002,22 @@ class QueryRunner:
             "size": int(sum(c.get("size", 0) for c in cols.values())),
         }
         return QueryResult(query, [record], [record])
+
+
+def _note_form(metrics: dict, plan, num_groups: int):
+    """`reduce_form` on the record, from the group space the generic
+    kernel is built with: the plan's K, or the segment-extended W*K
+    (D*W*K on the mesh) of the segment-cache partials program, which
+    branches on that number and not on K."""
+    metrics["reduce_form"] = reduce_form(
+        num_groups, [p.kind for p in plan.agg_plans])
+
+
+def _form_attr(metrics: dict) -> dict:
+    """The `dispatch` span's `reduce_form` attribute, where the record of
+    the query has one (a generic grouped aggregate on the device)."""
+    form = metrics.get("reduce_form")
+    return {"reduce_form": form} if form else {}
 
 
 def _next_pow2(n: int) -> int:

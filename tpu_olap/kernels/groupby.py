@@ -11,6 +11,7 @@ an allreduce, never a hash exchange, as long as K fits the dense budget
 from __future__ import annotations
 
 import contextlib
+import functools
 from dataclasses import dataclass
 
 import jax
@@ -30,6 +31,36 @@ class UnsupportedAggregation(Exception):
 
 
 _NO_SCOPE = contextlib.nullcontext()  # stateless, so one serves every call
+
+# The dense group space up to which the device program computes each slot of
+# a [K] table as a masked reduce over the rows (compare the key with the
+# slot, select, reduce) in place of XLA's scatter: the `k == 1` plain sum
+# widened to a few groups. Set from one sweep on a v5e
+# (tools/sweep_group_reduce.py; the table is in PERF.md section 6, PR 28):
+# the largest K of the sweep's grid at which the compare form was at least
+# twice as fast as the scatter for an int64 sum and an int32 count at 36M
+# and at 6M rows (there 22x and 3.8x; at 4,096 the count is 1.4x, at 8,192
+# the scatter wins it) and compiled in seconds. Past it the scatter stays.
+COMPARE_MAX_GROUPS = 2048
+# The compare form's row block: the [K, R] compare-select of one block is
+# at most this many bytes. 32 MiB is 137 blocks for an int64 sum over twelve
+# slots of 36M rows; on the chip 4 MiB is up to twice as slow and 128 MiB no
+# faster (same sweep).
+_CMP_BLOCK_BYTES = 32 << 20
+_SKETCH_KINDS = ("hll", "theta")
+
+
+def reduce_form(num_groups: int, kinds=()) -> str:
+    """Which program the generic dense reduce is, from what the plan
+    compiled and nothing else: "compare" where every [K] table of it is a
+    masked reduce a slot, "scatter" where XLA's scatter is in it — K past
+    COMPARE_MAX_GROUPS, or a sketch aggregate, whose [K, m] state keeps its
+    scatter-max at any K (its plan's counts and sums still take the
+    compare form at small K: the [K] tables ask with no kinds)."""
+    if num_groups > COMPARE_MAX_GROUPS or any(k in _SKETCH_KINDS
+                                              for k in kinds):
+        return "scatter"
+    return "compare"
 
 
 def stage_scope(name: str, xp):
@@ -336,6 +367,11 @@ def _seg_sum(v, key, k, xp):
         out = np.zeros((k,) + v.shape[1:], v.dtype)
         np.add.at(out, key, v)
         return out
+    if reduce_form(k) == "compare":
+        # dtype: jnp.sum widens an int32 count to int64, the scatter
+        # does not
+        return _cmp_reduce(v, key, k, 0, jnp.add,
+                           functools.partial(jnp.sum, dtype=v.dtype))
     return jax.ops.segment_sum(v, key, num_segments=k)
 
 
@@ -349,8 +385,45 @@ def _seg_minmax(v, key, k, kind, xp):
         out = np.full((k,), ident, v.dtype)
         (np.minimum if kind == "min" else np.maximum).at(out, key, v)
         return out
+    if reduce_form(k) == "compare":
+        return _cmp_reduce(v, key, k, _ident(v.dtype, kind),
+                           *((jnp.minimum, jnp.min) if kind == "min"
+                             else (jnp.maximum, jnp.max)))
     f = jax.ops.segment_min if kind == "min" else jax.ops.segment_max
     return f(v, key, num_segments=k)
+
+
+def _cmp_reduce(v, key, k, ident, merge, red):
+    """[N] values, [N] dense ids -> [K]: slot s is `red` over the rows
+    whose id is s, `ident` where no row has it. `v` is evaluated once.
+    The rows go by in blocks of R, each block's [K, R] compare-select
+    reduced to a [K] partial and `merge`d into the table, with R from
+    `_CMP_BLOCK_BYTES`: the most any backend can hold is one block's
+    [K, R], never [K, N] (the TPU fuses the compare-select into the
+    reduce and holds neither; XLA:CPU materializes it). A table of N <=
+    R rows is the one reduce with no loop. R is a power of two, so a
+    block starts on a tile boundary of the chip's layout."""
+    def block(vb, kb):
+        slots = jnp.arange(k, dtype=key.dtype)
+        return red(jnp.where(kb[None, :] == slots[:, None], vb[None, :],
+                             ident), axis=1)
+
+    n = v.shape[0]
+    r = _CMP_BLOCK_BYTES // (k * v.dtype.itemsize)
+    r = 1 << (max(r, 1).bit_length() - 1)
+    if n <= r:
+        return block(v, key)
+    steps = n // r
+
+    def body(i, acc):
+        def sl(a):
+            return jax.lax.dynamic_slice_in_dim(a, i * r, r)
+        return merge(acc, block(sl(v), sl(key)))
+
+    acc = jax.lax.fori_loop(0, steps, body, jnp.full((k,), ident, v.dtype))
+    if n > steps * r:
+        acc = merge(acc, block(v[steps * r:], key[steps * r:]))
+    return acc
 
 
 def _ident(dtype, kind):
