@@ -342,7 +342,6 @@ def phase_mesh(rows: int, seed: int, use_pallas: str, chips: int,
 
     from tools.multichip_smoke import SMOKE_QUERIES
     from tpu_olap.bench import register_ssb_parquet, write_ssb_parquet
-    from tpu_olap.planner import cost as cost_mod
 
     check(len(jax.devices()) >= chips,
           f"--chips {chips} needs {chips} devices, JAX reports "
@@ -394,9 +393,8 @@ def phase_mesh(rows: int, seed: int, use_pallas: str, chips: int,
         warm1, warm_m = (
             min(timed_ms(lambda: e.sql(sql)) for _ in range(3))
             for e in (e1, em))
-        cost = rec.get("cost") or {}
         say(f"mesh: {name} sha256 OK num_shards={rec.get('num_shards')} "
-            f"merge={rec.get('merge')} strategy={cost.get('strategy')} "
+            f"merge={rec.get('merge')} "
             f"mesh_program={rec.get('mesh_program')} "
             f"win/chip={rec.get('segments_window_per_chip')} "
             f"pallas={bool(rec.get('pallas'))} cold={cold_ms:.0f}ms "
@@ -408,12 +406,8 @@ def phase_mesh(rows: int, seed: int, use_pallas: str, chips: int,
             w = rec.get("segments_window_per_chip")
             check(w and w < per_chip,
                   f"windowed: no per-chip window (w={w}, of {per_chip})")
-    # planner/cost.py falls to built-in constants in silence for a
-    # backend it has no fit for — say which were used
-    fitted = cost_mod._calibration()
-    say(f"mesh: cost model backend={jax.default_backend()} "
-        f"fitted={sorted(fitted) or 'none (built-in fallback constants)'} "
-        f"constants={cost_mod.constants(em.config)}")
+    say(f"mesh: mesh_program={em.runner.mesh_program} "
+        f"(backend={jax.default_backend()}, one process)")
 
     rows_dev = em.sql("SELECT count(*) AS n FROM sys.devices")
     check(int(rows_dev.n[0]) == chips,

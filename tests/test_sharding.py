@@ -149,7 +149,7 @@ def test_per_chip_window_prunes_working_set():
     w = m["segments_window_per_chip"]
     assert w is not None and 0 < w < per_chip, (w, per_chip)
     assert m["num_shards"] == 8
-    assert m["cost"]["strategy"] in ("historicals", "broker")
+    assert m["mesh_program"] == "per_chip"
 
 
 def test_mesh_tier1_cache_shards_merge_at_broker():
@@ -231,8 +231,10 @@ def test_compaction_keeps_untouched_cache_shards():
 
 
 # ---------------------------------------------------------------------------
-# shard_map of the single-chip kernel (ISSUE 26): "historicals" is
-# plan.kernel on each chip's own rows — no collective, [K] partials a chip
+# shard_map of the single-chip kernel (ISSUE 26): the mesh program
+# "per_chip" is plan.kernel on each chip's own rows — no collective, [K]
+# partials a chip. Which program a mesh runs is a fact of the mesh (ISSUE
+# 29): "gspmd" only where it spans processes (sharding.is_multihost)
 
 
 def _theta_query():
@@ -255,6 +257,47 @@ ONE_MONTH_SQL = ("SELECT g, sum(v) AS s, min(v) AS mn, count(*) AS n "
                  "FROM m WHERE ts >= '1993-03-01' AND ts < '1993-04-01' "
                  "GROUP BY g ORDER BY g")
 
+
+
+def _sketch_build(num_shards=None, **cfg):
+    """A sketch-wide group table over few rows: [K, 2048] HLL registers
+    for K = 31 x 41 dense slots against 4,096 rows — the shape the
+    retired per-query cost model sent to the GSPMD spelling."""
+    rng = np.random.default_rng(3)
+    n = 4096
+    df = pd.DataFrame({
+        "ts": pd.to_datetime(rng.integers(725846400000, 757382400000, n),
+                             unit="ms"),
+        "dim": rng.choice([f"d{i}" for i in range(30)], n),
+        "val": rng.integers(0, 40, n).astype(np.int64),
+    })
+    eng = Engine(EngineConfig(num_shards=num_shards, **cfg))
+    eng.register_table("t", df, time_column="ts", block_rows=512)
+    return eng, df
+
+
+def _wide_build(num_shards=None, **cfg):
+    """A numeric dimension 968 values wide over 4,096 rows: twice the K
+    at which that model's merge estimate crossed its scan estimate."""
+    rng = np.random.default_rng(5)
+    n, k = 4096, 968
+    df = pd.DataFrame({
+        "ts": pd.to_datetime("2024-01-01")
+        + pd.to_timedelta(np.arange(n) % 9999, unit="s"),
+        "g": np.concatenate([np.array([0, k - 1]),
+                             rng.integers(0, k, n - 2)]).astype(np.int64),
+        "v": rng.integers(0, 100, n).astype(np.int64),
+    })
+    eng = Engine(EngineConfig(num_shards=num_shards, **cfg))
+    eng.register_table("t", df, time_column="ts", block_rows=512)
+    return eng, df
+
+
+SKETCH_SQL = """SELECT dim, val, count(DISTINCT dim) AS u FROM t
+                GROUP BY dim, val ORDER BY dim, val"""
+SMALL_SQL = "SELECT dim, sum(val) AS s FROM t GROUP BY dim ORDER BY dim"
+WIDE_SQL = "SELECT g, sum(v) AS s FROM t GROUP BY g ORDER BY g"
+
 # (id, engine builder, query, use_pallas, record's `pallas`, windowed)
 PER_CHIP_CASES = [
     ("ungrouped", build, QUERIES[0], "never", False, False),
@@ -269,6 +312,8 @@ PER_CHIP_CASES = [
     ("windowed-pallas", _month_build, WINDOW_SQL, "force", True, True),
     ("one-month", _month_build, ONE_MONTH_SQL, "never", False, True),
     ("one-month-pallas", _month_build, ONE_MONTH_SQL, "force", True, True),
+    ("sketch-heavy", _sketch_build, SKETCH_SQL, "never", False, False),
+    ("wide-numeric-dim", _wide_build, WIDE_SQL, "never", False, False),
 ]
 COLLECTIVES = ("all-gather", "all-reduce", "all-to-all",
                "collective-permute", "reduce-scatter")
@@ -287,7 +332,7 @@ def _answer_sha(eng, query):
                          PER_CHIP_CASES, ids=[c[0] for c in PER_CHIP_CASES])
 def test_per_chip_program(monkeypatch, case, builder, query, use_pallas,
                           want_pallas, windowed):
-    """The "historicals" mesh program is the single-chip kernel mapped
+    """The "per_chip" mesh program is the single-chip kernel mapped
     over the chips: answers sha256-equal to one chip's, no collective
     in the compiled program, a [K] partial table from every chip (the
     identity where a chip's segments hold no row of a group), and a
@@ -299,11 +344,11 @@ def test_per_chip_program(monkeypatch, case, builder, query, use_pallas,
     calls = []
     real = sh.mesh_agg_kernel
 
-    def spy(plan, mesh, per_chip, strategy, win=None):
-        jitted = real(plan, mesh, per_chip, strategy, win)
+    def spy(plan, mesh, per_chip, program, win=None):
+        jitted = real(plan, mesh, per_chip, program, win)
 
         def run(*args):
-            calls.append((plan, strategy, win, jitted, args))
+            calls.append((plan, program, win, jitted, args))
             return jitted(*args)
         return run
 
@@ -319,8 +364,8 @@ def test_per_chip_program(monkeypatch, case, builder, query, use_pallas,
     assert rec["path"] == ("pallas" if want_pallas else "dense")
     assert bool(rec.get("segments_window_per_chip")) == windowed
 
-    (plan, strategy, win, jitted, args), = calls
-    assert strategy == "historicals" and (win is not None) == windowed
+    (plan, program, win, jitted, args), = calls
+    assert program == "per_chip" and (win is not None) == windowed
     assert (plan.pallas_reason is None) == want_pallas
     text = jitted.lower(*args).compile().as_text()
     assert not [c for c in COLLECTIVES if c in text]
@@ -346,13 +391,92 @@ def test_per_chip_program(monkeypatch, case, builder, query, use_pallas,
             np.testing.assert_array_equal(np.asarray(got[name]), v)
 
 
-def test_gspmd_record_says_what_ran():
-    """force_strategy="broker" keeps the GSPMD spelling: the generic
+def _spanning_processes(monkeypatch):
+    """Substitute sharding.is_multihost: every mesh built from here on
+    reads as one that spans processes. Returns the meshes it was asked
+    about."""
+    from tpu_olap.executor import sharding as sh
+    asked = []
+
+    def spans(mesh):
+        asked.append(mesh)
+        return True
+    monkeypatch.setattr(sh, "is_multihost", spans)
+    return asked
+
+
+def test_gspmd_record_says_what_ran(monkeypatch):
+    """A mesh that spans processes keeps the GSPMD spelling: the generic
     key_fn over global shapes, so the record carries mesh_program
     "gspmd" and no `pallas`, whatever the plan was eligible for."""
     e1, _ = build(use_pallas="never")
-    e8, _ = build(num_shards=8, use_pallas="force", force_strategy="broker")
+    _spanning_processes(monkeypatch)
+    e8, _ = build(num_shards=8, use_pallas="force")
     assert _answer_sha(e1, SUM_SQL) == _answer_sha(e8, SUM_SQL)
     rec = e8.runner.history[-1]
     assert rec["mesh_program"] == "gspmd" and rec["merge"] == "gspmd"
     assert not rec.get("pallas") and rec["path"] == "dense"
+
+
+@pytest.mark.parametrize("program", ["per_chip", "gspmd"])
+def test_mesh_programs_agree_with_fallback(program, monkeypatch):
+    """Both spellings of the mesh aggregate against the pandas oracle."""
+    from tpu_olap.bench.parity import check_query
+    if program == "gspmd":
+        _spanning_processes(monkeypatch)
+    eng, _ = _sketch_build(num_shards=8)
+    check_query(eng, """
+        SELECT dim, sum(val) AS s, count() AS n, min(val) AS lo
+        FROM t GROUP BY dim ORDER BY dim
+    """, label=f"mesh_program={program}")
+    m = eng.runner.history[-1]
+    assert m["mesh_program"] == program
+    assert m["num_shards"] == 8
+
+
+def _mesh_keys(eng):
+    return [k for k in eng.runner._jit_cache if "mesh" in k]
+
+
+def test_mesh_program_is_a_property_of_the_mesh():
+    """One engine, a small and a sketch-heavy aggregate: both run the
+    per-chip program, and the jit-cache keys carry no strategy slot."""
+    eng, _ = _sketch_build(num_shards=8, use_pallas="never")
+    for sql in (SMALL_SQL, SKETCH_SQL):
+        eng.sql(sql)
+        rec = eng.runner.history[-1]
+        assert rec["mesh_program"] == "per_chip" and "cost" not in rec
+    assert eng.runner.mesh_program == "per_chip"
+    keys = _mesh_keys(eng)
+    assert len(keys) == 2
+    for k in keys:
+        # ..., "mesh", D, per-chip window width: nothing else
+        assert k[k.index("mesh"):] == ("mesh", 8, 0)
+        assert not {"historicals", "broker", "per_chip", "gspmd"} & set(k)
+
+
+def test_multiprocess_mesh_selects_gspmd_once(monkeypatch):
+    """With is_multihost substituted, a fresh runner's mesh_program is
+    "gspmd" for a small and for the sketch-heavy aggregate, and the
+    mesh was asked once, when it was built: not once a query."""
+    asked = _spanning_processes(monkeypatch)
+    eng, _ = _sketch_build(num_shards=8, use_pallas="never")
+    assert eng.runner.mesh_program is None and not asked  # no mesh yet
+    for sql in (SMALL_SQL, SKETCH_SQL):
+        eng.sql(sql)
+        rec = eng.runner.history[-1]
+        assert rec["mesh_program"] == "gspmd" and rec["merge"] == "gspmd"
+    assert eng.runner.mesh_program == "gspmd"
+    assert asked == [eng.runner.mesh]
+
+
+def test_explain_reports_mesh_program():
+    """EXPLAIN names the mesh's program for an aggregate; one chip has
+    no mesh and nothing to report."""
+    e8, _ = _sketch_build(num_shards=8)
+    out = e8.explain(SMALL_SQL)
+    assert out["rewritten"]
+    assert out["mesh_program"] == "per_chip" and "cost" not in out
+    e1, _ = _sketch_build()
+    out = e1.explain(SMALL_SQL)
+    assert out["rewritten"] and "mesh_program" not in out

@@ -105,15 +105,18 @@ def test_merge_propagates_local_overflow():
     cap = 64
     plans = [AggPlan("n", "count", (), np.int64)]
     env = {"cols": {}, "nulls": {}}
+    import jax
+    import jax.numpy as jnp
+
+    def chip(n):  # a chip's compact table, fetched as the broker does
+        return jax.device_get(sparse_group_reduce(
+            jnp.arange(n, dtype=jnp.int64), jnp.ones(n, bool), env, plans,
+            cap, {}, jnp))
     # chip A: 65 distinct keys -> local overflow drops one
-    key_a = np.arange(65, dtype=np.int64)
-    out_a = sparse_group_reduce(key_a, np.ones(65, bool), env, plans, cap,
-                                {}, np)
+    out_a = chip(65)
     assert int(out_a["_count"]) == 65  # local overflow signalled
     # chip B: subset of A's surviving keys
-    key_b = np.arange(32, dtype=np.int64)
-    out_b = sparse_group_reduce(key_b, np.ones(32, bool), env, plans, cap,
-                                {}, np)
+    out_b = chip(32)
     merged = merge_sparse([out_a, out_b], plans, cap, np)
     assert int(merged["_count"]) == 65  # NOT 64: retry must fire
 

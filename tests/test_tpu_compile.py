@@ -247,7 +247,7 @@ COLLECTIVES = ("all-gather", "all-reduce", "all-to-all",
 def test_sharded_program_compiles_for_four_chips(
         topo, no_persistent_cache, ssb_tables, monkeypatch, case, sql,
         windowed, want_pallas, want_scatter):
-    """The dense mesh program (sharding.mesh_agg_kernel, "historicals")
+    """The dense mesh program (sharding.mesh_agg_kernel, "per_chip")
     on a four-device Mesh built from the described topology: the
     single-chip plan.kernel mapped over the chips — the Mosaic call when
     the plan is eligible, XLA's scatter otherwise — on each chip's OWN
@@ -281,7 +281,7 @@ def test_sharded_program_compiles_for_four_chips(
     if windowed:
         args += (jax.ShapeDtypeStruct((), np.int32,
                                       sharding=NamedSharding(mesh, P())),)
-    fn = sh.mesh_agg_kernel(phys, mesh, per_chip, "historicals", win)
+    fn = sh.mesh_agg_kernel(phys, mesh, per_chip, "per_chip", win)
     text = fn.lower(*args).compile().as_text()
     assert ("tpu_custom_call" in text) == want_pallas
     assert ("scatter" in text) == want_scatter

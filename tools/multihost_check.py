@@ -10,9 +10,10 @@ MULTIHOST_2PROC.json.
 
 The production analog swaps the CPU platform + localhost coordinator
 for TPU pods — the jax API surface is identical (SURVEY.md §3.6: ICI
-within a slice, DCN across). Across processes the engine forces the
-GSPMD "broker" strategy (remote shards are not host-addressable, so the
-host broker merge cannot see them — executor.sharding.is_multihost).
+within a slice, DCN across). A mesh that spans processes runs the GSPMD
+spelling of every aggregate (`mesh_program: gspmd`: remote shards are not
+host-addressable, so the host broker merge cannot see them —
+executor.sharding.is_multihost, read once when the mesh is built).
 
 Usage: python tools/multihost_check.py            # parent: spawns 2 workers
        python tools/multihost_check.py <pid 0|1>  # worker mode

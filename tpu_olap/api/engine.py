@@ -838,22 +838,16 @@ class Engine:
 
     def explain(self, query: str) -> dict:
         """EXPLAIN DRUID REWRITE analog: the chosen QuerySpec (or the
-        fallback reason) without executing (SURVEY.md §4.5), plus the
-        cost-model dispatch decision (the reference logs its
-        DruidQueryCostModel choice the same way, SURVEY.md §6)."""
+        fallback reason) without executing (SURVEY.md §4.5), plus, for
+        an aggregate served over a mesh, the spelling the mesh runs it
+        in (the record's `mesh_program`)."""
+        from tpu_olap.executor.batch import AGG_QUERY_TYPES
         plan = self.planner.plan(query)
         out = plan.explain()
-        if plan.rewritten and plan.entry.is_accelerated:
-            from tpu_olap.executor.lowering import lower
-            from tpu_olap.planner import cost as cost_mod
-            try:
-                phys = lower(plan.query, plan.entry.segments, self.config)
-                if phys.kind == "agg":  # scan/select has no dispatch choice
-                    out["cost"] = cost_mod.decide(
-                        phys, self.config,
-                        self.config.num_shards or 1).to_json()
-            except _UNSUPPORTED as e:
-                out["cost"] = {"error": str(e)}
+        if plan.rewritten and plan.entry.is_accelerated \
+                and isinstance(plan.query, AGG_QUERY_TYPES) \
+                and self.runner.mesh is not None:
+            out["mesh_program"] = self.runner.mesh_program
         return out
 
     # -------------------------------------------------------- passthrough

@@ -3,9 +3,10 @@
 table's segments to a jitted XLA program, caches compiled programs by query
 *template* (literals stripped), keeps columns HBM-resident, and assembles
 Druid-shaped results host-side. Multi-chip execution shards the segment axis
-over a `NamedSharding` mesh with interleaved placement and merges per-chip
-unfinalized partials at a host broker — or hands the whole program to
-XLA's GSPMD partitioner (sharding.py; planner/cost.py picks).
+over a `NamedSharding` mesh with interleaved placement, maps the one-chip
+program over the chips (`jax.shard_map`) and merges per-chip unfinalized
+partials at a host broker; a mesh that spans processes hands the whole
+program to XLA's GSPMD partitioner instead (sharding.py).
 """
 
 from tpu_olap.executor.config import EngineConfig  # noqa: F401

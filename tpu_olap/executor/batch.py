@@ -15,10 +15,9 @@ Three entry points:
 
 - run_batch(runner, queries, table): the boxed batch executor — dedupe
   identical queries (one physical scan serves every copy), fuse
-  compatible dense-agg legs into one jitted program (or the chunked
-  numpy shared scan on the "cpu" platform), run everything else through
-  the ordinary single-query path. Per-leg failures are boxed, never
-  collective.
+  compatible dense-agg legs into one jitted program, run everything
+  else through the ordinary single-query path. Per-leg failures are
+  boxed, never collective.
 - Coalescer: the micro-batching window. Concurrent QueryRunner.execute()
   callers enqueue; the first arrival leads, sleeps batch_window_ms, and
   dispatches everyone who arrived in the window as one batch
@@ -28,8 +27,7 @@ Three entry points:
 Metrics: every leg of a fused dispatch records `batch_id` (count the
 shared pass ONCE per id), `batch_size` (logical queries served),
 `scan_ms_shared` (wall of the one shared pass) and `agg_ms` (this leg's
-share of it — measured per leg on the numpy platform, attributed by
-scanned-work weight on the jit platform, where the inside of one XLA
+share of it — attributed by scanned-work weight: the inside of one XLA
 program cannot be timed per leg). See docs/BATCH_EXECUTION.md.
 """
 
@@ -39,12 +37,10 @@ import json
 import threading
 import time
 
-import numpy as np
-
 from tpu_olap.executor.runner import QueryResult, _next_pow2
 from tpu_olap.ir.query import (GroupByQuerySpec, TimeseriesQuerySpec,
                                TopNQuerySpec)
-from tpu_olap.kernels.groupby import group_reduce_batch, merge_partials
+from tpu_olap.kernels.groupby import group_reduce_batch
 from tpu_olap.obs.trace import (current_query_id, span as _span,
                                 use_query_id)
 from tpu_olap.resilience.errors import InternalError
@@ -281,9 +277,8 @@ def _run_fused(runner, table, group, query_ids=None):
         # re-prepare (stale buffers could be poisoned by a device reset).
         # Two-staged like the single-query path (ISSUE 10): stage 1
         # (env build + fused program fire) under the enqueue lock,
-        # stage 2 (transfer / the numpy shared scan) lock-free — the
-        # leader no longer holds dispatch_lock while it computes or
-        # assembles.
+        # stage 2 (transfer) lock-free — the leader no longer holds
+        # dispatch_lock while it fetches or assembles.
         with runner._pipeline_slot():
             with runner._enqueue_lock(metrics_list[0]):
                 leg_envs, seg_masks = [], []
@@ -304,21 +299,13 @@ def _run_fused(runner, table, group, query_ids=None):
                         m["segments_window"] = win[1] * D_win
                         if runner.mesh is not None:
                             m["segments_window_per_chip"] = win[1]
-                enq = pin = None
-                if runner.config.platform != "cpu":
-                    enq = _enqueue_fused_device(
-                        runner, table, plans, leg_envs, valid,
-                        seg_masks, win)
-                    pin = runner._pin_inflight(enq[0])
+                outs_dev, hit, t_fire = _enqueue_fused_device(
+                    runner, table, plans, leg_envs, valid, seg_masks,
+                    win)
+                pin = runner._pin_inflight(outs_dev)
             if metrics_list[0].get("pipelined"):
                 for m in metrics_list[1:]:
                     m["pipelined"] = True
-            if enq is None:
-                # numpy shared scan: the chunked compute reads only its
-                # own env references, so it runs outside the lock
-                return _run_fused_numpy(runner, plans, leg_envs, valid,
-                                        seg_masks, win) + (False,)
-            outs_dev, hit, t_fire = enq
             outs = runner._fetch_tree(outs_dev, metrics_list[0], pin)
             if runner.mesh is not None:
                 # broker step: each leg's per-chip [D·K] unfinalized
@@ -348,7 +335,7 @@ def _run_fused(runner, table, group, query_ids=None):
                batch_size=n_logical) as ssp:
         partials_list, shared_ms, agg_ms, hit = runner._guarded_dispatch(
             dispatch, metrics_list[0], table.name)
-        if not hit and runner.config.platform != "cpu":
+        if not hit:
             # one fused executable per batch composition: attribute the
             # build to the first leg's record (counting it on every leg
             # would multiply one compile by batch_legs in /metrics)
@@ -542,86 +529,6 @@ def _enqueue_fused_device(runner, table, plans, leg_envs, valid,
     if mesh is not None:
         runner._note_chip_dispatch(range(D))
     return outs, hit, t0
-
-
-def _run_fused_numpy(runner, plans, leg_envs, valid, seg_masks, win):
-    """Chunked shared scan on the numpy platform: the union segment
-    window is sliced chunk by chunk, and every leg's kernel runs over
-    the chunk while it is cache-hot — each chunk's bytes stream from
-    DRAM once for all N legs instead of once per query. Chunks fan out
-    over a small thread pool (numpy releases the GIL on large array
-    ops). Per-leg partials merge in chunk order via merge_partials;
-    note chunked float sums can differ from the single-pass path in the
-    last ulp (addition reorders across chunk boundaries)."""
-    valid = np.asarray(valid)
-    n_seg = len(seg_masks[0])
-    lo, hi = (win[0], win[0] + win[1]) if win is not None else (0, n_seg)
-    C = max(1, int(runner.config.batch_chunk_segments))
-    bounds = [(a, min(a + C, hi)) for a in range(lo, hi, C)]
-    t_all = time.perf_counter()
-    agg_ms = [0.0] * len(plans)
-    mu = threading.Lock()
-
-    def slice_env(env, sl):
-        return {"cols": {n: v[sl] for n, v in env["cols"].items()},
-                "nulls": {n: v[sl] for n, v in env["nulls"].items()}}
-
-    def one_chunk(b):
-        a, z = b
-        sl = slice(a, z)
-        outs = []
-        for i, plan in enumerate(plans):
-            sm = seg_masks[i][sl]
-            if not sm.any():
-                outs.append(None)
-                continue
-            t0 = time.perf_counter()
-            out = plan.kernel(slice_env(leg_envs[i], sl), valid[sl], sm,
-                              plan.pool.consts)
-            dt = (time.perf_counter() - t0) * 1000
-            with mu:
-                agg_ms[i] += dt
-            outs.append({k: np.asarray(v) for k, v in out.items()})
-        return outs
-
-    threads = int(runner.config.batch_cpu_threads)
-    if threads == 0:
-        import os
-        threads = min(4, os.cpu_count() or 1)
-    if threads > 1 and len(bounds) > 1:
-        from concurrent.futures import ThreadPoolExecutor
-        with ThreadPoolExecutor(max_workers=threads) as ex:
-            chunk_outs = list(ex.map(one_chunk, bounds))
-    else:
-        chunk_outs = [one_chunk(b) for b in bounds]
-
-    partials_list = []
-    for i, plan in enumerate(plans):
-        acc = None
-        for outs in chunk_outs:
-            o = outs[i]
-            if o is None:
-                continue
-            acc = o if acc is None else merge_partials(acc, o,
-                                                       plan.agg_plans)
-        if acc is None:
-            # fully pruned/empty leg: one all-masked evaluation over a
-            # single segment yields the correctly-shaped zero partials
-            a = min(lo, max(0, n_seg - 1))
-            z = min(a + 1, n_seg)
-            sl = slice(a, z)
-            acc = plan.kernel(slice_env(leg_envs[i], sl), valid[sl],
-                              np.zeros(z - a, bool), plan.pool.consts)
-            acc = {k: np.asarray(v) for k, v in acc.items()}
-        partials_list.append(acc)
-    shared_ms = (time.perf_counter() - t_all) * 1000
-    # with chunks fanned over threads, per-leg CPU times sum past the
-    # shared wall; rescale so sum(agg_ms) <= scan_ms_shared holds (the
-    # documented attribution invariant) while keeping relative weights
-    total = sum(agg_ms)
-    if total > shared_ms > 0:
-        agg_ms = [a * shared_ms / total for a in agg_ms]
-    return partials_list, shared_ms, agg_ms
 
 
 # -------------------------------------------------------------- coalescer

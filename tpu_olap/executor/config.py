@@ -5,7 +5,7 @@ two-layer config (SURVEY.md §6 "Config / flag system": session SQLConf keys
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 
 import numpy as np
 
@@ -62,9 +62,6 @@ class EngineConfig:
     # fit one chip's table). A multi-host (DCN) mesh hands the whole
     # sparse program to GSPMD instead (global-budget capacity).
     sparse_merge: str = "exchange"
-
-    # segments per device dispatch (flattened rows = batch × block_rows)
-    max_segments_per_dispatch: int = 1 << 10
 
     # HBM residency budget (bytes) for device-cached column buffers across
     # all tables; least-recently-used columns evict when exceeded
@@ -126,14 +123,6 @@ class EngineConfig:
     batch_window_ms: float = 0.0
     # max logical queries per fused dispatch; larger batches split
     batch_max_queries: int = 16
-    # numpy-platform ("cpu") shared scan: segments per chunk of the
-    # chunked batch loop — each chunk is sliced once and fed to every
-    # leg while cache-hot. Chunked float sums can differ from the
-    # single-pass path in the last ulp (merge reorders addition).
-    batch_chunk_segments: int = 64
-    # numpy-platform batch parallelism across chunks (numpy releases the
-    # GIL on large array ops): 0 = auto (min(4, cores)), 1 = serial
-    batch_cpu_threads: int = 0
 
     # --- semantic result caching (executor.resultcache; docs/CACHING.md)
     # Tier 2: bounded LRU full-result cache keyed by (normalized query
@@ -238,9 +227,6 @@ class EngineConfig:
     # TABLE (deterministic for tests/benches).
     ingest_store_checkpoint_on_compact: bool = True
 
-    # execution platform: "device" = default jax backend, "cpu" = numpy path
-    platform: str = "device"
-
     # multi-chip: shard the segment axis across this many devices on a
     # 1-D 'chips' mesh (None/1 = single device) — jit + NamedSharding
     # over an INTERLEAVED segment->chip placement (executor/sharding.py:
@@ -262,20 +248,6 @@ class EngineConfig:
 
     # session timezone for granularity math (reference: tz.id conf key)
     time_zone: str = "UTC"
-
-    # cost model knobs (planner.cost). The four constants default to the
-    # fitted values in planner/cost_calibration.json for the running
-    # backend (tools/calibrate_cost.py writes them) and fall back to the
-    # coarse built-ins; set explicitly to pin.
-    cost_model_enabled: bool = True
-    shard_merge_factor: float = 1.0
-    cost_scan_ns_per_row_col: float | None = None
-    cost_merge_ns_per_byte: float | None = None
-    cost_collective_lat_us: float | None = None
-    cost_gspmd_overhead: float | None = None
-    # calibration/debug override: pin the dispatch strategy
-    # ("historicals" | "broker"); None = cost-model decision
-    force_strategy: str | None = None
 
     # failure detection / elastic recovery (SURVEY.md §6): device dispatch
     # retries after purging device caches; with a mesh, repeated failure
@@ -433,8 +405,6 @@ class EngineConfig:
     # None = no cap (pre-A/B behavior; "force" always ignores the cap).
     # Default set from the on-chip A/B once the probe banks it.
     pallas_auto_flop_budget: float | None = None
-
-    extra: dict = field(default_factory=dict)
 
     def apply_x64(self):
         if self.enable_x64:

@@ -216,7 +216,7 @@ class HbmLedger:
 
 
 class DeviceDataset:
-    """Lazy per-column stacks for one table on one platform.
+    """Lazy per-column device stacks for one table.
 
     With a mesh, stacks are padded to a multiple of the chip count with
     fully-invalid segments, reordered into the INTERLEAVED placement
@@ -234,10 +234,9 @@ class DeviceDataset:
     fix: a small append no longer re-uploads every column).
     """
 
-    def __init__(self, table: TableSegments, platform: str = "device",
-                 mesh=None, ledger: HbmLedger | None = None, prev=None):
+    def __init__(self, table: TableSegments, mesh=None,
+                 ledger: HbmLedger | None = None, prev=None):
         self.table = table
-        self.platform = platform
         self.mesh = mesh
         self.ledger = ledger
         self._cols: dict[str, object] = {}
@@ -261,8 +260,7 @@ class DeviceDataset:
         self._rebase = None
         self.rebased_cols = 0
         self.rebase_rows_uploaded = 0
-        if (prev is not None and platform != "cpu"
-                and prev.platform == platform
+        if (prev is not None
                 and prev.table is not table
                 and prev.table.block_rows == table.block_rows
                 and prev.mesh is mesh):
@@ -285,8 +283,6 @@ class DeviceDataset:
                 }
 
     def _put(self, arr: np.ndarray):
-        if self.platform == "cpu":
-            return arr
         import jax
         if self.mesh is not None:
             from tpu_olap.executor.sharding import shard_put

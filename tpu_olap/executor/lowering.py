@@ -490,7 +490,8 @@ def _lower_agg(query, table, config) -> PhysicalPlan:
                                           vexprs, need_time)
     pruned = [s.meta.segment_id for s in pruned_segs]
 
-    def _masked_key(env, valid, seg_mask, consts, xp, key_builder):
+    def _masked_key(env, valid, seg_mask, consts, key_builder):
+        xp = _jnp()
         flat = {c: a.reshape(-1) for c, a in env["cols"].items()}
         nulls = {c: a.reshape(-1) for c, a in env["nulls"].items()}
         materialize_virtuals(vexprs, flat, nulls, xp)
@@ -520,23 +521,20 @@ def _lower_agg(query, table, config) -> PhysicalPlan:
         return fenv, mask, key
 
     def kernel(env, valid, seg_mask, consts):
-        xp = np if isinstance(valid, np.ndarray) else _jnp()
-        fenv, mask, key = _masked_key(env, valid, seg_mask, consts, xp,
+        fenv, mask, key = _masked_key(env, valid, seg_mask, consts,
                                       build_group_key)
         return group_reduce(key, mask, fenv, agg_plans, total, consts)
 
     def key_fn(env, valid, seg_mask, consts):
-        xp = np if isinstance(valid, np.ndarray) else _jnp()
-        return _masked_key(env, valid, seg_mask, consts, xp,
-                           build_group_key)
+        return _masked_key(env, valid, seg_mask, consts, build_group_key)
 
     def make_sparse_kernel(cap):
         from tpu_olap.kernels.sparse_groupby import (build_group_key64,
                                                      sparse_group_reduce)
 
         def sparse_kernel(env, valid, seg_mask, consts):
-            xp = np if isinstance(valid, np.ndarray) else _jnp()
-            fenv, mask, key = _masked_key(env, valid, seg_mask, consts, xp,
+            xp = _jnp()
+            fenv, mask, key = _masked_key(env, valid, seg_mask, consts,
                                           build_group_key64)
             return sparse_group_reduce(key.astype(xp.int64), mask, fenv,
                                        agg_plans, cap, consts, xp)
@@ -565,14 +563,14 @@ def _lower_agg(query, table, config) -> PhysicalPlan:
 
 def _maybe_use_pallas(plan, query, table, config, filter_fn, imask_fn=None):
     """Swap the generic jnp kernel for the fused Pallas one-hot MXU reduce
-    when the plan fits its envelope (kernels.pallas_reduce). The numpy
-    ("cpu" platform) path never uses it; "auto" additionally requires the
-    TPU backend — interpret mode is for tests ("force"), not production."""
+    when the plan fits its envelope (kernels.pallas_reduce). "auto"
+    additionally requires the TPU backend — interpret mode is for tests
+    ("force"), not production."""
     if config.use_pallas not in ("auto", "force", "never"):
         raise ValueError(
             f"use_pallas must be 'auto', 'force', or 'never'; got "
             f"{config.use_pallas!r}")
-    if config.use_pallas == "never" or config.platform == "cpu":
+    if config.use_pallas == "never":
         return
     # cheap backend gate first: under "auto" off-TPU, skip the eligibility
     # scan entirely (it reads per-column min/max metadata)
@@ -681,7 +679,7 @@ def _lower_mask(query, table, config) -> PhysicalPlan:
         c for c in phys if table.schema[c] is not ColumnType.STRING))
 
     def kernel(env, valid, seg_mask, consts):
-        xp = np if isinstance(valid, np.ndarray) else _jnp()
+        xp = _jnp()
         flat = {c: a.reshape(-1) for c, a in env["cols"].items()}
         nulls = {c: a.reshape(-1) for c, a in env["nulls"].items()}
         materialize_virtuals(vexprs, flat, nulls, xp)

@@ -101,7 +101,7 @@ def _config_sig(config) -> tuple:
     Anything else (pallas, batching, budgets that only reroute between
     numerically-equivalent paths) stays out so it cannot fragment the
     cache."""
-    return (config.platform, config.enable_x64,
+    return (config.enable_x64,
             str(config.long_dtype), str(config.double_dtype),
             config.theta_k_cap, config.sparse_theta_k_cap,
             config.time_zone, config.skip_empty_buckets)

@@ -165,10 +165,6 @@ class QueryRunner:
         import threading
         self.config = config or EngineConfig()
         self.config.apply_x64()
-        if self.config.platform == "cpu" and (self.config.num_shards or 1) > 1:
-            raise ValueError(
-                "num_shards > 1 requires the jax device platform; the "
-                "numpy path ('cpu') is single-shard by construction")
         # Serializes device dispatch (the chip has one program queue,
         # SURVEY.md §3.5 P1). Engine.device_lock aliases this object so
         # engine-level admin ops and runner-level dispatch share one
@@ -199,6 +195,7 @@ class QueryRunner:
         self._plan_cache: OrderedDict = OrderedDict()  # lowered
         #                                  PhysicalPlans, per query JSON
         self._mesh = None
+        self.mesh_program = None   # set with the mesh (the mesh property)
         self._active_shards = config.num_shards if config else None
         self._chip_dispatches: dict = {}  # chip index -> dispatches
         self._wedged = False   # a deadline expired; re-probe before trusting
@@ -466,9 +463,7 @@ class QueryRunner:
         """Account a just-enqueued dispatch's output buffers in the HBM
         ledger until stage 2 transfers them (shapes/dtypes are known
         without blocking on the async computation). Returns the pin key
-        for _fetch_tree, or None on the numpy platform."""
-        if self.config.platform == "cpu":
-            return None
+        for _fetch_tree."""
         import jax
         nbytes = sum(int(getattr(a, "nbytes", 0) or 0)
                      for a in jax.tree_util.tree_leaves(out))
@@ -482,19 +477,15 @@ class QueryRunner:
         aggregate column — one host round trip, not one per array). Unpins
         the in-flight ledger entry and maintains the transfer gauge;
         the host-transfer fault site fires here."""
+        import jax
         t0 = time.perf_counter()
-        if self.config.platform != "cpu" and pin is None:
+        if pin is None:
             pin = self._pin_inflight(out)
         self._note_transfer(1)
         try:
             with self.stages.stage("transfer", metrics):
                 self._inject("host-transfer")
-                if self.config.platform == "cpu":
-                    host = {k: np.asarray(v) for k, v in out.items()} \
-                        if isinstance(out, dict) else np.asarray(out)
-                else:
-                    import jax
-                    host = jax.device_get(out)
+                host = jax.device_get(out)
         finally:
             self._note_transfer(-1)
             if pin is not None:
@@ -511,9 +502,9 @@ class QueryRunner:
         output tree fetches on its own transfer-stage slot
         (stages.map_stage), so D transfers overlap one another AND the
         next query's enqueue instead of serializing behind one
-        device_get. The numpy platform (or a single tree) degrades to
-        the one-call fetch — no thread hop for nothing."""
-        if self.config.platform == "cpu" or len(outs) <= 1:
+        device_get. A single tree degrades to the one-call fetch — no
+        thread hop for nothing."""
+        if len(outs) <= 1:
             return self._fetch_tree(outs, metrics, pin)
         t0 = time.perf_counter()
         try:
@@ -825,13 +816,10 @@ class QueryRunner:
         cache_pin_bytes rides alongside from the ResultCache reporter —
         plus ledger-managed high-watermark and headroom against the
         per-chip share of the HBM budget."""
+        import jax
         mesh = self.mesh
-        if self.config.platform == "cpu":
-            devs = [None]
-        else:
-            import jax
-            devs = list(mesh.devices.flat) if mesh is not None \
-                else jax.devices()[:1]
+        devs = list(mesh.devices.flat) if mesh is not None \
+            else jax.devices()[:1]
         D = len(devs)
         seg = [0] * D
         res_bytes = [0.0] * D
@@ -867,9 +855,9 @@ class QueryRunner:
                 if c < len(hwm["per_chip"]) else 0
             rows.append({
                 "index": c,
-                "device": str(d) if d is not None else "numpy-host",
-                "platform": getattr(d, "platform", "numpy"),
-                "process": getattr(d, "process_index", 0),
+                "device": str(d),
+                "platform": d.platform,
+                "process": d.process_index,
                 "chips": D,
                 "segments": seg[c],
                 "resident_bytes": int(res_bytes[c]),
@@ -899,10 +887,15 @@ class QueryRunner:
 
     @property
     def mesh(self):
-        if self._mesh is None and self.config.platform != "cpu" and \
-                (self._active_shards or 1) > 1:
-            from tpu_olap.executor.sharding import make_mesh
-            self._mesh = make_mesh(self._active_shards)
+        if self._mesh is None and (self._active_shards or 1) > 1:
+            from tpu_olap.executor import sharding as sh
+            self._mesh = sh.make_mesh(self._active_shards)
+            # which spelling of an aggregate runs is a fact of the
+            # mesh, not of a query: a host broker cannot see another
+            # process's shards, so a mesh that spans processes takes
+            # the GSPMD spelling (replicated outputs) for every query
+            self.mesh_program = "gspmd" if sh.is_multihost(self._mesh) \
+                else "per_chip"
             # the ledger learns the chip count the moment the mesh
             # exists, so every subsequent add splits per chip exactly
             # (ISSUE 17 per-chip HBM attribution)
@@ -1209,9 +1202,8 @@ class QueryRunner:
         def work():
             try:
                 maybe_inject(self.config, "reprobe", 0)
-                if self.config.platform != "cpu":
-                    import jax.numpy as jnp
-                    jnp.ones((8,), jnp.int32).sum().block_until_ready()
+                import jax.numpy as jnp
+                jnp.ones((8,), jnp.int32).sum().block_until_ready()
                 ok.set()
             except Exception:
                 pass
@@ -1413,7 +1405,7 @@ class QueryRunner:
         # config change or needlessly fragment the cache
         key = (table.name,
                _json.dumps(query.to_json(), sort_keys=True, default=str),
-               c.use_pallas, c.platform, c.enable_x64,
+               c.use_pallas, c.enable_x64,
                str(c.long_dtype), str(c.double_dtype),
                c.num_shards,
                c.dense_group_budget, c.numeric_dim_label_budget,
@@ -1519,8 +1511,8 @@ class QueryRunner:
             # old stacks first), releasing the stale ledger accounting —
             # in-flight queries that captured its env keep their
             # buffers alive by reference
-            ds = DeviceDataset(table, self.config.platform, self.mesh,
-                               self._hbm_ledger, prev=prev)
+            ds = DeviceDataset(table, self.mesh, self._hbm_ledger,
+                               prev=prev)
             if prev is not None:
                 prev.evict()
             self._datasets[key] = ds
@@ -1595,17 +1587,11 @@ class QueryRunner:
 
     def _build_derived(self, ds, plan: PhysicalPlan, dp):
         """Materialize one precomputed dim id stream [S, R] int32 on the
-        dataset's platform from its resident source column (dictionary
-        codes for remap, __time for timeformat)."""
+        device from its resident source column (dictionary codes for
+        remap, __time for timeformat)."""
         src = dp.source_col if dp.source_col is not None else TIME_COLUMN
         col = ds.col(src)
         consts = plan.pool.consts
-        if self.config.platform == "cpu":
-            shape = np.asarray(col).shape
-            flat = {"cols": {src: np.asarray(col).reshape(-1)},
-                    "nulls": {}}
-            return np.asarray(dp.ids(flat, consts, np),
-                              np.int32).reshape(shape)
         import jax
         import jax.numpy as jnp
 
@@ -1626,8 +1612,6 @@ class QueryRunner:
         over every row is ~60 ms on a v5e through XLA)."""
         col = ds.col(src)
         xmap = plan.pool.consts[cname]
-        if self.config.platform == "cpu":
-            return np.asarray(xmap)[np.asarray(col)].astype(np.int32)
         import jax
         import jax.numpy as jnp
         return jax.jit(
@@ -1642,10 +1626,6 @@ class QueryRunner:
         ids_from_cached)."""
         col = ds.col(TIME_COLUMN)
         consts = plan.pool.consts
-        if self.config.platform == "cpu":
-            return np.asarray(
-                plan.bucket_plan.build_stream(np.asarray(col), consts),
-                np.int32)
         import jax
         import jax.numpy as jnp
 
@@ -1702,14 +1682,6 @@ class QueryRunner:
         return windowed
 
     @staticmethod
-    def _window_numpy(env, valid, seg_mask, win):
-        lo, W = win
-        sl = slice(lo, lo + W)
-        wenv = {"cols": {c: a[sl] for c, a in env["cols"].items()},
-                "nulls": {c: a[sl] for c, a in env["nulls"].items()}}
-        return wenv, valid[sl], seg_mask[sl]
-
-    @staticmethod
     def _embed_windowed_mask(out: dict, plan: PhysicalPlan, win,
                              n_seg_full: int) -> dict:
         """Windowed mask back into the full segment stack: every
@@ -1726,36 +1698,6 @@ class QueryRunner:
         return out
 
     def _run_partials(self, plan: PhysicalPlan, metrics: dict) -> dict:
-        if self.config.platform == "cpu":
-            return self._run_partials_numpy(plan, metrics)
-        return self._run_partials_jax(plan, metrics)
-
-    def _run_partials_numpy(self, plan: PhysicalPlan,
-                            metrics: dict) -> dict:
-        with self._pipeline_slot():
-            # stage 1: only the env build (dataset/ledger mutation)
-            # needs the lock — the numpy kernel reads its own slices
-            with self._enqueue_lock(metrics):
-                env, valid, seg_mask = self._prepare(plan, metrics)
-            win = self._segment_window(plan, len(seg_mask))
-            if win is not None:
-                metrics["segments_window"] = win[1]
-            n_seg_full = len(seg_mask)
-            t0 = time.perf_counter()
-            with _span("dispatch", jit_cache_hit=False, num_shards=1):
-                if win is not None:
-                    env, valid, seg_mask = self._window_numpy(
-                        env, np.asarray(valid), seg_mask, win)
-                out = plan.kernel(env, np.asarray(valid), seg_mask,
-                                  plan.pool.consts)
-            metrics["execute_ms"] = (time.perf_counter() - t0) * 1000
-            metrics["jit_cache_hit"] = False
-            metrics["num_shards"] = 1
-            out = {k: np.asarray(v) for k, v in out.items()}
-        return self._embed_windowed_mask(out, plan, win, n_seg_full)
-
-    def _run_partials_jax(self, plan: PhysicalPlan,
-                          metrics: dict) -> dict:
         import jax
         if self.mesh is not None:
             return self._run_partials_mesh(plan, metrics)
@@ -1815,19 +1757,20 @@ class QueryRunner:
         """Sharded dispatch (executor.sharding; docs/TPU_NOTES.md
         "sharded serving"): columns sit placed per chip (interleaved
         segment→chip assignment), the per-chip LOCAL window slices each
-        chip's pruned working set, and the merge strategy follows
-        planner.cost — "historicals" runs the single-chip `plan.kernel`
-        on every chip's own rows (`jax.shard_map`, no collective),
-        brings the per-chip unfinalized partials back sharded and merges
-        them at the host broker with the segment-cache algebra;
-        "broker" hands the whole program to GSPMD (replicated outputs,
-        compiler-inserted psum/all-gather). Mask-kind plans (scan/
-        select/search) fetch sharded row masks and inverse-permute the
-        placed segment axis back to logical order."""
+        chip's pruned working set, and an aggregate runs the mesh's one
+        program (`mesh_program`, fixed when the mesh is built):
+        "per_chip" runs the single-chip `plan.kernel` on every chip's
+        own rows (`jax.shard_map`, no collective), brings the per-chip
+        unfinalized partials back sharded and merges them at the host
+        broker with the segment-cache algebra; "gspmd", the spelling of
+        a mesh that spans processes, hands the whole program to GSPMD
+        (replicated outputs, compiler-inserted psum/all-gather).
+        Mask-kind plans (scan/select/search) fetch sharded row masks and
+        inverse-permute the placed segment axis back to logical order."""
         from tpu_olap.executor import sharding as sh
-        from tpu_olap.planner import cost as cost_mod
 
         mesh = self.mesh
+        program = self.mesh_program
         D = mesh.devices.size
         with self._pipeline_slot():
             with self._enqueue_lock(metrics):
@@ -1835,27 +1778,13 @@ class QueryRunner:
                 S = len(seg_mask)
                 per_chip = S // D
                 is_agg = plan.kind == "agg" and plan.key_fn is not None
-                strategy = "mask"
                 win = None
-                if is_agg:
-                    with _span("cost-decision") as sp:
-                        decision = cost_mod.decide(plan, self.config, D)
-                        strategy = decision.strategy
-                        # DCN mesh: remote chips' shards are not host-
-                        # addressable, so the broker merge cannot see
-                        # them — GSPMD's replicated merge is the only
-                        # correct spelling across processes
-                        if strategy == "historicals" and \
-                                sh.is_multihost(mesh):
-                            strategy = "broker"
-                        sp.set(strategy=strategy)
-                    metrics["cost"] = decision.to_json()
-                    win = sh.local_window(plan.pruned_ids, D, per_chip) \
-                        if not plan.empty else None
+                if is_agg and not plan.empty:
+                    win = sh.local_window(plan.pruned_ids, D, per_chip)
                     if win is not None:
                         metrics["segments_window"] = win[1] * D
                         metrics["segments_window_per_chip"] = win[1]
-                key = plan.fingerprint() + ("mesh", D, strategy,
+                key = plan.fingerprint() + ("mesh", D,
                                             win[1] if win else 0)
                 jitted = self._jit_cache.get(key)
                 hit = jitted is not None
@@ -1864,14 +1793,15 @@ class QueryRunner:
                 else:
                     if is_agg:
                         jitted = sh.mesh_agg_kernel(plan, mesh, per_chip,
-                                                    strategy, win)
+                                                    program, win)
                     else:
                         jitted = sh.mesh_mask_kernel(plan, mesh)
                     self._jit_cache[key] = jitted
                     self._note_compile("mesh", metrics)
                 t0 = time.perf_counter()
                 with _span("dispatch", jit_cache_hit=hit, num_shards=D,
-                           strategy=strategy, **_form_attr(metrics)):
+                           mesh_program=program if is_agg else "mask",
+                           **_form_attr(metrics)):
                     consts_dev, seg_arg = self._args_for(plan, seg_mask,
                                                          mesh)
                     out = jitted(env, valid, seg_arg, consts_dev,
@@ -1886,17 +1816,17 @@ class QueryRunner:
         metrics["execute_ms"] = (time.perf_counter() - t0) * 1000
         metrics["jit_cache_hit"] = hit
         metrics["num_shards"] = D
-        if is_agg and strategy == "historicals":
-            with _span("broker-merge", num_shards=D):
-                out = sh.broker_merge(out, plan.agg_plans, D)
-            metrics["merge"] = "broker"
-            metrics["mesh_program"] = "per_chip"
-        elif is_agg:
-            metrics["merge"] = "gspmd"
-            metrics["mesh_program"] = "gspmd"
-            # the GSPMD spelling runs the generic key_fn, never the
-            # Mosaic call the plan was eligible for
-            metrics.pop("pallas", None)
+        if is_agg:
+            metrics["mesh_program"] = program
+            if program == "per_chip":
+                with _span("broker-merge", num_shards=D):
+                    out = sh.broker_merge(out, plan.agg_plans, D)
+                metrics["merge"] = "broker"
+            else:
+                metrics["merge"] = "gspmd"
+                # the GSPMD spelling runs the generic key_fn, never the
+                # Mosaic call the plan was eligible for
+                metrics.pop("pallas", None)
         if plan.kind == "mask":
             # placed -> logical segment order: the scan/select/search
             # assemblers index rows by GLOBAL logical segment id
@@ -2073,27 +2003,7 @@ class QueryRunner:
         t0 = time.perf_counter()
         hit = False
         attempts = 0
-        if self.config.platform == "cpu":
-            if win is not None:
-                env, valid, seg_mask = self._window_numpy(
-                    env, np.asarray(valid), seg_mask, win)
-            while True:
-                attempts += 1
-                with _span("sparse-attempt", cap=cap) as sp:
-                    out = plan.make_sparse_kernel(cap)(
-                        env, np.asarray(valid), seg_mask, plan.pool.consts)
-                    count = int(out["_count"])
-                    sp.set(present_groups=count)
-                if count <= cap:
-                    break
-                if count > cap_limit:
-                    raise UnsupportedAggregation(
-                        f"{count} present groups exceed sparse budget "
-                        f"{cap_limit}")
-                cap = _grown_cap(count, cap_limit)
-            out = {k: np.asarray(v) for k, v in out.items()}
-            metrics["num_shards"] = 1
-        elif mesh is None:
+        if mesh is None:
             import jax
             # pin the enqueued output tree like every other device path
             # (the caller blocks on the _count probe while the buffers
@@ -2162,7 +2072,7 @@ class QueryRunner:
 
             from tpu_olap.executor import sharding as sh
             from tpu_olap.kernels.sparse_groupby import merge_sparse
-            if sh.is_multihost(mesh):
+            if self.mesh_program == "gspmd":
                 # DCN mesh: remote chips' compact tables are not host-
                 # addressable, so neither the fan-out nor the broker
                 # merge can run — hand the WHOLE sparse program to
@@ -2305,7 +2215,7 @@ class QueryRunner:
             metrics["reduce_path"] = \
                 "scatter" if plan.total_groups > 1 else "reduce"
             metrics["pallas_reason"] = plan.pallas_reason
-            if plan.total_groups > 1 and self.config.platform != "cpu":
+            if plan.total_groups > 1:
                 # "scatter" names the generic grouped kernel; which
                 # device program that is — a masked reduce a slot, or
                 # XLA's scatter — is the kernel's own function of the plan
@@ -2357,9 +2267,8 @@ class QueryRunner:
                 return res
 
         packed = None
-        use_packed = self.config.platform != "cpu" and not keep_raw \
-            and self.mesh is None  # mesh: unfinalized partials only
-        #                            (the broker merge needs them)
+        # mesh: unfinalized partials only (the broker merge needs them)
+        use_packed = not keep_raw and self.mesh is None
         if use_packed:
             packed = self._dispatch(
                 lambda: self._run_packed(plan, metrics), metrics,
@@ -2503,24 +2412,7 @@ class QueryRunner:
             K = plan.total_groups
             lo, hi = min(compute_ids), max(compute_ids) + 1
             t0 = time.perf_counter()
-            if self.config.platform == "cpu":
-                W = hi - lo
-                with _span("dispatch", jit_cache_hit=False, segcache=True,
-                           num_shards=1):
-                    wenv, wvalid, wmask = self._window_numpy(
-                        env, np.asarray(valid), seg_mask, (lo, W))
-                    fenv, mask, key = plan.key_fn(wenv, wvalid, wmask,
-                                                  plan.pool.consts)
-                    from tpu_olap.kernels.groupby import group_reduce
-                    r = mask.size // W
-                    key2 = (np.repeat(np.arange(W, dtype=np.int64), r)
-                            * K + key.astype(np.int64))
-                    out = group_reduce(key2, mask, fenv, plan.agg_plans,
-                                       W * K, plan.pool.consts)
-                out = {k: np.asarray(v) for k, v in out.items()}
-                metrics["jit_cache_hit"] = False
-                metrics["num_shards"] = 1
-            elif self.mesh is not None:
+            if self.mesh is not None:
                 # mesh variant (docs/CACHING.md "cache shards"): the
                 # per-chip LOCAL window slices each chip's placed
                 # segments, the key extends by placed window position,
@@ -2897,16 +2789,15 @@ class QueryRunner:
                 lambda: self._run_partials(plan, metrics), metrics,
                 table.name)
             # per-dimension masked value counts over the stacked code
-            # columns, all dims packed into ONE result vector. On the
-            # device platform this is one extra jitted call (~0.2 ms of
-            # scatter-adds for all SSB dims at SF1) plus one mask
-            # round-trip (_run_partials materializes outputs to host;
-            # fusing the counts into the mask program itself would
-            # remove that transfer — future work). The numpy platform
-            # does the same bincounts in C. The dispatch mask may be
-            # padded past the segment stack (shard-multiple rounding) —
-            # slice, never the reverse (the kernels mask pruned
-            # segments in place rather than compacting them away)
+            # columns, all dims packed into ONE result vector: one extra
+            # jitted call (~0.2 ms of scatter-adds for all SSB dims at
+            # SF1) plus one mask round-trip (_run_partials materializes
+            # outputs to host; fusing the counts into the mask program
+            # itself would remove that transfer — future work). Under a
+            # mesh the host does the same bincounts. The dispatch mask
+            # may be padded past the segment stack (shard-multiple
+            # rounding) — slice, never the reverse (the kernels mask
+            # pruned segments in place rather than compacting them away)
             with self._pipeline_slot():
                 # the column fetch mutates the dataset cache and the
                 # counts program is a device dispatch: both stage-1
@@ -2924,8 +2815,7 @@ class QueryRunner:
                         raise AssertionError(
                             "search mask shorter than the segment stack")
                     packed_dev = None
-                    if self.config.platform != "cpu" \
-                            and ds.to_logical is None:
+                    if ds.to_logical is None:
                         packed_dev = _search_counts_packed(
                             cards, dev_mask.reshape(-1)[:n_flat], cols)
                 if packed_dev is None:

@@ -9,22 +9,23 @@ dense aggregate runs as the SINGLE-CHIP kernel under `jax.shard_map`:
 every chip reduces its own rows with the program one chip would run
 (the Pallas one-hot reduce included), and no collective is compiled in.
 
-Two dense merge strategies (planner.cost picks per query, same decision
-shape as the reference's broker-vs-direct-historicals choice):
+Which spelling of the dense aggregate runs is a fact of the mesh, fixed
+when it is built (QueryRunner.mesh), and the record's `mesh_program`
+names it:
 
-- "historicals": `shard_map(plan.kernel)` — each chip returns its
-  unfinalized [K, ...] partial table from plain [0, K) keys, the tables
-  come back laid end to end as [D·K, ...] (each chip's K-block lives in
-  its own HBM — zero cross-chip traffic), and a host-side **broker**
-  step merges the D tables with the exact algebra the segment cache and
-  cube folds already share (kernels.groupby.merge_partials /
-  partials_radix). One device fetch pulls every chip's shard
-  concurrently, so stage-2 transfers overlap across chips.
-- "broker": the WHOLE program in global shapes is handed to GSPMD under
-  `jax.jit(..., out_shardings=...)` — plain group keys, replicated
-  outputs, compiler-inserted psum/all-gather (the fan-out/merge is
-  opaque, like Druid's broker). The only correct spelling on a
-  multi-host mesh, whose remote shards the host broker cannot see.
+- "per_chip", every mesh within one process: `shard_map(plan.kernel)` —
+  each chip returns its unfinalized [K, ...] partial table from plain
+  [0, K) keys, the tables come back laid end to end as [D·K, ...] (each
+  chip's K-block lives in its own HBM — zero cross-chip traffic), and a
+  host-side **broker** step merges the D tables with the exact algebra
+  the segment cache and cube folds already share
+  (kernels.groupby.merge_partials / partials_radix). One device fetch
+  pulls every chip's shard concurrently, so stage-2 transfers overlap
+  across chips.
+- "gspmd", a mesh that spans processes (is_multihost), whose remote
+  shards the host broker cannot see: the WHOLE program in global shapes
+  is handed to GSPMD under `jax.jit(..., out_shardings=...)` — plain
+  group keys, replicated outputs, compiler-inserted psum/all-gather.
 
 Interleaved placement is what makes windowed dispatch prune PER-CHIP
 working sets (docs/TPU_NOTES.md): a contiguous time range of logical
@@ -33,7 +34,7 @@ segments [lo, hi) lands on every chip as the LOCAL range
 of its own [S/D, R] blocks exactly as one chip slices its window — each
 chip reads only its ~(hi-lo)/D pruned segments, with no cross-chip data
 movement and ONE compiled program per (template, local width). The
-GSPMD spellings (the "broker" strategy, the per-segment cache partials)
+GSPMD spellings (the "gspmd" program, the per-segment cache partials)
 reshape [S, R] → [D, S/D, R] and slice the local axis instead.
 
 High-cardinality sparse group-by fans out as true per-chip programs:
@@ -54,8 +55,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 AXIS = "chips"
-# legacy alias (pre-rewrite name for the 1-D segment axis)
-DATA_AXIS = AXIS
 
 
 def make_mesh(num_shards: int) -> Mesh:
@@ -224,11 +223,11 @@ def chip_extended_key(key, mask, D: int, blocks: int, K: int):
     return chip * jnp.int32(K) + key.astype(jnp.int32)
 
 
-def mesh_agg_kernel(plan, mesh: Mesh, per_chip: int, strategy: str,
+def mesh_agg_kernel(plan, mesh: Mesh, per_chip: int, program: str,
                     win=None):
     """Jitted dense-aggregation program over the mesh.
 
-    strategy "historicals": `plan.kernel` ITSELF — the function one
+    program "per_chip": `plan.kernel` ITSELF — the function one
     chip jits: the Pallas one-hot reduce when the plan is eligible, the
     generic key + group_reduce otherwise — `jax.shard_map`ped over the
     chip axis. Each chip sees its own [S/D, block_rows] blocks (with a
@@ -237,7 +236,7 @@ def mesh_agg_kernel(plan, mesh: Mesh, per_chip: int, strategy: str,
     [K, ...] partial tables end to end as [D·K, ...], a chip each — the
     layout broker_merge folds. No collective is in the program.
 
-    strategy "broker": plain keys over the GLOBAL shapes handed to
+    program "gspmd": plain keys over the GLOBAL shapes handed to
     GSPMD -> replicated [K] outputs, compiler-inserted cross-chip
     merges. Always the plan's generic key_fn (GSPMD cannot partition a
     Mosaic call).
@@ -245,7 +244,7 @@ def mesh_agg_kernel(plan, mesh: Mesh, per_chip: int, strategy: str,
     Signature matches the single-device jit paths:
     fn(env, valid, seg_mask, consts[, lo_local]) with `lo_local` traced
     when a per-chip window is active."""
-    if strategy == "historicals":
+    if program == "per_chip":
         from tpu_olap.executor.runner import QueryRunner
 
         local = plan.kernel if win is None \
@@ -256,6 +255,8 @@ def mesh_agg_kernel(plan, mesh: Mesh, per_chip: int, strategy: str,
         # check_vma off: pallas_call outputs carry no varying-axis type
         return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=in_specs,
                                      out_specs=seg, check_vma=False))
+    if program != "gspmd":
+        raise ValueError(f"unknown mesh program {program!r}")
 
     from tpu_olap.kernels.groupby import group_reduce
 

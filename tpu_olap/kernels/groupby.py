@@ -268,7 +268,7 @@ def _group_reduce(key, mask, env, plans, num_groups, consts, xp):
                 h, valid = _hash_fields(env, p, m, xp, consts)
                 out[p.name] = hll_mod.hll_update(h, valid,
                                                  xp.where(valid, key, 0),
-                                                 num_groups, xp)
+                                                 num_groups)
             else:
                 # Druid byRow=False: distinct over the UNION of each
                 # field's values — update once per field, max-merge
@@ -280,14 +280,14 @@ def _group_reduce(key, mask, env, plans, num_groups, consts, xp):
                     h, valid = _hash_fields(env, sub, m, xp, consts)
                     r = hll_mod.hll_update(h, valid,
                                            xp.where(valid, key, 0),
-                                           num_groups, xp)
+                                           num_groups)
                     regs = r if regs is None else xp.maximum(regs, r)
                 out[p.name] = regs
             continue
         if p.kind == "theta":
             h, valid = _hash_fields(env, p, m, xp, consts)
             out[p.name] = theta_mod.theta_update(h, valid, key, num_groups,
-                                                 p.theta_k, xp)
+                                                 p.theta_k)
             continue
         raise UnsupportedAggregation(p.kind)
     return out
@@ -452,7 +452,7 @@ def _hash_fields(env, p: AggPlan, mask, xp, consts):
             if nulls is not None:
                 valid = valid & ~nulls
             if x.dtype.kind == "f":
-                xi = _float_bits(x, xp)
+                xi = _float_bits(x)
             elif x.dtype.itemsize == 8:
                 # fold all 64 bits before narrowing so values differing
                 # only in high bits don't collide structurally
@@ -464,8 +464,5 @@ def _hash_fields(env, p: AggPlan, mask, xp, consts):
     return h, valid
 
 
-def _float_bits(x, xp):
-    x32 = x.astype(xp.float32)
-    if xp is np:
-        return x32.view(np.int32)
-    return jax.lax.bitcast_convert_type(x32, jnp.int32)
+def _float_bits(x):
+    return jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)

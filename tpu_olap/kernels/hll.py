@@ -16,43 +16,34 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tpu_olap.kernels.hashing import has_x64
+
 LOG2M = 11
 NUM_REGISTERS = 1 << LOG2M  # 2048
 _ALPHA = 0.7213 / (1 + 1.079 / NUM_REGISTERS)
 
 
-def hll_update(h, valid, key, num_groups, xp):
+def hll_update(h, valid, key, num_groups):
     """h: [N] int32 hashes; valid: [N] bool; key: [N] int32 group ids.
 
     Returns [num_groups, NUM_REGISTERS] int32 rho registers.
     """
-    u = h.astype(xp.uint32)
-    reg = (u & xp.uint32(NUM_REGISTERS - 1)).astype(xp.int32)
-    w = (u >> LOG2M).astype(xp.uint32)
+    u = h.astype(jnp.uint32)
+    reg = (u & jnp.uint32(NUM_REGISTERS - 1)).astype(jnp.int32)
+    w = (u >> LOG2M).astype(jnp.uint32)
     # rho = leading-zero count of the remaining (32-log2m) bits + 1
-    if xp is np:
-        # numpy: bit_length via log2; w==0 -> max rho
-        nz = w != 0
-        fl = np.zeros(w.shape, np.int32)
-        fl[nz] = np.floor(np.log2(w[nz].astype(np.float64))).astype(np.int32)
-        rho = np.where(nz, (32 - LOG2M) - fl, (32 - LOG2M) + 1).astype(np.int32)
-    else:
-        shifted = (w << LOG2M).astype(jnp.uint32)
-        rho = jnp.where(w == 0, (32 - LOG2M) + 1,
-                        jax.lax.clz(shifted.astype(jnp.int32)) + 1
-                        ).astype(jnp.int32)
-    rho = xp.where(valid, rho, 0)
+    shifted = (w << LOG2M).astype(jnp.uint32)
+    rho = jnp.where(w == 0, (32 - LOG2M) + 1,
+                    jax.lax.clz(shifted.astype(jnp.int32)) + 1
+                    ).astype(jnp.int32)
+    rho = jnp.where(valid, rho, 0)
     # index space is groups × 2048: compute in the widest int available so
     # group counts inside the dense budget can't overflow the flat index
     # (callers guard the x64-off case — see lowering's sketch radix check)
-    idx_dtype = xp.int64 if _wide_ints(xp) else xp.int32
+    idx_dtype = jnp.int64 if has_x64(jnp) else jnp.int32
     flat = key.astype(idx_dtype) * idx_dtype(NUM_REGISTERS) \
         + reg.astype(idx_dtype)
-    flat = xp.where(valid, flat, 0)
-    if xp is np:
-        regs = np.zeros(num_groups * NUM_REGISTERS, np.int32)
-        np.maximum.at(regs, flat, rho)
-        return regs.reshape(num_groups, NUM_REGISTERS)
+    flat = jnp.where(valid, flat, 0)
     regs = jax.ops.segment_max(rho, flat,
                                num_segments=num_groups * NUM_REGISTERS)
     regs = jnp.maximum(regs, 0)  # empty slots: segment_max yields -inf/min
@@ -61,11 +52,6 @@ def hll_update(h, valid, key, num_groups, xp):
 
 def hll_merge(a, b, xp):
     return xp.maximum(a, b)
-
-
-def _wide_ints(xp) -> bool:
-    from tpu_olap.kernels.hashing import has_x64
-    return has_x64(xp)
 
 
 def hll_estimate(registers, xp=np, float_dtype=np.float64):

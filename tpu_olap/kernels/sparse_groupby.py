@@ -133,11 +133,7 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp):
             raise UnsupportedAggregation(
                 f"sparse group-by does not support {p.kind!r}")
 
-    if xp is np:
-        order = np.argsort(operands[0], kind="stable")
-        sorted_ops = [o[order] for o in operands]
-    else:
-        sorted_ops = list(jax.lax.sort(tuple(operands), num_keys=1))
+    sorted_ops = list(jax.lax.sort(tuple(operands), num_keys=1))
 
     skey = sorted_ops[0]
     smask = sorted_ops[1]
@@ -173,7 +169,7 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp):
             h = sorted_ops[slots[f"h:{p.name}"]]
             valid = sorted_ops[slots[f"hv:{p.name}"]]
             regs = hll_mod.hll_update(h, valid, xp.where(valid, gid, 0),
-                                      cap + 1, xp)
+                                      cap + 1)
             out[p.name] = regs[:cap]
             continue
         if p.kind == "theta":
@@ -183,7 +179,7 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp):
             # itself; gid==cap (overflow/sentinel) rows land in the pad
             # row and are sliced off
             t = theta_mod.theta_update(h, valid, gid, cap + 1,
-                                       p.theta_k, xp)
+                                       p.theta_k)
             out[p.name] = t[:cap]
             continue
     return out

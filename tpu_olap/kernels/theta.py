@@ -13,40 +13,37 @@ full, the count is exact; else (k-1)/theta with theta = k-th smallest.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tpu_olap.kernels.hashing import to_unit_float
+from tpu_olap.kernels.hashing import has_x64, to_unit_float
 
 EMPTY = 1.0  # sentinel: empty slot (hashes are in [0, 1))
 
 
-def theta_update(h, valid, key, num_groups, k, xp):
+def theta_update(h, valid, key, num_groups, k):
     """h: [N] int32 hashes; -> [K, k] sorted unit-hash table."""
-    u = to_unit_float(h, xp)
-    u = xp.where(valid, u, EMPTY)
-    g = xp.where(valid, key.astype(xp.int32), num_groups)  # invalid -> end
-    if xp is np:
-        order = np.lexsort((u, g))
-    else:
-        order = jnp.lexsort((u, g))
+    u = to_unit_float(h, jnp)
+    u = jnp.where(valid, u, EMPTY)
+    g = jnp.where(valid, key.astype(jnp.int32), num_groups)  # invalid -> end
+    order = jnp.lexsort((u, g))
     gs, us = g[order], u[order]
-    first = xp.ones(gs.shape, bool)
+    first = jnp.ones(gs.shape, bool)
     if gs.shape[0] > 1:
         dup = (gs[1:] == gs[:-1]) & (us[1:] == us[:-1])
-        first = xp.concatenate([first[:1], ~dup])
+        first = jnp.concatenate([first[:1], ~dup])
     kept = first & (gs < num_groups) & (us < EMPTY)
     # rank of each kept row within its group
-    prefix = xp.cumsum(kept.astype(xp.int32)) - kept.astype(xp.int32)
-    start = _seg_min(xp.where(kept, prefix, np.int32(2**31 - 1)), gs,
-                     num_groups + 1, xp)
+    prefix = jnp.cumsum(kept.astype(jnp.int32)) - kept.astype(jnp.int32)
+    start = _seg_min(jnp.where(kept, prefix, np.int32(2**31 - 1)), gs,
+                     num_groups + 1)
     rank = prefix - start[gs]
     ok = kept & (rank < k)
-    from tpu_olap.kernels.hashing import has_x64
-    idt = xp.int64 if has_x64(xp) else xp.int32
-    flat = xp.where(ok, gs.astype(idt) * idt(k) + rank.astype(idt), 0)
-    vals = xp.where(ok, us, EMPTY)
-    table = _scatter_min(vals, flat, num_groups * k, xp)
+    idt = jnp.int64 if has_x64(jnp) else jnp.int32
+    flat = jnp.where(ok, gs.astype(idt) * idt(k) + rank.astype(idt), 0)
+    vals = jnp.where(ok, us, EMPTY)
+    table = _scatter_min(vals, flat, num_groups * k)
     return table.reshape(num_groups, k)
 
 
@@ -77,20 +74,10 @@ def theta_estimate(table, xp=np, float_dtype=np.float64):
     return xp.where(full, est_full, count.astype(float_dtype))
 
 
-def _seg_min(v, key, n, xp):
-    if xp is np:
-        out = np.full(n, 2**31 - 1, np.int32)
-        np.minimum.at(out, key, v.astype(np.int32))
-        return out
-    import jax
+def _seg_min(v, key, n):
     return jax.ops.segment_min(v.astype(jnp.int32), key, num_segments=n)
 
 
-def _scatter_min(v, flat, n, xp):
-    if xp is np:
-        out = np.full(n, EMPTY, np.float64)
-        np.minimum.at(out, flat, v)
-        return out
-    import jax
+def _scatter_min(v, flat, n):
     return jnp.minimum(
         jax.ops.segment_min(v, flat, num_segments=n), EMPTY)

@@ -10,26 +10,21 @@ kernel.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
-import numpy as np
 
 from tpu_olap.kernels.hashing import has_x64
 
 
-def top_k_groups(metric, present, threshold: int, inverted: bool, xp):
+def top_k_groups(metric, present, threshold: int, inverted: bool):
     """metric: [K] values; present: [K] bool (group has rows).
 
     Returns (indices [threshold], valid [threshold]) — group ids of the
     top-`threshold` by metric (bottom if inverted), absent groups last.
     """
     k = min(int(threshold), metric.shape[-1])
-    v = metric.astype(xp.float64 if has_x64(xp) else xp.float32)
-    v = xp.where(present, -v if inverted else v, -xp.inf)
-    if xp is np:
-        order = np.argsort(-v, kind="stable")[:k]
-        vals = v[order]
-    else:
-        import jax
-        vals, order = jax.lax.top_k(v, k)
-    valid = vals > -xp.inf
+    v = metric.astype(jnp.float64 if has_x64(jnp) else jnp.float32)
+    v = jnp.where(present, -v if inverted else v, -jnp.inf)
+    vals, order = jax.lax.top_k(v, k)
+    valid = vals > -jnp.inf
     return order, valid
