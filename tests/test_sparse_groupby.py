@@ -307,3 +307,201 @@ def test_sparse_theta_multichip_gather():
     eng = _engine(num_shards=8, sparse_merge="gather")
     check_query(eng,
                 "SELECT a, theta_sketch(b) AS db FROM t GROUP BY a")
+
+
+# --------------------------------------------------------------------------
+# The compact tables are read at the sorted runs' boundaries (PR 30): table
+# for table equal to a plain numpy reference that knows nothing of sorting.
+
+def _agg(name, kind, field=None, acc=np.int64, filter_fn=None):
+    from tpu_olap.kernels.groupby import AggPlan
+    return AggPlan(name, kind, (field,) if field else (), acc, filter_fn)
+
+
+def _numpy_tables(key, mask, env, plans, cap):
+    """The compact tables by definition: slot i is the i-th smallest
+    present key; one boolean selection a group, numpy's own reductions."""
+    from tpu_olap.kernels.groupby import _ident
+    from tpu_olap.kernels.sparse_groupby import SENTINEL
+    present = np.unique(key[mask])
+    out = {"_count": np.int32(len(present)),
+           "_keys": np.full(cap, SENTINEL, np.int64),
+           "_rows": np.zeros(cap, np.int32)}
+    for p in plans:
+        acc = np.dtype(p.acc_dtype)
+        out[p.name] = np.full(cap, _ident(acc, p.kind)
+                              if p.kind in ("min", "max") else 0, acc)
+        if p.kind in ("min", "max"):
+            out[f"_nn_{p.name}"] = np.zeros(cap, np.int32)
+    for i, k in enumerate(present[:cap]):
+        sel = mask & (key == k)
+        out["_keys"][i] = k
+        out["_rows"][i] = sel.sum()
+        for p in plans:
+            m = sel if p.filter_fn is None else sel & np.asarray(
+                p.filter_fn(env, {}))
+            if p.kind == "count":
+                out[p.name][i] = m.sum()
+                continue
+            nulls = env["nulls"].get(p.fields[0])
+            if nulls is not None:
+                m = m & ~nulls
+            x = env["cols"][p.fields[0]][m].astype(p.acc_dtype)
+            if p.kind == "sum":
+                out[p.name][i] = x.sum(dtype=p.acc_dtype)  # int64 wraps
+            else:
+                out[f"_nn_{p.name}"][i] = m.sum()
+                if x.size:
+                    out[p.name][i] = x.min() if p.kind == "min" else x.max()
+    return out
+
+
+def _positive(env, consts):
+    return env["cols"]["f"] > 0
+
+
+def _boundary_cases():
+    rng = np.random.default_rng(30)
+    n = 257
+    big = np.int64(1) << 62
+    ones = np.ones(n, bool)
+    v = rng.integers(-1000, 1000, n).astype(np.int64)
+    sums = [_agg("s", "sum", "v"), _agg("n", "count")]
+
+    def env(**cols):
+        return {"cols": cols, "nulls": {}}
+
+    yield "every-row-masked", rng.integers(0, 9, n), np.zeros(n, bool), \
+        env(v=v), sums, 8
+    yield "one-group-spans-all-rows", np.full(n, 5), ones, env(v=v), sums, 8
+    yield "count-equals-cap", np.arange(n) % 16 * 3, ones, env(v=v), sums, 16
+    yield "count-past-cap", np.arange(n) % 40, rng.random(n) < 0.9, \
+        env(v=v), sums, 16
+    # the first and the last sorted row are groups of one row, and a
+    # masked tail follows the last
+    edge = np.r_[0, np.full(n - 12, 7), 99, np.full(10, 3)]
+    yield "first-and-last-row-alone", edge, np.r_[np.ones(n - 10, bool),
+                                                  np.zeros(10, bool)], \
+        env(v=v), sums, 8
+    # the running prefix passes 2^63 and wraps; no group's own sum does
+    wrap = np.r_[np.full(6, big), np.full(6, -big), np.full(5, big), v[17:]]
+    yield "prefix-wraps-past-2^63", np.arange(n) // 2, ones, \
+        env(v=wrap), sums, 256
+    f = rng.integers(-3, 4, n)
+    yield "filtered-sum-and-count", rng.integers(0, 30, n), \
+        rng.random(n) < 0.8, env(v=v, f=f), \
+        [_agg("s", "sum", "v", filter_fn=_positive),
+         _agg("n", "count", filter_fn=_positive), _agg("all", "count")], 32
+    w = np.round(rng.random(n) * 50 - 25, 4)
+    nul = {"cols": {"w": w, "v": v},
+           "nulls": {"w": rng.random(n) < 0.4}}
+    yield "min-max-with-nulls", rng.integers(0, 60, n), \
+        rng.random(n) < 0.8, nul, \
+        [_agg("lo", "min", "w", np.float64), _agg("hi", "max", "w",
+                                                  np.float64),
+         _agg("xv", "max", "v"),
+         _agg("fl", "min", "v", filter_fn=lambda e, c: e["cols"]["v"] > 0)], \
+        64
+    yield "float64-sum", rng.integers(0, 20, n), rng.random(n) < 0.9, \
+        env(w=w * 1e6), [_agg("fs", "sum", "w", np.float64),
+                         _agg("n", "count")], 32
+
+
+@pytest.mark.parametrize("case", list(_boundary_cases()),
+                         ids=[c[0] for c in _boundary_cases()])
+def test_compact_tables_equal_the_numpy_reference(case):
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_olap.kernels.sparse_groupby import sparse_group_reduce
+    EngineConfig().apply_x64()   # int64 keys and sums, as under an Engine
+    _, key, mask, env, plans, cap = case
+    key = np.asarray(key, np.int64)
+
+    @jax.jit
+    def run(key, mask, env):
+        return sparse_group_reduce(key, mask, env, plans, cap, {}, jnp)
+
+    got = jax.device_get(run(key, mask, env))
+    want = _numpy_tables(key, mask, env, plans, cap)
+    assert int(got["_count"]) == int(want["_count"])
+    if want["_count"] > cap:
+        return  # an overflowing attempt owes the true count and no table
+    assert set(got) == set(want)
+    for name, table in want.items():
+        assert got[name].dtype == table.dtype, name
+        if table.dtype.kind == "f":
+            # the tolerance the parity oracle holds a float sum to
+            np.testing.assert_allclose(got[name], table, rtol=1e-9,
+                                       atol=1e-6, err_msg=name)
+        else:
+            np.testing.assert_array_equal(got[name], table, err_msg=name)
+
+
+def _dispatch_forms(eng):
+    def walk(tree):
+        yield tree
+        for c in tree.get("children", []):
+            yield from walk(c)
+    return [s["attrs"].get("reduce_form")
+            for s in walk(eng.tracer.last.to_json())
+            if s["name"] == "dispatch"]
+
+
+def test_no_scatter_in_a_sum_and_count_program_and_a_sketch_says_so():
+    """Integer sums, counts, `_rows` and `_keys` scatter no row: the
+    program of such a plan holds no scatter primitive at all. A sketch's
+    [cap, m] state keeps its scatter-max, and the record says so."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_olap.kernels.sparse_groupby import (sparse_group_reduce,
+                                                 sparse_reduce_form)
+    EngineConfig().apply_x64()
+    plans = [_agg("s", "sum", "v"), _agg("n", "count"),
+             _agg("fn", "count", filter_fn=_positive)]
+    n = 4096
+    env = {"cols": {"v": jnp.arange(n, dtype=jnp.int64),
+                    "f": jnp.arange(n, dtype=jnp.int64) - 7}, "nulls": {}}
+    jaxpr = jax.make_jaxpr(lambda k, m, e: sparse_group_reduce(
+        k, m, e, plans, 64, {}, jnp))(
+        jnp.arange(n, dtype=jnp.int64) % 50, jnp.ones(n, bool), env)
+    assert "scatter" not in str(jaxpr)
+    assert "sort" in str(jaxpr)
+    assert sparse_reduce_form(plans) == "boundary"
+    for kind, acc in (("min", np.int64), ("sum", np.float64),
+                      ("hll", np.int32), ("theta", np.int64)):
+        assert sparse_reduce_form(
+            plans + [_agg("x", kind, "v", acc)]) == "scatter", kind
+
+    eng = _engine()
+    eng.sql("SELECT a, b, approx_count_distinct(c) AS d, count(*) AS n "
+            "FROM t GROUP BY a, b")
+    rec = eng.history[-1]
+    assert rec["reduce_path"] == "sparse" and "fallback_reason" not in rec
+    assert rec["reduce_form"] == "scatter"
+    assert _dispatch_forms(eng) == ["scatter"]
+
+
+def test_record_and_dispatch_span_say_boundary():
+    eng = _engine()
+    check_query(eng, "SELECT a, b, sum(v) AS sv, count(*) AS n FROM t "
+                     "WHERE w < 40 GROUP BY a, b")
+    rec = eng.history[-1]
+    assert rec["reduce_path"] == "sparse" and rec["sparse"]
+    assert rec["reduce_form"] == "boundary"
+    assert _dispatch_forms(eng) == ["boundary"]
+
+
+def test_sparse_gspmd_spelling_parity(monkeypatch):
+    """A mesh that spans processes hands the whole sparse program to
+    GSPMD over global shapes (no fan-out, no broker merge): the boundary
+    reads partition like the rest of it."""
+    from tpu_olap.executor import sharding as sh
+    monkeypatch.setattr(sh, "is_multihost", lambda mesh: True)
+    eng = _engine(num_shards=8)
+    check_query(eng, SQL)
+    rec = eng.history[-1]
+    assert rec["sparse"] and rec["num_shards"] == 8
+    assert eng.runner.mesh_program == "gspmd"
+    assert rec["reduce_form"] == "scatter"   # SQL holds a min and a max

@@ -76,6 +76,11 @@ def test_template_equals_the_reference(eng, data, name):
         # a dozen dense slots, Q12 as many as l_shipmode has values
         assert rec["pallas_reason"]
         assert rec["reduce_form"] == "compare"
+    elif REDUCE_PATH[name] == "sparse":
+        # q3 and q10 hold an int64 sum and the row count: every [cap]
+        # table is read at the sorted runs' boundaries, no row scattered
+        assert rec["reduce_form"] == "boundary"
+        assert _dispatch_forms(eng) == ["boundary"]
     else:
         assert "reduce_form" not in rec
     if REDUCE_PATH[name] == "sparse":

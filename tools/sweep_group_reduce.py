@@ -15,6 +15,17 @@ the K past it are there to see where the two forms cross.
         described v5e: compile seconds and temporary bytes, nothing runs
     python tools/sweep_group_reduce.py --allow-cpu --rows 200000   # rehearsal
 
+`--sorted` sweeps the sparse group-by's reduce instead
+(`kernels/sparse_groupby.py`, PERF.md section 6, PR 30): group ids sorted
+into `--runs` runs with a masked tail, as they leave the sort, for an int64
+sum, an int32 count, an int64 min and a float64 sum; `segment_*` as XLA's
+scatter has it, the same with `indices_are_sorted=True`, and the boundary
+form (an integer sum is a difference of prefix sums at the runs' first
+rows; a min or a float sum a segmented running reduce read at their last)
+with each of three ways to the runs' first rows: a binary search of the
+slot numbers in the ids, a one-operand sort of the boundary positions, a
+scatter-min of the positions.
+
 Prints one JSON line a row and writes them to `--out`.
 """
 
@@ -68,13 +79,169 @@ def _inputs(n, dtype, k, seed=7):
     return (rng.random(n) < 0.5).astype(np.int32), key   # a filtered count
 
 
+RUNS = tuple(1 << e for e in range(10, 22))
+SORTED_ROWS = (36_000_000, 6_030_000)
+AGGS = {"int64_sum": ("int64", "sum"), "int32_count": ("int32", "sum"),
+        "int64_min": ("int64", "min"), "float64_sum": ("float64", "sum")}
+
+
+def _sorted_inputs(n, agg, runs, seed=7):
+    """(v, gid, cap): ids sorted into about 0.9 * runs runs over the first
+    98% of the rows, the rest the masked tail (gid == cap, v the
+    aggregate's identity), as `_sorted_segments` hands them on."""
+    rng = np.random.default_rng(seed)
+    dtype, kind = AGGS[agg]
+    cap = runs
+    n_valid = n - n // 50
+    boundary = rng.random(n_valid) < 0.9 * runs / n_valid
+    boundary[0] = True
+    gid = np.full(n, cap, np.int32)
+    gid[:n_valid] = np.minimum(np.cumsum(boundary, dtype=np.int32) - 1, cap)
+    if dtype == "int64":    # rows that pass int32, of either sign
+        v = rng.integers(-(1 << 40), 1 << 40, n, dtype=np.int64)
+    elif agg == "int32_count":
+        v = (rng.random(n) < 0.5).astype(np.int32)
+    else:
+        v = np.round(rng.random(n) * 1e4, 2)
+    v[n_valid:] = groupby._ident(np.dtype(dtype), kind) if kind == "min" \
+        else 0
+    return v, gid, cap
+
+
+def _sorted_reference(v, gid, cap, kind):
+    """numpy's answer by `reduceat` over the runs' first rows."""
+    first = np.flatnonzero(np.concatenate([[True], gid[1:] != gid[:-1]]))
+    op = np.add if kind == "sum" else np.minimum
+    want = np.full(cap + 1, groupby._ident(v.dtype, kind)
+                   if kind == "min" else 0, v.dtype)
+    want[gid[first]] = op.reduceat(v, first)
+    return want[:cap]
+
+
+def _starts_search(gid, cap):
+    return jnp.searchsorted(gid, jnp.arange(cap + 1, dtype=gid.dtype),
+                            side="left").astype(jnp.int32)
+
+
+def _first_rows(gid):
+    n = gid.shape[0]
+    boundary = jnp.concatenate([jnp.ones((1,), bool), gid[1:] != gid[:-1]])
+    return boundary, jnp.arange(n, dtype=jnp.int32)
+
+
+def _starts_sort(gid, cap):
+    # the boundary positions sorted to the front; the tail's own first row
+    # is the last of them, and fills every slot past the present runs
+    boundary, iota = _first_rows(gid)
+    n_valid = jnp.sum(gid < cap, dtype=jnp.int32)
+    pos = jax.lax.sort(jnp.where(boundary, iota, n_valid), is_stable=False)
+    return jnp.minimum(pos[:cap + 1], n_valid)
+
+
+def _starts_scatter(gid, cap):
+    _, iota = _first_rows(gid)
+    n_valid = jnp.sum(gid < cap, dtype=jnp.int32)
+    return jnp.full((cap + 1,), n_valid, jnp.int32).at[gid].min(
+        iota, indices_are_sorted=True)
+
+
+def _segment(v, gid, cap, kind, is_sorted):
+    f = jax.ops.segment_sum if kind == "sum" else jax.ops.segment_min
+    return f(v, gid, num_segments=cap + 1, indices_are_sorted=is_sorted)[:cap]
+
+
+def _boundary(v, gid, cap, kind, starts_fn):
+    starts = starts_fn(gid, cap)
+    if kind == "sum" and jnp.issubdtype(v.dtype, jnp.integer):
+        prefix = jnp.cumsum(v)
+        before = jnp.where(starts > 0, prefix[jnp.maximum(starts - 1, 0)], 0)
+        return before[1:] - before[:-1]
+    # a segmented running reduce, read at each run's last row
+    op = jnp.add if kind == "sum" else jnp.minimum
+    boundary, _ = _first_rows(gid)
+
+    def combine(a, b):
+        return a[0] | b[0], jnp.where(b[0], b[1], op(a[1], b[1]))
+    _, running = jax.lax.associative_scan(combine, (boundary, v))
+    ident = groupby._ident(v.dtype, kind) if kind == "min" else 0
+    return jnp.where(starts[1:] > starts[:-1],
+                     running[jnp.maximum(starts[1:] - 1, 0)], ident)
+
+
+SORTED_FORMS = {
+    "segment": functools.partial(_segment, is_sorted=False),
+    "segment_sorted": functools.partial(_segment, is_sorted=True),
+    "boundary_search": functools.partial(_boundary, starts_fn=_starts_search),
+    "boundary_sort": functools.partial(_boundary, starts_fn=_starts_sort),
+    "boundary_scatter": functools.partial(_boundary,
+                                          starts_fn=_starts_scatter),
+}
+
+
+def _measure(fn, spec, inputs, want, reps, rec):
+    """Compile `fn` for `spec`; with `inputs`, run it: the median of `reps`
+    warm runs and whether the table is `want` (floats: to 1e-9)."""
+    t0 = time.perf_counter()
+    compiled = jax.jit(fn).lower(*spec).compile()
+    rec["compile_s"] = round(time.perf_counter() - t0, 2)
+    ma = compiled.memory_analysis()
+    if ma is not None:
+        rec["temp_bytes"] = int(ma.temp_size_in_bytes)
+    if inputs is not None:
+        got = compiled(*inputs)
+        got.block_until_ready()
+        ms = []
+        for _ in range(reps):
+            t0 = time.perf_counter()
+            compiled(*inputs).block_until_ready()
+            ms.append((time.perf_counter() - t0) * 1e3)
+        rec["ms"] = round(statistics.median(ms), 3)
+        got = np.asarray(got)
+        rec["equal"] = bool(
+            np.array_equal(got, want) if got.dtype.kind != "f"
+            else np.allclose(got, want, rtol=1e-9, atol=0))
+    print(json.dumps(rec), flush=True)
+    return rec
+
+
+def sweep_sorted(args, sharding):
+    out = []
+    for n in args.rows or SORTED_ROWS:
+        for agg in args.aggs:
+            dtype, kind = AGGS[agg]
+            for runs in args.runs:
+                inputs = want = None
+                cap = runs
+                if not args.compile_only:
+                    v_np, gid_np, cap = _sorted_inputs(n, agg, runs)
+                    want = _sorted_reference(v_np, gid_np, cap, kind)
+                    inputs = (jnp.asarray(v_np), jnp.asarray(gid_np))
+                spec = [jax.ShapeDtypeStruct((n,), np.dtype(d),
+                                             sharding=sharding)
+                        for d in (dtype, "int32")]
+                for name in args.sorted_forms:
+                    fn = functools.partial(SORTED_FORMS[name], cap=cap,
+                                           kind=kind)
+                    out.append(_measure(
+                        fn, spec, inputs, want, args.reps,
+                        dict(rows=n, agg=agg, runs=runs, form=name)))
+    return out
+
+
 def main():
     ap = argparse.ArgumentParser()
+    ap.add_argument("--sorted", action="store_true",
+                    help="the sparse group-by's reduce over sorted runs")
+    ap.add_argument("--runs", type=int, nargs="*", default=list(RUNS))
+    ap.add_argument("--aggs", nargs="*", default=list(AGGS),
+                    choices=list(AGGS))
+    ap.add_argument("--sorted-forms", nargs="*", default=list(SORTED_FORMS),
+                    choices=list(SORTED_FORMS))
     ap.add_argument("--compile-only", action="store_true")
     ap.add_argument("--allow-cpu", action="store_true")
     ap.add_argument("--reps", type=int, default=3)
     ap.add_argument("--ks", type=int, nargs="*", default=list(KS))
-    ap.add_argument("--rows", type=int, nargs="*", default=list(ROWS))
+    ap.add_argument("--rows", type=int, nargs="*", default=None)
     ap.add_argument("--forms", nargs="*", default=list(FORMS),
                     choices=list(FORMS))
     ap.add_argument("--block-bytes", type=int, nargs="*",
@@ -99,50 +266,41 @@ def main():
             sys.exit(f"no chip: {dev.platform}")
         print(json.dumps({"device": dev.device_kind}), flush=True)
 
-    out = []
-    for n in args.rows:
-        n = -(-n // BLOCK) * BLOCK
-        for dtype in ("int64", "int32"):
-            for k in args.ks:
-                if not args.compile_only:
-                    v_np, key_np = _inputs(n, dtype, k)
-                    v, key = jnp.asarray(v_np), jnp.asarray(key_np)
-                    want = np.zeros(k, v_np.dtype)
-                    np.add.at(want, key_np, v_np)
-                for name, block in [(f, b) for f in args.forms
-                                    for b in (args.block_bytes
-                                              if f == "compare" else [0])]:
-                    form = FORMS[name]
-                    groupby._CMP_BLOCK_BYTES = block or \
-                        groupby._CMP_BLOCK_BYTES
-                    spec = [jax.ShapeDtypeStruct((n,), np.dtype(d),
-                                                 sharding=sharding)
-                            for d in (dtype, "int32")]
-                    t0 = time.perf_counter()
-                    compiled = jax.jit(functools.partial(form, k=k)) \
-                        .lower(*spec).compile()
-                    rec = dict(rows=n, dtype=dtype, k=k, form=name,
-                               **({"block_bytes": block} if block else {}),
-                               compile_s=round(time.perf_counter() - t0, 2))
-                    ma = compiled.memory_analysis()
-                    if ma is not None:
-                        rec["temp_bytes"] = int(ma.temp_size_in_bytes)
-                    if not args.compile_only:
-                        got = compiled(v, key)
-                        got.block_until_ready()
-                        ms = []
-                        for _ in range(args.reps):
-                            t0 = time.perf_counter()
-                            compiled(v, key).block_until_ready()
-                            ms.append((time.perf_counter() - t0) * 1e3)
-                        rec["ms"] = round(statistics.median(ms), 3)
-                        rec["equal"] = bool(
-                            np.array_equal(np.asarray(got), want))
-                    out.append(rec)
-                    print(json.dumps(rec), flush=True)
+    if args.sorted:
+        out = sweep_sorted(args, sharding)
+    else:
+        out = sweep_dense(args, sharding)
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     with open(args.out, "w") as f:
         json.dump(out, f, indent=1)
+
+
+def sweep_dense(args, sharding):
+    out = []
+    for n in args.rows or ROWS:
+        n = -(-n // BLOCK) * BLOCK
+        for dtype in ("int64", "int32"):
+            for k in args.ks:
+                inputs = want = None
+                if not args.compile_only:
+                    v_np, key_np = _inputs(n, dtype, k)
+                    inputs = (jnp.asarray(v_np), jnp.asarray(key_np))
+                    want = np.zeros(k, v_np.dtype)
+                    np.add.at(want, key_np, v_np)
+                spec = [jax.ShapeDtypeStruct((n,), np.dtype(d),
+                                             sharding=sharding)
+                        for d in (dtype, "int32")]
+                for name, block in [(f, b) for f in args.forms
+                                    for b in (args.block_bytes
+                                              if f == "compare" else [0])]:
+                    groupby._CMP_BLOCK_BYTES = block or \
+                        groupby._CMP_BLOCK_BYTES
+                    out.append(_measure(
+                        functools.partial(FORMS[name], k=k), spec, inputs,
+                        want, args.reps,
+                        dict(rows=n, dtype=dtype, k=k, form=name,
+                             **({"block_bytes": block} if block else {}))))
+    return out
 
 
 if __name__ == "__main__":

@@ -1962,7 +1962,14 @@ class QueryRunner:
         D × budget); "gather" all-gathers every chip's table. Returns
         (partials dict, count); exchange partial arrays are [D·cap_owner]
         slot tables (SENTINEL-keyed empties), others are [cap] compacts."""
-        with _span("dispatch", sparse=True) as sp:
+        from tpu_olap.kernels.sparse_groupby import sparse_reduce_form
+
+        # whether every [cap] table is read at the sorted runs' boundaries
+        # or an aggregate still scatters: the kernel's own function of the
+        # plan's aggregate kinds and dtypes, as the dense `reduce_form` is
+        # of num_groups
+        metrics["reduce_form"] = sparse_reduce_form(plan.agg_plans)
+        with _span("dispatch", sparse=True, **_form_attr(metrics)) as sp:
             out = self._run_sparse_inner(plan, metrics)
             sp.set(jit_cache_hit=metrics.get("jit_cache_hit"),
                    result_groups=metrics.get("result_groups"),

@@ -9,12 +9,24 @@ XLA's sort is fast on TPU and everything stays static-shaped:
   1. mixed-radix key in int64 (the radix product may exceed int32);
      masked rows get the +inf sentinel so they sort to the tail;
   2. one multi-operand `lax.sort` carries the key and every aggregate
-     input along;
+     input along (not the mask: a sorted row is masked exactly where its
+     key is the sentinel; not stable: no table depends on the order of
+     rows inside a run);
   3. group boundaries (key[i] != key[i-1]) -> cumsum -> dense ids in
      [0, n_unique); ids clip to a `cap` slot table (+1 overflow slot that
-     also swallows the sentinel tail);
-  4. segment reduces into [cap] arrays; slot i holds the i-th smallest
-     present group key, so results are already compact AND sorted;
+     also swallows the sentinel tail). After the sort a group is a
+     contiguous run: `starts[g]`, the run's first row, comes from a
+     second, one-operand sort of the first rows' positions;
+  4. the [cap] tables are READ AT THE RUN BOUNDARIES: a row count is
+     starts[g+1] - starts[g], a key is the run's first row's, an integer
+     sum or count is the difference of an inclusive prefix sum at the
+     run's two ends (wrapping arithmetic: exact). No row is scattered.
+     What a difference of prefixes cannot give keeps a segment reduce
+     over the sorted ids — a floating-point sum (the prefix's rounding
+     error is not the group's), min / max, the sketches' [cap, m] state —
+     and `sparse_reduce_form` says "scatter" of such a plan. Slot i holds
+     the i-th smallest present group key, so results are already compact
+     AND sorted;
   5. "_count" reports the true unique count — if it exceeds cap the
      runner re-runs with the next power of two (same adaptive-cap pattern
      as executor.packing).
@@ -87,6 +99,43 @@ def _seg_ext(v, gid, cap, kind, xp):
     return f(v, gid, num_segments=cap + 1)[:cap]
 
 
+def _run_starts(skey, cap, xp):
+    """[cap + 1] int32: starts[g] is the first row of the g-th run of equal
+    keys; for a slot past the last present group (and for g == cap when
+    nothing overflows) the row where the SENTINEL tail begins, so an empty
+    slot is an empty run. The first rows' positions, every other row
+    standing in as the tail's first, sorted: a second, one-operand sort
+    whose cost does not depend on cap (a binary search of the slot
+    numbers in the run ids costs 21 ns a slot a round: less up to 2^16
+    slots, 1.2 s at the budget's 2^21), and no row is scattered."""
+    import jax
+
+    n = skey.shape[0]
+    valid = skey != SENTINEL
+    first = valid & xp.concatenate([xp.ones((1,), bool),
+                                    skey[1:] != skey[:-1]])
+    tail = valid.sum(dtype=xp.int32)
+    pos = xp.where(first, xp.arange(n, dtype=xp.int32), tail)
+    if n < cap + 1:
+        pos = xp.concatenate([pos, xp.broadcast_to(tail, (cap + 1 - n,))])
+    return jax.lax.sort(pos, is_stable=False)[:cap + 1]
+
+
+def sparse_reduce_form(plans) -> str:
+    """Which program the sparse reduce is, from the plan's aggregate kinds
+    and accumulator dtypes and nothing else: "boundary" where every [cap]
+    table is read at the boundaries of the sorted runs, "scatter" where an
+    aggregate still scatters rows into its table — a sketch's [cap, m]
+    state, a min / max, a floating-point sum (a difference of prefix sums
+    would carry the prefix's rounding error, not the group's)."""
+    return "boundary" if all(_at_boundaries(p) for p in plans) else "scatter"
+
+
+def _at_boundaries(p) -> bool:
+    return p.kind == "count" or (
+        p.kind == "sum" and np.issubdtype(np.dtype(p.acc_dtype), np.integer))
+
+
 def sparse_group_reduce(key, mask, env, plans, cap, consts, xp):
     """[N] int64 keys + mask -> compacted per-group partials.
 
@@ -98,7 +147,9 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp):
 
     key = xp.where(mask, key, SENTINEL)
 
-    operands = [key, mask]
+    # the mask does not ride the sort: a sorted row is masked exactly
+    # where its key is the SENTINEL
+    operands = [key]
     slots = {}
 
     def carry(name, arr):
@@ -107,9 +158,9 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp):
 
     for p in plans:
         m = mask if p.filter_fn is None else (mask & p.filter_fn(env, consts))
-        if p.filter_fn is not None:
-            carry(f"m:{p.name}", m)
         if p.kind == "count":
+            if p.filter_fn is not None:
+                carry(f"m:{p.name}", m)
             continue
         if p.kind in ("sum", "min", "max"):
             x = env["cols"][p.fields[0]]
@@ -133,36 +184,59 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp):
             raise UnsupportedAggregation(
                 f"sparse group-by does not support {p.kind!r}")
 
-    sorted_ops = list(jax.lax.sort(tuple(operands), num_keys=1))
+    # no table depends on the order of the rows inside a run, so the sort
+    # need not be stable: XLA spells stability as one more operand, an
+    # iota that breaks ties
+    sorted_ops = list(jax.lax.sort(tuple(operands), num_keys=1,
+                                   is_stable=False))
 
     skey = sorted_ops[0]
-    smask = sorted_ops[1]
 
     gid, count = _sorted_segments(skey, cap, xp)
+    starts = _run_starts(skey, cap, xp)
 
-    def seg_sum(v):
-        return _seg_sum(v, gid, cap, xp)
+    def run_sum(v, acc_dtype):
+        """Exact integer sum of v over every run: the inclusive prefix
+        sum read at the run's last row less its value before the run's
+        first. Wrapping arithmetic makes the difference exact whatever
+        the prefix holds."""
+        prefix = xp.cumsum(v, dtype=acc_dtype)
+        before = xp.where(starts > 0, prefix[xp.maximum(starts - 1, 0)], 0)
+        return before[1:] - before[:-1]
 
-    def seg_ext(v, kind):
-        return _seg_ext(v, gid, cap, kind, xp)
+    def run_count(m):
+        # a count is at most N: an int32 prefix holds it
+        return run_sum(m, np.int32)
 
-    out = {"_count": count, "_rows": seg_sum(smask.astype(np.int32))}
-    out["_keys"] = seg_ext(skey, "min")  # all equal per group; SENTINEL fills
+    def segment(f, v):
+        # what a difference of prefixes cannot give: XLA's segment reduce,
+        # told that the ids are sorted
+        return f(v, gid, num_segments=cap + 1, indices_are_sorted=True)[:cap]
+
+    # inside a non-SENTINEL run every row is unmasked: its length is its
+    # row count. The key of slot g is its first row's; past the present
+    # groups that row is in the SENTINEL tail, or out of bounds
+    out = {"_count": count, "_rows": starts[1:] - starts[:-1],
+           "_keys": skey.at[starts[:cap]].get(mode="fill",
+                                              fill_value=SENTINEL)}
 
     for p in plans:
-        m = smask if p.filter_fn is None else sorted_ops[slots[f"m:{p.name}"]]
         if p.kind == "count":
-            # unfiltered COUNT(*) is the _rows reduction, already done
-            out[p.name] = out["_rows"].astype(p.acc_dtype) \
-                if p.filter_fn is None else seg_sum(m.astype(p.acc_dtype))
+            out[p.name] = (out["_rows"] if p.filter_fn is None else
+                           run_count(sorted_ops[slots[f"m:{p.name}"]])
+                           ).astype(p.acc_dtype)
             continue
         if p.kind == "sum":
-            out[p.name] = seg_sum(sorted_ops[slots[f"v:{p.name}"]])
+            v = sorted_ops[slots[f"v:{p.name}"]]
+            out[p.name] = run_sum(v, p.acc_dtype) if _at_boundaries(p) \
+                else segment(jax.ops.segment_sum, v)
             continue
         if p.kind in ("min", "max"):
-            out[p.name] = seg_ext(sorted_ops[slots[f"v:{p.name}"]], p.kind)
-            out[f"_nn_{p.name}"] = seg_sum(
-                sorted_ops[slots[f"nn:{p.name}"]].astype(np.int32)) \
+            out[p.name] = segment(
+                jax.ops.segment_min if p.kind == "min"
+                else jax.ops.segment_max, sorted_ops[slots[f"v:{p.name}"]])
+            out[f"_nn_{p.name}"] = \
+                run_count(sorted_ops[slots[f"nn:{p.name}"]]) \
                 if f"nn:{p.name}" in slots else out["_rows"]
             continue
         if p.kind == "hll":
