@@ -15,8 +15,10 @@ Phases, one chip (default):
   device   jax.devices()[0].platform must be "tpu"
   answers  6,000,000 lineorder rows; all 13 SSB queries, one min/max and
            one HLL query (float64 finals: the packed buffer's f32-pair
-           slabs, TPU only) against the pandas interpreter on the same
-           data (bench/parity.py::check_query)
+           slabs, TPU only) and a TopN over a 200,000-wide key (sparse
+           sort, threshold on the device, ties at the threshold) against
+           the pandas interpreter on the same data
+           (bench/parity.py::check_query)
   size     75,000,000 rows (SF100 on a v5e-8 / 8 chips) written as parquet,
            stream-ingested, then a QueryServer answers all 13 over POST /sql
            twice each; totals vs pyarrow, HTTP frame sha256 == Engine.sql's,
@@ -24,8 +26,9 @@ Phases, one chip (default):
            POST /debug/profile?ms=N under load: the capture holds the
            program's spans and no Python-tracer events
 With --chips 4, only: one-device engine vs num_shards=4 engine on 24,000,000
-rows — sha256 parity, per-chip window, sparse fan-out, sys.devices, bytes
-resident on every chip.
+rows — sha256 parity, per-chip window, sparse fan-out (a group-by and a
+TopN whose metric ties at the threshold), sys.devices, bytes resident on
+every chip.
 
 The LAST stdout line is one JSON object, {"ok": ..., "device": {...}}; exit
 code 0 only with "ok": true. `--allow-cpu` is the CPU REHEARSAL of the
@@ -68,6 +71,16 @@ F64_QUERIES = {
         FROM lineorder JOIN supplier ON lo_suppkey = s_suppkey
         GROUP BY s_region""", ("u",)),
 }
+
+
+# A TopN over a key too wide for the Pallas kernel and the compare form:
+# the sparse sort group-by serves it, one chip applies the threshold on
+# the device (100 group rows leave it), a mesh's broker merges whole tables
+# and ranks on the host. sum(lo_quantity) over ~30 rows a part ties at the
+# threshold for certain: the rows kept there are the first by part key.
+WIDE_TOPN_SQL = """
+    SELECT lo_partkey, sum(lo_quantity) AS qty FROM lineorder
+    GROUP BY lo_partkey ORDER BY qty DESC LIMIT 100"""
 
 
 class SmokeFailure(AssertionError):
@@ -146,11 +159,28 @@ def phase_answers(rows: int, seed: int, use_pallas: str):
             say(f"answers: {name} parity OK rows={len(frame)} "
                 f"path={rec.get('path')} packed={rec.get('packed')} "
                 f"device+oracle={time.perf_counter() - t0:.1f}s")
+        t0 = time.perf_counter()
+        frame = check_query(eng, WIDE_TOPN_SQL, label="wide-topn")
+        rec = last_record(eng)
+        check_device_record(rec, "wide-topn", 1)
+        # at a rehearsal's size the key is narrow and the dense plan stays
+        wide = rec.get("topn_group_space", 0) > 65_536
+        check(rec.get("query_type") == "topN" and len(frame) == 100
+              and (not wide or (rec.get("reduce_path") == "sparse"
+                                and rec.get("topn_rows_fetched") == 100)),
+              f"wide-topn: not a TopN with the threshold on the device: "
+              f"{rec}")
+        say(f"answers: wide-topn parity OK K={rec.get('topn_group_space')} "
+            f"path={rec.get('reduce_path')} rows_fetched="
+            f"{rec.get('topn_rows_fetched')} cap={rec.get('sparse_cap')} "
+            f"sha256={digest(frame)[:12]} "
+            f"device+oracle={time.perf_counter() - t0:.1f}s")
     finally:
         eng.clear_cache()
         eng.close()
-    say(f"answers: {len(queries)}/{len(queries)} queries match the "
-        f"pandas interpreter ({len(QUERIES)} SSB + {sorted(F64_QUERIES)})")
+    say(f"answers: {len(queries) + 1}/{len(queries) + 1} queries match the "
+        f"pandas interpreter ({len(QUERIES)} SSB + {sorted(F64_QUERIES)} + "
+        "wide-topn)")
 
 
 def post_sql(conn, sql: str):
@@ -447,6 +477,27 @@ def phase_mesh(rows: int, seed: int, use_pallas: str, chips: int,
     say(f"mesh: sparse fan-out sha256 OK groups={rec.get('result_groups')} "
         f"merge={rec.get('sparse_merge')} cold 1-chip={t1 - t0:.1f}s "
         f"mesh={t2 - t1:.1f}s (compile included)")
+    # the same table ranked: one chip applies the threshold in its program,
+    # the mesh's broker merges four whole tables and ranks on the host; the
+    # row count ties at the threshold, so equal frames mean the fan-out and
+    # the merge keep the tie rule (the key's own order). The mesh's
+    # per-chip program is the group-by's above, found in the compile cache
+    topn_sql = sparse_sql.replace("ORDER BY lo_suppkey LIMIT 20",
+                                  "ORDER BY n DESC LIMIT 100")
+    a, b = s1.sql(topn_sql), sm.sql(topn_sql)
+    rec1, rec = last_record(s1), last_record(sm)
+    check_device_record(rec1, "sparse topN 1-chip", 1)
+    check_device_record(rec, "sparse topN fan-out", chips)
+    check(rec1.get("query_type") == rec.get("query_type") == "topN"
+          and rec1.get("sparse") and rec.get("sparse")
+          and rec1.get("topn_rows_fetched") == 100
+          and len(a) == 100 and digest(a) == digest(b),
+          f"sparse topN: parity or path wrong: 1-chip {rec1}, mesh {rec}")
+    n = a["n"].to_numpy()
+    say(f"mesh: sparse topN sha256 OK K={rec.get('topn_group_space')} "
+        f"rows fetched 1-chip={rec1.get('topn_rows_fetched')} "
+        f"mesh={rec.get('topn_rows_fetched')} "
+        f"ties among the 100 kept={100 - len(set(n.tolist()))}")
     for e in (s1, sm):
         e.clear_cache()
         e.close()

@@ -402,6 +402,20 @@ def _boundary_cases():
          _agg("xv", "max", "v"),
          _agg("fl", "min", "v", filter_fn=lambda e, c: e["cols"]["v"] > 0)], \
         64
+    # a min / max of a column stored in 32 bits or fewer is reduced as
+    # int32 and widened a slot: the ends of each width's range, groups a
+    # filter empties, and beside them a long past 2^31, which is not
+    i8 = rng.integers(-128, 128, n).astype(np.int8)
+    i32 = rng.integers(-(1 << 31), 1 << 31, n).astype(np.int32)
+    i32[:4] = [np.iinfo(np.int32).min, np.iinfo(np.int32).max, 0, -1]
+    u32 = rng.integers(0, 1 << 32, n).astype(np.uint32)
+    yield "narrow-min-max", rng.integers(0, 40, n), rng.random(n) < 0.8, \
+        env(i8=i8, i32=i32, u32=u32, v=v * (big >> 20), f=f), \
+        [_agg("lo8", "min", "i8"), _agg("hi8", "max", "i8"),
+         _agg("lo32", "min", "i32"), _agg("hi32", "max", "i32"),
+         _agg("hiu", "max", "u32"), _agg("lov", "min", "v"),
+         _agg("few", "max", "i8", filter_fn=lambda e, c: e["cols"]["f"] > 2),
+         _agg("s", "sum", "i8")], 64
     yield "float64-sum", rng.integers(0, 20, n), rng.random(n) < 0.9, \
         env(w=w * 1e6), [_agg("fs", "sum", "w", np.float64),
                          _agg("n", "count")], 32
@@ -436,6 +450,17 @@ def test_compact_tables_equal_the_numpy_reference(case):
                                        atol=1e-6, err_msg=name)
         else:
             np.testing.assert_array_equal(got[name], table, err_msg=name)
+
+
+def test_a_min_or_max_rides_at_the_columns_width_up_to_int32():
+    from tpu_olap.kernels.sparse_groupby import _ext_dtype
+    for col in (np.int8, np.uint8, np.int16, np.uint16, np.int32):
+        assert _ext_dtype(col, np.int64) == np.int32
+    for col in (np.uint32, np.int64):
+        assert _ext_dtype(col, np.int64) == np.int64
+    assert _ext_dtype(np.int8, np.int32) == np.int32       # no 64-bit lanes
+    assert _ext_dtype(np.int8, np.float64) == np.float64   # a double min
+    assert _ext_dtype(np.float32, np.float64) == np.float64
 
 
 def _dispatch_forms(eng):

@@ -231,6 +231,45 @@ def test_generic_grouped_program_compare_or_scatter(
         assert temp < n_aggs * n * 8 < phys.total_groups * n * 8, (temp, n)
 
 
+def test_sparse_topn_program_compiles_for_v5e(topo, no_persistent_cache,
+                                              ssb_tables, monkeypatch):
+    """A TopN whose dense plan would be XLA's scatter (lo_partkey's 4,001
+    slots are past COMPARE_MAX_GROUPS and Pallas is kept off it, as its
+    factorized cap keeps it off l_partkey's 2,000,001 in the Druid
+    lineitem cell): the sparse program with the threshold at its end,
+    compiled for the chip. One
+    multi-operand sort, the one-operand sort of `starts`, the int64
+    `top_k`; no scatter, and `threshold` rows a table are its output."""
+    from jax.sharding import SingleDeviceSharding
+
+    from tpu_olap.kernels.groupby import COMPARE_MAX_GROUPS
+    _as_tpu(monkeypatch)
+    eng = _engine(ssb_tables, use_pallas="never")
+    phys = _physical(eng, """
+        SELECT lo_partkey, sum(lo_quantity) AS qty, count(*) AS n
+        FROM lineorder GROUP BY lo_partkey ORDER BY qty DESC LIMIT 100""")
+    assert phys.query.query_type == "topN" and phys.sparse
+    assert phys.pallas_reason is not None
+    assert COMPARE_MAX_GROUPS < phys.total_groups \
+        <= eng.config.sparse_group_budget
+    top = eng.runner._device_threshold(phys.query, phys)
+    assert top == ("qty", 100, False)
+    env, valid, seg_mask = eng.runner._prepare(phys, {})
+    consts_dev, seg_arg = eng.runner._args_for(phys, seg_mask, None)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    import jax
+    compiled = jax.jit(phys.make_sparse_kernel(phys.total_groups, top)) \
+        .lower(*_scaled((env, valid, seg_arg), 1, one_chip),
+               _scaled(consts_dev, 1, one_chip)).compile()
+    text = compiled.as_text()
+    assert " scatter(" not in text and " sort(" in text
+    out = jax.eval_shape(phys.make_sparse_kernel(phys.total_groups, top),
+                         env, valid, seg_arg, consts_dev)
+    assert {k: v.shape for k, v in out.items()} == {
+        "_count": (), "_rows": (100,), "_keys": (100,), "qty": (100,),
+        "n": (100,)}
+
+
 # (id, sql, per-chip window, expect the Pallas kernel, expect a scatter)
 MESH_CASES = [
     ("q2.1", QUERIES["q2.1"], False, True, False),

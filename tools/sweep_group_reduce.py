@@ -8,7 +8,10 @@ whether the table equals numpy's. `COMPARE_MAX_GROUPS` in
 `kernels/groupby.py` is set from this table (PERF.md section 6, PR 28): the
 largest K of the issue's grid (2 to 2,048) at which the compare form is at
 least twice as fast as the scatter at both sizes and compiles in seconds;
-the K past it are there to see where the two forms cross.
+the K past it are there to see where the two forms cross. The form
+`sparse_topn` is what `executor/lowering.py::topn_takes_sparse` sends a
+TopN to where the dense plan would be the scatter: the engine's sparse
+reduce and the threshold on the device (PERF.md section 6, PR 32).
 
     python tools/sweep_group_reduce.py                  # on the chip
     python tools/sweep_group_reduce.py --compile-only   # here, for a
@@ -68,7 +71,25 @@ def _bcast(v, key, k):
                    axis=1, dtype=v.dtype)
 
 
-FORMS = {"scatter": _scatter, "compare": _compare, "bcast": _bcast}
+def _sparse_topn(v, key, k):
+    """The other side of `lowering.topn_takes_sparse`: the engine's own
+    sparse reduce of one sum into a compact table of k slots (one sort
+    whose cost does not depend on k, the table read at the runs'
+    boundaries) and the TopN's threshold on the device. Returns the table,
+    which equals the dense one where every slot is present, and the 100
+    keys kept."""
+    from tpu_olap.kernels.sparse_groupby import (sparse_group_reduce,
+                                                 sparse_top_rows)
+    plans = [groupby.AggPlan("v", "sum", ("v",), v.dtype)]
+    out = sparse_group_reduce(key.astype(jnp.int64),
+                              jnp.ones(key.shape, bool),
+                              {"cols": {"v": v}, "nulls": {}}, plans, k, {},
+                              jnp)
+    return out["v"], sparse_top_rows(out, "v", 100, False)["_keys"]
+
+
+FORMS = {"scatter": _scatter, "compare": _compare, "bcast": _bcast,
+         "sparse_topn": _sparse_topn}
 
 
 def _inputs(n, dtype, k, seed=7):
@@ -188,14 +209,20 @@ def _measure(fn, spec, inputs, want, reps, rec):
     if ma is not None:
         rec["temp_bytes"] = int(ma.temp_size_in_bytes)
     if inputs is not None:
-        got = compiled(*inputs)
-        got.block_until_ready()
+        got = jax.block_until_ready(compiled(*inputs))
         ms = []
         for _ in range(reps):
             t0 = time.perf_counter()
-            compiled(*inputs).block_until_ready()
+            jax.block_until_ready(compiled(*inputs))
             ms.append((time.perf_counter() - t0) * 1e3)
         rec["ms"] = round(statistics.median(ms), 3)
+        if isinstance(got, tuple):
+            # (table, the keys a top-100 keeps): by (value descending, key
+            # ascending), as the engine's TopN cuts a tie
+            got, top = got
+            rec["top_equal"] = bool(np.array_equal(
+                np.asarray(top),
+                np.lexsort((np.arange(len(want)), -want))[:len(top)]))
         got = np.asarray(got)
         rec["equal"] = bool(
             np.array_equal(got, want) if got.dtype.kind != "f"
@@ -244,6 +271,8 @@ def main():
     ap.add_argument("--rows", type=int, nargs="*", default=None)
     ap.add_argument("--forms", nargs="*", default=list(FORMS),
                     choices=list(FORMS))
+    ap.add_argument("--dtypes", nargs="*", default=["int64", "int32"],
+                    choices=["int64", "int32"])
     ap.add_argument("--block-bytes", type=int, nargs="*",
                     default=[groupby._CMP_BLOCK_BYTES],
                     help="the compare form's row block, to sweep it")
@@ -279,7 +308,7 @@ def sweep_dense(args, sharding):
     out = []
     for n in args.rows or ROWS:
         n = -(-n // BLOCK) * BLOCK
-        for dtype in ("int64", "int32"):
+        for dtype in args.dtypes:
             for k in args.ks:
                 inputs = want = None
                 if not args.compile_only:

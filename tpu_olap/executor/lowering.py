@@ -116,15 +116,48 @@ def lower(query, table, config) -> PhysicalPlan:
 def _sparse_reject_reason(query, total, config) -> str | None:
     """None when the sort-based sparse path can serve this shape, else
     why not — the single source of truth for both the over-budget
-    routing decision and the in-branch rejections (GroupBy only: the
-    timeseries/topN assemblers index the dense bucket space)."""
-    if not isinstance(query, GroupByQuerySpec):
+    routing decision and the in-branch rejections (GroupBy and TopN: the
+    timeseries assembler indexes the dense bucket space)."""
+    if not isinstance(query, (GroupByQuerySpec, TopNQuerySpec)):
         return f"{query.query_type} has no sparse path"
     if total >= (1 << 62):
         return "the group space overflows the int64 sparse key"
     if not config.enable_x64:
         return "sparse group-by needs int64 keys (enable_x64=False)"
     return None
+
+
+def topn_takes_sparse(query, plan, config) -> bool:
+    """The one rule that sends a TopN whose group space is UNDER the dense
+    budget to the sparse path all the same (past the budget every shape
+    goes there, or is refused), from the lowered dense plan's static
+    facts. All of:
+    - the generic kernel would run it as XLA's scatter over the [K] space
+      (Pallas turned the plan down and `groupby.reduce_form` says so: K is
+      past the compare form) and hand the host K rows an aggregate to rank;
+    - no aggregate is a sketch: the dense plan keeps a theta sketch at the
+      query's own width, the compact table clamps it to
+      `sparse_theta_k_cap`, a coarser answer;
+    - an integer count or sum is among the aggregates, which the sparse
+      path reads at the sorted runs' boundaries (one sort whose cost does
+      not depend on K, a fifth of one scattered int64 sum: PERF.md section
+      6) and can rank on the device; a plan of min / max or float sums
+      alone scatters there as here and gains nothing from the sort;
+    - the compact table can hold every group of the space
+      (K <= sparse_group_budget), so nothing the dense plan serves is
+      refused."""
+    from tpu_olap.kernels.groupby import reduce_form
+    from tpu_olap.kernels.sparse_groupby import sparse_reduce_form
+    kinds = [p.kind for p in plan.agg_plans]
+    return (isinstance(query, TopNQuerySpec)
+            and plan.pallas_reason is not None
+            and reduce_form(plan.total_groups, kinds) == "scatter"
+            and not any(k in ("hll", "theta") for k in kinds)
+            and any(sparse_reduce_form([p]) == "boundary"
+                    for p in plan.agg_plans)
+            and plan.total_groups <= config.sparse_group_budget
+            and _sparse_reject_reason(query, plan.total_groups,
+                                      config) is None)
 
 
 def _mesh_size(config) -> int:
@@ -455,14 +488,6 @@ def _lower_agg(query, table, config) -> PhysicalPlan:
             raise UnsupportedAggregation(
                 f"group space {total} exceeds dense budget "
                 f"{config.dense_group_budget} and {reject}")
-        # theta rides the sparse path with a clamped sketch width (the
-        # [cap, k] table and its merge transients are per-group state;
-        # see EngineConfig.sparse_theta_k_cap)
-        import dataclasses as _dc
-        agg_plans = tuple(
-            _dc.replace(p, theta_k=min(p.theta_k,
-                                       config.sparse_theta_k_cap))
-            if p.kind == "theta" else p for p in agg_plans)
     if not sparse and not config.enable_x64:
         # sketch state is [groups × radix]; without 64-bit lanes the flat
         # scatter index must fit int32
@@ -528,36 +553,54 @@ def _lower_agg(query, table, config) -> PhysicalPlan:
     def key_fn(env, valid, seg_mask, consts):
         return _masked_key(env, valid, seg_mask, consts, build_group_key)
 
-    def make_sparse_kernel(cap):
+    # theta rides the sparse path with a clamped sketch width (the
+    # [cap, k] table and its merge transients are per-group state; see
+    # EngineConfig.sparse_theta_k_cap)
+    import dataclasses as _dc
+    sparse_agg_plans = tuple(
+        _dc.replace(p, theta_k=min(p.theta_k, config.sparse_theta_k_cap))
+        if p.kind == "theta" else p for p in agg_plans)
+
+    def make_sparse_kernel(cap, top=None):
+        """The sparse program for a compact table of `cap` slots; with
+        `top` = (metric, threshold, inverted) the table's rows that a TopN
+        keeps, the threshold applied on the device."""
         from tpu_olap.kernels.sparse_groupby import (build_group_key64,
-                                                     sparse_group_reduce)
+                                                     sparse_group_reduce,
+                                                     sparse_top_rows)
 
         def sparse_kernel(env, valid, seg_mask, consts):
             xp = _jnp()
             fenv, mask, key = _masked_key(env, valid, seg_mask, consts,
                                           build_group_key64)
-            return sparse_group_reduce(key.astype(xp.int64), mask, fenv,
-                                       agg_plans, cap, consts, xp)
+            out = sparse_group_reduce(key.astype(xp.int64), mask, fenv,
+                                      sparse_agg_plans, cap, consts, xp)
+            return out if top is None else sparse_top_rows(out, *top)
         return sparse_kernel
 
-    statics = ("agg", sizes, bucket_plan.kind,
-               tuple(dp.kind for dp in dim_plans),
-               tuple((p.kind, p.name) for p in agg_plans),
-               filter_fn is not None, imask_fn is not None,
-               "sparse" if sparse else "dense")
+    def build(sparse: bool) -> PhysicalPlan:
+        plans = sparse_agg_plans if sparse else agg_plans
+        statics = ("agg", sizes, bucket_plan.kind,
+                   tuple(dp.kind for dp in dim_plans),
+                   tuple((p.kind, p.name) for p in plans),
+                   filter_fn is not None, imask_fn is not None,
+                   "sparse" if sparse else "dense")
+        return PhysicalPlan(
+            query=query, table=table, kind="agg", pool=pool,
+            kernel=None if sparse else kernel,
+            statics=statics, dim_plans=dim_plans, bucket_plan=bucket_plan,
+            agg_plans=plans, sizes=sizes, total_groups=total,
+            pruned_ids=pruned, t_min=t_min, t_max=t_max, empty=empty,
+            columns=columns, null_cols=null_cols, virtual_exprs=vexprs,
+            filter_streams=_dedupe_streams(pool),
+            sparse=sparse, make_sparse_kernel=make_sparse_kernel if sparse
+            else None, key_fn=None if sparse else key_fn)
 
-    plan = PhysicalPlan(
-        query=query, table=table, kind="agg", pool=pool,
-        kernel=None if sparse else kernel,
-        statics=statics, dim_plans=dim_plans, bucket_plan=bucket_plan,
-        agg_plans=agg_plans, sizes=sizes, total_groups=total,
-        pruned_ids=pruned, t_min=t_min, t_max=t_max, empty=empty,
-        columns=columns, null_cols=null_cols, virtual_exprs=vexprs,
-        filter_streams=_dedupe_streams(pool),
-        sparse=sparse, make_sparse_kernel=make_sparse_kernel if sparse
-        else None, key_fn=None if sparse else key_fn)
+    plan = build(sparse)
     if not sparse:
         _maybe_use_pallas(plan, query, table, config, filter_fn, imask_fn)
+        if topn_takes_sparse(query, plan, config):
+            plan = build(True)
     return plan
 
 

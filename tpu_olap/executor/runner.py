@@ -1954,14 +1954,17 @@ class QueryRunner:
         metrics["packed"] = True
         return idx, compact, layout
 
-    def _run_sparse(self, plan: PhysicalPlan, metrics: dict):
+    def _run_sparse(self, plan: PhysicalPlan, metrics: dict, top=None):
         """Sort-based sparse group-by dispatch with adaptive compact-table
         cap (kernels.sparse_groupby). Multi-chip merge strategy per
         EngineConfig.sparse_merge: "exchange" hash-partitions compacted
         entries to key-owner chips over all_to_all (capacity scales
         D × budget); "gather" all-gathers every chip's table. Returns
         (partials dict, count); exchange partial arrays are [D·cap_owner]
-        slot tables (SENTINEL-keyed empties), others are [cap] compacts."""
+        slot tables (SENTINEL-keyed empties), others are [cap] compacts.
+        With `top` = (metric, threshold, inverted), one chip's program
+        ends in the TopN's threshold and the partials are its [threshold]
+        rows in rank order (`_device_threshold` says when)."""
         from tpu_olap.kernels.sparse_groupby import sparse_reduce_form
 
         # whether every [cap] table is read at the sorted runs' boundaries
@@ -1970,21 +1973,24 @@ class QueryRunner:
         # of num_groups
         metrics["reduce_form"] = sparse_reduce_form(plan.agg_plans)
         with _span("dispatch", sparse=True, **_form_attr(metrics)) as sp:
-            out = self._run_sparse_inner(plan, metrics)
+            out = self._run_sparse_inner(plan, metrics, top)
             sp.set(jit_cache_hit=metrics.get("jit_cache_hit"),
                    result_groups=metrics.get("result_groups"),
                    num_shards=metrics.get("num_shards"))
         return out
 
-    def _run_sparse_inner(self, plan: PhysicalPlan, metrics: dict):
+    def _run_sparse_inner(self, plan: PhysicalPlan, metrics: dict, top):
         with self._pipeline_slot():
-            return self._run_sparse_staged(plan, metrics)
+            return self._run_sparse_staged(plan, metrics, top)
 
-    def _run_sparse_staged(self, plan: PhysicalPlan, metrics: dict):
+    def _run_sparse_staged(self, plan: PhysicalPlan, metrics: dict,
+                           top=None):
         """Adaptive-cap sparse dispatch, two-staged: each attempt's jit
         build + async dispatch runs under the enqueue lock; the _count
         probe (a one-element sync) and the final whole-tree fetch run
-        lock-free, so an overflow retry re-enters stage 1."""
+        lock-free, so an overflow retry re-enters stage 1. `top` (one
+        chip only) puts the TopN's threshold at the program's end: the
+        fetch then brings `threshold` rows a table, not `cap`."""
         from tpu_olap.kernels.groupby import UnsupportedAggregation
 
         with self._enqueue_lock(metrics):
@@ -2003,9 +2009,21 @@ class QueryRunner:
         cap_limit = min(budget * (n_shards if use_exchange else 1),
                         plan.total_groups)
         local_limit = min(budget, plan.total_groups)
-        hint = self._cap_hints.get(base_key)
-        cap = min(local_limit, self.config.sparse_group_cap) \
-            if hint is None else _grown_cap(hint, local_limit)
+        # a group space that fits the compact table whole starts (and
+        # stays) at a cap that holds it: no attempt can overflow, and the
+        # sort compiles once, not once for the starting cap and again for
+        # the grown one (2-4 minutes a program at 60M rows). Not where a
+        # sketch's [cap, m] state rides: its cap follows the groups present
+        whole_space = plan.total_groups <= budget and not any(
+            p.kind in ("hll", "theta") for p in plan.agg_plans)
+
+        def first_cap(hint):
+            if whole_space:
+                return local_limit
+            return min(local_limit, self.config.sparse_group_cap) \
+                if hint is None else _grown_cap(hint, local_limit)
+
+        cap = first_cap(self._cap_hints.get(base_key))
 
         t0 = time.perf_counter()
         hit = False
@@ -2024,13 +2042,14 @@ class QueryRunner:
                             consts_dev, seg_arg = self._args_for(
                                 plan, seg_mask, None)
                             key = base_key + (cap,) \
-                                + ((win[1],) if win else ())
+                                + ((win[1],) if win else ()) \
+                                + (("top",) if top else ())
                             jitted = self._jit_cache.get(key)
                             hit = jitted is not None
                             if hit:
                                 _cache_lru_hit(self._jit_cache, key)
                             else:
-                                kern = plan.make_sparse_kernel(cap)
+                                kern = plan.make_sparse_kernel(cap, top)
                                 if win is not None:
                                     jitted = jax.jit(
                                         self._window_kernel(kern, win[1]))
@@ -2131,7 +2150,7 @@ class QueryRunner:
                 return out, count
             lhint = self._cap_hints.get(base_key + ("local",))
             if lhint is not None:
-                cap = _grown_cap(lhint, local_limit)
+                cap = first_cap(lhint)
             pin = None
             try:
                 while True:
@@ -2233,10 +2252,15 @@ class QueryRunner:
         # ride the unpacked per-array fetch instead
         keep_raw = theta_raw_fields(query.post_aggregations)
 
+        topn = isinstance(query, TopNQuerySpec)
+        if topn:
+            metrics["topn_group_space"] = plan.total_groups
         if plan.sparse:
             from tpu_olap.kernels.sparse_groupby import SENTINEL
+            top = self._device_threshold(query, plan) if topn else None
             out, count = self._dispatch(
-                lambda: self._run_sparse(plan, metrics), metrics, table.name)
+                lambda: self._run_sparse(plan, metrics, top), metrics,
+                table.name)
             t0 = time.perf_counter()
             with self.stages.stage("finalize", metrics):
                 with _span("finalize"):
@@ -2253,10 +2277,18 @@ class QueryRunner:
             sub = {n: np.asarray(arrays[n])[pm] for n in names}
             with self.stages.stage("assemble", metrics), \
                     _span("assemble"):
-                res = self._emit_groupby(query, plan, present, sub)
+                if topn:
+                    metrics["topn_rows_fetched"] = len(keys)
+                    res = self._emit_topn(query, plan, present, sub,
+                                          "device" if top else "host")
+                else:
+                    res = self._emit_groupby(query, plan, present, sub)
             res.metrics = metrics
             metrics["assemble_ms"] = (time.perf_counter() - t0) * 1000
             return res
+        if topn:
+            # the dense paths hand the host the whole [K] space to rank
+            metrics["topn_rows_fetched"] = plan.total_groups
 
         if self.result_cache.seg_enabled:
             arrays = self._run_agg_segcached(query, plan, metrics, specs,
@@ -2639,28 +2671,60 @@ class QueryRunner:
             druid.append({"version": "v1", "timestamp": ts, "event": ev})
         return QueryResult(query, rows, druid)
 
+    def _device_threshold(self, query, plan):
+        """(metric, threshold, inverted) where one chip's sparse program
+        can apply a TopN's threshold itself, else None (the host ranks the
+        fetched table): one bucket, no mesh (a mesh's broker merges whole
+        tables), and the metric an aggregate whose table column IS its
+        final value, held as an integer (a count or a long sum): a
+        post-aggregation, a sketch's estimate and a min / max's null are
+        made on the host, and a float's NaN ranks differently there."""
+        if self.mesh is not None or plan.sizes[0] != 1:
+            return None
+        for p in plan.agg_plans:
+            if p.name == query.metric and p.kind in ("count", "sum") \
+                    and np.issubdtype(np.dtype(p.acc_dtype), np.integer):
+                return (query.metric, query.threshold, query.inverted)
+        return None
+
     def _assemble_topn(self, query, plan, arrays) -> QueryResult:
         names = self._out_names(query)
-        n_b = plan.sizes[0]
-        d_size = plan.sizes[1]
-        metric = np.asarray(arrays[query.metric], np.float64) \
-            .reshape(n_b, d_size)
-        present = (arrays["_rows"] > 0).reshape(n_b, d_size)
+        present = np.nonzero(arrays["_rows"] > 0)[0]
+        sub = {n: np.asarray(arrays[n])[present] for n in names}
+        return self._emit_topn(query, plan, present, sub, "host")
+
+    def _emit_topn(self, query, plan, present, sub, where) -> QueryResult:
+        """present: flat group ids, sub: their final values — every present
+        group in ascending id order (`where` "host": the threshold is
+        applied here), or the rows a device threshold kept, in rank order
+        (`where` "device"). Shared tail of the dense and sparse paths. A
+        bucket's rows are its first `threshold` by the metric (last if
+        inverted); where the metric ties they come in the order they
+        arrived in, which is the dimension's own ascending order (label
+        order) either way."""
+        names = self._out_names(query)
+        buckets, dim_ids = self._decode_groups(plan, present)
         dp = plan.dim_plans[0]
+        with _span("topn-threshold", where=where, groups=len(present),
+                   threshold=query.threshold):
+            m = np.asarray(sub[query.metric], np.float64)
+            if query.inverted:
+                m = -m
+            # a group whose metric is null (NaN) or -inf is never ranked
+            ranked = np.flatnonzero(m > -np.inf)
+            # stable: by bucket, then by the metric, then as they came
+            ranked = ranked[np.lexsort((-m[ranked], buckets[ranked]))]
+            lo = np.searchsorted(buckets[ranked],
+                                 np.arange(plan.sizes[0] + 1))
         rows, druid = [], []
         for b in self._bucket_emit_ids(query, plan):
-            m = np.where(present[b],
-                         -metric[b] if query.inverted else metric[b],
-                         -np.inf)
-            order = np.argsort(-m, kind="stable")
-            order = order[m[order] > -np.inf][:query.threshold]
+            keep = ranked[lo[b]:lo[b + 1]][:query.threshold]
+            labels = dp.labels[dim_ids[dp.name][keep]]
             ts = iso(plan.bucket_plan.starts[b])
             result = []
-            for g in order:
-                flat = b * d_size + g
-                ev = {dp.name: render_value(dp.labels[g])}
-                ev.update({n: render_value(np.asarray(arrays[n])[flat])
-                           for n in names})
+            for i, g in enumerate(keep):
+                ev = {dp.name: render_value(labels[i])}
+                ev.update({n: render_value(sub[n][g]) for n in names})
                 result.append(ev)
                 rows.append({"timestamp": ts, **ev})
             druid.append({"timestamp": ts, "result": result})

@@ -136,6 +136,19 @@ def _at_boundaries(p) -> bool:
         p.kind == "sum" and np.issubdtype(np.dtype(p.acc_dtype), np.integer))
 
 
+def _ext_dtype(col_dtype, acc_dtype):
+    """The width a min / max rides the sort and its segment reduce at: an
+    extreme never leaves its input's range, so an integer column stored in
+    32 bits or fewer is reduced as int32 and widened to the accumulator
+    once a slot. XLA:TPU's scatter takes 8-9 ns a row a 32-bit element and
+    81-91 an int64, which it emulates (PERF.md section 6, PRs 28 and 30)."""
+    col_dtype, acc_dtype = np.dtype(col_dtype), np.dtype(acc_dtype)
+    if acc_dtype.kind == "i" and col_dtype.kind in "iu" \
+            and np.can_cast(col_dtype, np.int32):
+        return np.dtype(np.int32)
+    return acc_dtype
+
+
 def sparse_group_reduce(key, mask, env, plans, cap, consts, xp):
     """[N] int64 keys + mask -> compacted per-group partials.
 
@@ -169,9 +182,9 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp):
             if p.kind == "sum":
                 carry(f"v:{p.name}", xp.where(mm, x, 0).astype(p.acc_dtype))
             else:
-                ident = _ident(p.acc_dtype, p.kind)
+                dt = _ext_dtype(x.dtype, p.acc_dtype)
                 carry(f"v:{p.name}",
-                      xp.where(mm, x.astype(p.acc_dtype), ident))
+                      xp.where(mm, x.astype(dt), _ident(dt, p.kind)))
                 if p.filter_fn is not None or nulls is not None:
                     # mm == mask otherwise: the non-null count IS _rows,
                     # so skip both the sort operand and the reduction
@@ -232,12 +245,16 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp):
                 else segment(jax.ops.segment_sum, v)
             continue
         if p.kind in ("min", "max"):
-            out[p.name] = segment(
+            nn = run_count(sorted_ops[slots[f"nn:{p.name}"]]) \
+                if f"nn:{p.name}" in slots else out["_rows"]
+            v = segment(
                 jax.ops.segment_min if p.kind == "min"
                 else jax.ops.segment_max, sorted_ops[slots[f"v:{p.name}"]])
-            out[f"_nn_{p.name}"] = \
-                run_count(sorted_ops[slots[f"nn:{p.name}"]]) \
-                if f"nn:{p.name}" in slots else out["_rows"]
+            # an empty slot holds the accumulator's identity, whatever
+            # width the rows were reduced at
+            out[p.name] = xp.where(nn > 0, v.astype(p.acc_dtype),
+                                   _ident(p.acc_dtype, p.kind))
+            out[f"_nn_{p.name}"] = nn
             continue
         if p.kind == "hll":
             h = sorted_ops[slots[f"h:{p.name}"]]
@@ -257,6 +274,23 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp):
             out[p.name] = t[:cap]
             continue
     return out
+
+
+def sparse_top_rows(tables: dict, metric: str, threshold: int,
+                    inverted: bool) -> dict:
+    """The rows of a compact table (`sparse_group_reduce`'s) that a TopN by
+    `metric` keeps, as [threshold] tables in rank order: the threshold
+    applied on the device, so that `threshold` group rows and not `cap`
+    leave it. `_count` stays the table's own (the cap-overflow probe reads
+    it); a rank past the present groups holds the SENTINEL key of the
+    empty slot it points at."""
+    from tpu_olap.kernels.topk import top_k_groups
+
+    keys = tables["_keys"]
+    order, _ = top_k_groups(tables[metric], keys != SENTINEL, threshold,
+                            inverted)
+    return {name: t if name == "_count" else t[order]
+            for name, t in tables.items()}
 
 
 def merge_sparse(parts: list, plans, cap, xp):

@@ -1,19 +1,24 @@
 """Top-K group selection (TopN queries).
 
 Unlike Druid's *approximate* per-segment topN + broker re-rank (SURVEY.md
-§8.4 #2), the dense group table makes exact top-K cheap: one lax.top_k
-over the [K] metric array. Druid-approximate behavior is therefore a
-strict-accuracy win, not a compatibility break; the context flag
-`useApproximateTopN` exists for parity testing but maps to the same exact
-kernel.
+§8.4 #2), a whole group table on one device makes exact top-K cheap: one
+lax.top_k over the table's metric column. Druid-approximate behavior is
+therefore a strict-accuracy win, not a compatibility break; the context
+flag `useApproximateTopN` exists for parity testing but maps to the same
+exact kernel.
+
+The caller is the sparse path's compact table (`sparse_groupby.
+sparse_top_rows`): slot i holds the i-th smallest present key, and
+lax.top_k puts the lower index first among equal values, so ties at the
+threshold are kept in the dimension's own ascending order — the rule of the
+host assembler (`runner._emit_topn`, a stable argsort over label order).
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-from tpu_olap.kernels.hashing import has_x64
+import numpy as np
 
 
 def top_k_groups(metric, present, threshold: int, inverted: bool):
@@ -21,10 +26,11 @@ def top_k_groups(metric, present, threshold: int, inverted: bool):
 
     Returns (indices [threshold], valid [threshold]) — group ids of the
     top-`threshold` by metric (bottom if inverted), absent groups last.
+    An integer metric is ranked as the integer it is (exact past 2^53).
     """
     k = min(int(threshold), metric.shape[-1])
-    v = metric.astype(jnp.float64 if has_x64(jnp) else jnp.float32)
-    v = jnp.where(present, -v if inverted else v, -jnp.inf)
-    vals, order = jax.lax.top_k(v, k)
-    valid = vals > -jnp.inf
-    return order, valid
+    v = -metric if inverted else metric
+    lowest = np.iinfo(v.dtype).min if jnp.issubdtype(v.dtype, jnp.integer) \
+        else -jnp.inf
+    _, order = jax.lax.top_k(jnp.where(present, v, lowest), k)
+    return order, present[order]

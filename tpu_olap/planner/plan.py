@@ -1159,6 +1159,18 @@ class _Rewriter:
         self._agg_by_key[k] = name
         return name
 
+    def _agg_cast(self, name: str) -> str | None:
+        """A long min / max is an integer in SQL (and from the pandas
+        fallback); the device hands it over as float64, so that a group
+        without a non-null row can be NaN: cast the frame's column back."""
+        from tpu_olap.ir.aggregations import MaxAggregation, MinAggregation
+        for a in self.aggs:
+            if a.name == name and isinstance(a, (MinAggregation,
+                                                 MaxAggregation)) \
+                    and a.value_type == "long":
+                return "int"
+        return None
+
     _THETA_SET_FNS = {"theta_sketch_intersect": "INTERSECT",
                       "theta_sketch_union": "UNION",
                       "theta_sketch_not": "NOT"}
@@ -1301,7 +1313,8 @@ class _Rewriter:
                                             oc.cast))
             elif _contains_agg(e):
                 name = self._agg_output(e)
-                outputs.append(OutputColumn(alias or _render(e), name))
+                outputs.append(OutputColumn(alias or _render(e), name,
+                                            self._agg_cast(name)))
             else:
                 raise RewriteError(
                     f"projection {_render(e)} is neither grouped nor "
