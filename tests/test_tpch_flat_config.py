@@ -89,6 +89,32 @@ def test_template_equals_the_reference(eng, data, name):
             == data["reference"]["groups"][name]
 
 
+@pytest.mark.parametrize("shards", [1, 4])
+def test_q12_reads_three_resident_streams(data, shards):
+    """Q12's two row-by-row comparisons read derived streams (the ranks of
+    l_commitdate and l_receiptdate, l_commitdate's values as int64
+    millis), built by its first dispatch and by no later one; on one chip
+    and on the mesh's per-chip programs, exact, on the generic kernel."""
+    eng = _engine(data, num_shards=shards)
+    sql = tpch_flat.templates()["q12"]
+    builds = []
+    for _ in range(2):
+        served, rec = _served(eng, sql)
+        assert verify.answer_mismatches(served, data["expected"]["q12"]) == []
+        assert rec["reduce_path"] == "scatter"
+        assert rec["reduce_form"] == "compare"
+        assert rec.get("num_shards", 1) == shards
+        assert rec["filter_streams"] == 3
+        builds.append(rec["filter_stream_builds"])
+    assert builds == [3, 0]
+    plan = eng.planner.plan(sql)
+    phys = eng.runner._lower_cached(plan.query, plan.entry.segments)
+    ds = eng.runner._datasets[tpch_flat.TABLE]
+    assert sorted(str(ds._derived[t].dtype)
+                  for t, _, _ in phys.filter_streams) \
+        == ["int32", "int32", "int64"]
+
+
 def test_past_the_bound_the_generic_kernel_is_the_scatter(eng):
     """o_orderdate x l_returnflag is a dense space of ~9,600 slots, past
     COMPARE_MAX_GROUPS, and sum_charge's input passes int32, so Pallas

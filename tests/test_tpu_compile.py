@@ -270,6 +270,42 @@ def test_sparse_topn_program_compiles_for_v5e(topo, no_persistent_cache,
         "n": (100,)}
 
 
+def test_q12_shaped_program_has_no_row_gather_on_v5e(topo,
+                                                     no_persistent_cache,
+                                                     monkeypatch):
+    """TPC-H Q12's shape at 6M rows, compiled for the chip: both
+    row-by-row comparisons read resident streams, so no `kCustom` gather
+    fusion has a row-sized result (the parent's two, the u32 halves of an
+    int64 dictionary gathered by every row's code, took 355 ms each at
+    36M rows; the `IN` list's small predicate table is no gather on the
+    chip). Without the streams the closures' gather spelling brings them
+    back: the control."""
+    from jax.sharding import SingleDeviceSharding
+
+    from test_colcmp import _dates_frame, _q12_shaped_dispatch
+    _as_tpu(monkeypatch)
+    eng = Engine(EngineConfig(fallback_on_device_failure=False))
+    # dictionaries as wide as the cell's: XLA:TPU turns a gather from a
+    # table of a few dozen entries into a select an entry
+    eng.register_table("d", _dates_frame(span=2500), time_column="ts")
+    phys, programs = _q12_shaped_dispatch(eng)
+    assert phys.pallas_reason is not None and len(phys.filter_streams) == 3
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    factor = math.ceil(GENERIC_ROWS / phys.table.block_rows)
+    rows = factor * phys.table.block_rows
+    row_gather_fusions = {}
+    for name, (jitted, (*data, consts)) in programs.items():
+        assert len(data[1]) == 1    # one segment block, scaled to 6M rows
+        text = jitted.lower(*_scaled(tuple(data), factor, one_chip),
+                            _scaled(consts, 1, one_chip)).compile().as_text()
+        row_gather_fusions[name] = [
+            ln for ln in text.splitlines()
+            if "kind=kCustom" in ln and "/gather" in ln
+            and re.search(rf"= \w+\[{rows}\]", ln)]
+    assert row_gather_fusions["with_streams"] == []
+    assert len(row_gather_fusions["without_streams"]) >= 2
+
+
 # (id, sql, per-chip window, expect the Pallas kernel, expect a scatter)
 MESH_CASES = [
     ("q2.1", QUERIES["q2.1"], False, True, False),

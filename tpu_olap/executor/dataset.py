@@ -424,12 +424,13 @@ class DeviceDataset:
         return self._nulls[name]
 
     def derived(self, token: str, build, pinned=frozenset()):
-        """Device-resident derived int32 stream [S, R] (precomputed dim
-        ids: remap/timeformat gathers), computed ONCE per content token
-        and reused across queries — a per-dispatch 6M-row 1-D gather is
-        ~60 ms on a v5e through the XLA lowering; a resident stream costs
-        one HBM read like any other column. Ledger-tracked (4 B/row) and
-        evictable; an evicted stream transparently rebuilds. `pinned`
+        """Device-resident derived stream [S, R] (precomputed dim ids:
+        remap/timeformat gathers; a filter's translated codes, ranks or
+        millis), computed ONCE per content token and reused across
+        queries — a per-dispatch 6M-row 1-D gather is ~60 ms on a v5e
+        through the XLA lowering; a resident stream costs one HBM read
+        like any other column. Ledger-tracked at the built array's bytes
+        and evictable; an evicted stream transparently rebuilds. `pinned`
         must carry the in-flight query's working set so this add cannot
         evict buffers the same query is about to use."""
         if token not in self._derived:
@@ -437,8 +438,7 @@ class DeviceDataset:
             self._derived[token] = arr
             if self.ledger is not None:
                 key = (self.table.name, "derived", token)
-                nbytes = int(np.prod(self.shape)) * 4
-                self.ledger.add(key, nbytes,
+                self.ledger.add(key, int(arr.nbytes),
                                 lambda: self._derived.pop(token, None),
                                 pinned)
         elif self.ledger is not None:

@@ -1525,6 +1525,8 @@ class QueryRunner:
             out = self._prepare_inner(plan, metrics)
             sp.set(rows_scanned=metrics.get("rows_scanned"),
                    segments_scanned=metrics.get("segments_scanned"),
+                   filter_streams=metrics["filter_streams"],
+                   filter_stream_builds=metrics["filter_stream_builds"],
                    num_shards=self._active_shards or 1)
         return out
 
@@ -1534,6 +1536,10 @@ class QueryRunner:
         env = ds.env(plan.columns, plan.null_cols)
         bp = plan.bucket_plan
         bp_token = bp.cache_token if bp is not None else None
+        # resident filter streams this dispatch reads, and how many of
+        # them it had to build (0 once warm; 1 again after an eviction)
+        metrics["filter_streams"] = len(plan.filter_streams)
+        metrics["filter_stream_builds"] = 0
         tokens = [dp.cache_token for dp in plan.dim_plans
                   if dp.cache_token is not None] \
             + ([bp_token] if bp_token else []) \
@@ -1556,11 +1562,11 @@ class QueryRunner:
                     bp_token,
                     lambda: self._build_bucket_stream(ds, plan), pinned)
             for token, src, cname in plan.filter_streams:
-                env["cols"]["\0d:" + token] = ds.derived(
-                    token,
-                    lambda src=src, cname=cname:
-                        self._build_filter_stream(ds, plan, src, cname),
-                    pinned)
+                def build(src=src, cname=cname):
+                    metrics["filter_stream_builds"] += 1
+                    return self._build_filter_stream(ds, plan, src, cname)
+                env["cols"]["\0d:" + token] = ds.derived(token, build,
+                                                         pinned)
         valid = ds.valid()
         seg_mask = ds.segment_mask(plan.pruned_ids if not plan.empty else [])
         metrics["segments_total"] = len(table.segments)
@@ -1606,16 +1612,16 @@ class QueryRunner:
         return jax.jit(f)(col)
 
     def _build_filter_stream(self, ds, plan: PhysicalPlan, src, cname):
-        """Materialize a filter-owned derived id stream [S, R] int32:
-        the columnComparison cross-dictionary translation gather, paid
-        once per (table, column pair), not per dispatch (a 1-D gather
-        over every row is ~60 ms on a v5e through XLA)."""
+        """Materialize a filter-owned derived stream [S, R] in its
+        table's dtype (int32 ids and ranks, int64 millis): the
+        columnComparison's gather of a dictionary-sized table by every
+        row's code, paid once per (table, content token), not per
+        dispatch (10 ns a row a 32-bit half on a v5e through XLA)."""
         col = ds.col(src)
         xmap = plan.pool.consts[cname]
         import jax
         import jax.numpy as jnp
-        return jax.jit(
-            lambda c: jnp.asarray(xmap)[c].astype(jnp.int32))(col)
+        return jax.jit(lambda c: jnp.asarray(xmap)[c])(col)
 
     def _build_bucket_stream(self, ds, plan: PhysicalPlan):
         """Resident bucket stream [S, R] int32: the per-row pass

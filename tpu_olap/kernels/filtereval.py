@@ -304,6 +304,23 @@ def compile_filter(spec, table, pool: ConstPool, virtual_exprs=None):
 
     colcmp_cache: dict = {}
 
+    def _stream(kind, col, tbl, *key):
+        """A dictionary-sized table read by every row's code of `col`,
+        as a resident derived stream in the table's dtype: the runner
+        builds `tbl[codes]` once a content token and places it in the
+        env like a column. -> reader(env, c); it gathers only where no
+        runner placed the stream (host / interpreter callers)."""
+        cname = pool.add(tbl, tbl.dtype)
+        token = _stream_token(kind, col, *key, tbl)
+        pool.streams.append((token, col, cname))
+        pool.tag(f"{kind}:{token}")  # closure structure depends on it
+        dname = "\0d:" + token
+
+        def read(env, c):
+            hit = env["cols"].get(dname)
+            return hit if hit is not None else c[cname][env["cols"][col]]
+        return read
+
     def _colcmp_pair(a, b):
         """One (a, b) equality leg of a columnComparison filter. NULL
         operands never match (module-docstring boolean rule; NotFilter
@@ -337,16 +354,8 @@ def compile_filter(spec, table, pool: ConstPool, virtual_exprs=None):
         xmap = np.full(da.size + 1, -1, np.int32)
         for i, v in enumerate(da.values):
             xmap[i + 1] = db.id_of(v)
-        cname = pool.add(xmap, np.int32)
-        token = _stream_token("cc", a, b, xmap)
-        pool.streams.append((token, a, cname))
-        pool.tag(f"cc:{token}")  # closure structure depends on the stream
-        dname = "\0d:" + token
-
-        def fn(env, c):
-            hit = env["cols"].get(dname)
-            ta_ids = hit if hit is not None else c[cname][env["cols"][a]]
-            return ta_ids == env["cols"][b]
+        ta_ids = _stream("cc", a, xmap, b)
+        fn = lambda env, c: ta_ids(env, c) == env["cols"][b]  # noqa: E731
         colcmp_cache[(a, b)] = fn
         return fn
 
@@ -357,7 +366,7 @@ def compile_filter(spec, table, pool: ConstPool, virtual_exprs=None):
         like the equality pair's translation stream, with NULL at the end
         of the order where it makes the comparison false. The time column
         against a string column compares epoch millis with the
-        dictionary's values read as ISO dates."""
+        dictionary's values read as ISO dates, a derived stream too."""
         ta, tb = col_type(a), col_type(b)
         a_str, b_str = ta is ColumnType.STRING, tb is ColumnType.STRING
         less = (lambda x, y: x < y) if op == "<" else (lambda x, y: x <= y)
@@ -372,19 +381,8 @@ def compile_filter(spec, table, pool: ConstPool, virtual_exprs=None):
                 ranks[0] = null_rank
                 ranks[1:] = np.searchsorted(merged,
                                             np.asarray(d.values, str))
-                cname = pool.add(ranks, np.int32)
-                token = _stream_token("cr", col, ranks)
-                pool.streams.append((token, col, cname))
-                pool.tag(f"cr:{token}")
-                sides.append((col, cname, "\0d:" + token))
-
-            def side(env, c, col, cname, dname):
-                hit = env["cols"].get(dname)
-                return hit if hit is not None \
-                    else c[cname][env["cols"][col]]
-
-            return lambda env, c: less(side(env, c, *sides[0]),
-                                       side(env, c, *sides[1]))
+                sides.append(_stream("cr", col, ranks))
+            return lambda env, c: less(sides[0](env, c), sides[1](env, c))
         if TIME_COLUMN not in (a, b) or a_str == b_str:
             raise UnsupportedFilter(
                 f"ordered columnComparison of {a!r} and {b!r}: two string "
@@ -401,12 +399,13 @@ def compile_filter(spec, table, pool: ConstPool, virtual_exprs=None):
                 ms[i + 1] = parse_iso_datetime(str(v))
             except ValueError:
                 pass
-        cname = pool.add(ms, np.int64)
+        # int64 to keep a time of day; `never` is in the table, so the
+        # token tells the two sides apart
+        s_ms = _stream("ct", scol, ms)
 
         def fn(env, c):
-            s_ms = c[cname][env["cols"][scol]]
             t = env["cols"][TIME_COLUMN]
-            return less(s_ms, t) if a_str else less(t, s_ms)
+            return less(s_ms(env, c), t) if a_str else less(t, s_ms(env, c))
         return fn
 
     def _table_filter(col, typ, make_table):

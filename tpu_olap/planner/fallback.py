@@ -25,6 +25,7 @@ from tpu_olap.planner.exprutil import (contains_agg as _contains_agg,
 from tpu_olap.planner.sqlparse import (AGG_FUNCS, SelectStmt, UnionStmt)
 from tpu_olap.resilience.errors import QueryError
 from tpu_olap.segments.dictionary import _like_to_regex
+from tpu_olap.utils.timeutil import parse_iso_datetime
 
 _TIME_FUNCS = {"year", "month", "day", "dayofmonth", "quarter",
                "hour", "minute", "second"}
@@ -2168,6 +2169,32 @@ def _ts(series, time_col):
     return pd.to_datetime(series, unit="ms")
 
 
+def _iso_strings_as_times(left, right):
+    """A datetime column ordered against a string column (the device's
+    columnComparison of the time column with a column of ISO dates): the
+    strings read by the device's own parser, NULL and what is no date
+    NaT, which compares False."""
+    is_time = pd.api.types.is_datetime64_any_dtype
+
+    def as_times(s, like):
+        def millis(v):
+            try:
+                return parse_iso_datetime(str(v))
+            except ValueError:
+                return None
+        return pd.to_datetime(
+            s.map({v: millis(v) for v in s.dropna().unique()}), unit="ms",
+            utc=getattr(like.dtype, "tz", None) is not None)
+
+    if is_time(left) and not is_time(right) and not \
+            pd.api.types.is_numeric_dtype(right):
+        right = as_times(right, left)
+    elif is_time(right) and not is_time(left) and not \
+            pd.api.types.is_numeric_dtype(left):
+        left = as_times(left, right)
+    return left, right
+
+
 def _eval(e, df, time_col):
     """Expression -> Series aligned with df (scalar for Lit)."""
     if isinstance(e, Lit):
@@ -2213,6 +2240,9 @@ def _eval(e, df, time_col):
         right = _eval(e.right, df, time_col)
         if e.op == "/":
             left = left.astype(float) if hasattr(left, "astype") else left
+        if e.op in ("<", "<=", ">", ">=") and isinstance(e.left, Col) \
+                and isinstance(e.right, Col):
+            left, right = _iso_strings_as_times(left, right)
         out = _APPLY[e.op](left, right)
         if e.op in ("==", "!=", "<", "<=", ">", ">=", "&&", "||") and \
                 hasattr(out, "fillna"):
