@@ -110,3 +110,27 @@ def test_chip_smoke_rehearsal_passes_but_never_reports_ok(chips):
     assert last["device"]["count"] == chips
     assert proc.returncode not in (0, 1)  # not success, not a failed phase
     assert '"ok": true' not in proc.stdout
+
+
+def test_the_engine_keeps_the_memory_it_frees(monkeypatch):
+    """glibc's malloc is told to keep freed memory (no trim under 1 GiB,
+    no fresh mapping under 32 MiB): every Engine asks, the call takes
+    effect on this Linux, and a libc without `mallopt` is a quiet no."""
+    import ctypes
+
+    from tpu_olap import Engine
+
+    assert platform.retain_freed_memory() is True
+    asked = []
+    monkeypatch.setattr(platform, "retain_freed_memory",
+                        lambda: asked.append(1))
+    Engine()
+    assert asked == [1]
+    monkeypatch.undo()
+
+    class NoMallopt:
+        def __getattr__(self, name):
+            raise AttributeError(name)
+
+    monkeypatch.setattr(ctypes, "CDLL", lambda *a, **k: NoMallopt())
+    assert platform.retain_freed_memory() is False

@@ -117,7 +117,7 @@ def test_merge_propagates_local_overflow():
     assert int(out_a["_count"]) == 65  # local overflow signalled
     # chip B: subset of A's surviving keys
     out_b = chip(32)
-    merged = merge_sparse([out_a, out_b], plans, cap, np)
+    merged = merge_sparse([out_a, out_b], plans, cap)
     assert int(merged["_count"]) == 65  # NOT 64: retry must fire
 
 

@@ -1,5 +1,6 @@
-"""The deployment `tpch-flat-sf10-chip` (perfbench/configs) at 60,000 rows
-on the CPU: the benchmark's own generator through `Engine.register_table`
+"""The deployments `tpch-flat-sf10-chip` and `tpch-flat-sf10-mesh4`
+(perfbench/configs) at 60,000 rows on the CPU, one device and a mesh of
+four: the benchmark's own generator through `Engine.register_table`
 and `Engine.sql`, every template's answer against the benchmark's plain
 reference by the comparison that decides `correct` (equality), the record
 naming the group-reduce implementation that served it, and nothing served
@@ -65,12 +66,28 @@ def _served(eng, sql):
              "rows": json.loads(df.to_json(orient="records"))}, rec)
 
 
+@pytest.fixture(scope="module")
+def eng4(data):
+    return _engine(data, use_pallas="force", num_shards=4)
+
+
+@pytest.fixture(scope="module")
+def eng4_broker(data):
+    return _engine(data, use_pallas="force", num_shards=4,
+                   mesh_merge="broker")
+
+
+@pytest.mark.parametrize("shards", [1, 4])
 @pytest.mark.parametrize("name", sorted(REDUCE_PATH))
-def test_template_equals_the_reference(eng, data, name):
+def test_template_equals_the_reference(eng, eng4, data, name, shards):
+    """Every template through one chip's program and through the mesh's
+    (each chip the one-chip program on its own rows, the broker's merge
+    above them): the same answer, the same path, the record's num_shards."""
+    eng = eng if shards == 1 else eng4
     served, rec = _served(eng, tpch_flat.templates()[name])
     assert verify.answer_mismatches(served, data["expected"][name]) == []
     assert rec["reduce_path"] == REDUCE_PATH[name]
-    assert rec.get("num_shards", 1) == 1
+    assert rec.get("num_shards", 1) == shards
     if REDUCE_PATH[name] == "scatter":
         # the generic grouped kernel, here a masked reduce a slot: Q1 has
         # a dozen dense slots, Q12 as many as l_shipmode has values
@@ -85,8 +102,216 @@ def test_template_equals_the_reference(eng, data, name):
         assert "reduce_form" not in rec
     if REDUCE_PATH[name] == "sparse":
         assert rec["sparse_attempts"] >= 1
-        assert rec["sparse_cap"] >= rec["present_groups"] \
-            == data["reference"]["groups"][name]
+        # on a mesh the cap is a chip's, the groups the merged table's
+        assert rec["present_groups"] == data["reference"]["groups"][name]
+        assert shards * rec["sparse_cap"] >= rec["present_groups"]
+
+
+MESH_SPARSE_COUNTERS = ("sparse_merge_rows_in", "sparse_fetch_bytes")
+
+
+@pytest.mark.parametrize("where", ["device", "broker"])
+@pytest.mark.parametrize("name", ["q3", "q10"])
+def test_mesh_sparse_dispatch_names_its_parts(eng, eng4, eng4_broker, name,
+                                              where, monkeypatch):
+    """The span tree of a mesh's sparse query: the cap attempt and its
+    count probe, the merge of the chips' present rows and the fetch, all
+    under `dispatch`; the record counts what the merge was handed. Merged
+    on the device (the default), one chip's copy of the merged table is
+    fetched and the host's merge is never called; merged at the broker,
+    the four chips' rows are fetched first. Both answer alike. One chip's
+    sparse query has no merge and carries none of it."""
+    from tpu_olap.kernels import sparse_groupby
+
+    handed = []
+    merge = sparse_groupby.merge_sparse
+
+    def watched(parts, *args):
+        handed.append(sum(len(p["_keys"]) for p in parts))
+        return merge(parts, *args)
+
+    monkeypatch.setattr(sparse_groupby, "merge_sparse", watched)
+    mesh = eng4 if where == "device" else eng4_broker
+    sql = tpch_flat.templates()[name]
+    served, rec = _served(mesh, sql)
+    assert rec["merge"] == where
+    dispatch = [s for s in _walk(mesh.tracer.last.to_json())
+                if s["name"] == "dispatch"]
+    assert len(dispatch) == 1
+    under = {s["name"]: s for s in _walk(dispatch[0])}
+    assert {"sparse-attempt", "count-probe", "sparse-shard-fetch",
+            "broker-merge"} <= set(under)
+    attempt, fetch, merged = (under[n]["attrs"] for n in (
+        "sparse-attempt", "sparse-shard-fetch", "broker-merge"))
+    assert attempt["cap"] == rec["sparse_cap"] \
+        >= attempt["present_groups"] > 0
+    assert "jit_cache_hit" in attempt
+    assert merged["where"] == where
+    assert fetch["bytes"] == rec["sparse_fetch_bytes"] > 0
+    assert merged["rows_in"] == rec["sparse_merge_rows_in"] \
+        >= merged["groups_out"] == rec["present_groups"]
+    if where == "device":
+        # a power-of-two bucket of the merged groups leaves one chip
+        assert handed == [] and fetch["chips"] == 1
+        assert merged["groups_out"] <= fetch["rows"] \
+            <= max(64, 2 * merged["groups_out"])
+    else:
+        # a power-of-two bucket of the fullest chip's present groups,
+        # not the cap, leaves each chip
+        assert fetch["chips"] == 4 and fetch["rows"] % 4 == 0
+        assert attempt["present_groups"] <= fetch["rows"] // 4 \
+            <= max(64, 2 * attempt["present_groups"])
+        assert fetch["rows"] >= merged["rows_in"] == handed[-1]
+    assert served == _served(eng, sql)[0]
+    one = eng.runner.history[-1]
+    assert not any(k in one for k in MESH_SPARSE_COUNTERS + ("merge",))
+    assert "broker-merge" not in {
+        s["name"] for s in _walk(eng.tracer.last.to_json())}
+
+
+@pytest.mark.parametrize("where", ["device", "broker"])
+def test_the_head_programs_first_build_is_a_counted_compile(
+        eng4, eng4_broker, where):
+    """The program that cuts the tables (every chip's, or the merged one)
+    to a bucket of present rows is built once a bucket: that build shows on the record like the
+    sort program's (a warm-up run that compiled is run again), and a
+    warm dispatch shows none."""
+    sql = tpch_flat.templates()["q3"]
+    mesh = eng4 if where == "device" else eng4_broker
+    for _ in range(3):   # the cap re-sizes from the first run's count
+        _, warm = _served(mesh, sql)
+    assert warm["jit_cache_hit"] is True and not warm.get("recompiles")
+    cache = mesh.runner._jit_cache
+    heads = [k for k in list(cache) if k[0] == "sparse-head"]
+    assert heads
+    for k in heads:
+        del cache[k]
+    _, rec = _served(mesh, sql)
+    assert rec["jit_cache_hit"] is False and rec["recompiles"] == 1
+    _, again = _served(mesh, sql)
+    assert again["jit_cache_hit"] is True and not again.get("recompiles")
+
+
+def test_four_chips_tables_merge_to_the_one_chip_table():
+    """The broker's merge of four chips' compact tables is the table one
+    chip builds over all the rows, for a sum, a count, a min and a max;
+    one chip holds no row of the window, and one is full (count == cap)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpu_olap.kernels.groupby import AggPlan
+    from tpu_olap.kernels.sparse_groupby import (SENTINEL, merge_device,
+                                                 merge_sparse,
+                                                 sparse_group_reduce)
+
+    cap = 64
+    plans = [AggPlan("s", "sum", ("v",), np.int64),
+             AggPlan("n", "count", (), np.int64),
+             AggPlan("lo", "min", ("v",), np.int64),
+             AggPlan("hi", "max", ("v",), np.int64)]
+    rng = np.random.default_rng(36)
+    chips = [(rng.permutation(np.repeat(np.arange(cap), 3)), True),  # full
+             (rng.integers(0, 200, 500), False),   # no row of the window
+             (rng.integers(40, 90, 400), True),
+             (rng.integers(1 << 40, (1 << 40) + 30, 300), True)]
+    chips = [(k.astype(np.int64), np.full(len(k), m),
+              rng.integers(-1000, 1000, len(k))) for k, m in chips]
+
+    def table(keys, mask, v, cap):
+        env = {"cols": {"v": jnp.asarray(v)}, "nulls": {}}
+        return jax.device_get(sparse_group_reduce(
+            jnp.asarray(keys), jnp.asarray(mask), env, plans, cap, {}, jnp))
+
+    parts = [table(*c, cap) for c in chips]
+    counts = [int(p["_count"]) for p in parts]
+    assert counts[0] == cap and counts[1] == 0
+    # the broker is handed each chip's present rows
+    handed = [dict({k: v[:n] for k, v in p.items() if k != "_count"},
+                   _count=np.int32(n)) for p, n in zip(parts, counts)]
+    whole = table(*(np.concatenate(x) for x in zip(*chips)), 256)
+    n = int(whole["_count"])
+    for given in (handed, parts):   # and whole [cap] tables merge alike
+        merged = merge_sparse(given, plans, 256)
+        assert int(merged["_count"]) == n
+        assert (merged["_keys"][n:] == SENTINEL).all()
+        for name in ("_keys", "_rows", "s", "n", "lo", "hi"):
+            assert np.array_equal(merged[name][:n], whole[name][:n]), name
+    # the device's merge of the same four tables laid end to end: the
+    # broker's table slot for slot, the reduces' identities past it
+    laid = {k: jnp.concatenate([jnp.asarray(p[k]) for p in parts])
+            for k in parts[0] if k != "_count"}
+    on_device = jax.device_get(merge_device(laid, plans, len(parts), jnp))
+    assert int(on_device["_count"]) == n
+    assert set(on_device) == set(merged)
+    for name, table in merged.items():
+        if name != "_count":
+            assert np.array_equal(on_device[name][:256], table), name
+            assert len(on_device[name]) == 4 * cap
+
+
+def test_the_brokers_merge_past_its_cap_and_of_nothing():
+    """Sorted runs of distinct keys merge to one run of distinct keys, the
+    empty slots past it the SENTINEL's; a merge that does not fit its cap
+    says how many groups there were (the runner then stops the query);
+    and four chips with no group at all merge to an empty table."""
+    import numpy as np
+
+    from tpu_olap.kernels import sparse_groupby as sg
+    from tpu_olap.kernels.groupby import AggPlan
+
+    plans = [AggPlan("s", "sum", ("v",), np.int64),
+             AggPlan("lo", "min", ("v",), np.int64)]
+    rng = np.random.default_rng(7)
+    parts = []
+    for n in (700, 0, 450, 64):
+        keys = np.sort(rng.choice(1500, n, replace=False)).astype(np.int64)
+        v = rng.integers(-50, 50, n)
+        parts.append({"_keys": keys, "_rows": np.ones(n, np.int32), "s": v,
+                      "lo": v, "_nn_lo": np.ones(n, np.int32),
+                      "_count": np.int32(n)})
+    distinct = np.unique(np.concatenate([p["_keys"] for p in parts]))
+    whole = sg.merge_sparse(parts, plans, 4096)
+    n = int(whole["_count"])
+    assert n == len(distinct) and len(whole["_keys"]) == 4096
+    assert np.array_equal(whole["_keys"][:n], distinct)
+    assert (whole["_keys"][n:] == sg.SENTINEL).all()
+    assert int(whole["_rows"].sum()) == 700 + 450 + 64
+    assert int(whole["s"][:n].sum()) == sum(int(p["s"].sum()) for p in parts)
+    cut = sg.merge_sparse(parts, plans, 128)   # past the cap: counted
+    assert len(cut["_keys"]) == 128 and int(cut["_count"]) == n > 128
+    none = sg.merge_sparse([{k: v[:0] if k != "_count" else np.int32(0)
+                             for k, v in p.items()} for p in parts],
+                           plans, 64)
+    assert int(none["_count"]) == 0
+    assert (none["_keys"] == sg.SENTINEL).all() and not none["_rows"].any()
+
+
+@pytest.mark.parametrize("name", ["q3", "q10"])
+def test_tied_revenues_keep_one_chips_rows_and_order_on_the_mesh(data, name):
+    """Every lineitem worth the same: a group's revenue is its row count
+    times a constant, so the ten (twenty) rows a LIMIT keeps are cut out
+    of long runs of equal revenues and the last ORDER BY key decides. The
+    mesh's merged table gives the rows and the order one chip gives."""
+    import pyarrow.compute as pc
+
+    t = pa.concat_tables([pq.read_table(p) for p in sorted(data["paths"])])
+    for col, value in (("l_extendedprice", 100_000), ("l_discount", 0)):
+        i = t.schema.get_field_index(col)
+        t = t.set_column(i, col, pa.array([value] * t.num_rows,
+                                          t.schema.field(col).type))
+    frames = []
+    for shards in (1, 4):
+        eng = Engine(EngineConfig(**SERVED_BY_DEVICE, num_shards=shards))
+        eng.register_table(tpch_flat.TABLE, t, time_column="l_shipdate")
+        served, rec = _served(eng, tpch_flat.templates()[name])
+        assert rec["reduce_path"] == "sparse" \
+            and rec.get("num_shards", 1) == shards
+        frames.append(served)
+    assert frames[0] == frames[1]
+    revenue = [r["revenue"] for r in frames[0]["rows"]]
+    assert len(revenue) in (10, 20) and len(set(revenue)) < len(revenue)
+    assert pc.sum(t["l_discount"]).as_py() == 0
 
 
 @pytest.mark.parametrize("shards", [1, 4])

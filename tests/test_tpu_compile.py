@@ -374,3 +374,81 @@ def test_sharded_program_compiles_for_four_chips(
         assert "onehot_group_reduce" in call
         rows = (win[1] if windowed else per_chip) * block_rows
         assert f"[1,{rows}]" in call
+
+
+def test_sparse_program_maps_over_four_chips_as_one_program(
+        topo, no_persistent_cache, ssb_tables, monkeypatch):
+    """The mesh's sparse group-by (sharding.mesh_sparse_kernel) on a
+    four-device Mesh of the described topology: the one-chip sort/compact
+    kernel mapped over the chips as ONE program a cap (a single-device jit
+    a chip compiles the sort once a chip: the persistent cache's key holds
+    the device assignment), each chip's sort over its own rows, no
+    collective and no scatter, the D tables laid end to end; the program
+    that cuts tables to the present groups' bucket before a fetch; and
+    the merge of the D tables on the device."""
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from tpu_olap.executor import sharding as sh
+    _as_tpu(monkeypatch)
+    eng = _engine(ssb_tables, use_pallas="never")
+    phys = _physical(eng, """
+        SELECT lo_orderkey, lo_partkey, sum(lo_revenue) AS revenue,
+               count(*) AS n
+        FROM lineorder GROUP BY lo_orderkey, lo_partkey
+        ORDER BY revenue DESC, lo_orderkey LIMIT 10""")
+    assert phys.sparse and phys.total_groups > eng.config.sparse_group_budget
+    mesh = Mesh(np.asarray(topo.devices[:4]), (sh.AXIS,))
+    env, valid, seg_mask = eng.runner._prepare(phys, {})
+    per_chip, block_rows, cap = 2, phys.table.block_rows, 1 << 12
+    n_seg = 4 * per_chip
+
+    def struct(x, spec):
+        shape = tuple(x.shape)
+        if spec != P():
+            shape = (n_seg,) + shape[1:]
+        return jax.ShapeDtypeStruct(shape, x.dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    seg = P(sh.AXIS)
+    args = (jax.tree_util.tree_map(lambda x: struct(x, seg), env),
+            struct(valid, seg), struct(seg_mask, seg),
+            {k: struct(v, P()) for k, v in phys.pool.consts.items()})
+    fn = sh.mesh_sparse_kernel(phys, mesh, cap)
+    text = fn.lower(*args).compile().as_text()
+    assert " sort(" in text and " scatter(" not in text
+    assert not [c for c in COLLECTIVES if c in text]
+    shapes = {tuple(int(d) for d in m.split(","))
+              for m in re.findall(r"\w\d+\[([\d,]+)\]", text)}
+    assert (per_chip * block_rows,) in shapes   # a chip sorts its own rows
+    assert not [s for s in shapes if math.prod(s) == n_seg * block_rows]
+    out = jax.eval_shape(fn, *args)
+    assert {k: v.shape for k, v in out.items()} == {
+        "_count": (4,), "_rows": (4 * cap,), "_keys": (4 * cap,),
+        "revenue": (4 * cap,), "n": (4 * cap,)}
+    tables = {k: jax.ShapeDtypeStruct(v.shape, v.dtype,
+                                      sharding=NamedSharding(mesh, seg))
+              for k, v in out.items() if k != "_count"}
+    head = sh.mesh_head_kernel(mesh, 1 << 10)
+    head_text = head.lower(tables).compile().as_text()
+    assert not [c for c in COLLECTIVES if c in head_text]
+    assert {k: v.shape for k, v in jax.eval_shape(head, tables).items()} \
+        == {k: (4 << 10,) for k in tables}
+    # the merge on the device: every chip gathers the others' first rows
+    # (the program's one collective) and sorts them twice; nothing is
+    # gathered by row or scattered, and the merged table is replicated
+    merge = sh.mesh_merge_kernel(phys, mesh, 1 << 10)
+    merge_text = merge.lower(tables).compile().as_text()
+    assert "all-gather" in merge_text and merge_text.count(" sort(") == 2
+    assert " scatter(" not in merge_text and " gather(" not in merge_text
+    merged = jax.eval_shape(merge, tables)
+    assert {k: v.shape for k, v in merged.items()} == dict(
+        {k: (4 << 10,) for k in tables}, _count=())
+    whole = {k: jax.ShapeDtypeStruct(v.shape, v.dtype,
+                                     sharding=NamedSharding(mesh, P()))
+             for k, v in merged.items() if k != "_count"}
+    cut = sh.mesh_head_kernel(mesh, 1 << 9, merged=True)
+    assert not [c for c in COLLECTIVES
+                if c in cut.lower(whole).compile().as_text()]
+    assert {k: v.shape for k, v in jax.eval_shape(cut, whole).items()} \
+        == {k: (1 << 9,) for k in tables}

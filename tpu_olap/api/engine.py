@@ -36,7 +36,7 @@ from tpu_olap.resilience.faults import maybe_inject
 from tpu_olap.segments.ingest import (DEFAULT_BLOCK_ROWS, ingest_arrow,
                                       ingest_pandas, ingest_parquet,
                                       ingest_parquet_stream)
-from tpu_olap.utils.platform import configure_compile_cache
+from tpu_olap.utils import platform as _platform
 
 _UNSUPPORTED = (UnsupportedAggregation, UnsupportedFilter,
                 UnsupportedGranularity, UnsupportedDimension)
@@ -69,12 +69,12 @@ def _failure_status(e: BaseException) -> int:
 class Engine:
     def __init__(self, config: EngineConfig | None = None):
         self.config = config or EngineConfig()
-        configure_compile_cache()  # before the first jit
+        _platform.configure_compile_cache()  # before the first jit
+        _platform.retain_freed_memory()
         self.catalog = Catalog()
         self.runner = QueryRunner(self.config)
         self.planner = DruidPlanner(self.catalog, self.config)
-        # observability surfaces (tpu_olap.obs): the runner owns both —
-        # it is where records complete — these aliases are the API
+        # observability (tpu_olap.obs): the runner's; these are the API
         self.tracer = self.runner.tracer
         self.metrics = self.runner.metrics
         self.last_plan = None

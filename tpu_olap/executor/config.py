@@ -53,15 +53,27 @@ class EngineConfig:
     # timeseries) on the dense path
     dense_sketch_state_budget: int = 1 << 28
     # multi-chip sparse merge strategy: both run per-chip local
-    # compaction as fan-out single-device programs over the resident
-    # shards, then the host BROKER re-merges the D compact tables
-    # (executor/sharding.py). "exchange" lets the broker table hold
+    # compaction as the one-chip program mapped over the mesh
+    # (shard_map, one compile a cap), then the host BROKER merges the D
+    # compact tables' present rows (executor/sharding.py). "exchange"
+    # lets the broker table hold
     # D x sparse_group_budget present groups (capacity scales with chip
     # count, any key skew absorbed — there are no hash owners);
     # "gather" keeps the legacy global-budget contract (all groups must
     # fit one chip's table). A multi-host (DCN) mesh hands the whole
     # sparse program to GSPMD instead (global-budget capacity).
     sparse_merge: str = "exchange"
+    # where a one-process mesh merges those D compact tables. "device":
+    # every chip all-gathers the others' present rows over ICI and runs
+    # the merge (one sort of them, the tables read at the runs'
+    # boundaries); the host fetches the merged table from one chip and
+    # sorts nothing. "broker": the host fetches the D tables and merges
+    # them in numpy, as Druid's broker merges its historicals' partial
+    # results — 15 to 53 ms for the same 600,000 rows from one second
+    # to another on the chip machine's host (PERF.md, PR 36), where the
+    # device's 8 ms is 8 ms. A plan with a sketch aggregate ([cap, m]
+    # state) merges at the broker whatever this says.
+    mesh_merge: str = "device"
 
     # HBM residency budget (bytes) for device-cached column buffers across
     # all tables; least-recently-used columns evict when exceeded

@@ -496,45 +496,6 @@ class QueryRunner:
                 + (time.perf_counter() - t0) * 1000, 3)
         return host
 
-    def _fetch_trees(self, outs: list, metrics: dict | None = None,
-                     pin=None):
-        """Per-chip transfer nodes (docs/EXECUTION.md): each chip's
-        output tree fetches on its own transfer-stage slot
-        (stages.map_stage), so D transfers overlap one another AND the
-        next query's enqueue instead of serializing behind one
-        device_get. A single tree degrades to the one-call fetch — no
-        thread hop for nothing."""
-        if len(outs) <= 1:
-            return self._fetch_tree(outs, metrics, pin)
-        t0 = time.perf_counter()
-        try:
-            host = self.stages.map_stage(
-                "transfer",
-                [(lambda o=o: self._fetch_chip(o, metrics))
-                 for o in outs])
-        finally:
-            if pin is not None:
-                self._hbm_ledger.unpin_inflight(pin)
-        if metrics is not None:
-            metrics["transfer_ms"] = round(
-                metrics.get("transfer_ms", 0.0)
-                + (time.perf_counter() - t0) * 1000, 3)
-            metrics["transfer_fanout"] = len(outs)
-        return host
-
-    def _fetch_chip(self, out, metrics: dict | None = None):
-        """One chip's transfer node: its own transfer-stage slot + the
-        host-transfer fault site. No pin bookkeeping — the caller's
-        fan-out pin covers the whole set until every chip lands."""
-        self._note_transfer(1)
-        try:
-            with self.stages.stage("transfer", metrics):
-                self._inject("host-transfer")
-                import jax
-                return jax.device_get(out)
-        finally:
-            self._note_transfer(-1)
-
     def _metric_path(self, m: dict) -> str:
         """Dashboard path label: which execution flavor served this
         record (docs/OBSERVABILITY.md)."""
@@ -1962,12 +1923,12 @@ class QueryRunner:
 
     def _run_sparse(self, plan: PhysicalPlan, metrics: dict, top=None):
         """Sort-based sparse group-by dispatch with adaptive compact-table
-        cap (kernels.sparse_groupby). Multi-chip merge strategy per
-        EngineConfig.sparse_merge: "exchange" hash-partitions compacted
-        entries to key-owner chips over all_to_all (capacity scales
-        D × budget); "gather" all-gathers every chip's table. Returns
-        (partials dict, count); exchange partial arrays are [D·cap_owner]
-        slot tables (SENTINEL-keyed empties), others are [cap] compacts.
+        cap (kernels.sparse_groupby). On a mesh every chip compacts its
+        own rows and the D tables are merged where EngineConfig.mesh_merge
+        says (the chips, or the host broker); EngineConfig.sparse_merge
+        "exchange" lets the merged table hold D × budget groups, "gather"
+        one chip's budget. Returns (partials dict, count): compact
+        tables, SENTINEL-keyed past the present groups.
         With `top` = (metric, threshold, inverted), one chip's program
         ends in the TopN's threshold and the partials are its [threshold]
         rows in rank order (`_device_threshold` says when)."""
@@ -2088,22 +2049,21 @@ class QueryRunner:
                     self._hbm_ledger.unpin_inflight(pin)
             metrics["num_shards"] = 1
         else:
-            # multi-chip sparse: per-chip FAN-OUT dispatch + broker
-            # merge (docs/TPU_NOTES.md "sharded serving"). Each chip's
-            # resident shard runs the local sort/compact kernel as its
-            # own single-device program (the shards are addressable
-            # arrays — no re-upload, and the D async dispatches
-            # enqueue before any is fetched, so per-chip compute and
-            # transfers overlap); the host broker re-merges the D
-            # compact tables with kernels.sparse_groupby.merge_sparse.
-            # sparse_merge="exchange" lets the broker table hold
+            # multi-chip sparse: the one-chip sort/compact kernel mapped
+            # over the mesh + a merge (docs/TPU_NOTES.md "sharded
+            # serving"). Every chip compacts its resident shard in ONE
+            # program a cap (sharding.mesh_sparse_kernel: no collective,
+            # one compile whatever the mesh's size); the chips' present
+            # rows are merged on the device (sharding.mesh_merge_kernel)
+            # or fetched and merged by the broker (merge_sparse).
+            # sparse_merge="exchange" lets the merged table hold
             # D x sparse_group_budget present groups (capacity scales
             # with chip count); "gather" keeps the legacy global-budget
             # contract (every group must fit one chip's table).
             import jax
 
             from tpu_olap.executor import sharding as sh
-            from tpu_olap.kernels.sparse_groupby import merge_sparse
+            from tpu_olap.kernels import sparse_groupby as sg
             if self.mesh_program == "gspmd":
                 # DCN mesh: remote chips' compact tables are not host-
                 # addressable, so neither the fan-out nor the broker
@@ -2161,28 +2121,32 @@ class QueryRunner:
             try:
                 while True:
                     attempts += 1
-                    with self._enqueue_lock(metrics):
-                        consts_dev, seg_arg = self._args_for(
-                            plan, seg_mask, mesh)
-                        key = base_key + ("fanout", cap)
-                        jitted = self._jit_cache.get(key)
-                        hit = jitted is not None
-                        if hit:
-                            _cache_lru_hit(self._jit_cache, key)
-                        else:
-                            jitted = jax.jit(plan.make_sparse_kernel(cap))
-                            self._jit_cache[key] = jitted
-                            self._note_compile("sparse", metrics)
-                        chips = sh.chip_args(env, valid, seg_arg,
-                                             consts_dev, mesh)
-                        outs = [jitted(e, v, m, c)
-                                for (e, v, m, c) in chips]
-                        prev, pin = pin, self._pin_inflight(outs)
-                        self._note_chip_dispatch(range(n_shards))
-                    if prev is not None:
-                        self._hbm_ledger.unpin_inflight(prev)
-                    counts = [int(o["_count"]) for o in outs]
-                    local_max = max(counts)
+                    with _span("sparse-attempt", cap=cap) as sp:
+                        with self._enqueue_lock(metrics):
+                            consts_dev, seg_arg = self._args_for(
+                                plan, seg_mask, mesh)
+                            key = base_key + ("mesh", cap)
+                            jitted = self._jit_cache.get(key)
+                            hit = jitted is not None
+                            if hit:
+                                _cache_lru_hit(self._jit_cache, key)
+                            else:
+                                jitted = sh.mesh_sparse_kernel(plan, mesh,
+                                                               cap)
+                                self._jit_cache[key] = jitted
+                                self._note_compile("sparse", metrics)
+                            out = jitted(env, valid, seg_arg, consts_dev)
+                            prev, pin = pin, self._pin_inflight(out)
+                            self._note_chip_dispatch(range(n_shards))
+                        if prev is not None:
+                            self._hbm_ledger.unpin_inflight(prev)
+                        # the D-element sync that waits for the sorts
+                        with _span("count-probe"):
+                            counts = [int(c) for c in
+                                      jax.device_get(out["_count"])]
+                        local_max = max(counts)
+                        sp.set(present_groups=local_max,
+                               jit_cache_hit=hit)
                     if local_max <= cap:
                         break
                     if local_max > local_limit:
@@ -2190,21 +2154,93 @@ class QueryRunner:
                             f"{local_max} per-chip present groups "
                             f"exceed sparse budget {local_limit}")
                     cap = _grown_cap(local_max, local_limit)
-                parts = self._fetch_trees(outs, metrics, pin)
-                pin = None  # consumed (fetch unpins)
+                def program(key, build, what):
+                    """A second program of the dispatch, built once a key
+                    (a counted compile); call under the enqueue lock."""
+                    nonlocal hit
+                    fn = self._jit_cache.get(key)
+                    if fn is None:
+                        fn = self._jit_cache[key] = build()
+                        self._note_compile(what, metrics)
+                        hit = False
+                    else:
+                        _cache_lru_hit(self._jit_cache, key)
+                    return fn
+
+                # what leaves the chips is the present groups' size, not
+                # the cap's: tables are cut on the device to a power-of-
+                # two bucket (a program a bucket, no sort in it) before
+                # the one fetch
+                def head(tables, rows, merged):
+                    with self._enqueue_lock(metrics):
+                        return program(
+                            ("sparse-head", n_shards, rows, merged),
+                            lambda: sh.mesh_head_kernel(mesh, rows, merged),
+                            "sparse-head")(tables)
+
+                tables = {k: v for k, v in out.items() if k != "_count"}
+                rows = min(cap, max(64, _next_pow2(local_max)))
+                rows_in = sum(counts)
+                cap_global = min(cap_limit, max(64, _next_pow2(rows_in)))
+                on_device = self.config.mesh_merge == "device" \
+                    and sg.merges_on_device(plan.agg_plans)
+                if on_device:
+                    # every chip gathers the others' first `rows` slots
+                    # and merges them; the host waits for the merged
+                    # count and fetches one chip's copy of the table
+                    with _span("broker-merge", num_shards=n_shards,
+                               where="device") as sp:
+                        with self._enqueue_lock(metrics):
+                            tables = program(
+                                base_key + ("mesh-merge", rows),
+                                lambda: sh.mesh_merge_kernel(plan, mesh,
+                                                             rows),
+                                "sparse-merge")(tables)
+                        count = int(tables.pop("_count"))
+                        sp.set(rows_in=rows_in, groups_out=count)
+                    if count > cap_limit:
+                        raise UnsupportedAggregation(
+                            f"{count} present groups exceed sparse "
+                            f"budget {cap_limit}")
+                    n_from, n_rows = 1, max(64, _next_pow2(count))
+                    if n_rows < n_shards * rows:
+                        tables = head(tables, n_rows, True)
+                    else:
+                        n_rows = n_shards * rows
+                else:
+                    n_from, n_rows = n_shards, n_shards * rows
+                    if rows < cap:
+                        tables = head(tables, rows, False)
+                with _span("sparse-shard-fetch", chips=n_from,
+                           rows=n_rows) as sp:
+                    tables = self._fetch_tree(tables, metrics, pin)
+                    pin = None  # consumed (fetch unpins)
+                    fetched = sum(int(a.nbytes) for a in tables.values())
+                    sp.set(bytes=fetched)
             finally:
                 if pin is not None:
                     self._hbm_ledger.unpin_inflight(pin)
-            with _span("broker-merge", num_shards=n_shards):
-                cap_global = min(cap_limit, max(64, _next_pow2(
-                    max(1, sum(counts)))))
-                out = merge_sparse(parts, plan.agg_plans, cap_global,
-                                   np)
-                count = int(out["_count"])
-                if count > cap_limit:
-                    raise UnsupportedAggregation(
-                        f"{count} present groups exceed sparse budget "
-                        f"{cap_limit}")
+            if on_device:
+                out = dict(tables, _count=np.int32(count))
+            else:
+                # the broker merges the chips' present rows
+                with _span("broker-merge", num_shards=n_shards,
+                           where="broker") as sp:
+                    parts = [dict({k: v[:n] for k, v in t.items()},
+                                  _count=np.int32(n))
+                             for t, n in zip(
+                                 sh.chip_tables(tables, n_shards), counts)]
+                    out = sg.merge_sparse(parts, plan.agg_plans,
+                                          cap_global)
+                    count = int(out["_count"])
+                    sp.set(rows_in=rows_in, groups_out=count)
+                    if count > cap_limit:
+                        raise UnsupportedAggregation(
+                            f"{count} present groups exceed sparse "
+                            f"budget {cap_limit}")
+            metrics["merge"] = "device" if on_device else "broker"
+            metrics["sparse_fetch_bytes"] = fetched
+            metrics["sparse_merge_rows_in"] = rows_in
             self._cap_hints[base_key + ("local",)] = local_max
             metrics["num_shards"] = n_shards
             if use_exchange:

@@ -7,7 +7,8 @@ segment→chip assignment (segment i → chip i mod D, the way a Druid
 coordinator balances an interval's segments across historicals), and the
 dense aggregate runs as the SINGLE-CHIP kernel under `jax.shard_map`:
 every chip reduces its own rows with the program one chip would run
-(the Pallas one-hot reduce included), and no collective is compiled in.
+(the Pallas one-hot reduce included), and no collective is compiled in
+(the sparse group-by's merge, below, is the one exception).
 
 Which spelling of the dense aggregate runs is a fact of the mesh, fixed
 when it is built (QueryRunner.mesh), and the record's `mesh_program`
@@ -37,13 +38,23 @@ movement and ONE compiled program per (template, local width). The
 GSPMD spellings (the "gspmd" program, the per-segment cache partials)
 reshape [S, R] → [D, S/D, R] and slice the local axis instead.
 
-High-cardinality sparse group-by fans out as true per-chip programs:
-each chip's resident shard (an addressable single-device array — no
-re-upload) runs the local sort/compact kernel, the D dispatches enqueue
-asynchronously and fetch together, and the host broker re-merges the
-compact tables (kernels.sparse_groupby.merge_sparse). Present-group
-capacity under sparse_merge="exchange" is D × the per-chip budget —
-the broker holds the union, so capacity scales with chip count.
+High-cardinality sparse group-by runs the same way: the one-chip
+sort/compact kernel under `jax.shard_map` (mesh_sparse_kernel), ONE
+program a cap for the whole mesh — a single-device jit a chip would
+compile the sort once a chip, the persistent cache's key holding the
+device assignment — whose [D·cap] compact tables stay on their chips.
+They are merged where EngineConfig.mesh_merge says. "device" (the
+default): a second program (mesh_merge_kernel) has every chip all-gather
+the others' present rows — a power-of-two bucket of the largest count —
+and merge them (kernels.sparse_groupby.merge_device), the engine's one
+collective; the host waits for the merged count, cuts the replicated
+table to a bucket of it (mesh_head_kernel) and fetches one copy.
+"broker", and any plan with a sketch aggregate: mesh_head_kernel cuts
+every chip's tables to that bucket, one fetch brings the D of them, and
+kernels.sparse_groupby.merge_sparse merges the key-sorted tables in
+numpy. Present-group capacity under sparse_merge="exchange" is D × the
+per-chip budget — the merged table holds the union, so capacity scales
+with chip count.
 """
 
 from __future__ import annotations
@@ -140,32 +151,70 @@ def replicate_put(arr, mesh: Mesh):
     return jax.device_put(arr, replicated_spec(mesh))
 
 
-def chip_shards(arr, mesh: Mesh) -> list:
-    """Per-chip single-device views of a sharded (or replicated) array,
-    in mesh order — each is a committed jax.Array resident on its chip,
-    usable directly as an input to a per-device jitted program (the
-    sparse fan-out path). No copies: the shards are the same buffers
-    the sharded array owns."""
-    by_dev = {s.device: s.data for s in arr.addressable_shards}
-    return [by_dev[d] for d in mesh.devices.flat]
+def mesh_sparse_kernel(plan, mesh: Mesh, cap: int):
+    """Jitted sparse group-by over the mesh: the one-chip program
+    (`plan.make_sparse_kernel(cap)`: key, sort, the [cap] tables read at
+    the runs' boundaries) `jax.shard_map`ped over the chip axis, as
+    mesh_agg_kernel maps the dense one. Each chip compacts its own rows;
+    out_specs=P(chips) lays the D tables end to end as [D·cap, ...] and
+    the D true counts as `_count` [D], a chip each. No collective is in
+    the program, and it compiles once a cap whatever the mesh's size."""
+    local = plan.make_sparse_kernel(cap)
+
+    def per_chip(env, valid, seg_mask, consts):
+        out = local(env, valid, seg_mask, consts)
+        return dict(out, _count=out["_count"].reshape(1))
+
+    seg, rep = P(AXIS), P()
+    return jax.jit(jax.shard_map(per_chip, mesh=mesh,
+                                 in_specs=(seg, seg, seg, rep),
+                                 out_specs=seg, check_vma=False))
 
 
-def chip_args(env, valid, seg_mask, consts, mesh: Mesh) -> list:
-    """Per-chip (env, valid, seg_mask, consts) argument tuples for the
-    sparse fan-out dispatch: sharded arrays split into their resident
-    per-device shards, replicated consts resolve to each chip's copy —
-    every piece is already on its chip, so the D single-device programs
-    launch with zero re-upload."""
-    D = mesh.devices.size
-    cols = {k: chip_shards(v, mesh) for k, v in env["cols"].items()}
-    nulls = {k: chip_shards(v, mesh) for k, v in env["nulls"].items()}
-    vs = chip_shards(valid, mesh)
-    ms = chip_shards(seg_mask, mesh)
-    cs = {k: chip_shards(v, mesh) for k, v in consts.items()}
-    return [({"cols": {k: cols[k][c] for k in cols},
-              "nulls": {k: nulls[k][c] for k in nulls}},
-             vs[c], ms[c], {k: cs[k][c] for k in cs})
-            for c in range(D)]
+def mesh_head_kernel(mesh: Mesh, rows: int, merged: bool = False):
+    """Jitted [D·cap, ...] -> [D·rows, ...]: every chip's first `rows`
+    slots of each compact table. Slot i of a chip's table holds its i-th
+    smallest present key, so the first `count` slots are all it has to
+    say; what the broker fetches is then the present groups' size, not
+    the cap's. `merged`: the tables are mesh_merge_kernel's, one
+    replicated table and not a chip's each."""
+    def head(tables):
+        return {name: t[:rows] for name, t in tables.items()}
+
+    spec = P() if merged else P(AXIS)
+    return jax.jit(jax.shard_map(head, mesh=mesh, in_specs=(spec,),
+                                 out_specs=spec, check_vma=False))
+
+
+def mesh_merge_kernel(plan, mesh: Mesh, rows: int):
+    """Jitted [D·cap, ...] -> replicated [D·rows, ...]: the chips'
+    compact tables merged on the device. Every chip all-gathers the
+    others' first `rows` slots over ICI (a few megabytes) and runs the
+    same merge (kernels.sparse_groupby.merge_device: two sorts of D·rows
+    partial rows), so the merged table stands on every chip and the host
+    fetches one copy of it: nothing of a sparse query is sorted or
+    reduced on the host."""
+    import jax.numpy as jnp
+
+    from tpu_olap.kernels.sparse_groupby import merge_device
+
+    def merge(tables):
+        whole = {name: jax.lax.all_gather(t[:rows], AXIS, tiled=True)
+                 for name, t in tables.items()}
+        return merge_device(whole, plan.agg_plans, mesh.devices.size, jnp)
+
+    return jax.jit(jax.shard_map(merge, mesh=mesh, in_specs=(P(AXIS),),
+                                 out_specs=P(), check_vma=False))
+
+
+def chip_tables(out: dict, num_shards: int) -> list:
+    """{name: [D·n, ...]} fetched per-chip tables -> D dicts of [n, ...]
+    views, in mesh order (no copy)."""
+    split = {name: np.asarray(v).reshape(
+                 (num_shards, -1) + np.shape(v)[1:])
+             for name, v in out.items()}
+    return [{name: v[d] for name, v in split.items()}
+            for d in range(num_shards)]
 
 
 def local_window(pruned_ids, num_shards: int, per_chip: int):
@@ -321,11 +370,6 @@ def broker_merge(out: dict, agg_plans, num_shards: int) -> dict:
     registers max-merge, theta tables re-merge losslessly)."""
     from tpu_olap.kernels.groupby import merge_partials
 
-    parts = []
-    for d in range(num_shards):
-        parts.append({
-            name: np.asarray(v).reshape(
-                (num_shards, -1) + np.asarray(v).shape[1:])[d]
-            for name, v in out.items()})
     return functools.reduce(
-        lambda a, b: merge_partials(a, b, agg_plans), parts)
+        lambda a, b: merge_partials(a, b, agg_plans),
+        chip_tables(out, num_shards))

@@ -7,7 +7,7 @@ Query execution is an explicit per-query stage graph —
 — and this module is the small scheduler that drives it. Each stage
 class owns a bounded worker pool (StagePool): a stage section occupies
 one pool slot for its duration, waiters queue on the pool, and async
-submissions (the per-chip transfer fan-out, background graphs) run on
+submissions (background graphs) run on
 real pool worker threads. The executor's previous shape — the caller's
 thread doing host-transfer AND assembly while the next query waits on
 one coarse lock — becomes independent per-stage capacities: transfer
@@ -93,7 +93,7 @@ class StagePool:
       (synchronous stages on the query's own thread — no handoff cost,
       the pool bounds stage *concurrency* and accounts queue wait);
     - submit(): the task runs on a pool worker thread (asynchronous
-      stages: per-chip transfer fan-out, background graph bodies),
+      stages: background graph bodies),
       spawned on demand up to max_workers and reaped when idle.
 
     Slots are re-entrant per thread (a nested section on the same
@@ -444,26 +444,6 @@ class StageScheduler:
                             {"stage": name,
                              "wait_ms": round(waited_ms, 3),
                              "run_ms": round(run_ms, 3)})
-
-    def submit(self, name: str, fn) -> _Future:
-        """Run `fn` asynchronously on the named stage's pool (the
-        per-chip transfer fan-out: enqueue D programs, then overlap D
-        fetches on transfer workers)."""
-        return self.pools[name].submit(fn)
-
-    def map_stage(self, name: str, fns):
-        """Fan a list of thunks across the named stage's pool and
-        return results in order; with one thunk (or a stopped pool) run
-        inline — a single-device transfer must not pay a thread hop."""
-        fns = list(fns)
-        if len(fns) <= 1:
-            return [fn() for fn in fns]
-        try:
-            futs = [self.pools[name].submit(fn) for fn in fns[1:]]
-        except RuntimeError:  # pool stopped (engine closing): run inline
-            return [fn() for fn in fns]
-        first = fns[0]()  # caller participates instead of idling
-        return [first] + [f.result() for f in futs]
 
     def reclaim_stranded(self, older_than_s: float | None = None) -> int:
         """Wedge recovery: free stage slots held by abandoned threads

@@ -293,22 +293,72 @@ def sparse_top_rows(tables: dict, metric: str, threshold: int,
             for name, t in tables.items()}
 
 
-def merge_sparse(parts: list, plans, cap, xp):
-    """Merge compacted tables (e.g. the [D, cap] slices of an all_gather):
-    concatenate and re-reduce by key. Values are already partial
-    aggregates, so the merge semantics differ from row reduction — sums
-    and counts re-sum, min/max re-extremize, HLL registers re-max, theta
-    re-merges pairwise."""
+def merges_on_device(plans) -> bool:
+    """Whether `merge_device` holds every aggregate of the plan: counts,
+    sums, mins and maxes are [cap] tables that ride a sort beside the
+    key. A sketch's [cap, m] state does not, and keeps the broker's arm
+    (`merge_sparse`)."""
+    return all(p.kind in ("count", "sum", "min", "max") for p in plans)
+
+
+def merge_device(tables: dict, plans, parts: int, xp):
+    """Merge `parts` compact tables on the device. `tables` holds them
+    laid end to end (an all_gather of the chips' first rows; an empty
+    slot carries the SENTINEL key and the reduces' identities). A chip's
+    table holds a key once, so after one sort by key a group is a run of
+    at most `parts` rows: the run's first row takes in the `parts - 1`
+    rows after it where they carry its key — `_rows`, counts, sums and
+    non-null counts re-sum, min / max re-extremise — and a second sort,
+    by the key on a run's first row and the SENTINEL elsewhere, leaves
+    the merged groups first, in key order, as the one-chip program's
+    table has them. No row is gathered or scattered. -> tables as long
+    as the input, `_count` the merged groups."""
     import jax
 
-    keys = xp.concatenate([p["_keys"] for p in parts])
+    names = [n for n in tables if n != "_keys"]
+    sorted_ops = jax.lax.sort(
+        (tables["_keys"],) + tuple(tables[n] for n in names),
+        num_keys=1, is_stable=False)
+    skey = sorted_ops[0]
+    kinds = {p.name: p.kind for p in plans if p.kind in ("min", "max")}
+    first = xp.concatenate([xp.ones((1,), bool), skey[1:] != skey[:-1]]) \
+        & (skey != SENTINEL)
 
-    if xp is np:
-        order = np.argsort(keys, kind="stable")
-    else:
-        (_, order) = jax.lax.sort(
-            (keys, xp.arange(keys.shape[0], dtype=xp.int32)), num_keys=1)
-        order = order.astype(xp.int32)
+    def later(v, k, fill):
+        """v as it stands k rows on, `fill` past the end."""
+        return xp.concatenate([v[k:], xp.full((k,), fill, v.dtype)])
+
+    merged = []
+    for name, v in zip(names, sorted_ops[1:]):
+        kind = kinds.get(name, "sum")
+        fill = _ident(v.dtype, kind) if kind != "sum" else 0
+        f = {"sum": xp.add, "min": xp.minimum, "max": xp.maximum}[kind]
+        acc = v
+        for k in range(1, parts):
+            same = later(skey, k, SENTINEL) == skey
+            acc = f(acc, xp.where(same, later(v, k, fill), fill))
+        merged.append(acc)
+    out = jax.lax.sort(
+        (xp.where(first, skey, SENTINEL),) + tuple(merged),
+        num_keys=1, is_stable=False)
+    empty = out[0] == SENTINEL
+    result = {"_count": first.sum(dtype=xp.int32), "_keys": out[0]}
+    for name, v in zip(names, out[1:]):
+        kind = kinds.get(name, "sum")
+        result[name] = xp.where(
+            empty, _ident(v.dtype, kind) if kind != "sum" else 0, v)
+    return result
+
+
+def merge_sparse(parts: list, plans, cap):
+    """The host broker's merge of compacted tables (the chips' present
+    rows, fetched): concatenate and re-reduce by key into [cap] tables.
+    Values are already partial aggregates, so the merge semantics differ
+    from row reduction — sums and counts re-sum, min/max re-extremize,
+    HLL registers re-max, theta re-merges pairwise."""
+    xp = np
+    keys = np.concatenate([p["_keys"] for p in parts])
+    order = np.argsort(keys, kind="stable")
     skey = keys[order]
     gid, count = _sorted_segments(skey, cap, xp)
     # a chip whose LOCAL table overflowed already dropped groups; the

@@ -92,3 +92,30 @@ def env_flag(name: str, default: bool = False) -> bool:
     if v is None:
         return default
     return v.strip().lower() not in ("", "0", "false", "no", "off")
+
+
+def retain_freed_memory() -> bool:
+    """Keep the memory the process frees (glibc's malloc; elsewhere a
+    no-op, False). Engine.__init__ calls it.
+
+    A query's host temporaries are the same few megabytes every time:
+    the tables a mesh's sparse dispatch fetches, the concatenations and
+    the sort of the broker's merge. Left to its dynamic thresholds glibc
+    hands them back to the kernel (a heap top past the trim threshold, a
+    chunk past the mmap threshold) and faults them in again, page by
+    page, at the next query -- or keeps them, as the thresholds and the
+    heap's layout happen to stand, for seconds at a time. On the chip
+    machine that alone moved a q10's fetch between 11 and 33 ms and its
+    merge between 31 and 92 (PERF.md, PR 36). So: the mmap threshold at
+    its largest (32 MiB), the trim threshold past any query's
+    temporaries (1 GiB); setting either also ends the dynamic
+    adjustment."""
+    import ctypes
+
+    try:
+        mallopt = ctypes.CDLL(None).mallopt
+    except (OSError, AttributeError):
+        return False
+    m_trim_threshold, m_mmap_threshold = -1, -3   # <malloc.h>
+    return bool(mallopt(m_mmap_threshold, 32 << 20)
+                and mallopt(m_trim_threshold, 1 << 30))
