@@ -126,10 +126,11 @@ def test_wide_topn_on_the_sparse_path_equals_the_reference(eng_sparse, data,
     served, rec = _served(eng_sparse, druid_lineitem.templates()[name])
     assert verify.answer_mismatches(served, data["expected"][name]) == []
     assert (rec["query_type"], rec["reduce_path"]) == ("topN", "sparse")
-    # min / max still ride jax.ops.segment_*: the side of
-    # sparse_reduce_form that no cell had
-    assert rec["reduce_form"] == ("boundary" if name == "top_100_parts"
-                                  else "scatter")
+    # l_discount is stored as int8: its min and max are read at the sorted
+    # runs' last rows from an int32 word, no row scattered (PR 37)
+    assert rec["reduce_form"] == "boundary"
+    assert rec.get("ext_word_bits") == (None if name == "top_100_parts"
+                                        else 32)
     assert rec["topn_rows_fetched"] == 100 < rec["sparse_cap"]
     assert rec["topn_group_space"] == PARTS + 1
     assert rec["present_groups"] == PARTS

@@ -452,6 +452,155 @@ def test_compact_tables_equal_the_numpy_reference(case):
             np.testing.assert_array_equal(got[name], table, err_msg=name)
 
 
+# --------------------------------------------------------------------------
+# An integer min / max of a column stored in 32 bits or fewer is read at the
+# runs' last rows from a running maximum of (run id << b) | code (PR 37).
+
+EXT_AGGS = {
+    "min": lambda f: [_agg("lo", "min", "x", filter_fn=f)],
+    "max": lambda f: [_agg("hi", "max", "x", filter_fn=f)],
+    "both-of-one-column": lambda f: [_agg("lo", "min", "x", filter_fn=f),
+                                     _agg("hi", "max", "x"),
+                                     _agg("s", "sum", "x")],
+    "of-two-columns": lambda f: [_agg("lo", "min", "x"),
+                                 _agg("hi", "max", "y", filter_fn=f),
+                                 _agg("n", "count")],
+}
+# (cap, the share of rows the query's filter keeps, distinct keys)
+EXT_SHAPES = {
+    "cap-holds-with-empty-slots": (64, 0.8, 40),
+    "cap-overflows": (16, 0.9, 40),
+    "every-row-masked": (8, 0.0, 9),
+    "cap-past-an-int32-word": (1 << 14, 0.8, 40),
+}
+
+
+def _ext_case(stored, rows, shape):
+    """Keys, mask, env: a column `x` and a column `y` of dtype `stored`
+    that hold the dtype's two ends, negatives and a group of one value."""
+    cap, keep, groups = EXT_SHAPES[shape]
+    rng = np.random.default_rng([30, np.dtype(stored).itemsize, cap])
+    n = 257
+    lim = np.iinfo(stored)
+    cols = {}
+    for c in ("x", "y"):
+        v = rng.integers(lim.min, lim.max, n, dtype=np.int64, endpoint=True)
+        v[:6] = [lim.min, lim.max, 0, -1, lim.min + 1, lim.max - 1]
+        cols[c] = rng.permutation(v).astype(stored)
+    cols["f"] = rng.integers(-3, 4, n)
+    env = {"cols": cols, "nulls": {}}
+    if rows == "nulls":
+        env["nulls"] = {"x": rng.random(n) < 0.4, "y": rng.random(n) < 0.9}
+    key = rng.integers(0, groups, n).astype(np.int64)
+    key[key == 3] = 2          # a group no row has: the slots shift
+    return key, rng.random(n) < keep, env, cap
+
+
+@pytest.mark.parametrize("shape", sorted(EXT_SHAPES))
+@pytest.mark.parametrize("rows", ["no-filter", "filtered-aggregator",
+                                  "nulls"])
+@pytest.mark.parametrize("aggs", sorted(EXT_AGGS))
+@pytest.mark.parametrize("stored", ["int8", "int16", "int32", "int64"])
+def test_integer_min_max_tables_equal_the_numpy_reference(stored, aggs,
+                                                          rows, shape):
+    """Every [cap] table, `_nn_<name>` and `_count` among them, equal to
+    numpy's for each stored width, on either side of each word width, with
+    a filtered aggregator (whole groups left out: the identity, not a
+    neighbour's value) and with nulls."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_olap.kernels.sparse_groupby import (ext_word_dtype,
+                                                 sparse_group_reduce,
+                                                 sparse_reduce_form)
+    EngineConfig().apply_x64()
+    key, mask, env, cap = _ext_case(stored, rows, shape)
+    plans = EXT_AGGS[aggs](_positive if rows == "filtered-aggregator"
+                           else None)
+    word = ext_word_dtype(stored, np.int64, cap)
+    assert word == {"int8": np.int32, "int64": None, "int32": np.int64,
+                    "int16": np.int32 if cap < 1 << 14 else np.int64}[stored]
+    assert sparse_reduce_form(plans, {"x": stored, "y": stored}, cap) \
+        == ("scatter" if word is None else "boundary")
+    got = jax.device_get(sparse_group_reduce(
+        jnp.asarray(key), jnp.asarray(mask),
+        {k: {c: jnp.asarray(a) for c, a in d.items()}
+         for k, d in env.items()}, plans, cap, {}, jnp))
+    want = _numpy_tables(key, mask, env, plans, cap)
+    assert int(got["_count"]) == int(want["_count"])
+    if want["_count"] > cap:
+        assert shape == "cap-overflows"
+        return  # an overflowing attempt owes the true count and no table
+    assert shape != "cap-overflows" and set(got) == set(want)
+    for name, table in want.items():
+        assert got[name].dtype == table.dtype, name
+        np.testing.assert_array_equal(got[name], table, err_msg=name)
+
+
+@pytest.mark.parametrize("dtype,n,block", [
+    ("int32", 1000, 8), ("int64", 1000, 8), ("int64", 1024, 8),
+    ("int64", 7, 8), ("int64", 3 * 4096 + 5, 4096)])
+def test_running_max_equals_numpys(monkeypatch, dtype, n, block):
+    """The 64-bit running maximum runs along blocks (the chip's compiler
+    does not take a long one-dimensional one): blocks of blocks, a ragged
+    last block, a length under one block; the 32-bit one is one scan."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_olap.kernels import sparse_groupby as sg
+    EngineConfig().apply_x64()
+    monkeypatch.setattr(sg, "_SCAN_BLOCK", block)
+    rng = np.random.default_rng(n)
+    top = np.iinfo(dtype).max
+    word = np.sort(rng.integers(0, top >> 9, n, dtype=dtype)) << 9 \
+        | rng.integers(0, 512, n, dtype=dtype)
+    got = jax.device_get(sg._running_max(jnp.asarray(word)))
+    assert got.dtype == word.dtype
+    np.testing.assert_array_equal(got, np.maximum.accumulate(word))
+
+
+def test_a_narrow_min_max_program_holds_no_scatter_and_an_int64_ones_does():
+    """A sum, a min and a max of an int8 column: no scatter primitive in
+    the program, one running maximum a min / max, and the column rides the
+    sort once for both. The same over a column stored in 64 bits keeps
+    `jax.ops.segment_min` / `segment_max`, and the form says so."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_olap.kernels.sparse_groupby import (ext_word_bits,
+                                                 sparse_group_reduce,
+                                                 sparse_reduce_form)
+    EngineConfig().apply_x64()
+    plans = [_agg("s", "sum", "v"), _agg("lo", "min", "v"),
+             _agg("hi", "max", "v")]
+    n, cap = 4096, 64
+
+    def program(dtype):
+        """(the program's text, the operands of its widest sort)"""
+        env = {"cols": {"v": jnp.arange(n).astype(dtype)}, "nulls": {}}
+        jaxpr = jax.make_jaxpr(lambda k, m, e: sparse_group_reduce(
+            k, m, e, plans, cap, {}, jnp))(
+            jnp.arange(n, dtype=jnp.int64) % 50, jnp.ones(n, bool), env)
+        return str(jaxpr), max(len(e.invars) for e in jaxpr.jaxpr.eqns
+                               if e.primitive.name == "sort")
+
+    (narrow, narrow_sorts), (wide, wide_sorts) = \
+        program(jnp.int8), program(jnp.int64)
+    assert "scatter" not in narrow and narrow.count("cummax") == 2
+    assert "scatter" in wide and "cummax" not in wide
+    # key, the sum's operand, the column once | key, sum, min, max
+    assert (narrow_sorts, wide_sorts) == (3, 4)
+    for dtype, form, bits in ((np.int8, "boundary", 32),
+                              (np.int32, "boundary", 64),
+                              (np.int64, "scatter", None)):
+        assert sparse_reduce_form(plans, {"v": dtype}, cap) == form
+        assert ext_word_bits(plans, {"v": dtype}, cap) == bits
+    # the word follows the cap too: nine bits of code under 22 of run id
+    assert ext_word_bits(plans, {"v": np.int8}, 1 << 21) == 32
+    assert ext_word_bits(plans, {"v": np.int8}, 1 << 22) == 64
+    assert ext_word_bits(plans[:1], {"v": np.int8}, cap) is None
+
+
 def test_a_min_or_max_rides_at_the_columns_width_up_to_int32():
     from tpu_olap.kernels.sparse_groupby import _ext_dtype
     for col in (np.int8, np.uint8, np.int16, np.uint16, np.int32):
@@ -493,11 +642,14 @@ def test_no_scatter_in_a_sum_and_count_program_and_a_sketch_says_so():
         jnp.arange(n, dtype=jnp.int64) % 50, jnp.ones(n, bool), env)
     assert "scatter" not in str(jaxpr)
     assert "sort" in str(jaxpr)
-    assert sparse_reduce_form(plans) == "boundary"
+    stored = {c: a.dtype for c, a in env["cols"].items()}
+    assert sparse_reduce_form(plans, stored, 64) == "boundary"
+    # a min is a scatter only of a column stored in 64 bits (or a double)
     for kind, acc in (("min", np.int64), ("sum", np.float64),
                       ("hll", np.int32), ("theta", np.int64)):
         assert sparse_reduce_form(
-            plans + [_agg("x", kind, "v", acc)]) == "scatter", kind
+            plans + [_agg("x", kind, "v", acc)], stored, 64) == "scatter", \
+            kind
 
     eng = _engine()
     eng.sql("SELECT a, b, approx_count_distinct(c) AS d, count(*) AS n "
@@ -529,4 +681,8 @@ def test_sparse_gspmd_spelling_parity(monkeypatch):
     rec = eng.history[-1]
     assert rec["sparse"] and rec["num_shards"] == 8
     assert eng.runner.mesh_program == "gspmd"
-    assert rec["reduce_form"] == "scatter"   # SQL holds a min and a max
+    # SQL's min(w) is a double's, which no integer word holds; its max(v),
+    # stored as int16 with nulls, is read from one beside it
+    assert rec["reduce_form"] == "scatter"
+    assert rec["ext_word_bits"] == (32 if rec["sparse_cap"] < 1 << 14
+                                    else 64)

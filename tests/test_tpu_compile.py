@@ -270,6 +270,78 @@ def test_sparse_topn_program_compiles_for_v5e(topo, no_persistent_cache,
         "n": (100,)}
 
 
+def test_sparse_min_max_program_compiles_for_v5e(topo, no_persistent_cache,
+                                                 monkeypatch, tmp_path):
+    """`top_100_parts_details` of the Druid lineitem cell, as the chip
+    runs it (the part keys past the dense budget): two int64 sums and the
+    min and max of `l_discount`, stored as int8, read at the sorted runs'
+    last rows from a running maximum of an int32 word. The chip's compiler
+    takes the program; no scatter is in it, the running reduces are
+    reduce-windows, and the column rides the sort once for both."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    from perfbench.datasets import druid_lineitem
+    from tpu_olap.kernels.sparse_groupby import ext_word_bits
+    _as_tpu(monkeypatch)
+    rows, seed = 60_000, 2_147_483_659
+    data = druid_lineitem.generate(rows, seed, str(tmp_path), workers=1,
+                                   orders_per_chunk=7_000)
+    eng = Engine(EngineConfig(fallback_on_device_failure=False,
+                              dense_group_budget=1024))
+    druid_lineitem.register(eng, data["paths"], rows, seed)
+    phys = _physical(eng,
+                     druid_lineitem.templates()["top_100_parts_details"])
+    assert phys.query.query_type == "topN" and phys.sparse
+    top = eng.runner._device_threshold(phys.query, phys)
+    assert top == ("sum_quantity", 100, False)
+    env, valid, seg_mask = eng.runner._prepare(phys, {})
+    assert env["cols"]["l_discount"].dtype == "int8"
+    stored = {c: a.dtype for c, a in env["cols"].items()}
+    # the cell's cap: 2,000,001 part keys, 21 bits over nine of code
+    for cap in (phys.total_groups, 2_000_001):
+        assert ext_word_bits(phys.agg_plans, stored, cap) == 32
+    consts_dev, seg_arg = eng.runner._args_for(phys, seg_mask, None)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    kernel = phys.make_sparse_kernel(phys.total_groups, top)
+    lowered = jax.jit(kernel).lower(
+        *_scaled((env, valid, seg_arg), 1, one_chip),
+        _scaled(consts_dev, 1, one_chip))
+    main_sort = max(re.findall(r"stablehlo\.sort\"?\(([^)]*)\)",
+                               lowered.as_text()), key=len)
+    # key, two sums, l_discount once
+    assert len(main_sort.split(",")) == 4, main_sort
+    text = lowered.compile().as_text()
+    assert " scatter(" not in text and " sort(" in text
+    assert " reduce-window(" in text
+    out = jax.eval_shape(kernel, env, valid, seg_arg, consts_dev)
+    assert {k: (v.shape, str(v.dtype)) for k, v in out.items()
+            if "discount" in k} == {
+        "min_discount": ((100,), "int64"), "max_discount": ((100,), "int64"),
+        "_nn_min_discount": ((100,), "int32"),
+        "_nn_max_discount": ((100,), "int32")}
+
+
+@pytest.mark.parametrize("word", ["int32", "int64"])
+def test_running_max_compiles_at_the_druid_cells_rows(topo,
+                                                      no_persistent_cache,
+                                                      word):
+    """The running maximum a sparse min / max is read from, over the
+    59,986,052 rows of the Druid lineitem cell: the chip's compiler takes
+    the int32 word as one `lax.cummax` and the int64 word along blocks (a
+    one-dimensional int64 `lax.cummax` of that length takes it down, and a
+    million rows a minute: PERF.md section 6, PR 37)."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    from tpu_olap.kernels.sparse_groupby import _running_max
+    EngineConfig().apply_x64()
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    compiled = jax.jit(_running_max).lower(jax.ShapeDtypeStruct(
+        (59_986_052,), word, sharding=one_chip)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
 def test_q12_shaped_program_has_no_row_gather_on_v5e(topo,
                                                      no_persistent_cache,
                                                      monkeypatch):

@@ -19,15 +19,27 @@ reduce and the threshold on the device (PERF.md section 6, PR 32).
     python tools/sweep_group_reduce.py --allow-cpu --rows 200000   # rehearsal
 
 `--sorted` sweeps the sparse group-by's reduce instead
-(`kernels/sparse_groupby.py`, PERF.md section 6, PR 30): group ids sorted
-into `--runs` runs with a masked tail, as they leave the sort, for an int64
-sum, an int32 count, an int64 min and a float64 sum; `segment_*` as XLA's
-scatter has it, the same with `indices_are_sorted=True`, and the boundary
-form (an integer sum is a difference of prefix sums at the runs' first
-rows; a min or a float sum a segmented running reduce read at their last)
-with each of three ways to the runs' first rows: a binary search of the
-slot numbers in the ids, a one-operand sort of the boundary positions, a
-scatter-min of the positions.
+(`kernels/sparse_groupby.py`, PERF.md section 6, PRs 30 and 37): group ids
+sorted into `--runs` runs with a masked tail, as they leave the sort, for
+an int64 sum, an int32 count, an int64 min, a float64 sum and the min / max
+of a column stored as int8 or int32 (carried as int32, as the engine's sort
+carries it); `segment_*` as XLA's scatter has it, the same with
+`indices_are_sorted=True`, and the boundary form (an integer sum is a
+difference of prefix sums at the runs' first rows; a min or a float sum a
+segmented running reduce read at their last) with each of three ways to the
+runs' first rows: a binary search of the slot numbers in the ids, a
+one-operand sort of the boundary positions, a scatter-min of the positions.
+For a stored integer min / max, PR 37's forms: `cummax_word32` and
+`cummax_word64`, the running maximum of the one word (run id << b) | code
+read at the runs' last rows (what the engine ships, its word width forced
+here); and, as a comparison only, the value as a second sort key
+(`sort_key2`: a two-operand sort by (id, value), the min at a run's first
+row and the max at its last) beside what that sort costs with one key and
+the word read after it (`sort_key1`).
+
+    python tools/sweep_group_reduce.py --sorted --rows 59986052 \
+        --runs 2000000 --aggs int8_min int8_max int32_min \
+        --sorted-forms segment_sorted cummax_word32 cummax_word64
 
 Prints one JSON line a row and writes them to `--out`.
 """
@@ -49,7 +61,7 @@ import numpy as np  # noqa: E402
 
 jax.config.update("jax_enable_x64", True)
 
-from tpu_olap.kernels import groupby  # noqa: E402
+from tpu_olap.kernels import groupby, sparse_groupby  # noqa: E402
 
 KS = (2, 8, 32, 128, 512, 2048, 4096, 8192, 16384)
 ROWS = (36_000_000, 6_000_000)
@@ -102,8 +114,15 @@ def _inputs(n, dtype, k, seed=7):
 
 RUNS = tuple(1 << e for e in range(10, 22))
 SORTED_ROWS = (36_000_000, 6_030_000)
-AGGS = {"int64_sum": ("int64", "sum"), "int32_count": ("int32", "sum"),
-        "int64_min": ("int64", "min"), "float64_sum": ("float64", "sum")}
+# name: (dtype the rows are carried at, kind, dtype the column is stored at)
+AGGS = {"int64_sum": ("int64", "sum", "int64"),
+        "int32_count": ("int32", "sum", "int32"),
+        "int64_min": ("int64", "min", "int64"),
+        "float64_sum": ("float64", "sum", "float64"),
+        "int8_min": ("int32", "min", "int8"),
+        "int8_max": ("int32", "max", "int8"),
+        "int32_min": ("int32", "min", "int32"),
+        "int32_max": ("int32", "max", "int32")}
 
 
 def _sorted_inputs(n, agg, runs, seed=7):
@@ -111,7 +130,7 @@ def _sorted_inputs(n, agg, runs, seed=7):
     98% of the rows, the rest the masked tail (gid == cap, v the
     aggregate's identity), as `_sorted_segments` hands them on."""
     rng = np.random.default_rng(seed)
-    dtype, kind = AGGS[agg]
+    dtype, kind, stored = AGGS[agg]
     cap = runs
     n_valid = n - n // 50
     boundary = rng.random(n_valid) < 0.9 * runs / n_valid
@@ -120,11 +139,14 @@ def _sorted_inputs(n, agg, runs, seed=7):
     gid[:n_valid] = np.minimum(np.cumsum(boundary, dtype=np.int32) - 1, cap)
     if dtype == "int64":    # rows that pass int32, of either sign
         v = rng.integers(-(1 << 40), 1 << 40, n, dtype=np.int64)
+    elif kind != "sum":     # the stored width's whole range, both ends
+        lim = np.iinfo(stored)
+        v = rng.integers(lim.min, lim.max, n, dtype=np.int32, endpoint=True)
     elif agg == "int32_count":
         v = (rng.random(n) < 0.5).astype(np.int32)
     else:
         v = np.round(rng.random(n) * 1e4, 2)
-    v[n_valid:] = groupby._ident(np.dtype(dtype), kind) if kind == "min" \
+    v[n_valid:] = groupby._ident(np.dtype(dtype), kind) if kind != "sum" \
         else 0
     return v, gid, cap
 
@@ -132,9 +154,9 @@ def _sorted_inputs(n, agg, runs, seed=7):
 def _sorted_reference(v, gid, cap, kind):
     """numpy's answer by `reduceat` over the runs' first rows."""
     first = np.flatnonzero(np.concatenate([[True], gid[1:] != gid[:-1]]))
-    op = np.add if kind == "sum" else np.minimum
+    op = {"sum": np.add, "min": np.minimum, "max": np.maximum}[kind]
     want = np.full(cap + 1, groupby._ident(v.dtype, kind)
-                   if kind == "min" else 0, v.dtype)
+                   if kind != "sum" else 0, v.dtype)
     want[gid[first]] = op.reduceat(v, first)
     return want[:cap]
 
@@ -167,7 +189,8 @@ def _starts_scatter(gid, cap):
 
 
 def _segment(v, gid, cap, kind, is_sorted):
-    f = jax.ops.segment_sum if kind == "sum" else jax.ops.segment_min
+    f = {"sum": jax.ops.segment_sum, "min": jax.ops.segment_min,
+         "max": jax.ops.segment_max}[kind]
     return f(v, gid, num_segments=cap + 1, indices_are_sorted=is_sorted)[:cap]
 
 
@@ -189,6 +212,49 @@ def _boundary(v, gid, cap, kind, starts_fn):
                      running[jnp.maximum(starts[1:] - 1, 0)], ident)
 
 
+class WordTooNarrow(ValueError):
+    """The run id over the code does not fit the word asked for."""
+
+
+def _cummax_word(v, gid, cap, kind, stored, word):
+    """`sparse_group_reduce`'s read of a stored integer min / max (its
+    `_run_ext`), the word's width forced: the running maximum of
+    (run id << b) | code, read at each run's last row."""
+    b = 8 * np.dtype(stored).itemsize + 1
+    if cap.bit_length() + b > 8 * np.dtype(word).itemsize - 1:
+        raise WordTooNarrow(f"{cap.bit_length()} bits of run id over {b} "
+                            f"of code do not fit an {word} word")
+    starts = _starts_sort(gid, cap)
+    table = sparse_groupby._run_ext(v, None, gid, starts, kind, stored,
+                                    np.dtype(word))
+    return jnp.where(starts[1:] > starts[:-1], table.astype(jnp.int32),
+                     groupby._ident(np.dtype(np.int32), kind))
+
+
+def _sort_then_read(v, gid, cap, kind, stored, num_keys):
+    """The whole of either way from rows to table, the sort included (the
+    sweep's ids are sorted already; the sort's cost does not depend on
+    that). Two keys: the value is the second sort key, so a run's min is
+    at its first row and its max at its last, and nothing runs along the
+    rows after the sort. One key: the engine's sort, the word read after
+    it."""
+    gid, v = jax.lax.sort((gid, v), num_keys=num_keys, is_stable=False)
+    if num_keys == 1:
+        return _cummax_word(v, gid, cap, kind, stored, "int32")
+    starts = _starts_sort(gid, cap)
+    at = starts[:-1] if kind == "min" else jnp.maximum(starts[1:] - 1, 0)
+    return jnp.where(starts[1:] > starts[:-1], v[at],
+                     groupby._ident(v.dtype, kind))
+
+
+# the forms of a stored integer min / max alone: they take the column's
+# stored dtype
+STORED_FORMS = {
+    "cummax_word32": functools.partial(_cummax_word, word="int32"),
+    "cummax_word64": functools.partial(_cummax_word, word="int64"),
+    "sort_key1": functools.partial(_sort_then_read, num_keys=1),
+    "sort_key2": functools.partial(_sort_then_read, num_keys=2),
+}
 SORTED_FORMS = {
     "segment": functools.partial(_segment, is_sorted=False),
     "segment_sorted": functools.partial(_segment, is_sorted=True),
@@ -196,6 +262,7 @@ SORTED_FORMS = {
     "boundary_sort": functools.partial(_boundary, starts_fn=_starts_sort),
     "boundary_scatter": functools.partial(_boundary,
                                           starts_fn=_starts_scatter),
+    **STORED_FORMS,
 }
 
 
@@ -203,7 +270,12 @@ def _measure(fn, spec, inputs, want, reps, rec):
     """Compile `fn` for `spec`; with `inputs`, run it: the median of `reps`
     warm runs and whether the table is `want` (floats: to 1e-9)."""
     t0 = time.perf_counter()
-    compiled = jax.jit(fn).lower(*spec).compile()
+    try:
+        compiled = jax.jit(fn).lower(*spec).compile()
+    except WordTooNarrow as e:
+        rec["skipped"] = str(e)
+        print(json.dumps(rec), flush=True)
+        return rec
     rec["compile_s"] = round(time.perf_counter() - t0, 2)
     ma = compiled.memory_analysis()
     if ma is not None:
@@ -216,6 +288,7 @@ def _measure(fn, spec, inputs, want, reps, rec):
             jax.block_until_ready(compiled(*inputs))
             ms.append((time.perf_counter() - t0) * 1e3)
         rec["ms"] = round(statistics.median(ms), 3)
+        rec["ns_per_row"] = round(rec["ms"] * 1e6 / rec["rows"], 3)
         if isinstance(got, tuple):
             # (table, the keys a top-100 keeps): by (value descending, key
             # ascending), as the engine's TopN cuts a tie
@@ -235,7 +308,7 @@ def sweep_sorted(args, sharding):
     out = []
     for n in args.rows or SORTED_ROWS:
         for agg in args.aggs:
-            dtype, kind = AGGS[agg]
+            dtype, kind, stored = AGGS[agg]
             for runs in args.runs:
                 inputs = want = None
                 cap = runs
@@ -247,8 +320,13 @@ def sweep_sorted(args, sharding):
                                              sharding=sharding)
                         for d in (dtype, "int32")]
                 for name in args.sorted_forms:
-                    fn = functools.partial(SORTED_FORMS[name], cap=cap,
-                                           kind=kind)
+                    if name in STORED_FORMS and (
+                            kind == "sum" or dtype != "int32"):
+                        continue   # a stored integer min / max alone
+                    fn = functools.partial(
+                        SORTED_FORMS[name], cap=cap, kind=kind,
+                        **({"stored": stored} if name in STORED_FORMS
+                           else {}))
                     out.append(_measure(
                         fn, spec, inputs, want, args.reps,
                         dict(rows=n, agg=agg, runs=runs, form=name)))

@@ -139,22 +139,24 @@ def topn_takes_sparse(query, plan, config) -> bool:
       query's own width, the compact table clamps it to
       `sparse_theta_k_cap`, a coarser answer;
     - an integer count or sum is among the aggregates, which the sparse
-      path reads at the sorted runs' boundaries (one sort whose cost does
-      not depend on K, a fifth of one scattered int64 sum: PERF.md section
-      6) and can rank on the device; a plan of min / max or float sums
-      alone scatters there as here and gains nothing from the sort;
+      path reads as a difference of prefix sums at the sorted runs'
+      boundaries (one sort whose cost does not depend on K, a fifth of one
+      scattered int64 sum: PERF.md section 6) and can rank on the device;
+      a plan of float sums alone scatters there as here and gains nothing
+      from the sort, and whether a plan of integer min / max alone, which
+      the sparse path reads at the runs' last rows since PR 37, should
+      leave the dense kernel is not measured: it stays;
     - the compact table can hold every group of the space
       (K <= sparse_group_budget), so nothing the dense plan serves is
       refused."""
     from tpu_olap.kernels.groupby import reduce_form
-    from tpu_olap.kernels.sparse_groupby import sparse_reduce_form
+    from tpu_olap.kernels.sparse_groupby import prefix_summed
     kinds = [p.kind for p in plan.agg_plans]
     return (isinstance(query, TopNQuerySpec)
             and plan.pallas_reason is not None
             and reduce_form(plan.total_groups, kinds) == "scatter"
             and not any(k in ("hll", "theta") for k in kinds)
-            and any(sparse_reduce_form([p]) == "boundary"
-                    for p in plan.agg_plans)
+            and any(prefix_summed(p) for p in plan.agg_plans)
             and plan.total_groups <= config.sparse_group_budget
             and _sparse_reject_reason(query, plan.total_groups,
                                       config) is None)

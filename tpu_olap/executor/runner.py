@@ -1932,18 +1932,12 @@ class QueryRunner:
         With `top` = (metric, threshold, inverted), one chip's program
         ends in the TopN's threshold and the partials are its [threshold]
         rows in rank order (`_device_threshold` says when)."""
-        from tpu_olap.kernels.sparse_groupby import sparse_reduce_form
-
-        # whether every [cap] table is read at the sorted runs' boundaries
-        # or an aggregate still scatters: the kernel's own function of the
-        # plan's aggregate kinds and dtypes, as the dense `reduce_form` is
-        # of num_groups
-        metrics["reduce_form"] = sparse_reduce_form(plan.agg_plans)
-        with _span("dispatch", sparse=True, **_form_attr(metrics)) as sp:
+        with _span("dispatch", sparse=True) as sp:
             out = self._run_sparse_inner(plan, metrics, top)
             sp.set(jit_cache_hit=metrics.get("jit_cache_hit"),
                    result_groups=metrics.get("result_groups"),
-                   num_shards=metrics.get("num_shards"))
+                   num_shards=metrics.get("num_shards"),
+                   **_form_attr(metrics))
         return out
 
     def _run_sparse_inner(self, plan: PhysicalPlan, metrics: dict, top):
@@ -1962,6 +1956,9 @@ class QueryRunner:
 
         with self._enqueue_lock(metrics):
             env, valid, seg_mask = self._prepare(plan, metrics)
+        # the width each stored column is resident at: with the plan's
+        # kinds and the cap, what the kernel picks its reduce from
+        stored = {c: a.dtype for c, a in env["cols"].items()}
         win = self._segment_window(plan, len(seg_mask))
         if win is not None:
             metrics["segments_window"] = win[1]
@@ -2112,7 +2109,7 @@ class QueryRunner:
                 metrics["execute_ms"] = \
                     (time.perf_counter() - t0) * 1000
                 metrics["jit_cache_hit"] = hit
-                self._note_sparse(metrics, attempts, cap, count)
+                self._note_sparse(metrics, plan, stored, attempts, cap, count)
                 return out, count
             lhint = self._cap_hints.get(base_key + ("local",))
             if lhint is not None:
@@ -2249,14 +2246,27 @@ class QueryRunner:
         self._cap_hints[base_key] = count
         metrics["execute_ms"] = (time.perf_counter() - t0) * 1000
         metrics["jit_cache_hit"] = hit
-        self._note_sparse(metrics, attempts, cap, count)
+        self._note_sparse(metrics, plan, stored, attempts, cap, count)
         return out, count
 
     @staticmethod
-    def _note_sparse(metrics: dict, attempts: int, cap: int, count: int):
+    def _note_sparse(metrics: dict, plan, stored: dict, attempts: int,
+                     cap: int, count: int):
         """The sparse dispatch's counters on the record: how many cap
         attempts ran (1 once the template's hint is warm), the compact
-        table's final cap, and the groups present in it."""
+        table's final cap, and the groups present in it; and which
+        program the reduce of that cap was — whether every [cap] table
+        is read at the sorted runs' boundaries or an aggregate still
+        scatters, and the width of the word a min / max is read from:
+        the kernel's own function of the plan's aggregate kinds and
+        dtypes, the columns' stored dtypes and the cap, as the dense
+        `reduce_form` is of num_groups."""
+        from tpu_olap.kernels import sparse_groupby as sg
+        metrics["reduce_form"] = sg.sparse_reduce_form(plan.agg_plans,
+                                                       stored, cap)
+        bits = sg.ext_word_bits(plan.agg_plans, stored, cap)
+        if bits is not None:
+            metrics["ext_word_bits"] = bits
         metrics["sparse"] = True
         metrics["sparse_attempts"] = attempts
         metrics["sparse_cap"] = metrics["result_cap"] = cap
@@ -3018,9 +3028,10 @@ def _note_form(metrics: dict, plan, num_groups: int):
 
 def _form_attr(metrics: dict) -> dict:
     """The `dispatch` span's `reduce_form` attribute, where the record of
-    the query has one (a generic grouped aggregate on the device)."""
-    form = metrics.get("reduce_form")
-    return {"reduce_form": form} if form else {}
+    the query has one (a generic grouped aggregate on the device), and
+    beside it a sparse min / max's `ext_word_bits`."""
+    return {k: metrics[k] for k in ("reduce_form", "ext_word_bits")
+            if metrics.get(k)}
 
 
 def _next_pow2(n: int) -> int:

@@ -20,13 +20,17 @@ XLA's sort is fast on TPU and everything stays static-shaped:
   4. the [cap] tables are READ AT THE RUN BOUNDARIES: a row count is
      starts[g+1] - starts[g], a key is the run's first row's, an integer
      sum or count is the difference of an inclusive prefix sum at the
-     run's two ends (wrapping arithmetic: exact). No row is scattered.
-     What a difference of prefixes cannot give keeps a segment reduce
-     over the sorted ids — a floating-point sum (the prefix's rounding
-     error is not the group's), min / max, the sketches' [cap, m] state —
-     and `sparse_reduce_form` says "scatter" of such a plan. Slot i holds
-     the i-th smallest present group key, so results are already compact
-     AND sorted;
+     run's two ends (wrapping arithmetic: exact), an integer min / max of
+     a column stored in 32 bits or fewer is read at the run's last row
+     from a running maximum of the one word (run id << b) | code(value):
+     the run ids do not decrease, so the plain maximum is segmented by
+     construction (`ext_word_dtype`). No row is scattered. What neither
+     gives keeps a segment reduce over the sorted ids — a floating-point
+     sum (the prefix's rounding error is not the group's), a min / max of
+     a column stored in 64 bits or of a double, the sketches' [cap, m]
+     state — and `sparse_reduce_form` says "scatter" of such a plan. Slot
+     i holds the i-th smallest present group key, so results are already
+     compact AND sorted;
   5. "_count" reports the true unique count — if it exceeds cap the
      runner re-runs with the next power of two (same adaptive-cap pattern
      as executor.packing).
@@ -121,32 +125,121 @@ def _run_starts(skey, cap, xp):
     return jax.lax.sort(pos, is_stable=False)[:cap + 1]
 
 
-def sparse_reduce_form(plans) -> str:
-    """Which program the sparse reduce is, from the plan's aggregate kinds
-    and accumulator dtypes and nothing else: "boundary" where every [cap]
-    table is read at the boundaries of the sorted runs, "scatter" where an
-    aggregate still scatters rows into its table — a sketch's [cap, m]
-    state, a min / max, a floating-point sum (a difference of prefix sums
-    would carry the prefix's rounding error, not the group's)."""
-    return "boundary" if all(_at_boundaries(p) for p in plans) else "scatter"
+# rows a block of the 64-bit running maximum: XLA:TPU compiles a
+# one-dimensional one of 4,096 int64 in seconds, of a million in a minute,
+# and falls over at sixty million (PERF.md section 6, PR 37)
+_SCAN_BLOCK = 4096
 
 
-def _at_boundaries(p) -> bool:
+def _running_max(word):
+    """Inclusive running maximum of [N] non-negative words. An int32 one is
+    `lax.cummax`, which XLA:TPU lowers as it lowers the prefix sums'
+    `cumsum`; an int64 one is the same along blocks of `_SCAN_BLOCK` rows,
+    each block raised to the running maximum of the blocks before it."""
+    import jax
+    import jax.numpy as jnp
+
+    n = word.shape[0]
+    if word.dtype.itemsize <= 4 or n <= _SCAN_BLOCK:
+        return jax.lax.cummax(word)
+    blocks = -(-n // _SCAN_BLOCK)
+    rows = jnp.pad(word, (0, blocks * _SCAN_BLOCK - n)) \
+        .reshape(blocks, _SCAN_BLOCK)
+    inner = jax.lax.cummax(rows, axis=1)
+    before = jnp.concatenate([jnp.zeros((1,), word.dtype),
+                              _running_max(inner[:-1, -1])])
+    return jnp.maximum(inner, before[:, None]).reshape(-1)[:n]
+
+
+def _run_ext(v, counted, gid, starts, kind, col_dtype, word):
+    """Exact min / max of v over every sorted run, as [cap] values of
+    dtype `word` (garbage in an empty run): the running maximum of
+    (run id << b) | code(v), read at the run's last row. gid does not
+    decrease, so at that row the maximum holds the run's own id above the
+    largest code seen in the run; `counted` (None: every row) is False on
+    the rows the aggregator leaves out, which code as 0."""
+    import jax.numpy as jnp
+
+    lim, b = np.iinfo(col_dtype), np.iinfo(col_dtype).bits + 1
+    v = v.astype(word)
+    code = v - lim.min + 1 if kind == "max" else lim.max - v + 1
+    if counted is not None:
+        code = jnp.where(counted, code, 0)
+    running = _running_max((gid.astype(word) << b) | code)
+    code = running[jnp.maximum(starts[1:] - 1, 0)] & ((1 << b) - 1)
+    return code - 1 + lim.min if kind == "max" else lim.max + 1 - code
+
+
+def sparse_reduce_form(plans, col_dtypes, cap) -> str:
+    """Which program the sparse reduce is, from static facts alone — the
+    plan's aggregate kinds and accumulator dtypes, the dtype each
+    aggregated column is stored at (`col_dtypes`: field -> dtype; a name
+    the dataset does not store, a virtual column, is materialised in 64
+    bits and may be left out) and the compact table's cap: "boundary"
+    where every [cap] table is read at the boundaries of the sorted runs,
+    "scatter" where an aggregate still scatters rows into its table — a
+    sketch's [cap, m] state, a floating-point sum (a difference of prefix
+    sums would carry the prefix's rounding error, not the group's), a min
+    / max of a double or of a column stored in 64 bits."""
+    return "boundary" if all(
+        prefix_summed(p) or _ext_word(p, col_dtypes, cap) is not None
+        for p in plans) else "scatter"
+
+
+def ext_word_bits(plans, col_dtypes, cap):
+    """Bits of the widest word a min / max of `plans` is read from at the
+    runs' last rows (32 | 64); None where none is."""
+    words = [w for p in plans
+             if (w := _ext_word(p, col_dtypes, cap)) is not None]
+    return max(8 * w.itemsize for w in words) if words else None
+
+
+def prefix_summed(p) -> bool:
+    """A count or an integer sum: a difference of prefix sums at the
+    run's two ends."""
     return p.kind == "count" or (
         p.kind == "sum" and np.issubdtype(np.dtype(p.acc_dtype), np.integer))
 
 
-def _ext_dtype(col_dtype, acc_dtype):
-    """The width a min / max rides the sort and its segment reduce at: an
-    extreme never leaves its input's range, so an integer column stored in
-    32 bits or fewer is reduced as int32 and widened to the accumulator
-    once a slot. XLA:TPU's scatter takes 8-9 ns a row a 32-bit element and
-    81-91 an int64, which it emulates (PERF.md section 6, PRs 28 and 30)."""
-    col_dtype, acc_dtype = np.dtype(col_dtype), np.dtype(acc_dtype)
-    if acc_dtype.kind == "i" and col_dtype.kind in "iu" \
-            and np.can_cast(col_dtype, np.int32):
+def _ext_word(p, col_dtypes, cap):
+    if p.kind not in ("min", "max"):
+        return None
+    return ext_word_dtype(col_dtypes.get(p.fields[0]), p.acc_dtype, cap)
+
+
+def ext_word_dtype(col_dtype, acc_dtype, cap):
+    """The dtype of the word an integer min / max is read from at the
+    runs' last rows, or None where it keeps the segment reduce. A column
+    stored in w <= 32 bits has 2^w values: code(v) = v - lowest + 1 for a
+    max and highest - v + 1 for a min, 0 on a row the aggregator leaves
+    out, takes w + 1 bits under the run id's cap.bit_length(). The width
+    follows from those two facts: an int32 word where they fit 31 bits,
+    an int64 word where they fit 63, else (a column stored in 64 bits, a
+    double accumulator) no word."""
+    if col_dtype is None or not _narrow_int(col_dtype, acc_dtype):
+        return None
+    bits = int(cap).bit_length() + np.iinfo(col_dtype).bits + 1
+    if bits <= 31:
         return np.dtype(np.int32)
-    return acc_dtype
+    return np.dtype(np.int64) if bits <= 63 else None
+
+
+def _ext_dtype(col_dtype, acc_dtype):
+    """The width a min / max rides the sort at: an extreme never leaves
+    its input's range, so an integer column stored in 32 bits or fewer
+    rides as int32 and is widened to the accumulator once a slot. Such a
+    column is read at the runs' last rows (`ext_word_dtype`); what keeps
+    the segment reduce rides at the accumulator's width: XLA:TPU's scatter
+    takes 8-9 ns a row a 32-bit element and 81-91 an int64, which it
+    emulates (PERF.md section 6, PRs 28 and 30)."""
+    return np.dtype(np.int32 if _narrow_int(col_dtype, acc_dtype)
+                    else acc_dtype)
+
+
+def _narrow_int(col_dtype, acc_dtype) -> bool:
+    col_dtype = np.dtype(col_dtype)
+    return np.dtype(acc_dtype).kind == "i" and col_dtype.kind in "iu" \
+        and np.can_cast(col_dtype, np.int32)
 
 
 def sparse_group_reduce(key, mask, env, plans, cap, consts, xp):
@@ -164,10 +257,12 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp):
     # where its key is the SENTINEL
     operands = [key]
     slots = {}
+    words = {}   # min / max name -> (its column's operand, word, stored)
 
     def carry(name, arr):
-        slots[name] = len(operands)
-        operands.append(arr)
+        if name not in slots:
+            slots[name] = len(operands)
+            operands.append(arr)
 
     for p in plans:
         m = mask if p.filter_fn is None else (mask & p.filter_fn(env, consts))
@@ -183,8 +278,17 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp):
                 carry(f"v:{p.name}", xp.where(mm, x, 0).astype(p.acc_dtype))
             else:
                 dt = _ext_dtype(x.dtype, p.acc_dtype)
-                carry(f"v:{p.name}",
-                      xp.where(mm, x.astype(dt), _ident(dt, p.kind)))
+                word = ext_word_dtype(x.dtype, p.acc_dtype, cap)
+                if word is not None:
+                    # read at the runs' last rows, where a row left out
+                    # codes as 0: the column rides unfilled, once for
+                    # every min and max of it
+                    operand = f"x:{p.fields[0]}:{dt}"
+                    words[p.name] = (operand, word, np.dtype(x.dtype))
+                    carry(operand, x.astype(dt))
+                else:
+                    carry(f"v:{p.name}",
+                          xp.where(mm, x.astype(dt), _ident(dt, p.kind)))
                 if p.filter_fn is not None or nulls is not None:
                     # mm == mask otherwise: the non-null count IS _rows,
                     # so skip both the sort operand and the reduction
@@ -222,8 +326,8 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp):
         return run_sum(m, np.int32)
 
     def segment(f, v):
-        # what a difference of prefixes cannot give: XLA's segment reduce,
-        # told that the ids are sorted
+        # what neither gives: XLA's segment reduce, told that the ids are
+        # sorted
         return f(v, gid, num_segments=cap + 1, indices_are_sorted=True)[:cap]
 
     # inside a non-SENTINEL run every row is unmasked: its length is its
@@ -241,15 +345,21 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp):
             continue
         if p.kind == "sum":
             v = sorted_ops[slots[f"v:{p.name}"]]
-            out[p.name] = run_sum(v, p.acc_dtype) if _at_boundaries(p) \
+            out[p.name] = run_sum(v, p.acc_dtype) if prefix_summed(p) \
                 else segment(jax.ops.segment_sum, v)
             continue
         if p.kind in ("min", "max"):
-            nn = run_count(sorted_ops[slots[f"nn:{p.name}"]]) \
-                if f"nn:{p.name}" in slots else out["_rows"]
-            v = segment(
-                jax.ops.segment_min if p.kind == "min"
-                else jax.ops.segment_max, sorted_ops[slots[f"v:{p.name}"]])
+            counted = sorted_ops[slots[f"nn:{p.name}"]] \
+                if f"nn:{p.name}" in slots else None
+            nn = out["_rows"] if counted is None else run_count(counted)
+            if p.name in words:
+                operand, word, col_dtype = words[p.name]
+                v = _run_ext(sorted_ops[slots[operand]], counted, gid,
+                             starts, p.kind, col_dtype, word)
+            else:
+                v = segment(
+                    jax.ops.segment_min if p.kind == "min" else
+                    jax.ops.segment_max, sorted_ops[slots[f"v:{p.name}"]])
             # an empty slot holds the accumulator's identity, whatever
             # width the rows were reduced at
             out[p.name] = xp.where(nn > 0, v.astype(p.acc_dtype),
