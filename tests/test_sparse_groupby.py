@@ -586,8 +586,10 @@ def test_a_narrow_min_max_program_holds_no_scatter_and_an_int64_ones_does():
 
     (narrow, narrow_sorts), (wide, wide_sorts) = \
         program(jnp.int8), program(jnp.int64)
-    assert "scatter" not in narrow and narrow.count("cummax") == 2
-    assert "scatter" in wide and "cummax" not in wide
+    # a running maximum is bound as its reduce-window (`_running`)
+    assert "scatter" not in narrow \
+        and narrow.count("reduce_window_max") == 2
+    assert "scatter" in wide and "reduce_window_max" not in wide
     # key, the sum's operand, the column once | key, sum, min, max
     assert (narrow_sorts, wide_sorts) == (3, 4)
     for dtype, form, bits in ((np.int8, "boundary", 32),

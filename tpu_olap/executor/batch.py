@@ -465,18 +465,17 @@ def _window_fused(fused, W: int, mesh=None, per_chip: int = 0):
         from tpu_olap.executor.sharding import _slice_local
         D = mesh.devices.size
 
-        def fn(buffers, valid, seg_masks, consts_list, lo):
-            def sl(a):
-                return _slice_local(a, D, per_chip, lo, W)
-            return fused([sl(b) for b in buffers], sl(valid),
-                         [sl(m) for m in seg_masks], consts_list)
-        return fn
+        def sl(a, lo):
+            return _slice_local(a, D, per_chip, lo, W)
+    else:
+        def sl(a, lo):
+            return jax.lax.dynamic_slice_in_dim(a, lo, W, axis=0)
 
     def fn(buffers, valid, seg_masks, consts_list, lo):
-        def sl(a):
-            return jax.lax.dynamic_slice_in_dim(a, lo, W, axis=0)
-        return fused([sl(b) for b in buffers], sl(valid),
-                     [sl(m) for m in seg_masks], consts_list)
+        with jax.named_scope("window"):
+            args = ([sl(b, lo) for b in buffers], sl(valid, lo),
+                    [sl(m, lo) for m in seg_masks])
+        return fused(*args, consts_list)
     return fn
 
 

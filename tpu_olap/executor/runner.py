@@ -2604,13 +2604,16 @@ class QueryRunner:
         def fn(env, valid, seg_mask, consts, lo):
             def sl(a):
                 return jax.lax.dynamic_slice_in_dim(a, lo, W, axis=0)
-            wenv = {"cols": {c: sl(a) for c, a in env["cols"].items()},
+            with jax.named_scope("window"):
+                wenv = {
+                    "cols": {c: sl(a) for c, a in env["cols"].items()},
                     "nulls": {c: sl(a) for c, a in env["nulls"].items()}}
-            fenv, mask, key = plan.key_fn(wenv, sl(valid), sl(seg_mask),
-                                          consts)
-            r = mask.shape[0] // W
-            seg_local = jnp.repeat(jnp.arange(W, dtype=jnp.int32), r)
-            key2 = seg_local * jnp.int32(K) + key.astype(jnp.int32)
+                valid, seg_mask = sl(valid), sl(seg_mask)
+            fenv, mask, key = plan.key_fn(wenv, valid, seg_mask, consts)
+            with jax.named_scope("key"):
+                r = mask.shape[0] // W
+                seg_local = jnp.repeat(jnp.arange(W, dtype=jnp.int32), r)
+                key2 = seg_local * jnp.int32(K) + key.astype(jnp.int32)
             return group_reduce(key2, mask, fenv, plan.agg_plans, W * K,
                                 consts)
         return fn
@@ -2651,13 +2654,15 @@ class QueryRunner:
         if query.descending:
             bucket_ids = bucket_ids[::-1]
         present = arrays["_rows"] > 0
-        for b in bucket_ids:
-            if skip_empty and not present[b]:
-                continue
-            vals = {n: render_value(arrays[n][b]) for n in names}
-            ts = iso(plan.bucket_plan.starts[b])
-            rows.append({"timestamp": ts, **vals})
-            druid.append({"timestamp": ts, "result": vals})
+        with _span("assemble-rows") as sp:
+            for b in bucket_ids:
+                if skip_empty and not present[b]:
+                    continue
+                vals = {n: render_value(arrays[n][b]) for n in names}
+                ts = iso(plan.bucket_plan.starts[b])
+                rows.append({"timestamp": ts, **vals})
+                druid.append({"timestamp": ts, "result": vals})
+            sp.set(rows=len(rows))
         return QueryResult(query, rows, druid)
 
     def _decode_groups(self, plan, idx: np.ndarray):
@@ -2677,16 +2682,27 @@ class QueryRunner:
         return buckets, dim_ids
 
     def _assemble_groupby(self, query, plan, arrays) -> QueryResult:
-        names = self._out_names(query)
-        present = np.nonzero(arrays["_rows"] > 0)[0]
-        sub = {n: np.asarray(arrays[n])[present] for n in names}
-        return self._emit_groupby(query, plan, present, sub)
+        return self._emit_groupby(query, plan, None, arrays)
+
+    def _decode_present(self, query, plan, present, sub):
+        """-> (present, sub, bucket ids, {dim name -> dense ids}) under the
+        leaf span `decode-groups`. `present` None: `sub` holds the dense
+        [K] tables, cut here to the groups that have rows."""
+        with _span("decode-groups") as sp:
+            if present is None:
+                present = np.nonzero(sub["_rows"] > 0)[0]
+                sub = {n: np.asarray(sub[n])[present]
+                       for n in self._out_names(query)}
+            sp.set(groups=len(present))
+            return (present, sub) + self._decode_groups(plan, present)
 
     def _emit_groupby(self, query, plan, present, sub) -> QueryResult:
         """present: flat group ids (any int width); sub: compact per-group
-        final values. Shared tail of the dense and sparse paths."""
+        final values (present None: the dense [K] tables). Shared tail of
+        the dense and sparse paths."""
         names = self._out_names(query)
-        buckets, dim_ids = self._decode_groups(plan, present)
+        present, sub, buckets, dim_ids = self._decode_present(
+            query, plan, present, sub)
         labels = {dp.name: dp.labels for dp in plan.dim_plans}
 
         if query.having is not None:
@@ -2709,18 +2725,21 @@ class QueryRunner:
 
         # labels and rows of what is emitted only: a LIMIT over a sparse
         # group-by keeps tens of rows of hundreds of thousands of groups
-        buckets = buckets[order]
-        dim_vals = {d: labels[d][ids[order]] for d, ids in dim_ids.items()}
-        sub = {n: sub[n][order] for n in names}
-        rows, druid = [], []
-        starts = plan.bucket_plan.starts
-        for i in range(len(order)):
-            ts = iso(starts[buckets[i]])
-            ev = {dp.name: render_value(dim_vals[dp.name][i])
-                  for dp in plan.dim_plans}
-            ev.update({n: render_value(sub[n][i]) for n in names})
-            rows.append({"timestamp": ts, **ev})
-            druid.append({"version": "v1", "timestamp": ts, "event": ev})
+        with _span("assemble-rows", rows=len(order)):
+            buckets = buckets[order]
+            dim_vals = {d: labels[d][ids[order]]
+                        for d, ids in dim_ids.items()}
+            sub = {n: sub[n][order] for n in names}
+            rows, druid = [], []
+            starts = plan.bucket_plan.starts
+            for i in range(len(order)):
+                ts = iso(starts[buckets[i]])
+                ev = {dp.name: render_value(dim_vals[dp.name][i])
+                      for dp in plan.dim_plans}
+                ev.update({n: render_value(sub[n][i]) for n in names})
+                rows.append({"timestamp": ts, **ev})
+                druid.append({"version": "v1", "timestamp": ts,
+                              "event": ev})
         return QueryResult(query, rows, druid)
 
     def _device_threshold(self, query, plan):
@@ -2740,10 +2759,7 @@ class QueryRunner:
         return None
 
     def _assemble_topn(self, query, plan, arrays) -> QueryResult:
-        names = self._out_names(query)
-        present = np.nonzero(arrays["_rows"] > 0)[0]
-        sub = {n: np.asarray(arrays[n])[present] for n in names}
-        return self._emit_topn(query, plan, present, sub, "host")
+        return self._emit_topn(query, plan, None, arrays, "host")
 
     def _emit_topn(self, query, plan, present, sub, where) -> QueryResult:
         """present: flat group ids, sub: their final values — every present
@@ -2755,7 +2771,8 @@ class QueryRunner:
         arrived in, which is the dimension's own ascending order (label
         order) either way."""
         names = self._out_names(query)
-        buckets, dim_ids = self._decode_groups(plan, present)
+        present, sub, buckets, dim_ids = self._decode_present(
+            query, plan, present, sub)
         dp = plan.dim_plans[0]
         with _span("topn-threshold", where=where, groups=len(present),
                    threshold=query.threshold):
@@ -2769,17 +2786,19 @@ class QueryRunner:
             lo = np.searchsorted(buckets[ranked],
                                  np.arange(plan.sizes[0] + 1))
         rows, druid = [], []
-        for b in self._bucket_emit_ids(query, plan):
-            keep = ranked[lo[b]:lo[b + 1]][:query.threshold]
-            labels = dp.labels[dim_ids[dp.name][keep]]
-            ts = iso(plan.bucket_plan.starts[b])
-            result = []
-            for i, g in enumerate(keep):
-                ev = {dp.name: render_value(labels[i])}
-                ev.update({n: render_value(sub[n][g]) for n in names})
-                result.append(ev)
-                rows.append({"timestamp": ts, **ev})
-            druid.append({"timestamp": ts, "result": result})
+        with _span("assemble-rows") as sp:
+            for b in self._bucket_emit_ids(query, plan):
+                keep = ranked[lo[b]:lo[b + 1]][:query.threshold]
+                labels = dp.labels[dim_ids[dp.name][keep]]
+                ts = iso(plan.bucket_plan.starts[b])
+                result = []
+                for i, g in enumerate(keep):
+                    ev = {dp.name: render_value(labels[i])}
+                    ev.update({n: render_value(sub[n][g]) for n in names})
+                    result.append(ev)
+                    rows.append({"timestamp": ts, **ev})
+                druid.append({"timestamp": ts, "result": result})
+            sp.set(rows=len(rows))
         return QueryResult(query, rows, druid)
 
     # ----------------------------------------------------------- scan paths

@@ -178,11 +178,16 @@ def mesh_head_kernel(mesh: Mesh, rows: int, merged: bool = False):
     say; what the broker fetches is then the present groups' size, not
     the cap's. `merged`: the tables are mesh_merge_kernel's, one
     replicated table and not a chip's each."""
-    def head(tables):
-        return {name: t[:rows] for name, t in tables.items()}
+    # (named `head_rows` / `merge_tables` since PR 38, which gave them
+    # their stages: a program that differs from a cached one in metadata
+    # alone is loaded from the persistent cache with the OLD op_names, as
+    # JAX's key leaves metadata out; the module's name is in the key)
+    def head_rows(tables):
+        with jax.named_scope("pack"):
+            return {name: t[:rows] for name, t in tables.items()}
 
     spec = P() if merged else P(AXIS)
-    return jax.jit(jax.shard_map(head, mesh=mesh, in_specs=(spec,),
+    return jax.jit(jax.shard_map(head_rows, mesh=mesh, in_specs=(spec,),
                                  out_specs=spec, check_vma=False))
 
 
@@ -198,12 +203,15 @@ def mesh_merge_kernel(plan, mesh: Mesh, rows: int):
 
     from tpu_olap.kernels.sparse_groupby import merge_device
 
-    def merge(tables):
-        whole = {name: jax.lax.all_gather(t[:rows], AXIS, tiled=True)
-                 for name, t in tables.items()}
-        return merge_device(whole, plan.agg_plans, mesh.devices.size, jnp)
+    def merge_tables(tables):
+        with jax.named_scope("merge"):
+            whole = {name: jax.lax.all_gather(t[:rows], AXIS, tiled=True)
+                     for name, t in tables.items()}
+            return merge_device(whole, plan.agg_plans, mesh.devices.size,
+                                jnp)
 
-    return jax.jit(jax.shard_map(merge, mesh=mesh, in_specs=(P(AXIS),),
+    return jax.jit(jax.shard_map(merge_tables, mesh=mesh,
+                                 in_specs=(P(AXIS),),
                                  out_specs=P(), check_vma=False))
 
 
@@ -252,9 +260,10 @@ def _slice_local(a, D: int, per_chip: int, lo, W: int):
 def _window_env(env, valid, seg_mask, D, per_chip, lo, W):
     sl = functools.partial(_slice_local, D=D, per_chip=per_chip,
                            lo=lo, W=W)
-    wenv = {"cols": {c: sl(a) for c, a in env["cols"].items()},
-            "nulls": {c: sl(a) for c, a in env["nulls"].items()}}
-    return wenv, sl(valid), sl(seg_mask)
+    with jax.named_scope("window"):
+        wenv = {"cols": {c: sl(a) for c, a in env["cols"].items()},
+                "nulls": {c: sl(a) for c, a in env["nulls"].items()}}
+        return wenv, sl(valid), sl(seg_mask)
 
 
 def chip_extended_key(key, mask, D: int, blocks: int, K: int):
@@ -266,10 +275,11 @@ def chip_extended_key(key, mask, D: int, blocks: int, K: int):
     from shard_map's out_specs with plain keys."""
     import jax.numpy as jnp
 
-    r = mask.shape[0] // (D * blocks)
-    chip = jnp.repeat(
-        jnp.arange(D * blocks, dtype=jnp.int32) // jnp.int32(blocks), r)
-    return chip * jnp.int32(K) + key.astype(jnp.int32)
+    with jax.named_scope("key"):
+        r = mask.shape[0] // (D * blocks)
+        chip = jnp.repeat(
+            jnp.arange(D * blocks, dtype=jnp.int32) // jnp.int32(blocks), r)
+        return chip * jnp.int32(K) + key.astype(jnp.int32)
 
 
 def mesh_agg_kernel(plan, mesh: Mesh, per_chip: int, program: str,

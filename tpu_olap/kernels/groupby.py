@@ -63,12 +63,37 @@ def reduce_form(num_groups: int, kinds=()) -> str:
     return "compare"
 
 
+# The stages of a device program, one flat vocabulary: every op of every
+# jitted program lies under one of these `jax.named_scope`s, and the
+# innermost one in an op's `op_name` path is its stage (the path's last
+# part is the primitive's own name, which may spell like a stage: `sort`,
+# `gather`). docs/OBSERVABILITY.md says which stage wraps what.
+STAGES = (
+    "window",     # the dynamic slice of the [S, R] inputs to the pruned
+    #               segments (runner._window_kernel)
+    "filter",     # the row mask: validity, the filter, the interval mask
+    "key",        # the dimensions' ids and their mixed-radix group key
+    "reduce",     # the dense [K] tables: Pallas one-hot, compare, scatter
+    "pack",       # finalize, compact and lay out the one fetched buffer
+    "sort",       # sparse: the multi-operand sort and its operands
+    "runs",       # sparse: run boundaries, run ids, first-row positions
+    "prefix",     # sparse: prefix sums and running maxima over the rows
+    "gather",     # sparse: the [cap] tables read at the runs' boundaries
+    "segment",    # sparse: what still segment-reduces the sorted rows
+    "threshold",  # a TopN's top-k of the compact table, on the device
+    "merge",      # mesh: all_gather of the chips' tables and their merge
+)
+
+
 def stage_scope(name: str, xp):
     """`jax.named_scope(name)` while a device program is being traced
     (xp is jax.numpy), nothing on the numpy path: every op of the jitted
-    programs carries its stage — filter, key, reduce, pack — in its
-    op_name, which the profiler records beside the compiler's own name
-    for the op."""
+    programs carries its stage, one of `STAGES`, in its op_name, which the
+    profiler records beside the compiler's own name for the op (a
+    capture's event metadata holds it as the stat `tf_op`). A scope
+    touches the ops' metadata and nothing else of the compiled program."""
+    if name not in STAGES:
+        raise ValueError(f"{name!r} is not a stage; STAGES has {STAGES}")
     return _NO_SCOPE if xp is np else jax.named_scope(name)
 
 

@@ -788,12 +788,15 @@ def build_kernel(plan, table, config, filter_fn, interpret: bool,
                         ids = dp.ids(flat_env, consts, jnp)
                         pre_in.append(
                             ids.astype(jnp.int32).reshape(1, n))
-        mask2 = mask.reshape(1, n)
-        col_in = [_narrow(env["cols"][c].reshape(1, n), jnp)
-                  for c in col_names]
-        null_in = [env["nulls"][c].reshape(1, n) for c in null_names]
-        const_in = [_narrow(jnp.asarray(consts[c]).reshape(1, -1), jnp)
-                    for c in const_names]
+        # the kernel's operands in its [1, n] layout are the reduce's too:
+        # where XLA copies a column to get it, the copy carries the stage
+        with jax.named_scope("reduce"):
+            mask2 = mask.reshape(1, n)
+            col_in = [_narrow(env["cols"][c].reshape(1, n), jnp)
+                      for c in col_names]
+            null_in = [env["nulls"][c].reshape(1, n) for c in null_names]
+            const_in = [_narrow(jnp.asarray(consts[c]).reshape(1, -1), jnp)
+                        for c in const_names]
 
         n_chunks = -(-grid_rows // spc)
         _spc = np.int32(spc)

@@ -48,7 +48,7 @@ import numpy as np
 from tpu_olap.kernels import hll as hll_mod
 from tpu_olap.kernels import theta as theta_mod
 from tpu_olap.kernels.groupby import (UnsupportedAggregation, _hash_fields,
-                                      _ident)
+                                      _ident, stage_scope)
 
 SENTINEL = np.int64(np.iinfo(np.int64).max)
 
@@ -70,6 +70,28 @@ def build_group_key64(ids, sizes, xp):
     return key, total
 
 
+def _running(x, kind: str, axis=0):
+    """Inclusive running sum (`kind` "add") or maximum ("max") of x along
+    `axis` on the device: `lax.cumsum` / `lax.cummax` as JAX itself lowers
+    them for the TPU, one reduce-window over the whole axis, bound here
+    and not through them. `jnp.cumsum`, `lax.cumsum` and `lax.cummax`
+    lower as out-of-line functions, and an op inside one loses the
+    caller's `named_scope` (its op_name is `reduce_window_sum` and no
+    more): a capture could then not tell the run ids' prefix sum from an
+    aggregate's."""
+    import jax
+
+    n = x.shape[axis]
+    if n == 0:
+        return x
+    dims, pads = [1] * x.ndim, [(0, 0)] * x.ndim
+    dims[axis], pads[axis] = n, (n - 1, 0)
+    init = 0 if kind == "add" else np.iinfo(x.dtype).min
+    return jax.lax.reduce_window(x, np.array(init, x.dtype),
+                                 getattr(jax.lax, kind), dims,
+                                 [1] * x.ndim, pads)
+
+
 def _sorted_segments(skey, cap, xp):
     """boundary/gid/count core shared by row reduction and table merge:
     gid clips into the dropped overflow+sentinel slot `cap`."""
@@ -77,7 +99,8 @@ def _sorted_segments(skey, cap, xp):
         xp.ones((1,), bool),
         skey[1:] != skey[:-1],
     ])
-    gid = xp.cumsum(boundary.astype(xp.int32)) - 1
+    flags = boundary.astype(xp.int32)
+    gid = (np.cumsum(flags) if xp is np else _running(flags, "add")) - 1
     count = (boundary & (skey != SENTINEL)).sum(dtype=xp.int32)
     gid = xp.where((gid < cap) & (skey != SENTINEL), gid, cap)
     return gid, count
@@ -133,19 +156,22 @@ _SCAN_BLOCK = 4096
 
 def _running_max(word):
     """Inclusive running maximum of [N] non-negative words. An int32 one is
-    `lax.cummax`, which XLA:TPU lowers as it lowers the prefix sums'
-    `cumsum`; an int64 one is the same along blocks of `_SCAN_BLOCK` rows,
+    `lax.cummax` (`_running`), which XLA:TPU lowers as it lowers the prefix
+    sums' `cumsum`; an int64 one is the same along blocks of `_SCAN_BLOCK`
+    rows,
     each block raised to the running maximum of the blocks before it."""
     import jax
     import jax.numpy as jnp
 
     n = word.shape[0]
     if word.dtype.itemsize <= 4 or n <= _SCAN_BLOCK:
-        return jax.lax.cummax(word)
+        return _running(word, "max")
     blocks = -(-n // _SCAN_BLOCK)
-    rows = jnp.pad(word, (0, blocks * _SCAN_BLOCK - n)) \
+    # lax.pad, not jnp.pad: the latter is an out-of-line function too
+    rows = jax.lax.pad(word, np.array(0, word.dtype),
+                       [(0, blocks * _SCAN_BLOCK - n, 0)]) \
         .reshape(blocks, _SCAN_BLOCK)
-    inner = jax.lax.cummax(rows, axis=1)
+    inner = _running(rows, "max", axis=1)
     before = jnp.concatenate([jnp.zeros((1,), word.dtype),
                               _running_max(inner[:-1, -1])])
     return jnp.maximum(inner, before[:, None]).reshape(-1)[:n]
@@ -161,13 +187,15 @@ def _run_ext(v, counted, gid, starts, kind, col_dtype, word):
     import jax.numpy as jnp
 
     lim, b = np.iinfo(col_dtype), np.iinfo(col_dtype).bits + 1
-    v = v.astype(word)
-    code = v - lim.min + 1 if kind == "max" else lim.max - v + 1
-    if counted is not None:
-        code = jnp.where(counted, code, 0)
-    running = _running_max((gid.astype(word) << b) | code)
-    code = running[jnp.maximum(starts[1:] - 1, 0)] & ((1 << b) - 1)
-    return code - 1 + lim.min if kind == "max" else lim.max + 1 - code
+    with stage_scope("prefix", jnp):
+        v = v.astype(word)
+        code = v - lim.min + 1 if kind == "max" else lim.max - v + 1
+        if counted is not None:
+            code = jnp.where(counted, code, 0)
+        running = _running_max((gid.astype(word) << b) | code)
+    with stage_scope("gather", jnp):
+        code = running[jnp.maximum(starts[1:] - 1, 0)] & ((1 << b) - 1)
+        return code - 1 + lim.min if kind == "max" else lim.max + 1 - code
 
 
 def sparse_reduce_form(plans, col_dtypes, cap) -> str:
@@ -251,11 +279,8 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp):
     """
     import jax
 
-    key = xp.where(mask, key, SENTINEL)
-
     # the mask does not ride the sort: a sorted row is masked exactly
     # where its key is the SENTINEL
-    operands = [key]
     slots = {}
     words = {}   # min / max name -> (its column's operand, word, stored)
 
@@ -264,62 +289,74 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp):
             slots[name] = len(operands)
             operands.append(arr)
 
-    for p in plans:
-        m = mask if p.filter_fn is None else (mask & p.filter_fn(env, consts))
-        if p.kind == "count":
+    with stage_scope("sort", xp):
+        operands = [xp.where(mask, key, SENTINEL)]
+        for p in plans:
+            m = mask
             if p.filter_fn is not None:
-                carry(f"m:{p.name}", m)
-            continue
-        if p.kind in ("sum", "min", "max"):
-            x = env["cols"][p.fields[0]]
-            nulls = env["nulls"].get(p.fields[0])
-            mm = m & ~nulls if nulls is not None else m
-            if p.kind == "sum":
-                carry(f"v:{p.name}", xp.where(mm, x, 0).astype(p.acc_dtype))
-            else:
-                dt = _ext_dtype(x.dtype, p.acc_dtype)
-                word = ext_word_dtype(x.dtype, p.acc_dtype, cap)
-                if word is not None:
-                    # read at the runs' last rows, where a row left out
-                    # codes as 0: the column rides unfilled, once for
-                    # every min and max of it
-                    operand = f"x:{p.fields[0]}:{dt}"
-                    words[p.name] = (operand, word, np.dtype(x.dtype))
-                    carry(operand, x.astype(dt))
-                else:
+                with stage_scope("filter", xp):
+                    m = mask & p.filter_fn(env, consts)
+            if p.kind == "count":
+                if p.filter_fn is not None:
+                    carry(f"m:{p.name}", m)
+                continue
+            if p.kind in ("sum", "min", "max"):
+                x = env["cols"][p.fields[0]]
+                nulls = env["nulls"].get(p.fields[0])
+                mm = m & ~nulls if nulls is not None else m
+                if p.kind == "sum":
                     carry(f"v:{p.name}",
-                          xp.where(mm, x.astype(dt), _ident(dt, p.kind)))
-                if p.filter_fn is not None or nulls is not None:
-                    # mm == mask otherwise: the non-null count IS _rows,
-                    # so skip both the sort operand and the reduction
-                    carry(f"nn:{p.name}", mm)
-        elif p.kind in ("hll", "theta"):
-            h, valid = _hash_fields(env, p, m, xp, consts)
-            carry(f"h:{p.name}", h)
-            carry(f"hv:{p.name}", valid)
-        else:
-            raise UnsupportedAggregation(
-                f"sparse group-by does not support {p.kind!r}")
+                          xp.where(mm, x, 0).astype(p.acc_dtype))
+                else:
+                    dt = _ext_dtype(x.dtype, p.acc_dtype)
+                    word = ext_word_dtype(x.dtype, p.acc_dtype, cap)
+                    if word is not None:
+                        # read at the runs' last rows, where a row left
+                        # out codes as 0: the column rides unfilled, once
+                        # for every min and max of it
+                        operand = f"x:{p.fields[0]}:{dt}"
+                        words[p.name] = (operand, word, np.dtype(x.dtype))
+                        carry(operand, x.astype(dt))
+                    else:
+                        carry(f"v:{p.name}",
+                              xp.where(mm, x.astype(dt),
+                                       _ident(dt, p.kind)))
+                    if p.filter_fn is not None or nulls is not None:
+                        # mm == mask otherwise: the non-null count IS
+                        # _rows, so skip both the sort operand and the
+                        # reduction
+                        carry(f"nn:{p.name}", mm)
+            elif p.kind in ("hll", "theta"):
+                h, valid = _hash_fields(env, p, m, xp, consts)
+                carry(f"h:{p.name}", h)
+                carry(f"hv:{p.name}", valid)
+            else:
+                raise UnsupportedAggregation(
+                    f"sparse group-by does not support {p.kind!r}")
 
-    # no table depends on the order of the rows inside a run, so the sort
-    # need not be stable: XLA spells stability as one more operand, an
-    # iota that breaks ties
-    sorted_ops = list(jax.lax.sort(tuple(operands), num_keys=1,
-                                   is_stable=False))
+        # no table depends on the order of the rows inside a run, so the
+        # sort need not be stable: XLA spells stability as one more
+        # operand, an iota that breaks ties
+        sorted_ops = list(jax.lax.sort(tuple(operands), num_keys=1,
+                                       is_stable=False))
 
     skey = sorted_ops[0]
 
-    gid, count = _sorted_segments(skey, cap, xp)
-    starts = _run_starts(skey, cap, xp)
+    with stage_scope("runs", xp):
+        gid, count = _sorted_segments(skey, cap, xp)
+        starts = _run_starts(skey, cap, xp)
 
     def run_sum(v, acc_dtype):
         """Exact integer sum of v over every run: the inclusive prefix
         sum read at the run's last row less its value before the run's
         first. Wrapping arithmetic makes the difference exact whatever
         the prefix holds."""
-        prefix = xp.cumsum(v, dtype=acc_dtype)
-        before = xp.where(starts > 0, prefix[xp.maximum(starts - 1, 0)], 0)
-        return before[1:] - before[:-1]
+        with stage_scope("prefix", xp):
+            prefix = _running(v.astype(acc_dtype), "add")
+        with stage_scope("gather", xp):
+            before = xp.where(starts > 0,
+                              prefix[xp.maximum(starts - 1, 0)], 0)
+            return before[1:] - before[:-1]
 
     def run_count(m):
         # a count is at most N: an int32 prefix holds it
@@ -328,14 +365,17 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp):
     def segment(f, v):
         # what neither gives: XLA's segment reduce, told that the ids are
         # sorted
-        return f(v, gid, num_segments=cap + 1, indices_are_sorted=True)[:cap]
+        with stage_scope("segment", xp):
+            return f(v, gid, num_segments=cap + 1,
+                     indices_are_sorted=True)[:cap]
 
     # inside a non-SENTINEL run every row is unmasked: its length is its
     # row count. The key of slot g is its first row's; past the present
     # groups that row is in the SENTINEL tail, or out of bounds
-    out = {"_count": count, "_rows": starts[1:] - starts[:-1],
-           "_keys": skey.at[starts[:cap]].get(mode="fill",
-                                              fill_value=SENTINEL)}
+    with stage_scope("gather", xp):
+        out = {"_count": count, "_rows": starts[1:] - starts[:-1],
+               "_keys": skey.at[starts[:cap]].get(mode="fill",
+                                                  fill_value=SENTINEL)}
 
     for p in plans:
         if p.kind == "count":
@@ -362,15 +402,17 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp):
                     jax.ops.segment_max, sorted_ops[slots[f"v:{p.name}"]])
             # an empty slot holds the accumulator's identity, whatever
             # width the rows were reduced at
-            out[p.name] = xp.where(nn > 0, v.astype(p.acc_dtype),
-                                   _ident(p.acc_dtype, p.kind))
+            with stage_scope("gather", xp):
+                out[p.name] = xp.where(nn > 0, v.astype(p.acc_dtype),
+                                       _ident(p.acc_dtype, p.kind))
             out[f"_nn_{p.name}"] = nn
             continue
         if p.kind == "hll":
             h = sorted_ops[slots[f"h:{p.name}"]]
             valid = sorted_ops[slots[f"hv:{p.name}"]]
-            regs = hll_mod.hll_update(h, valid, xp.where(valid, gid, 0),
-                                      cap + 1)
+            with stage_scope("segment", xp):
+                regs = hll_mod.hll_update(h, valid,
+                                          xp.where(valid, gid, 0), cap + 1)
             out[p.name] = regs[:cap]
             continue
         if p.kind == "theta":
@@ -379,8 +421,9 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp):
             # theta_update routes invalid rows to the num_groups pad row
             # itself; gid==cap (overflow/sentinel) rows land in the pad
             # row and are sliced off
-            t = theta_mod.theta_update(h, valid, gid, cap + 1,
-                                       p.theta_k)
+            with stage_scope("segment", xp):
+                t = theta_mod.theta_update(h, valid, gid, cap + 1,
+                                           p.theta_k)
             out[p.name] = t[:cap]
             continue
     return out
@@ -394,13 +437,16 @@ def sparse_top_rows(tables: dict, metric: str, threshold: int,
     leave it. `_count` stays the table's own (the cap-overflow probe reads
     it); a rank past the present groups holds the SENTINEL key of the
     empty slot it points at."""
+    import jax.numpy as jnp
+
     from tpu_olap.kernels.topk import top_k_groups
 
-    keys = tables["_keys"]
-    order, _ = top_k_groups(tables[metric], keys != SENTINEL, threshold,
-                            inverted)
-    return {name: t if name == "_count" else t[order]
-            for name, t in tables.items()}
+    with stage_scope("threshold", jnp):
+        keys = tables["_keys"]
+        order, _ = top_k_groups(tables[metric], keys != SENTINEL, threshold,
+                                inverted)
+        return {name: t if name == "_count" else t[order]
+                for name, t in tables.items()}
 
 
 def merges_on_device(plans) -> bool:
