@@ -131,6 +131,9 @@ def test_wide_topn_on_the_sparse_path_equals_the_reference(eng_sparse, data,
     assert rec["reduce_form"] == "boundary"
     assert rec.get("ext_word_bits") == (None if name == "top_100_parts"
                                         else 32)
+    # the program ranks first: of its [cap] tables it gathers the ranked
+    # sum's alone and reads the others at the 100 kept rows (PR 39)
+    assert rec["cap_tables"] == 1
     assert rec["topn_rows_fetched"] == 100 < rec["sparse_cap"]
     assert rec["topn_group_space"] == PARTS + 1
     assert rec["present_groups"] == PARTS

@@ -614,14 +614,17 @@ def test_a_min_or_max_rides_at_the_columns_width_up_to_int32():
     assert _ext_dtype(np.float32, np.float64) == np.float64
 
 
-def _dispatch_forms(eng):
+def _walk_spans(eng):
     def walk(tree):
         yield tree
         for c in tree.get("children", []):
             yield from walk(c)
+    return walk(eng.tracer.last.to_json())
+
+
+def _dispatch_forms(eng):
     return [s["attrs"].get("reduce_form")
-            for s in walk(eng.tracer.last.to_json())
-            if s["name"] == "dispatch"]
+            for s in _walk_spans(eng) if s["name"] == "dispatch"]
 
 
 def test_no_scatter_in_a_sum_and_count_program_and_a_sketch_says_so():
@@ -670,6 +673,246 @@ def test_record_and_dispatch_span_say_boundary():
     assert rec["reduce_path"] == "sparse" and rec["sparse"]
     assert rec["reduce_form"] == "boundary"
     assert _dispatch_forms(eng) == ["boundary"]
+
+
+# --------------------------------------------------------------------------
+# A program that ends in a TopN's threshold ranks first and reads the tables
+# it does not rank at the rows it keeps (PR 39): table for table equal to
+# the spelling it replaced, kept here as the reference.
+
+def _all_tables_then_cut(tables, metric, threshold, inverted):
+    """Every [cap] table, then `top_k` over the metric's, then each table
+    indexed with the kept slots: `sparse_top_rows` as it stood before
+    PR 39."""
+    from tpu_olap.kernels.sparse_groupby import SENTINEL
+    from tpu_olap.kernels.topk import top_k_groups
+    order, _ = top_k_groups(tables[metric], tables["_keys"] != SENTINEL,
+                            threshold, inverted)
+    return {name: t if name == "_count" else t[order]
+            for name, t in tables.items()}
+
+
+def _top_cases():
+    """(id, key, mask, env, plans, cap, (metric, threshold, inverted))"""
+    rng = np.random.default_rng(39)
+    n = 1021
+    v = rng.integers(-1000, 1000, n).astype(np.int64)
+    u = rng.integers(0, 50, n).astype(np.int64)
+    f = rng.integers(-3, 4, n)
+    i8 = rng.integers(-128, 128, n).astype(np.int8)
+    i32 = rng.integers(-(1 << 31), 1 << 31, n).astype(np.int32)
+    w = np.round(rng.random(n) * 50 - 25, 4)
+    key = rng.integers(0, 90, n).astype(np.int64)
+    key[key == 3] = 2          # a group no row has: the slots shift
+    mask = rng.random(n) < 0.85
+
+    def env(nulls=None, **cols):
+        return {"cols": cols, "nulls": nulls or {}}
+
+    s = _agg("s", "sum", "v")
+    yield "integer-sum", key, mask, env(v=v), [s], 128, ("s", 10, False)
+    yield "two-sums-a-filtered-count-and-a-filtered-sum", key, mask, \
+        env(v=v, u=u, f=f), \
+        [s, _agg("su", "sum", "u"), _agg("fn", "count", filter_fn=_positive),
+         _agg("fs", "sum", "u", filter_fn=_positive), _agg("n", "count")], \
+        128, ("s", 10, False)
+    ext8 = [s, _agg("lo", "min", "d"), _agg("hi", "max", "d")]
+    yield "min-max-of-int8-int32-word", key, mask, env(v=v, d=i8), ext8, \
+        128, ("s", 10, False)
+    yield "min-max-of-int32-int64-word", key, mask, env(v=v, d=i32), ext8, \
+        128, ("s", 10, False)
+    yield "min-max-of-int16-cap-past-an-int32-word", key, mask, \
+        env(v=v, d=i32.astype(np.int16)), ext8, 1 << 14, ("s", 10, False)
+    yield "min-max-with-nulls", key, mask, \
+        env({"d": rng.random(n) < 0.6}, v=v, d=i8, f=f), \
+        ext8 + [_agg("few", "max", "d",
+                     filter_fn=lambda e, c: e["cols"]["f"] > 2)], \
+        128, ("s", 10, False)
+    # a metric of four values over ninety groups: the threshold falls
+    # inside a tie, which the lower slot (the smaller key) wins
+    tied = np.ones(n, np.int64)
+    yield "ties-at-the-threshold", key % 40, np.ones(n, bool), \
+        env(v=tied * (key % 40 % 4), d=i8), ext8, 64, ("s", 7, False)
+    yield "inverted", key, mask, env(v=v, d=i8), ext8, 128, ("s", 10, True)
+    yield "ties-inverted", key % 40, np.ones(n, bool), \
+        env(v=tied * (key % 40 % 4), d=i8), ext8, 64, ("s", 7, True)
+    yield "threshold-past-the-present-groups", key % 6, mask, \
+        env(v=v, d=i8, f=f), \
+        ext8 + [_agg("fn", "count", filter_fn=_positive)], 32, \
+        ("s", 20, False)
+    yield "threshold-past-the-cap", key % 6, mask, env(v=v, d=i8), ext8, \
+        16, ("s", 100, False)
+    yield "cap-overflows", key, mask, env(v=v, d=i8), ext8, 16, \
+        ("s", 10, False)
+    yield "every-row-masked", key, np.zeros(n, bool), env(v=v, d=i8), \
+        ext8, 16, ("s", 10, False)
+    yield "float-sum-beside-the-ranked-sum", key, mask, env(v=v, w=w * 1e6), \
+        [s, _agg("fs", "sum", "w", np.float64), _agg("n", "count")], 128, \
+        ("s", 10, False)
+    yield "int64-min-beside-the-ranked-sum", key, mask, \
+        env(v=v, d=v * (np.int64(1) << 40)), ext8, 128, ("s", 10, False)
+    yield "ranked-by-the-row-count", key, mask, env(v=v, d=i8), \
+        [_agg("n", "count")] + ext8, 128, ("n", 10, False)
+    yield "ranked-by-a-filtered-count", key, mask, env(v=v, d=i8, f=f), \
+        [_agg("fn", "count", filter_fn=_positive)] + ext8, 128, \
+        ("fn", 10, True)
+    yield "ranked-by-a-filtered-sum", key, mask, env(v=v, d=i8, f=f), \
+        [_agg("fs", "sum", "v", filter_fn=_positive)] + ext8, 128, \
+        ("fs", 10, False)
+
+
+@pytest.mark.parametrize("case", list(_top_cases()),
+                         ids=[c[0] for c in _top_cases()])
+def test_kept_rows_equal_every_table_cut_after_the_ranking(case):
+    """`top`'s [threshold] tables, `_keys`, `_rows` and `_nn_<name>` among
+    them, equal to the whole tables indexed with `top_k`'s slots, rank for
+    rank: the same ties, the SENTINEL key and the identities at a rank past
+    the present groups, `_count` the table's own where the cap overflows."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_olap.kernels.sparse_groupby import (SENTINEL, cap_tables,
+                                                 sparse_group_reduce)
+    EngineConfig().apply_x64()
+    name, key, mask, env, plans, cap, top = case
+    metric, threshold, inverted = top
+
+    @jax.jit
+    def run(key, mask, env):
+        return (sparse_group_reduce(key, mask, env, plans, cap, {}, jnp,
+                                    top),
+                _all_tables_then_cut(
+                    sparse_group_reduce(key, mask, env, plans, cap, {}, jnp),
+                    *top))
+
+    got, want = jax.device_get(run(key, mask, env))
+    present = len(np.unique(key[mask]))
+    assert int(got["_count"]) == int(want["_count"]) == present
+    assert set(got) == set(want)
+    kept = min(threshold, cap)
+    for name, table in want.items():
+        assert got[name].dtype == table.dtype, name
+        assert got[name].shape == table.shape, name
+        if name != "_count":
+            assert len(table) == kept, name
+        if table.dtype.kind == "f":
+            np.testing.assert_allclose(got[name], table, rtol=1e-9,
+                                       atol=1e-6, err_msg=name)
+        else:
+            np.testing.assert_array_equal(got[name], table, err_msg=name)
+    # the ranks past the present groups hold the empty slot's values
+    empty = np.arange(kept) >= min(present, cap)
+    assert (got["_keys"][empty] == SENTINEL).all() \
+        and (got["_keys"][~empty] != SENTINEL).all()
+    assert (got["_rows"][empty] == 0).all()
+    # and the ranking is the metric's, the smaller key first among equals
+    m = got[metric][~empty].astype(np.int64) * (1 if inverted else -1)
+    ranks = np.lexsort((got["_keys"][~empty], m))
+    np.testing.assert_array_equal(ranks, np.arange(len(m)))
+    stored = {c: a.dtype for c, a in env["cols"].items()}
+    assert cap_tables(plans, stored, cap, top, set(env["nulls"])) \
+        < cap_tables(plans, stored, cap, None, set(env["nulls"]))
+
+
+def _cap_sized_gathers(lowered, cap):
+    """How many gathers of the lowered program give a [cap] or [cap + 1]
+    result: one a table read at every slot (a prefix is read at the
+    cap + 1 run boundaries)."""
+    import re
+    sizes = [int(m.group(1)) for m in re.finditer(
+        r'"?stablehlo\.gather"?\(.*->\s*tensor<(\d+)x', lowered.as_text())]
+    assert sizes, "no gather in the lowered text: has its spelling changed?"
+    return sum(1 for n in sizes if n in (cap, cap + 1))
+
+
+def _lowered_sparse(plans, cap, top, dtype=np.int8, nulls=False):
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_olap.kernels.sparse_groupby import sparse_group_reduce
+    EngineConfig().apply_x64()
+    n = 4096
+    env = {"cols": {"v": jnp.arange(n, dtype=jnp.int64),
+                    "u": jnp.arange(n, dtype=jnp.int64) % 7,
+                    "w": jnp.arange(n, dtype=jnp.float64),
+                    "d": jnp.arange(n).astype(dtype)},
+           "nulls": {"d": jnp.arange(n) % 3 == 0} if nulls else {}}
+    return jax.jit(lambda k, m, e: sparse_group_reduce(
+        k, m, e, plans, cap, {}, jnp, top)).lower(
+        jnp.arange(n, dtype=jnp.int64) % 50, jnp.ones(n, bool), env), \
+        {c: a.dtype for c, a in env["cols"].items()}, set(env["nulls"])
+
+
+TOP_PLANS = [("s", "sum", "v"), ("su", "sum", "u"), ("lo", "min", "d"),
+             ("hi", "max", "d")]
+
+
+def test_a_top_program_gathers_one_cap_sized_table():
+    """A sum, a second sum, a min and a max, ranked by the first sum: the
+    lowered program holds exactly one gather whose result is cap-sized,
+    the ranked sum's prefix at the run boundaries (one int64 gather here;
+    the chip splits it in two u32 halves). Every other gather reads
+    `threshold` slots, and `cap_tables` says the same."""
+    from tpu_olap.kernels.sparse_groupby import cap_tables
+    plans = [_agg(*a) for a in TOP_PLANS]
+    cap, top = 64, ("s", 10, False)
+    lowered, stored, nullable = _lowered_sparse(plans, cap, top)
+    assert _cap_sized_gathers(lowered, cap) == 1 \
+        == cap_tables(plans, stored, cap, top, nullable)
+    assert '"stablehlo.scatter"(' not in lowered.as_text()
+    # a float sum beside them keeps its segment reduce: a second table
+    plans.append(_agg("fs", "sum", "w", np.float64))
+    lowered, stored, nullable = _lowered_sparse(plans, cap, top)
+    assert _cap_sized_gathers(lowered, cap) == 1
+    assert lowered.as_text().count('"stablehlo.scatter"(') == 1
+    assert cap_tables(plans, stored, cap, top, nullable) == 2
+    # ranked by the row count: a difference of `starts`, no gather at all
+    plans = [_agg("n", "count")] + plans[:4]
+    lowered, stored, nullable = _lowered_sparse(plans, cap,
+                                                ("n", 10, False))
+    assert _cap_sized_gathers(lowered, cap) == 0 \
+        == cap_tables(plans, stored, cap, ("n", 10, False), nullable)
+
+
+@pytest.mark.parametrize("nulls", [False, True], ids=["no-nulls", "nulls"])
+def test_a_program_without_top_gathers_one_cap_sized_table_a_table(nulls):
+    """The same plan without `top` reads every table at every slot, as
+    before PR 39: `_keys`, the two sums, the min and the max (and, where
+    the column has nulls, a non-null count each): one cap-sized gather a
+    table, the number `cap_tables` gives."""
+    from tpu_olap.kernels.sparse_groupby import cap_tables
+    plans = [_agg(*a) for a in TOP_PLANS] + [_agg("n", "count")]
+    cap = 64
+    lowered, stored, nullable = _lowered_sparse(plans, cap, None,
+                                                nulls=nulls)
+    assert _cap_sized_gathers(lowered, cap) == (7 if nulls else 5) \
+        == cap_tables(plans, stored, cap, None, nullable)
+
+
+@pytest.mark.parametrize("sql,form,tables", [
+    # the TopN's threshold is in the program: the ranked sum's table alone
+    ("SELECT a, sum(v) AS sv, count(*) AS n, max(v) AS xv FROM t "
+     "GROUP BY a ORDER BY sv DESC LIMIT 5", "boundary", 1),
+    # a float metric is ranked on the host: every table, as a group-by's
+    ("SELECT a, sum(w) AS sw, sum(v) AS sv FROM t "
+     "GROUP BY a ORDER BY sw DESC LIMIT 5", "scatter", 3),
+    # _keys, sv, xv and its non-null count (v has nulls); n is _rows
+    ("SELECT a, b, sum(v) AS sv, count(*) AS n, max(v) AS xv FROM t "
+     "GROUP BY a, b", "boundary", 4),
+], ids=["device-threshold-topn", "host-ranked-topn", "group-by"])
+def test_record_and_dispatch_span_count_the_cap_tables(sql, form, tables):
+    eng = _engine()
+    check_query(eng, sql)
+    rec = eng.history[-1]
+    assert rec["reduce_path"] == "sparse" and rec["sparse"], rec
+    assert rec["reduce_form"] == form
+    assert rec["cap_tables"] == tables
+    assert [s["attrs"].get("cap_tables")
+            for s in _walk_spans(eng) if s["name"] == "dispatch"] == [tables]
+    if "LIMIT" in sql:
+        assert rec["query_type"] == "topN"
+        assert ("topn_rows_fetched" in rec and rec["topn_rows_fetched"] == 5) \
+            == (tables == 1)
 
 
 def test_sparse_gspmd_spelling_parity(monkeypatch):

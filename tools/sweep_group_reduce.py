@@ -11,7 +11,16 @@ least twice as fast as the scatter at both sizes and compiles in seconds;
 the K past it are there to see where the two forms cross. The form
 `sparse_topn` is what `executor/lowering.py::topn_takes_sparse` sends a
 TopN to where the dense plan would be the scatter: the engine's sparse
-reduce and the threshold on the device (PERF.md section 6, PR 32).
+reduce and the threshold on the device (PERF.md section 6, PR 32), which
+ranks first and reads the tables it does not rank at the 100 kept rows
+(PR 39). `--tables N` puts N more integer sums beside the ranked one, and
+`sparse_topn_tables_first` is the spelling before PR 39 (every [K] table,
+then the ranking, then the cut): at one N the two differ by N + 1 K-sized
+gathers (the keys' and each table's) against as many kept-row reads.
+
+    python tools/sweep_group_reduce.py --rows 59986052 --ks 2000001 \
+        --dtypes int64 --tables 0 3 \
+        --forms sparse_topn sparse_topn_tables_first
 
     python tools/sweep_group_reduce.py                  # on the chip
     python tools/sweep_group_reduce.py --compile-only   # here, for a
@@ -83,25 +92,43 @@ def _bcast(v, key, k):
                    axis=1, dtype=v.dtype)
 
 
-def _sparse_topn(v, key, k):
+def _sparse_topn(v, key, k, tables=0, rank_first=True):
     """The other side of `lowering.topn_takes_sparse`: the engine's own
-    sparse reduce of one sum into a compact table of k slots (one sort
-    whose cost does not depend on k, the table read at the runs'
-    boundaries) and the TopN's threshold on the device. Returns the table,
-    which equals the dense one where every slot is present, and the 100
-    keys kept."""
-    from tpu_olap.kernels.sparse_groupby import (sparse_group_reduce,
-                                                 sparse_top_rows)
-    plans = [groupby.AggPlan("v", "sum", ("v",), v.dtype)]
+    sparse reduce into a compact table of k slots (one sort whose cost
+    does not depend on k, the tables read at the runs' boundaries) with
+    the TopN's threshold on the device: the sum of v, which ranks, and
+    `tables` more integer sums beside it, each a sort operand, a prefix
+    sum and a table of its own. `rank_first` is the engine's program
+    (`sparse_group_reduce`'s `top`): of the [k] tables the ranked one
+    alone is gathered, the others are read at the 100 kept rows. Without
+    it, the spelling that stood until PR 39, kept here to price a
+    k-sized gather against a kept-row read: every [k] table, then
+    `top_k`, then each table cut to the kept slots. Returns the ranked
+    sum at the kept rows, their keys, and the other tables' kept rows."""
+    from tpu_olap.kernels.sparse_groupby import (SENTINEL,
+                                                 sparse_group_reduce)
+    from tpu_olap.kernels.topk import top_k_groups
+    cols = {"v": v, **{f"t{i}": v ^ (i + 1) for i in range(tables)}}
+    plans = [groupby.AggPlan(c, "sum", (c,), v.dtype) for c in cols]
+    top = ("v", 100, False)
     out = sparse_group_reduce(key.astype(jnp.int64),
                               jnp.ones(key.shape, bool),
-                              {"cols": {"v": v}, "nulls": {}}, plans, k, {},
-                              jnp)
-    return out["v"], sparse_top_rows(out, "v", 100, False)["_keys"]
+                              {"cols": cols, "nulls": {}}, plans, k, {},
+                              jnp, top if rank_first else None)
+    if not rank_first:
+        order, _ = top_k_groups(out["v"], out["_keys"] != SENTINEL,
+                                *top[1:])
+        out = {name: t if name == "_count" else t[order]
+               for name, t in out.items()}
+    return (out["v"], out["_keys"]) + tuple(out[c] for c in cols if c != "v")
 
 
 FORMS = {"scatter": _scatter, "compare": _compare, "bcast": _bcast,
-         "sparse_topn": _sparse_topn}
+         "sparse_topn": _sparse_topn,
+         "sparse_topn_tables_first": functools.partial(_sparse_topn,
+                                                       rank_first=False)}
+# the forms `--tables` multiplies
+SPARSE_FORMS = ("sparse_topn", "sparse_topn_tables_first")
 
 
 def _inputs(n, dtype, k, seed=7):
@@ -290,12 +317,13 @@ def _measure(fn, spec, inputs, want, reps, rec):
         rec["ms"] = round(statistics.median(ms), 3)
         rec["ns_per_row"] = round(rec["ms"] * 1e6 / rec["rows"], 3)
         if isinstance(got, tuple):
-            # (table, the keys a top-100 keeps): by (value descending, key
-            # ascending), as the engine's TopN cuts a tie
-            got, top = got
-            rec["top_equal"] = bool(np.array_equal(
-                np.asarray(top),
-                np.lexsort((np.arange(len(want)), -want))[:len(top)]))
+            # (the ranked sum at the rows a top-100 keeps, their keys,
+            # ...): by (value descending, key ascending), as the engine's
+            # TopN cuts a tie
+            got, top = got[:2]
+            kept = np.lexsort((np.arange(len(want)), -want))[:len(top)]
+            rec["top_equal"] = bool(np.array_equal(np.asarray(top), kept))
+            want = want[kept]
         got = np.asarray(got)
         rec["equal"] = bool(
             np.array_equal(got, want) if got.dtype.kind != "f"
@@ -351,6 +379,9 @@ def main():
                     choices=list(FORMS))
     ap.add_argument("--dtypes", nargs="*", default=["int64", "int32"],
                     choices=["int64", "int32"])
+    ap.add_argument("--tables", type=int, nargs="*", default=[0],
+                    help="the sparse TopN forms' integer sums beside the "
+                         "ranked one: a boundary table each")
     ap.add_argument("--block-bytes", type=int, nargs="*",
                     default=[groupby._CMP_BLOCK_BYTES],
                     help="the compare form's row block, to sweep it")
@@ -397,15 +428,19 @@ def sweep_dense(args, sharding):
                 spec = [jax.ShapeDtypeStruct((n,), np.dtype(d),
                                              sharding=sharding)
                         for d in (dtype, "int32")]
-                for name, block in [(f, b) for f in args.forms
-                                    for b in (args.block_bytes
-                                              if f == "compare" else [0])]:
+                for name, block, tables in [
+                        (f, b, t) for f in args.forms
+                        for b in (args.block_bytes
+                                  if f == "compare" else [0])
+                        for t in (args.tables
+                                  if f in SPARSE_FORMS else [None])]:
                     groupby._CMP_BLOCK_BYTES = block or \
                         groupby._CMP_BLOCK_BYTES
+                    more = {} if tables is None else {"tables": tables}
                     out.append(_measure(
-                        functools.partial(FORMS[name], k=k), spec, inputs,
-                        want, args.reps,
-                        dict(rows=n, dtype=dtype, k=k, form=name,
+                        functools.partial(FORMS[name], k=k, **more), spec,
+                        inputs, want, args.reps,
+                        dict(rows=n, dtype=dtype, k=k, form=name, **more,
                              **({"block_bytes": block} if block else {}))))
     return out
 

@@ -566,18 +566,19 @@ def _lower_agg(query, table, config) -> PhysicalPlan:
     def make_sparse_kernel(cap, top=None):
         """The sparse program for a compact table of `cap` slots; with
         `top` = (metric, threshold, inverted) the table's rows that a TopN
-        keeps, the threshold applied on the device."""
+        keeps, the threshold applied on the device: that program ranks
+        first and reads the tables it does not rank at the kept rows
+        (`sparse_group_reduce`)."""
         from tpu_olap.kernels.sparse_groupby import (build_group_key64,
-                                                     sparse_group_reduce,
-                                                     sparse_top_rows)
+                                                     sparse_group_reduce)
 
         def sparse_kernel(env, valid, seg_mask, consts):
             xp = _jnp()
             fenv, mask, key = _masked_key(env, valid, seg_mask, consts,
                                           build_group_key64)
-            out = sparse_group_reduce(key.astype(xp.int64), mask, fenv,
-                                      sparse_agg_plans, cap, consts, xp)
-            return out if top is None else sparse_top_rows(out, *top)
+            return sparse_group_reduce(key.astype(xp.int64), mask, fenv,
+                                       sparse_agg_plans, cap, consts, xp,
+                                       top)
         return sparse_kernel
 
     def build(sparse: bool) -> PhysicalPlan:

@@ -1930,8 +1930,9 @@ class QueryRunner:
         one chip's budget. Returns (partials dict, count): compact
         tables, SENTINEL-keyed past the present groups.
         With `top` = (metric, threshold, inverted), one chip's program
-        ends in the TopN's threshold and the partials are its [threshold]
-        rows in rank order (`_device_threshold` says when)."""
+        holds the TopN's threshold and the partials are its [threshold]
+        rows in rank order (`_device_threshold` says when); the record's
+        `cap_tables` says how many [cap] tables either program built."""
         with _span("dispatch", sparse=True) as sp:
             out = self._run_sparse_inner(plan, metrics, top)
             sp.set(jit_cache_hit=metrics.get("jit_cache_hit"),
@@ -1950,8 +1951,11 @@ class QueryRunner:
         build + async dispatch runs under the enqueue lock; the _count
         probe (a one-element sync) and the final whole-tree fetch run
         lock-free, so an overflow retry re-enters stage 1. `top` (one
-        chip only) puts the TopN's threshold at the program's end: the
-        fetch then brings `threshold` rows a table, not `cap`."""
+        chip only) puts the TopN's threshold in the program, which ranks
+        the metric's [cap] table first and reads every other table at the
+        rows it keeps (`sparse_group_reduce`); `_count` stays the table's
+        own, so the probe below reads what it read, and the fetch brings
+        `threshold` rows a table, not `cap`."""
         from tpu_olap.kernels.groupby import UnsupportedAggregation
 
         with self._enqueue_lock(metrics):
@@ -1959,6 +1963,7 @@ class QueryRunner:
         # the width each stored column is resident at: with the plan's
         # kinds and the cap, what the kernel picks its reduce from
         stored = {c: a.dtype for c, a in env["cols"].items()}
+        nullable = frozenset(env["nulls"])
         win = self._segment_window(plan, len(seg_mask))
         if win is not None:
             metrics["segments_window"] = win[1]
@@ -2109,7 +2114,8 @@ class QueryRunner:
                 metrics["execute_ms"] = \
                     (time.perf_counter() - t0) * 1000
                 metrics["jit_cache_hit"] = hit
-                self._note_sparse(metrics, plan, stored, attempts, cap, count)
+                self._note_sparse(metrics, plan, stored, nullable, attempts,
+                                  cap, count)
                 return out, count
             lhint = self._cap_hints.get(base_key + ("local",))
             if lhint is not None:
@@ -2246,27 +2252,33 @@ class QueryRunner:
         self._cap_hints[base_key] = count
         metrics["execute_ms"] = (time.perf_counter() - t0) * 1000
         metrics["jit_cache_hit"] = hit
-        self._note_sparse(metrics, plan, stored, attempts, cap, count)
+        self._note_sparse(metrics, plan, stored, nullable, attempts, cap,
+                          count, top)
         return out, count
 
     @staticmethod
-    def _note_sparse(metrics: dict, plan, stored: dict, attempts: int,
-                     cap: int, count: int):
+    def _note_sparse(metrics: dict, plan, stored: dict, nullable,
+                     attempts: int, cap: int, count: int, top=None):
         """The sparse dispatch's counters on the record: how many cap
         attempts ran (1 once the template's hint is warm), the compact
         table's final cap, and the groups present in it; and which
         program the reduce of that cap was — whether every [cap] table
         is read at the sorted runs' boundaries or an aggregate still
-        scatters, and the width of the word a min / max is read from:
-        the kernel's own function of the plan's aggregate kinds and
-        dtypes, the columns' stored dtypes and the cap, as the dense
-        `reduce_form` is of num_groups."""
+        scatters, the width of the word a min / max is read from, and
+        how many [cap] tables it gathers or segment-reduces (every table,
+        or with `top` the ranked one and what still segment-reduces):
+        the kernel's own functions of the plan's aggregate kinds and
+        dtypes, the columns' stored dtypes (`nullable`: those with a null
+        mask), the cap and `top`, as the dense `reduce_form` is of
+        num_groups."""
         from tpu_olap.kernels import sparse_groupby as sg
         metrics["reduce_form"] = sg.sparse_reduce_form(plan.agg_plans,
                                                        stored, cap)
         bits = sg.ext_word_bits(plan.agg_plans, stored, cap)
         if bits is not None:
             metrics["ext_word_bits"] = bits
+        metrics["cap_tables"] = sg.cap_tables(plan.agg_plans, stored, cap,
+                                              top, nullable)
         metrics["sparse"] = True
         metrics["sparse_attempts"] = attempts
         metrics["sparse_cap"] = metrics["result_cap"] = cap
@@ -3048,9 +3060,11 @@ def _note_form(metrics: dict, plan, num_groups: int):
 def _form_attr(metrics: dict) -> dict:
     """The `dispatch` span's `reduce_form` attribute, where the record of
     the query has one (a generic grouped aggregate on the device), and
-    beside it a sparse min / max's `ext_word_bits`."""
-    return {k: metrics[k] for k in ("reduce_form", "ext_word_bits")
-            if metrics.get(k)}
+    beside it a sparse min / max's `ext_word_bits` and the sparse
+    program's `cap_tables`."""
+    return {k: metrics[k]
+            for k in ("reduce_form", "ext_word_bits", "cap_tables")
+            if metrics.get(k) is not None}
 
 
 def _next_pow2(n: int) -> int:

@@ -30,7 +30,14 @@ XLA's sort is fast on TPU and everything stays static-shaped:
      a column stored in 64 bits or of a double, the sketches' [cap, m]
      state — and `sparse_reduce_form` says "scatter" of such a plan. Slot
      i holds the i-th smallest present group key, so results are already
-     compact AND sorted;
+     compact AND sorted. Where the program ends in a TopN's threshold
+     (`top`), it RANKS FIRST: only the ranked metric's table is built at
+     [cap] (and `_rows`, a difference of `starts`), `top_k` picks the
+     `threshold` slots it keeps, and every other table is read at those
+     slots alone, from the same sorted operands, prefix sums and running
+     words at the kept runs' two ends (what segment-reduces builds its
+     [cap] table and is indexed); `cap_tables` counts the [cap] tables
+     either program gathers or segment-reduces;
   5. "_count" reports the true unique count — if it exceeds cap the
      runner re-runs with the next power of two (same adaptive-cap pattern
      as executor.packing).
@@ -177,13 +184,13 @@ def _running_max(word):
     return jnp.maximum(inner, before[:, None]).reshape(-1)[:n]
 
 
-def _run_ext(v, counted, gid, starts, kind, col_dtype, word):
-    """Exact min / max of v over every sorted run, as [cap] values of
-    dtype `word` (garbage in an empty run): the running maximum of
-    (run id << b) | code(v), read at the run's last row. gid does not
-    decrease, so at that row the maximum holds the run's own id above the
-    largest code seen in the run; `counted` (None: every row) is False on
-    the rows the aggregator leaves out, which code as 0."""
+def _run_ext(v, counted, gid, starts, kind, col_dtype, word, at=None):
+    """Exact min / max of v over the sorted runs of the slots `at` (None:
+    every slot, [cap] values), of dtype `word` (garbage in an empty run):
+    the running maximum of (run id << b) | code(v), read at the run's last
+    row. gid does not decrease, so at that row the maximum holds the run's
+    own id above the largest code seen in the run; `counted` (None: every
+    row) is False on the rows the aggregator leaves out, which code as 0."""
     import jax.numpy as jnp
 
     lim, b = np.iinfo(col_dtype), np.iinfo(col_dtype).bits + 1
@@ -194,7 +201,8 @@ def _run_ext(v, counted, gid, starts, kind, col_dtype, word):
             code = jnp.where(counted, code, 0)
         running = _running_max((gid.astype(word) << b) | code)
     with stage_scope("gather", jnp):
-        code = running[jnp.maximum(starts[1:] - 1, 0)] & ((1 << b) - 1)
+        ends = starts[1:] if at is None else starts[at + 1]
+        code = running[jnp.maximum(ends - 1, 0)] & ((1 << b) - 1)
         return code - 1 + lim.min if kind == "max" else lim.max + 1 - code
 
 
@@ -270,12 +278,23 @@ def _narrow_int(col_dtype, acc_dtype) -> bool:
         and np.can_cast(col_dtype, np.int32)
 
 
-def sparse_group_reduce(key, mask, env, plans, cap, consts, xp):
+def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None):
     """[N] int64 keys + mask -> compacted per-group partials.
 
     Returns {"_keys": [cap] int64 (SENTINEL marks empty slots),
              "_count": [] int32 true unique count,
              "_rows": [cap], <agg name>: [cap] or [cap, m], ...}.
+
+    With `top` = (metric, threshold, inverted), the rows of that table a
+    TopN by `metric` (a count or a sum of `plans`) keeps, as
+    [min(threshold, cap)] tables in rank order: the program ranks first.
+    Of the [cap] tables it builds the metric's alone (and `_rows`, which
+    says which slots are present), `top_k` picks the kept slots, and every
+    other table is read at those: the boundary readers below take the
+    slots they read at, every slot where there is no `top`. `_count` stays
+    the table's own (the cap-overflow probe reads it); a rank past the
+    present groups holds the SENTINEL key and the identities of the empty
+    slot it points at.
     """
     import jax
 
@@ -346,21 +365,25 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp):
         gid, count = _sorted_segments(skey, cap, xp)
         starts = _run_starts(skey, cap, xp)
 
-    def run_sum(v, acc_dtype):
-        """Exact integer sum of v over every run: the inclusive prefix
-        sum read at the run's last row less its value before the run's
-        first. Wrapping arithmetic makes the difference exact whatever
-        the prefix holds."""
+    def run_sum(v, acc_dtype, at=None):
+        """Exact integer sum of v over the runs of the slots `at` (None:
+        every slot): the inclusive prefix sum read at the run's last row
+        less its value before the run's first. Wrapping arithmetic makes
+        the difference exact whatever the prefix holds."""
         with stage_scope("prefix", xp):
             prefix = _running(v.astype(acc_dtype), "add")
-        with stage_scope("gather", xp):
-            before = xp.where(starts > 0,
-                              prefix[xp.maximum(starts - 1, 0)], 0)
-            return before[1:] - before[:-1]
 
-    def run_count(m):
+        def before(row):
+            return xp.where(row > 0, prefix[xp.maximum(row - 1, 0)], 0)
+        with stage_scope("gather", xp):
+            if at is None:
+                ends = before(starts)
+                return ends[1:] - ends[:-1]
+            return before(starts[at + 1]) - before(starts[at])
+
+    def run_count(m, at=None):
         # a count is at most N: an int32 prefix holds it
-        return run_sum(m, np.int32)
+        return run_sum(m, np.int32, at)
 
     def segment(f, v):
         # what neither gives: XLA's segment reduce, told that the ids are
@@ -369,37 +392,66 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp):
             return f(v, gid, num_segments=cap + 1,
                      indices_are_sorted=True)[:cap]
 
+    def kept(t, at):
+        # a [cap] table that is built whole, cut to the slots
+        if at is None:
+            return t
+        with stage_scope("gather", xp):
+            return t[at]
+
+    def table(p, at=None):
+        """A count's or a sum's table at the slots `at`."""
+        if p.kind == "count":
+            return kept(rows, at) if p.filter_fn is None else \
+                run_count(sorted_ops[slots[f"m:{p.name}"]], at)
+        v = sorted_ops[slots[f"v:{p.name}"]]
+        if prefix_summed(p):
+            return run_sum(v, p.acc_dtype, at)
+        return kept(segment(jax.ops.segment_sum, v), at)
+
     # inside a non-SENTINEL run every row is unmasked: its length is its
-    # row count. The key of slot g is its first row's; past the present
-    # groups that row is in the SENTINEL tail, or out of bounds
+    # row count, and a slot is present exactly where its run is not empty
     with stage_scope("gather", xp):
-        out = {"_count": count, "_rows": starts[1:] - starts[:-1],
-               "_keys": skey.at[starts[:cap]].get(mode="fill",
-                                                  fill_value=SENTINEL)}
+        rows = starts[1:] - starts[:-1]
+
+    at = ranked = whole = None
+    if top is not None:
+        from tpu_olap.kernels.topk import top_k_groups
+        metric, threshold, inverted = top
+        ranked = next(p for p in plans if p.name == metric)
+        if ranked.kind not in ("count", "sum"):
+            raise UnsupportedAggregation(
+                f"no device threshold by a {ranked.kind!r}")
+        whole = table(ranked)
+        with stage_scope("threshold", xp):
+            at, _ = top_k_groups(whole, rows > 0, threshold, inverted)
+
+    # The key of slot g is its first row's; past the present groups that
+    # row is in the SENTINEL tail, or out of bounds
+    with stage_scope("gather", xp):
+        out = {"_count": count, "_rows": kept(rows, at),
+               "_keys": skey.at[starts[:cap] if at is None else starts[at]]
+               .get(mode="fill", fill_value=SENTINEL)}
 
     for p in plans:
-        if p.kind == "count":
-            out[p.name] = (out["_rows"] if p.filter_fn is None else
-                           run_count(sorted_ops[slots[f"m:{p.name}"]])
-                           ).astype(p.acc_dtype)
-            continue
-        if p.kind == "sum":
-            v = sorted_ops[slots[f"v:{p.name}"]]
-            out[p.name] = run_sum(v, p.acc_dtype) if prefix_summed(p) \
-                else segment(jax.ops.segment_sum, v)
+        if p.kind in ("count", "sum"):
+            # the ranked table is built once: its kept rows are its own
+            out[p.name] = (kept(whole, at) if p is ranked else
+                           table(p, at)).astype(p.acc_dtype)
             continue
         if p.kind in ("min", "max"):
             counted = sorted_ops[slots[f"nn:{p.name}"]] \
                 if f"nn:{p.name}" in slots else None
-            nn = out["_rows"] if counted is None else run_count(counted)
+            nn = out["_rows"] if counted is None else run_count(counted, at)
             if p.name in words:
                 operand, word, col_dtype = words[p.name]
                 v = _run_ext(sorted_ops[slots[operand]], counted, gid,
-                             starts, p.kind, col_dtype, word)
+                             starts, p.kind, col_dtype, word, at)
             else:
-                v = segment(
+                v = kept(segment(
                     jax.ops.segment_min if p.kind == "min" else
-                    jax.ops.segment_max, sorted_ops[slots[f"v:{p.name}"]])
+                    jax.ops.segment_max, sorted_ops[slots[f"v:{p.name}"]]),
+                    at)
             # an empty slot holds the accumulator's identity, whatever
             # width the rows were reduced at
             with stage_scope("gather", xp):
@@ -413,7 +465,7 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp):
             with stage_scope("segment", xp):
                 regs = hll_mod.hll_update(h, valid,
                                           xp.where(valid, gid, 0), cap + 1)
-            out[p.name] = regs[:cap]
+            out[p.name] = kept(regs[:cap], at)
             continue
         if p.kind == "theta":
             h = sorted_ops[slots[f"h:{p.name}"]]
@@ -424,29 +476,36 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp):
             with stage_scope("segment", xp):
                 t = theta_mod.theta_update(h, valid, gid, cap + 1,
                                            p.theta_k)
-            out[p.name] = t[:cap]
+            out[p.name] = kept(t[:cap], at)
             continue
     return out
 
 
-def sparse_top_rows(tables: dict, metric: str, threshold: int,
-                    inverted: bool) -> dict:
-    """The rows of a compact table (`sparse_group_reduce`'s) that a TopN by
-    `metric` keeps, as [threshold] tables in rank order: the threshold
-    applied on the device, so that `threshold` group rows and not `cap`
-    leave it. `_count` stays the table's own (the cap-overflow probe reads
-    it); a rank past the present groups holds the SENTINEL key of the
-    empty slot it points at."""
-    import jax.numpy as jnp
+def cap_tables(plans, col_dtypes, cap, top=None, nullable=()) -> int:
+    """How many [cap]-sized tables `sparse_group_reduce`'s program gathers
+    or segment-reduces, from static facts alone (`sparse_reduce_form`'s,
+    and `nullable`: the fields that carry a null mask). `_rows`, a
+    difference of `starts`, costs no gather and is not counted, nor is
+    what reads it (an unfiltered count, the non-null count of a min / max
+    that leaves no row out). Without `top` every table: `_keys` and one an
+    aggregate, two a min / max with a non-null count of its own. With
+    `top` the ranked metric's alone, beside what still segment-reduces
+    (a float sum, a 64-bit min / max, a sketch): the others are read at
+    the `threshold` kept rows."""
+    def gathered(p):
+        # not read off `_rows`
+        return p.kind != "count" or p.filter_fn is not None
 
-    from tpu_olap.kernels.topk import top_k_groups
+    def segment_reduced(p):
+        return not prefix_summed(p) \
+            and _ext_word(p, col_dtypes, cap) is None
 
-    with stage_scope("threshold", jnp):
-        keys = tables["_keys"]
-        order, _ = top_k_groups(tables[metric], keys != SENTINEL, threshold,
-                                inverted)
-        return {name: t if name == "_count" else t[order]
-                for name, t in tables.items()}
+    if top is not None:
+        return sum(1 for p in plans if segment_reduced(p)
+                   or (p.name == top[0] and gathered(p)))
+    own_count = sum(1 for p in plans if p.kind in ("min", "max") and (
+        p.filter_fn is not None or p.fields[0] in nullable))
+    return 1 + sum(1 for p in plans if gathered(p)) + own_count
 
 
 def merges_on_device(plans) -> bool:

@@ -7,11 +7,15 @@ therefore a strict-accuracy win, not a compatibility break; the context
 flag `useApproximateTopN` exists for parity testing but maps to the same
 exact kernel.
 
-The caller is the sparse path's compact table (`sparse_groupby.
-sparse_top_rows`): slot i holds the i-th smallest present key, and
-lax.top_k puts the lower index first among equal values, so ties at the
-threshold are kept in the dimension's own ascending order — the rule of the
-host assembler (`runner._emit_topn`, a stable argsort over label order).
+The caller is the sparse program that ends in a TopN's threshold
+(`sparse_groupby.sparse_group_reduce` with `top`), which ranks BEFORE it
+builds the tables it does not rank: `metric` is the ranked aggregate's
+[cap] table, `present` the slots whose sorted run is not empty, and the
+indices returned are the slots every other table is then read at. Slot i
+holds the i-th smallest present key, and lax.top_k puts the lower index
+first among equal values, so ties at the threshold are kept in the
+dimension's own ascending order — the rule of the host assembler
+(`runner._emit_topn`, a stable argsort over label order).
 """
 
 from __future__ import annotations
