@@ -50,6 +50,12 @@ SPARSE_TOPN_SQL = """
     SELECT lo_partkey, sum(lo_quantity) AS qty, count(*) AS n
     FROM lineorder GROUP BY lo_partkey ORDER BY qty DESC LIMIT 100"""
 
+SPARSE_HAVING_SQL = """
+    SELECT lo_partkey, sum(lo_quantity) AS qty, count(*) AS n,
+           max(lo_discount) AS hi
+    FROM lineorder WHERE lo_quantity < 40 GROUP BY lo_partkey
+    HAVING sum(lo_quantity) > 60 AND count(*) < 9"""
+
 # the ops a stage is told by: where one of these has no stage, seconds of a
 # capture have no name
 TOLD = ("stablehlo.sort", "stablehlo.reduce_window", "stablehlo.gather",
@@ -175,7 +181,7 @@ def _scatter(ssb_tables, monkeypatch):
     return lowered
 
 
-def _sparse(ssb_tables, sql, form, top=False, **cfg):
+def _sparse(ssb_tables, sql, form, top=False, having=False, **cfg):
     import jax
 
     from tpu_olap.kernels.sparse_groupby import sparse_reduce_form
@@ -188,7 +194,9 @@ def _sparse(ssb_tables, sql, form, top=False, **cfg):
     threshold = eng.runner._device_threshold(phys.query, phys) \
         if top else None
     assert (threshold is not None) == top
-    return jax.jit(phys.make_sparse_kernel(4096, threshold)).lower(*args)
+    assert eng.runner._device_having(phys) == having
+    return jax.jit(phys.make_sparse_kernel(
+        4096, threshold, 1024 if having else None)).lower(*args)
 
 
 def _mesh(ssb_tables, which):
@@ -227,6 +235,9 @@ PROGRAMS = [
     ("sparse-topn", lambda t, m: _sparse(t, SPARSE_TOPN_SQL, "boundary",
                                          top=True, use_pallas="never"),
      (SPARSE_STAGES - {"filter"}) | {"threshold"}),
+    ("sparse-having", lambda t, m: _sparse(t, SPARSE_HAVING_SQL, "boundary",
+                                           having=True),
+     SPARSE_STAGES | {"having"}),
     ("mesh-sparse", lambda t, m: _mesh(t, "sort"), SPARSE_STAGES),
     ("mesh-merge", lambda t, m: _mesh(t, "merge"), {"merge"}),
     ("mesh-head", lambda t, m: _mesh(t, "head"), {"pack"}),
@@ -266,7 +277,9 @@ def test_every_told_op_of_a_device_program_lies_in_one_stage(
         assert by["prefix"] >= 1, by
         sorts = sorted(_stage(name) for kind, name, _s in ops
                        if kind == "stablehlo.sort")
-        assert sorts == ["runs", "sort"], sorts
+        # a device HAVING compacts the passing slots with a sort of its own
+        assert sorts == (["having"] if case == "sparse-having" else []) \
+            + ["runs", "sort"], sorts
     if case == "pallas":
         assert [_stage(name) for kind, name, _s in ops
                 if "pallas_call" in name] == ["reduce"]

@@ -369,9 +369,12 @@ def _dispatch_forms(eng):
 
 
 def test_sparse_cap_grows_once_and_the_retry_is_the_warm_program(data):
-    """A template whose present groups pass the starting cap: the first run
-    overflows, grows the compact table once and compiles twice; the next
-    run starts from the grown cap and finds that program in the jit cache."""
+    """A template whose group space is past the budget and whose present
+    groups no hint tells: the first run counts them (a program that builds
+    no table), sizes the compact table from the count and compiles twice;
+    the next run starts from that cap and finds the program in the jit
+    cache. A hint that has gone stale (the groups grew past it) still
+    overflows, grows the table once and leaves the grown cap behind."""
     eng = _engine(data, sparse_group_cap=64)
     sql = tpch_flat.templates()["q10"]
     _served(eng, sql)
@@ -385,10 +388,21 @@ def test_sparse_cap_grows_once_and_the_retry_is_the_warm_program(data):
     assert first["present_groups"] == second["present_groups"] == groups
     assert first.get("recompiles") == 2
     assert second.get("jit_cache_hit") and not second.get("recompiles")
-    assert [s["attrs"]["cap"] for s in spans
-            if s["name"] == "sparse-attempt"] == [64, 1024]
+    assert [s["attrs"].get("cap", s["attrs"].get("present_groups"))
+            for s in spans
+            if s["name"] in ("sparse-count", "sparse-attempt")] \
+        == [groups, 1024]
     assert {"count-probe", "host-transfer", "ordered-limit"} \
         <= {s["name"] for s in spans}
+    hints = eng.runner._cap_hints
+    stale = [k for k, v in hints.items() if v == groups]
+    assert len(stale) == 1
+    hints[stale[0]] = 20
+    _served(eng, sql)
+    third = eng.runner.history[-1]
+    assert third["sparse_attempts"] == 2 and third["sparse_cap"] == 1024
+    assert [s["attrs"]["cap"] for s in _walk(eng.tracer.last.to_json())
+            if s["name"] == "sparse-attempt"] == [64, 1024]
 
 
 def _walk(tree):

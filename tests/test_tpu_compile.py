@@ -322,6 +322,66 @@ def test_sparse_min_max_program_compiles_for_v5e(topo, no_persistent_cache,
         "_nn_max_discount": ((100,), "int32")}
 
 
+def test_sparse_having_program_compiles_for_v5e(topo, no_persistent_cache,
+                                                monkeypatch, tmp_path):
+    """Q18 of `tpch-flat-sf10-having-chip`, as the chip runs it: the sparse
+    program with the HAVING at its end (compiled on small shapes under a
+    cap of 2^16 slots; the cell's is 2^24, whose word is checked). The tested
+    sum's prefix is the one table gathered at [cap]; max(o_totalprice)
+    rides the sort as int32 and is read from an int64 running maximum (25
+    + 32 + 1 bits) at the kept rows; three sorts (the rows, `starts`, the
+    passing slots), no scatter, `kept` rows a table out. The count probe
+    is one sort and no table."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    from perfbench.datasets import tpch_flat_having
+    from tpu_olap.kernels.sparse_groupby import cap_tables, ext_word_bits
+    _as_tpu(monkeypatch)
+    rows, seed, cap, kept = 60_000, 2_147_483_659, 1 << 24, 1024
+    data = tpch_flat_having.generate(rows, seed, str(tmp_path), workers=1,
+                                     orders_per_chunk=7_000)
+    eng = Engine(EngineConfig(fallback_on_device_failure=False,
+                              sparse_group_budget=cap))
+    tpch_flat_having.register(eng, data["paths"], rows, seed)
+    phys = _physical(eng, tpch_flat_having.templates()["q18"])
+    assert phys.query.query_type == "groupBy" and phys.sparse
+    assert phys.total_groups > cap
+    assert eng.runner._device_having(phys)
+    assert phys.having[1] == {"sum_quantity"}
+    env, valid, seg_mask = eng.runner._prepare(phys, {})
+    assert env["cols"]["o_totalprice"].dtype == "int32"
+    stored = {c: a.dtype for c, a in env["cols"].items()}
+    assert ext_word_bits(phys.agg_plans, stored, cap) == 64
+    assert cap_tables(phys.agg_plans, stored, cap,
+                      having=phys.having[1]) == 1
+    assert cap_tables(phys.agg_plans, stored, cap) == 3
+    consts_dev, seg_arg = eng.runner._args_for(phys, seg_mask, None)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    args = (*_scaled((env, valid, seg_arg), 1, one_chip),
+            _scaled(consts_dev, 1, one_chip))
+    assert ext_word_bits(phys.agg_plans, stored, 1 << 16) == 64
+    kernel = phys.make_sparse_kernel(1 << 16, None, kept)
+    lowered = jax.jit(kernel).lower(*args)
+    main_sort = max(re.findall(r"stablehlo\.sort\"?\(([^)]*)\)",
+                               lowered.as_text()), key=len)
+    # key, l_quantity's sum operand, o_totalprice once
+    assert len(main_sort.split(",")) == 3, main_sort
+    text = lowered.compile().as_text()
+    assert " scatter(" not in text and text.count(" sort(") == 3
+    out = jax.eval_shape(kernel, env, valid, seg_arg, consts_dev)
+    assert {k: (v.shape, str(v.dtype)) for k, v in out.items()} == {
+        "_count": ((), "int32"), "_kept": ((), "int32"),
+        "_rows": ((kept,), "int32"), "_keys": ((kept,), "int64"),
+        "sum_quantity": ((kept,), "int64"),
+        "o_totalprice": ((kept,), "int64"),
+        "_nn_o_totalprice": ((kept,), "int32")}
+    count = jax.jit(phys.make_sparse_kernel(None)).lower(*args).compile()
+    assert count.as_text().count(" sort(") == 1
+    assert jax.eval_shape(phys.make_sparse_kernel(None), env, valid,
+                          seg_arg, consts_dev)["_count"].shape == ()
+
+
 @pytest.mark.parametrize("word", ["int32", "int64"])
 def test_running_max_compiles_at_the_druid_cells_rows(topo,
                                                       no_persistent_cache,

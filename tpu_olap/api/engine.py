@@ -840,10 +840,17 @@ class Engine:
         """EXPLAIN DRUID REWRITE analog: the chosen QuerySpec (or the
         fallback reason) without executing (SURVEY.md §4.5), plus, for
         an aggregate served over a mesh, the spelling the mesh runs it
-        in (the record's `mesh_program`)."""
+        in (the record's `mesh_program`) and, for a GroupBy with a
+        HAVING, who decides it (the record's `having_where`: `device`
+        where the sparse program cuts the table itself, else `host`;
+        null where lowering finds no device plan for the query)."""
         from tpu_olap.executor.batch import AGG_QUERY_TYPES
         plan = self.planner.plan(query)
         out = plan.explain()
+        if plan.rewritten and plan.entry.is_accelerated \
+                and getattr(plan.query, "having", None) is not None:
+            out["having_where"] = self.runner.having_where(
+                plan.query, plan.entry.segments)
         if plan.rewritten and plan.entry.is_accelerated \
                 and isinstance(plan.query, AGG_QUERY_TYPES) \
                 and self.runner.mesh is not None:

@@ -58,6 +58,9 @@ class PhysicalPlan:
     pallas_reason: str | None = "not attempted"  # None = pallas kernel active
     sparse: bool = False       # sort-based path for huge group spaces
     make_sparse_kernel: object = None   # cap -> kernel fn (sparse only)
+    # (test, names) of a GroupBy's HAVING where the sparse program can
+    # decide it (sparse_groupby.compile_having), else None: the host does
+    having: object = None
     # fn(env, valid, seg_mask, consts) -> (fenv, mask, key): the plan's
     # filter+dim front half WITHOUT the reduce, so the batch executor
     # can fuse N legs' reduces over one shared scan (dense agg only;
@@ -563,22 +566,36 @@ def _lower_agg(query, table, config) -> PhysicalPlan:
         _dc.replace(p, theta_k=min(p.theta_k, config.sparse_theta_k_cap))
         if p.kind == "theta" else p for p in agg_plans)
 
-    def make_sparse_kernel(cap, top=None):
+    # a HAVING the sparse program can decide (its literals join the pool)
+    having = None
+    if sparse and isinstance(query, GroupByQuerySpec) \
+            and query.having is not None:
+        from tpu_olap.kernels.sparse_groupby import compile_having
+        having = compile_having(query.having, sparse_agg_plans, pool)
+
+    def make_sparse_kernel(cap, top=None, kept=None):
         """The sparse program for a compact table of `cap` slots; with
         `top` = (metric, threshold, inverted) the table's rows that a TopN
         keeps, the threshold applied on the device: that program ranks
         first and reads the tables it does not rank at the kept rows
-        (`sparse_group_reduce`)."""
+        (`sparse_group_reduce`); with `kept` the rows the plan's HAVING
+        lets through, in a bucket of `kept` rows: the same cut by a
+        predicate. `cap` None: the program that counts the groups present
+        and builds no table (`sparse_group_count`)."""
         from tpu_olap.kernels.sparse_groupby import (build_group_key64,
+                                                     sparse_group_count,
                                                      sparse_group_reduce)
 
         def sparse_kernel(env, valid, seg_mask, consts):
             xp = _jnp()
             fenv, mask, key = _masked_key(env, valid, seg_mask, consts,
                                           build_group_key64)
-            return sparse_group_reduce(key.astype(xp.int64), mask, fenv,
-                                       sparse_agg_plans, cap, consts, xp,
-                                       top)
+            if cap is None:
+                return sparse_group_count(key.astype(xp.int64), mask, xp)
+            return sparse_group_reduce(
+                key.astype(xp.int64), mask, fenv, sparse_agg_plans, cap,
+                consts, xp, top,
+                None if kept is None else having + (kept,))
         return sparse_kernel
 
     def build(sparse: bool) -> PhysicalPlan:
@@ -597,7 +614,8 @@ def _lower_agg(query, table, config) -> PhysicalPlan:
             columns=columns, null_cols=null_cols, virtual_exprs=vexprs,
             filter_streams=_dedupe_streams(pool),
             sparse=sparse, make_sparse_kernel=make_sparse_kernel if sparse
-            else None, key_fn=None if sparse else key_fn)
+            else None, having=having,
+            key_fn=None if sparse else key_fn)
 
     plan = build(sparse)
     if not sparse:

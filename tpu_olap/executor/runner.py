@@ -1921,7 +1921,8 @@ class QueryRunner:
         metrics["packed"] = True
         return idx, compact, layout
 
-    def _run_sparse(self, plan: PhysicalPlan, metrics: dict, top=None):
+    def _run_sparse(self, plan: PhysicalPlan, metrics: dict, top=None,
+                    having=False):
         """Sort-based sparse group-by dispatch with adaptive compact-table
         cap (kernels.sparse_groupby). On a mesh every chip compacts its
         own rows and the D tables are merged where EngineConfig.mesh_merge
@@ -1931,22 +1932,25 @@ class QueryRunner:
         tables, SENTINEL-keyed past the present groups.
         With `top` = (metric, threshold, inverted), one chip's program
         holds the TopN's threshold and the partials are its [threshold]
-        rows in rank order (`_device_threshold` says when); the record's
-        `cap_tables` says how many [cap] tables either program built."""
+        rows in rank order (`_device_threshold` says when); with `having`
+        it holds the plan's HAVING and the partials are the groups that
+        pass, in a bucket of `_kept` rows (`_device_having`); the record's
+        `cap_tables` says how many [cap] tables each program built."""
         with _span("dispatch", sparse=True) as sp:
-            out = self._run_sparse_inner(plan, metrics, top)
+            out = self._run_sparse_inner(plan, metrics, top, having)
             sp.set(jit_cache_hit=metrics.get("jit_cache_hit"),
                    result_groups=metrics.get("result_groups"),
                    num_shards=metrics.get("num_shards"),
                    **_form_attr(metrics))
         return out
 
-    def _run_sparse_inner(self, plan: PhysicalPlan, metrics: dict, top):
+    def _run_sparse_inner(self, plan: PhysicalPlan, metrics: dict, top,
+                          having):
         with self._pipeline_slot():
-            return self._run_sparse_staged(plan, metrics, top)
+            return self._run_sparse_staged(plan, metrics, top, having)
 
     def _run_sparse_staged(self, plan: PhysicalPlan, metrics: dict,
-                           top=None):
+                           top=None, having=False):
         """Adaptive-cap sparse dispatch, two-staged: each attempt's jit
         build + async dispatch runs under the enqueue lock; the _count
         probe (a one-element sync) and the final whole-tree fetch run
@@ -1955,7 +1959,15 @@ class QueryRunner:
         the metric's [cap] table first and reads every other table at the
         rows it keeps (`sparse_group_reduce`); `_count` stays the table's
         own, so the probe below reads what it read, and the fetch brings
-        `threshold` rows a table, not `cap`."""
+        `threshold` rows a table, not `cap`. `having` (one chip only) puts
+        the plan's HAVING there the same way: the program builds the
+        tested aggregates' [cap] tables, compacts the slots that pass
+        into a power-of-two bucket and reads every other table at those;
+        `_kept` says how many passed, a bucket they do not fit is grown
+        and the attempt run again, as a cap is. A group space past the
+        budget whose count no hint tells is counted first, by a program
+        that builds no table, so that the first compact table holds it:
+        each cap is a compile of the sort."""
         from tpu_olap.kernels.groupby import UnsupportedAggregation
 
         with self._enqueue_lock(metrics):
@@ -1992,7 +2004,15 @@ class QueryRunner:
             return min(local_limit, self.config.sparse_group_cap) \
                 if hint is None else _grown_cap(hint, local_limit)
 
-        cap = first_cap(self._cap_hints.get(base_key))
+        hint = self._cap_hints.get(base_key)
+        cap = first_cap(hint)
+        # the HAVING's bucket: the power of two that holds the most
+        # groups any literal of the template has let through
+        kept_key = base_key + ("kept",)
+
+        def kept_bucket(cap):
+            return min(cap, max(HAVING_KEPT_MIN, _next_pow2(
+                self._cap_hints.get(kept_key, 0))))
 
         t0 = time.perf_counter()
         hit = False
@@ -2003,32 +2023,59 @@ class QueryRunner:
             # (the caller blocks on the _count probe while the buffers
             # occupy HBM); a retry/raise unpins the superseded pin
             pin = None
+
+            def over_budget(count):
+                return UnsupportedAggregation(
+                    f"{count} present groups exceed sparse budget "
+                    f"{cap_limit}")
+
+            def run(cap, kept=None):
+                """Build (once a key, a counted compile) and enqueue the
+                program of `cap` (None: the count alone); call under the
+                enqueue lock. -> (its output tree, jit cache hit)"""
+                consts_dev, seg_arg = self._args_for(plan, seg_mask, None)
+                key = base_key + (cap,) \
+                    + ((win[1],) if win else ()) \
+                    + (("top",) if top else ()) \
+                    + (("having", kept) if kept else ())
+                jitted = self._jit_cache.get(key)
+                hit = jitted is not None
+                if hit:
+                    _cache_lru_hit(self._jit_cache, key)
+                else:
+                    kern = plan.make_sparse_kernel(cap, top, kept)
+                    if win is not None:
+                        jitted = jax.jit(
+                            self._window_kernel(kern, win[1]))
+                    else:
+                        jitted = jax.jit(kern)
+                    self._jit_cache[key] = jitted
+                    self._note_compile("sparse", metrics)
+                out = jitted(env, valid, seg_arg, consts_dev,
+                             win[0]) if win is not None else \
+                    jitted(env, valid, seg_arg, consts_dev)
+                return out, hit
+
             try:
+                if hint is None and not whole_space:
+                    # nothing says how many groups are present: count
+                    # them before any table is sized (a run of a sort,
+                    # so an attempt)
+                    attempts += 1
+                    with _span("sparse-count") as sp:
+                        with self._enqueue_lock(metrics):
+                            out, hit = run(None)
+                        count = int(out["_count"])
+                        sp.set(present_groups=count, jit_cache_hit=hit)
+                    if count > cap_limit:
+                        raise over_budget(count)
+                    cap = _grown_cap(count, cap_limit)
+                kept = kept_bucket(cap) if having else None
                 while True:
                     attempts += 1
                     with _span("sparse-attempt", cap=cap) as sp:
                         with self._enqueue_lock(metrics):
-                            consts_dev, seg_arg = self._args_for(
-                                plan, seg_mask, None)
-                            key = base_key + (cap,) \
-                                + ((win[1],) if win else ()) \
-                                + (("top",) if top else ())
-                            jitted = self._jit_cache.get(key)
-                            hit = jitted is not None
-                            if hit:
-                                _cache_lru_hit(self._jit_cache, key)
-                            else:
-                                kern = plan.make_sparse_kernel(cap, top)
-                                if win is not None:
-                                    jitted = jax.jit(
-                                        self._window_kernel(kern, win[1]))
-                                else:
-                                    jitted = jax.jit(kern)
-                                self._jit_cache[key] = jitted
-                                self._note_compile("sparse", metrics)
-                            out = jitted(env, valid, seg_arg, consts_dev,
-                                         win[0]) if win is not None else \
-                                jitted(env, valid, seg_arg, consts_dev)
+                            out, hit = run(cap, kept)
                             prev, pin = pin, self._pin_inflight(out)
                         if prev is not None:
                             self._hbm_ledger.unpin_inflight(prev)
@@ -2036,13 +2083,23 @@ class QueryRunner:
                         with _span("count-probe"):
                             count = int(out["_count"])
                         sp.set(present_groups=count, jit_cache_hit=hit)
-                    if count <= cap:
-                        break
-                    if count > cap_limit:
-                        raise UnsupportedAggregation(
-                            f"{count} present groups exceed sparse "
-                            f"budget {cap_limit}")
-                    cap = _grown_cap(count, cap_limit)
+                    if count > cap:
+                        if count > cap_limit:
+                            raise over_budget(count)
+                        cap = _grown_cap(count, cap_limit)
+                        kept = kept_bucket(cap) if having else None
+                        continue
+                    if having:
+                        with _span("having", where="device",
+                                   groups_in=count) as sp:
+                            n_kept = int(out["_kept"])
+                            sp.set(groups_out=n_kept)
+                        self._cap_hints[kept_key] = max(
+                            n_kept, self._cap_hints.get(kept_key, 0))
+                        if n_kept > kept:
+                            kept = kept_bucket(cap)
+                            continue
+                    break
                 with _span("host-transfer", cap=cap):
                     out = self._fetch_tree(out, metrics, pin)
                 pin = None  # consumed (fetch unpins)
@@ -2253,12 +2310,13 @@ class QueryRunner:
         metrics["execute_ms"] = (time.perf_counter() - t0) * 1000
         metrics["jit_cache_hit"] = hit
         self._note_sparse(metrics, plan, stored, nullable, attempts, cap,
-                          count, top)
+                          count, top, plan.having[1] if having else None)
         return out, count
 
     @staticmethod
     def _note_sparse(metrics: dict, plan, stored: dict, nullable,
-                     attempts: int, cap: int, count: int, top=None):
+                     attempts: int, cap: int, count: int, top=None,
+                     having=None):
         """The sparse dispatch's counters on the record: how many cap
         attempts ran (1 once the template's hint is warm), the compact
         table's final cap, and the groups present in it; and which
@@ -2266,11 +2324,11 @@ class QueryRunner:
         is read at the sorted runs' boundaries or an aggregate still
         scatters, the width of the word a min / max is read from, and
         how many [cap] tables it gathers or segment-reduces (every table,
-        or with `top` the ranked one and what still segment-reduces):
-        the kernel's own functions of the plan's aggregate kinds and
-        dtypes, the columns' stored dtypes (`nullable`: those with a null
-        mask), the cap and `top`, as the dense `reduce_form` is of
-        num_groups."""
+        or with `top` the ranked one, with `having` the tested ones, and
+        what still segment-reduces): the kernel's own functions of the
+        plan's aggregate kinds and dtypes, the columns' stored dtypes
+        (`nullable`: those with a null mask), the cap and the cut, as the
+        dense `reduce_form` is of num_groups."""
         from tpu_olap.kernels import sparse_groupby as sg
         metrics["reduce_form"] = sg.sparse_reduce_form(plan.agg_plans,
                                                        stored, cap)
@@ -2278,7 +2336,7 @@ class QueryRunner:
         if bits is not None:
             metrics["ext_word_bits"] = bits
         metrics["cap_tables"] = sg.cap_tables(plan.agg_plans, stored, cap,
-                                              top, nullable)
+                                              top, nullable, having)
         metrics["sparse"] = True
         metrics["sparse_attempts"] = attempts
         metrics["sparse_cap"] = metrics["result_cap"] = cap
@@ -2319,12 +2377,15 @@ class QueryRunner:
         topn = isinstance(query, TopNQuerySpec)
         if topn:
             metrics["topn_group_space"] = plan.total_groups
+        having = self._device_having(plan)
+        if getattr(query, "having", None) is not None:
+            metrics["having_where"] = "device" if having else "host"
         if plan.sparse:
             from tpu_olap.kernels.sparse_groupby import SENTINEL
             top = self._device_threshold(query, plan) if topn else None
             out, count = self._dispatch(
-                lambda: self._run_sparse(plan, metrics, top), metrics,
-                table.name)
+                lambda: self._run_sparse(plan, metrics, top, having),
+                metrics, table.name)
             t0 = time.perf_counter()
             with self.stages.stage("finalize", metrics):
                 with _span("finalize"):
@@ -2346,13 +2407,20 @@ class QueryRunner:
                     res = self._emit_topn(query, plan, present, sub,
                                           "device" if top else "host")
                 else:
-                    res = self._emit_groupby(query, plan, present, sub)
+                    if "having_where" in metrics:
+                        metrics["having_rows_fetched"] = len(keys)
+                    if having:
+                        metrics["having_groups_in"] = count
+                    res = self._emit_groupby(query, plan, present, sub,
+                                             decided=having)
             res.metrics = metrics
             metrics["assemble_ms"] = (time.perf_counter() - t0) * 1000
             return res
         if topn:
             # the dense paths hand the host the whole [K] space to rank
             metrics["topn_rows_fetched"] = plan.total_groups
+        elif "having_where" in metrics:
+            metrics["having_rows_fetched"] = plan.total_groups
 
         if self.result_cache.seg_enabled:
             arrays = self._run_agg_segcached(query, plan, metrics, specs,
@@ -2708,22 +2776,29 @@ class QueryRunner:
             sp.set(groups=len(present))
             return (present, sub) + self._decode_groups(plan, present)
 
-    def _emit_groupby(self, query, plan, present, sub) -> QueryResult:
+    def _emit_groupby(self, query, plan, present, sub,
+                      decided=False) -> QueryResult:
         """present: flat group ids (any int width); sub: compact per-group
         final values (present None: the dense [K] tables). Shared tail of
-        the dense and sparse paths."""
+        the dense and sparse paths. `decided`: the groups are those the
+        device's HAVING let through (`_device_having`), so none is tested
+        here; they are decoded, ordered and limited as any."""
         names = self._out_names(query)
         present, sub, buckets, dim_ids = self._decode_present(
             query, plan, present, sub)
         labels = {dp.name: dp.labels for dp in plan.dim_plans}
 
-        if query.having is not None:
-            hmask = eval_having(
-                query.having, sub,
-                {d: labels[d][ids] for d, ids in dim_ids.items()})
-            buckets = buckets[hmask]
-            dim_ids = {k: v[hmask] for k, v in dim_ids.items()}
-            sub = {k: v[hmask] for k, v in sub.items()}
+        if query.having is not None and not decided:
+            with _span("having", where="host",
+                       groups_in=len(present)) as sp:
+                hmask = eval_having(
+                    query.having, sub,
+                    {d: labels[d][ids] for d, ids in dim_ids.items()})
+                buckets = buckets[hmask]
+                dim_ids = {k: v[hmask] for k, v in dim_ids.items()}
+                sub = {k: v[hmask] for k, v in sub.items()}
+                sp.set(groups_out=len(buckets))
+            self._last_metrics["having_groups_in"] = len(present)
 
         order = np.arange(len(buckets))
         ls = query.limit_spec
@@ -2769,6 +2844,29 @@ class QueryRunner:
                     and np.issubdtype(np.dtype(p.acc_dtype), np.integer):
                 return (query.metric, query.threshold, query.inverted)
         return None
+
+    def _device_having(self, plan) -> bool:
+        """Whether one chip's sparse program decides the GroupBy's HAVING
+        itself (the host then fetches the rows that passed, not the
+        table): the plan took the sparse path, there is no mesh (a chip's
+        partial sum decides nothing, and the broker merges whole tables)
+        and lowering found the predicate decidable from integer tables
+        alone (`sparse_groupby.compile_having`; `_device_threshold`'s
+        rule, with a comparison in the rank's place)."""
+        return self.mesh is None and plan.having is not None
+
+    def having_where(self, query, table) -> str | None:
+        """`device` | `host`: who would decide the GroupBy's HAVING, as
+        its record's `having_where` says after a run (EXPLAIN's line);
+        None where the query has no device plan at all (it fails, or the
+        fallback answers it whole)."""
+        from tpu_olap.kernels.filtereval import UnsupportedFilter
+        from tpu_olap.kernels.groupby import UnsupportedAggregation
+        try:
+            plan = self._lower_cached_inner(query, table)
+        except (UnsupportedAggregation, UnsupportedFilter):
+            return None
+        return "device" if self._device_having(plan) else "host"
 
     def _assemble_topn(self, query, plan, arrays) -> QueryResult:
         return self._emit_topn(query, plan, None, arrays, "host")
@@ -3060,15 +3158,22 @@ def _note_form(metrics: dict, plan, num_groups: int):
 def _form_attr(metrics: dict) -> dict:
     """The `dispatch` span's `reduce_form` attribute, where the record of
     the query has one (a generic grouped aggregate on the device), and
-    beside it a sparse min / max's `ext_word_bits` and the sparse
-    program's `cap_tables`."""
+    beside it a sparse min / max's `ext_word_bits`, the sparse program's
+    `cap_tables` and who decides a GroupBy's HAVING (`having_where`)."""
     return {k: metrics[k]
-            for k in ("reduce_form", "ext_word_bits", "cap_tables")
+            for k in ("reduce_form", "ext_word_bits", "cap_tables",
+                      "having_where")
             if metrics.get(k) is not None}
 
 
 def _next_pow2(n: int) -> int:
     return 1 << max(0, int(n) - 1).bit_length() if n > 1 else 1
+
+
+# rows of the smallest bucket a device HAVING compacts the passing groups
+# into (and the host fetches a table): a report's HAVING keeps hundreds of
+# groups out of millions, and each bucket size is a compile of the sort
+HAVING_KEPT_MIN = 1024
 
 
 def _grown_cap(count: int, limit: int) -> int:

@@ -81,6 +81,8 @@ STAGES = (
     "gather",     # sparse: the [cap] tables read at the runs' boundaries
     "segment",    # sparse: what still segment-reduces the sorted rows
     "threshold",  # a TopN's top-k of the compact table, on the device
+    "having",     # sparse: a HAVING's predicate over the tested [cap]
+    #               tables and the compaction of the slots that pass
     "merge",      # mesh: all_gather of the chips' tables and their merge
 )
 

@@ -36,8 +36,12 @@ XLA's sort is fast on TPU and everything stays static-shaped:
      `threshold` slots it keeps, and every other table is read at those
      slots alone, from the same sorted operands, prefix sums and running
      words at the kept runs' two ends (what segment-reduces builds its
-     [cap] table and is indexed); `cap_tables` counts the [cap] tables
-     either program gathers or segment-reduces;
+     [cap] table and is indexed). Where a HAVING the device can decide
+     ends the program (`having`), the same cut with a predicate in the
+     rank's place: only the tested aggregates' tables are built at [cap],
+     the passing slots are compacted in slot order into a `kept` bucket
+     and every other table is read there; `cap_tables` counts the [cap]
+     tables each program gathers or segment-reduces;
   5. "_count" reports the true unique count — if it exceeds cap the
      runner re-runs with the next power of two (same adaptive-cap pattern
      as executor.packing).
@@ -278,7 +282,8 @@ def _narrow_int(col_dtype, acc_dtype) -> bool:
         and np.can_cast(col_dtype, np.int32)
 
 
-def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None):
+def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
+                        having=None):
     """[N] int64 keys + mask -> compacted per-group partials.
 
     Returns {"_keys": [cap] int64 (SENTINEL marks empty slots),
@@ -295,6 +300,16 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None):
     the table's own (the cap-overflow probe reads it); a rank past the
     present groups holds the SENTINEL key and the identities of the empty
     slot it points at.
+
+    With `having` = (test, names, kept) from `compile_having`, the groups
+    a HAVING lets through, as [kept] tables in ascending slot (= key)
+    order: the predicate in the rank's place. Of the [cap] tables only
+    those of the aggregates `names` that `test` reads are built (and
+    `_rows`); `_kept` counts the present slots that pass, which are
+    compacted into the first `_kept` of `kept` rows (the runner re-runs
+    with a larger bucket where they do not fit, as it does for `cap`),
+    and every other table is read at those slots. A row past `_kept`
+    holds the SENTINEL key and no rows.
     """
     import jax
 
@@ -414,7 +429,32 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None):
     with stage_scope("gather", xp):
         rows = starts[1:] - starts[:-1]
 
-    at = ranked = whole = None
+    def extreme(p, rows_at, at=None):
+        """A min's or a max's table at the slots `at` and its non-null
+        count there (`rows_at`, the slots' row counts, where the
+        aggregator leaves no row out)."""
+        counted = sorted_ops[slots[f"nn:{p.name}"]] \
+            if f"nn:{p.name}" in slots else None
+        nn = rows_at if counted is None else run_count(counted, at)
+        if p.name in words:
+            operand, word, col_dtype = words[p.name]
+            v = _run_ext(sorted_ops[slots[operand]], counted, gid,
+                         starts, p.kind, col_dtype, word, at)
+        else:
+            v = kept(segment(
+                jax.ops.segment_min if p.kind == "min" else
+                jax.ops.segment_max, sorted_ops[slots[f"v:{p.name}"]]),
+                at)
+        # an empty slot holds the accumulator's identity, whatever
+        # width the rows were reduced at
+        with stage_scope("gather", xp):
+            return xp.where(nn > 0, v.astype(p.acc_dtype),
+                            _ident(p.acc_dtype, p.kind)), nn
+
+    # the [cap] tables a cut is decided from, built before it:
+    # name -> (table, non-null count or None)
+    at = live = None
+    whole = {}
     if top is not None:
         from tpu_olap.kernels.topk import top_k_groups
         metric, threshold, inverted = top
@@ -422,9 +462,26 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None):
         if ranked.kind not in ("count", "sum"):
             raise UnsupportedAggregation(
                 f"no device threshold by a {ranked.kind!r}")
-        whole = table(ranked)
+        whole[metric] = (table(ranked), None)
         with stage_scope("threshold", xp):
-            at, _ = top_k_groups(whole, rows > 0, threshold, inverted)
+            at, _ = top_k_groups(whole[metric][0], rows > 0, threshold,
+                                 inverted)
+    elif having is not None:
+        test, names, n_keep = having
+        for p in plans:
+            if p.name in names:
+                whole[p.name] = (table(p), None) \
+                    if p.kind in ("count", "sum") else extreme(p, rows)
+        with stage_scope("having", xp):
+            passing = (rows > 0) & test(whole, consts)
+            n_kept = passing.sum(dtype=xp.int32)
+            # the passing slots first, in slot order: a one-operand sort,
+            # as `starts` is (no scatter, and a gather a kept row)
+            slot = jax.lax.sort(
+                xp.where(passing, xp.arange(cap, dtype=xp.int32), cap),
+                is_stable=False)[:n_keep]
+            live = slot < cap
+            at = xp.minimum(slot, cap - 1)
 
     # The key of slot g is its first row's; past the present groups that
     # row is in the SENTINEL tail, or out of bounds
@@ -432,32 +489,26 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None):
         out = {"_count": count, "_rows": kept(rows, at),
                "_keys": skey.at[starts[:cap] if at is None else starts[at]]
                .get(mode="fill", fill_value=SENTINEL)}
+        if live is not None:
+            out["_kept"] = n_kept
+            out["_rows"] = xp.where(live, out["_rows"], 0)
+            out["_keys"] = xp.where(live, out["_keys"], SENTINEL)
 
     for p in plans:
+        if p.name in whole:
+            # a table the cut was decided from is built once: its kept
+            # rows are its own
+            v, nn = whole[p.name]
+            out[p.name] = kept(v, at).astype(p.acc_dtype)
+            if p.kind in ("min", "max"):
+                out[f"_nn_{p.name}"] = out["_rows"] if nn is rows \
+                    else kept(nn, at)
+            continue
         if p.kind in ("count", "sum"):
-            # the ranked table is built once: its kept rows are its own
-            out[p.name] = (kept(whole, at) if p is ranked else
-                           table(p, at)).astype(p.acc_dtype)
+            out[p.name] = table(p, at).astype(p.acc_dtype)
             continue
         if p.kind in ("min", "max"):
-            counted = sorted_ops[slots[f"nn:{p.name}"]] \
-                if f"nn:{p.name}" in slots else None
-            nn = out["_rows"] if counted is None else run_count(counted, at)
-            if p.name in words:
-                operand, word, col_dtype = words[p.name]
-                v = _run_ext(sorted_ops[slots[operand]], counted, gid,
-                             starts, p.kind, col_dtype, word, at)
-            else:
-                v = kept(segment(
-                    jax.ops.segment_min if p.kind == "min" else
-                    jax.ops.segment_max, sorted_ops[slots[f"v:{p.name}"]]),
-                    at)
-            # an empty slot holds the accumulator's identity, whatever
-            # width the rows were reduced at
-            with stage_scope("gather", xp):
-                out[p.name] = xp.where(nn > 0, v.astype(p.acc_dtype),
-                                       _ident(p.acc_dtype, p.kind))
-            out[f"_nn_{p.name}"] = nn
+            out[p.name], out[f"_nn_{p.name}"] = extreme(p, out["_rows"], at)
             continue
         if p.kind == "hll":
             h = sorted_ops[slots[f"h:{p.name}"]]
@@ -481,17 +532,19 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None):
     return out
 
 
-def cap_tables(plans, col_dtypes, cap, top=None, nullable=()) -> int:
+def cap_tables(plans, col_dtypes, cap, top=None, nullable=(),
+               having=None) -> int:
     """How many [cap]-sized tables `sparse_group_reduce`'s program gathers
     or segment-reduces, from static facts alone (`sparse_reduce_form`'s,
     and `nullable`: the fields that carry a null mask). `_rows`, a
     difference of `starts`, costs no gather and is not counted, nor is
     what reads it (an unfiltered count, the non-null count of a min / max
-    that leaves no row out). Without `top` every table: `_keys` and one an
+    that leaves no row out). Without a cut every table: `_keys` and one an
     aggregate, two a min / max with a non-null count of its own. With
-    `top` the ranked metric's alone, beside what still segment-reduces
-    (a float sum, a 64-bit min / max, a sketch): the others are read at
-    the `threshold` kept rows."""
+    `top` the ranked metric's alone, with `having` (the names its
+    predicate reads) the tested aggregates', beside what still
+    segment-reduces (a float sum, a 64-bit min / max, a sketch): the
+    others are read at the kept rows."""
     def gathered(p):
         # not read off `_rows`
         return p.kind != "count" or p.filter_fn is not None
@@ -500,12 +553,94 @@ def cap_tables(plans, col_dtypes, cap, top=None, nullable=()) -> int:
         return not prefix_summed(p) \
             and _ext_word(p, col_dtypes, cap) is None
 
-    if top is not None:
-        return sum(1 for p in plans if segment_reduced(p)
-                   or (p.name == top[0] and gathered(p)))
-    own_count = sum(1 for p in plans if p.kind in ("min", "max") and (
-        p.filter_fn is not None or p.fields[0] in nullable))
-    return 1 + sum(1 for p in plans if gathered(p)) + own_count
+    def own_count(p):
+        return p.kind in ("min", "max") and (
+            p.filter_fn is not None or p.fields[0] in nullable)
+
+    if top is not None or having is not None:
+        decides = {top[0]} if top is not None else set(having)
+        return sum(1 + own_count(p) if p.name in decides and gathered(p)
+                   else int(segment_reduced(p)) for p in plans)
+    return 1 + sum(gathered(p) + own_count(p) for p in plans)
+
+
+def sparse_group_count(key, mask, xp):
+    """{"_count": [] int32}: the groups present among the unmasked rows,
+    and nothing else: a one-operand sort of the key and its run
+    boundaries, no table. What the runner asks before it sizes the first
+    compact table of a group space past the budget, whose count it has no
+    hint of: a cap attempt that overflows compiles the whole multi-operand
+    sort program only to learn this number."""
+    import jax
+
+    with stage_scope("sort", xp):
+        skey = jax.lax.sort(xp.where(mask, key, SENTINEL), is_stable=False)
+    with stage_scope("runs", xp):
+        first = xp.concatenate([xp.ones((1,), bool), skey[1:] != skey[:-1]])
+        return {"_count": (first & (skey != SENTINEL)).sum(dtype=xp.int32)}
+
+
+def compile_having(spec, plans, pool):
+    """(test, names) where the device can decide the HAVING `spec` of a
+    sparse group-by, else None (the host decides it over the fetched
+    table, `results.eval_having`). It can where `spec` is built of
+    greaterThan / lessThan / equalTo under and / or / not alone, every
+    literal is a whole number an int64 holds, and every aggregate tested
+    is one whose table column IS its final value, held as an integer: a
+    count, a long sum, an integer min / max. A post-aggregation, a
+    sketch's estimate, a float (its NaN) and a dimension's value are made
+    on the host, and one of them anywhere keeps the whole HAVING there.
+    `names` are the aggregates read; `test(tables, consts)` takes
+    {name: ([cap] table, [cap] non-null count or None)} and gives the
+    [cap] mask as the host would: a min / max over no row is null there,
+    and a comparison with null is false. The literals ride the ConstPool
+    (`pool`), so one program serves every literal."""
+    import functools
+    import operator
+
+    from tpu_olap.ir import having as H
+
+    by_name = {p.name: p for p in plans}
+    names = set()
+    compare = {H.GreaterThanHaving: operator.gt,
+               H.LessThanHaving: operator.lt, H.EqualToHaving: operator.eq}
+
+    def decidable(h) -> bool:
+        if type(h) in compare:
+            p, v = by_name.get(h.aggregation), h.value
+            return p is not None and (prefix_summed(p) or (
+                p.kind in ("min", "max")
+                and np.dtype(p.acc_dtype).kind == "i")) \
+                and not isinstance(v, bool) \
+                and isinstance(v, (int, float)) \
+                and abs(v) < (1 << 62) and v == int(v)
+        if isinstance(h, (H.AndHaving, H.OrHaving)):
+            return bool(h.having_specs) and all(
+                decidable(x) for x in h.having_specs)
+        return isinstance(h, H.NotHaving) and decidable(h.having_spec)
+
+    def build(h):
+        if type(h) in compare:
+            op, name = compare[type(h)], h.aggregation
+            c = pool.add(int(h.value), np.int64)
+            names.add(name)
+
+            def leaf(tables, consts):
+                t, nn = tables[name]
+                m = op(t.astype(np.int64), consts[c])
+                return m if nn is None else m & (nn > 0)
+            return leaf
+        if isinstance(h, H.NotHaving):
+            inner = build(h.having_spec)
+            return lambda tables, consts: ~inner(tables, consts)
+        parts = [build(x) for x in h.having_specs]
+        join = operator.and_ if isinstance(h, H.AndHaving) else operator.or_
+        return lambda tables, consts: functools.reduce(
+            join, (f(tables, consts) for f in parts))
+
+    if not decidable(spec):
+        return None
+    return build(spec), frozenset(names)
 
 
 def merges_on_device(plans) -> bool:
