@@ -134,6 +134,10 @@ def test_wide_topn_on_the_sparse_path_equals_the_reference(eng_sparse, data,
     # the program ranks first: of its [cap] tables it gathers the ranked
     # sum's alone and reads the others at the 100 kept rows (PR 39)
     assert rec["cap_tables"] == 1
+    # l_quantity (int8) and l_extendedprice (int32) are summed as one
+    # int32 word each: ~30 rows a part times 10,495,000 cents fit (PR 41)
+    assert rec["sum_word_bits"] == 32 and "narrow_fallback" not in rec
+    assert rec["sparse_attempts"] == 1
     assert rec["topn_rows_fetched"] == 100 < rec["sparse_cap"]
     assert rec["topn_group_space"] == PARTS + 1
     assert rec["present_groups"] == PARTS

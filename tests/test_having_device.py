@@ -70,6 +70,9 @@ def test_q18_equals_the_plain_reference_and_pandas(q18, template):
     assert rec["reduce_path"] == "sparse" and rec["having_where"] == "device"
     assert rec["reduce_form"] == "boundary" and rec["ext_word_bits"] == 64
     assert rec["cap_tables"] == 1
+    # the tested sum(l_quantity), stored as int8, rides as one int32 word:
+    # at most seven lines of fifty an order (PR 41)
+    assert rec["sum_word_bits"] == 32 and "narrow_fallback" not in rec
     assert rec["having_groups_in"] == reference["n_orders"] \
         == rec["present_groups"]
     assert rec["having_rows_fetched"] == runner_mod.HAVING_KEPT_MIN

@@ -915,6 +915,401 @@ def test_record_and_dispatch_span_count_the_cap_tables(sql, form, tables):
             == (tables == 1)
 
 
+# --------------------------------------------------------------------------
+# An integer sum of a column stored in 32 bits or fewer rides the sort, its
+# prefix sum and its boundary gather as ONE int32 word where the device shows
+# that every group's sum fits one (PR 41): table for table equal to the wide
+# program, which stays as the reference and as what a wider dataset runs.
+
+def _over_ten(tables, consts):
+    return tables["s"][0].astype(np.int64) > 10
+
+
+def _narrow_cases():
+    """(id, key, mask, env, plans, cap, top, having)"""
+    rng = np.random.default_rng(41)
+    n = 1531
+    key = rng.integers(0, 120, n).astype(np.int64)
+    key[key == 3] = 2          # a group no row has: the slots shift
+    mask = rng.random(n) < 0.85
+    f = rng.integers(-3, 4, n)
+    wide = rng.integers(-(1 << 40), 1 << 40, n)
+
+    def column(stored):
+        lim = min(int(np.iinfo(stored).max), 1 << 24)
+        x = rng.integers(-lim, lim + 1, n).astype(stored)
+        x[:2] = [-lim, lim]
+        return x
+
+    def env(nulls=None, **cols):
+        return {"cols": cols, "nulls": nulls or {}}
+
+    s, count = _agg("s", "sum", "x"), _agg("n", "count")
+    cuts = {"uncut": (None, None), "top": (("s", 10, False), None),
+            "having": (None, (_over_ten, frozenset({"s"}), 64))}
+    for stored in ("int8", "int16", "int32"):
+        for cut, (top, having) in cuts.items():
+            yield f"{stored}-{cut}", key, mask, env(x=column(stored)), \
+                [s, count], 128, top, having
+    x = column("int16")
+    yield "every-value-negative", key, mask, env(x=-np.abs(x) - 1), \
+        [s, count], 128, None, None
+    yield "top-inverted", key, mask, env(x=x), [s, count], 128, \
+        ("s", 10, True), None
+    filtered = [_agg("s", "sum", "x", filter_fn=_positive), count,
+                _agg("all", "sum", "x")]
+    for cut, (top, having) in cuts.items():
+        yield f"filtered-sum-{cut}", key, mask, env(x=x, f=f), filtered, \
+            128, top, having
+    for cut, (top, having) in cuts.items():
+        yield f"nullable-column-{cut}", key, mask, \
+            env({"x": rng.random(n) < 0.4}, x=x), [s, count], 128, top, \
+            having
+    # beside sums that stay wide (a column stored in 64 bits, as a virtual
+    # column is; a double), a min / max of the same column, a second
+    # narrow sum that is not ranked
+    mixed = [s, _agg("sw", "sum", "v"), _agg("fs", "sum", "w", np.float64),
+             _agg("lo", "min", "x"), _agg("hi", "max", "y"),
+             _agg("sy", "sum", "y"), count]
+    w = np.round(rng.random(n) * 50 - 25, 4)
+    for cut, (top, having) in cuts.items():
+        yield f"beside-wide-sums-and-min-max-{cut}", key, mask, \
+            env(x=x, y=column("int8"), v=wide, w=w), mixed, 128, top, having
+    yield "cap-overflows", key, mask, env(x=x), [s, count], 16, None, None
+    yield "every-row-masked", key, np.zeros(n, bool), env(x=x), \
+        [s, count], 16, ("s", 10, False), None
+
+
+@pytest.mark.parametrize("case", list(_narrow_cases()),
+                         ids=[c[0] for c in _narrow_cases()])
+def test_narrow_sums_equal_the_wide_programs_tables(case):
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_olap.kernels.sparse_groupby import sparse_group_reduce
+    EngineConfig().apply_x64()
+    _, key, mask, env, plans, cap, top, having = case
+
+    @jax.jit
+    def run(key, mask, env):
+        return [sparse_group_reduce(key, mask, env, plans, cap, {}, jnp,
+                                    top, having, narrow)
+                for narrow in (True, False)]
+
+    got, want = jax.device_get(run(key, mask, env))
+    assert "_narrow_ok" not in want
+    # at most 2^24 a row over a few dozen rows a group: the sums fit
+    ok = got.pop("_narrow_ok")
+    assert ok.dtype == bool and ok.shape == ()
+    assert ok or want["_count"] > cap
+    assert set(got) == set(want)
+    for name, table in want.items():
+        assert got[name].dtype == table.dtype, name
+        if table.dtype.kind == "f":
+            np.testing.assert_allclose(got[name], table, rtol=1e-9,
+                                       atol=1e-6, err_msg=name)
+        else:
+            np.testing.assert_array_equal(got[name], table, err_msg=name)
+    if top is None and having is None and want["_count"] <= cap:
+        ref = _numpy_tables(key, mask, env, plans, cap)
+        for p in plans:
+            if p.kind == "sum" and np.dtype(p.acc_dtype).kind == "i":
+                assert got[p.name].dtype == np.int64
+                np.testing.assert_array_equal(got[p.name], ref[p.name])
+
+
+I32 = np.iinfo(np.int32)
+
+
+@pytest.mark.parametrize("values,rows_a_group,ok", [
+    ([I32.max, 5, -7, 0], 1, True),          # a sum of exactly 2^31 - 1
+    ([-I32.max, 5, -7, 0], 1, True),         # and of -2^31 + 1
+    ([I32.min, 5, -7, 0], 1, False),         # |v| = 2^31: one past
+    ([1 << 30, 1 << 30, 3, 4], 2, False),    # a group sums to 2^31
+    ([-(1 << 30), -(1 << 30), -1, 4], 2, False),
+    ([(1 << 30) - 1, (1 << 30) - 1, -3, 4], 2, True),   # 2^31 - 2
+    ([1 << 29] * 6, 3, True),                # 3 * 2^29 < 2^31
+    ([1 << 29] * 8, 4, False),               # 4 * 2^29 = 2^31
+    ([1 << 6] * 8, 4, True),
+], ids=["max", "minus-max", "min", "two-rows-2^31", "two-rows-minus-2^31",
+        "two-rows-under", "three-rows-under", "four-rows-2^31", "small"])
+def test_narrow_ok_holds_exactly_where_rows_times_the_largest_value_fit(
+        values, rows_a_group, ok):
+    """`_narrow_ok`: the longest run times the largest |value| is at most
+    2^31 - 1. Where it says so the narrow tables are numpy's int64 sums;
+    where it does not the wide program's are (and the narrow one's may
+    have wrapped)."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_olap.kernels.sparse_groupby import sparse_group_reduce
+    EngineConfig().apply_x64()
+    x = np.asarray(values, np.int64).astype(np.int32)
+    # a masked row's value is not the column's: it rides as 0
+    x = np.r_[x, I32.min].astype(np.int32)
+    key = np.r_[np.arange(len(values)) // rows_a_group, 0].astype(np.int64)
+    mask = np.r_[np.ones(len(values), bool), False]
+    env = {"cols": {"x": x}, "nulls": {}}
+    plans = [_agg("s", "sum", "x")]
+    got = {narrow: jax.device_get(jax.jit(
+        lambda k, m, e: sparse_group_reduce(k, m, e, plans, 8, {}, jnp,
+                                            None, None, narrow))(
+        key, mask, env)) for narrow in (True, False)}
+    assert bool(got[True]["_narrow_ok"]) == ok
+    want = _numpy_tables(key, mask, env, plans, 8)["s"]
+    np.testing.assert_array_equal(got[False]["s"], want)
+    if ok:
+        np.testing.assert_array_equal(got[True]["s"], want)
+
+
+def _sort_operand_dtypes(fn, *args):
+    """The operand dtypes of the widest sort anywhere in fn's jaxpr."""
+    import jax
+
+    def sorts(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "sort":
+                yield [str(v.aval.dtype) for v in eqn.invars]
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from sorts(sub)
+    return max(sorts(jax.make_jaxpr(fn)(*args).jaxpr), key=len)
+
+
+def _narrow_env(n=4096):
+    import jax.numpy as jnp
+    return {"cols": {"q": (jnp.arange(n) % 50).astype(jnp.int8),
+                     "p": jnp.arange(n, dtype=jnp.int32),
+                     "v": jnp.arange(n, dtype=jnp.int64),
+                     "w": jnp.arange(n, dtype=jnp.float64)},
+            "nulls": {}}, jnp.arange(n, dtype=jnp.int64) % 50, \
+        jnp.ones(n, bool)
+
+
+def _lowered_text(plans, narrow, top=None, having=None):
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_olap.kernels.sparse_groupby import sparse_group_reduce
+    EngineConfig().apply_x64()
+    env, key, mask = _narrow_env()
+    return jax.jit(lambda k, m, e: sparse_group_reduce(
+        k, m, e, plans, 64, {}, jnp, top, having, narrow)).lower(
+        key, mask, env).as_text()
+
+
+@pytest.mark.parametrize("plans", [
+    [("s", "sum", "v"), ("n", "count")],
+    [("fs", "sum", "w", np.float64), ("n", "count")],
+    [("lo", "min", "q"), ("hi", "max", "p"), ("n", "count")],
+    [("n", "count")],
+], ids=["int64-stored-sum", "float-sum", "min-max-alone", "count-alone"])
+@pytest.mark.parametrize("cut", ["uncut", "top", "having"])
+def test_a_plan_with_no_narrow_sum_lowers_to_the_same_text_either_way(
+        plans, cut):
+    """Asked for the narrow program, a plan none of whose sums is of a
+    column stored in 32 bits or fewer (a virtual column is materialised
+    in int64) gives the wide program's StableHLO byte for byte: no flag,
+    no other operand."""
+    from tpu_olap.kernels.sparse_groupby import narrow_sums, sum_word_bits
+    plans = [_agg(*a) for a in plans]
+    ranked = plans[0].name if plans[0].kind in ("sum", "count") \
+        and np.dtype(plans[0].acc_dtype).kind == "i" else "n"
+    top = (ranked, 10, False) if cut == "top" else None
+    having = (lambda t, c: t["n"][0] > 3, frozenset({"n"}), 16) \
+        if cut == "having" else None
+    wide = _lowered_text(plans, False, top, having)
+    assert _lowered_text(plans, True, top, having) == wide
+    stored = {c: a.dtype for c, a in _narrow_env()[0]["cols"].items()}
+    assert not narrow_sums(plans, stored)
+    assert sum_word_bits(plans, stored, True) \
+        == sum_word_bits(plans, stored, False) \
+        == (64 if plans[0].name == "s" else None)
+
+
+def test_a_narrow_sum_rides_the_sort_as_one_int32_operand():
+    """`sum(q)` (int8) and `sum(p)` (int32) beside a sum of an int64
+    column: asked for, the first two ride as int32 and their prefixes
+    are read by ONE gather a boundary each; the third, and everything
+    where it is not asked for, at the accumulator's 64 bits."""
+    import jax.numpy as jnp
+
+    from tpu_olap.kernels.sparse_groupby import (narrow_sums,
+                                                 sparse_group_reduce,
+                                                 sum_word_bits)
+    EngineConfig().apply_x64()
+    plans = [_agg("sq", "sum", "q"), _agg("sp", "sum", "p"),
+             _agg("sv", "sum", "v")]
+    env, key, mask = _narrow_env()
+    stored = {c: a.dtype for c, a in env["cols"].items()}
+
+    def program(narrow):
+        return lambda k, m, e: sparse_group_reduce(
+            k, m, e, plans, 64, {}, jnp, None, None, narrow)
+
+    assert _sort_operand_dtypes(program(True), key, mask, env) \
+        == ["int64", "int32", "int32", "int64"]
+    assert _sort_operand_dtypes(program(False), key, mask, env) \
+        == ["int64"] * 4
+    assert narrow_sums(plans, stored) and narrow_sums(plans[:1], stored)
+    assert not narrow_sums(plans[2:], stored)
+    # the widest word a sum rides at
+    assert sum_word_bits(plans, stored, True) == 64
+    assert sum_word_bits(plans[:2], stored, True) == 32
+    assert sum_word_bits(plans[:2], stored, False) == 64
+    # an int32 accumulator (x64 off) has nothing to narrow
+    assert not narrow_sums([_agg("sq", "sum", "q", np.int32)], stored)
+    text = _lowered_text(plans[:2], True)
+    assert "xi64>" in text      # the key, and the tables widened a slot
+    assert text != _lowered_text(plans[:2], False)
+
+
+def _narrow_engine(df, **cfg):
+    eng = Engine(EngineConfig(dense_group_budget=64,
+                              fallback_on_device_failure=False, **cfg))
+    eng.register_table("t", df, time_column="ts", block_rows=512)
+    return eng
+
+
+def _narrow_df(p_top, n=3000, groups=400, seed=41):
+    """`q` as TPC-H's quantity (1-50, stored as int8), `p` a price stored
+    as int32 whose largest value is `p_top`, `k` the group."""
+    rng = np.random.default_rng(seed)
+    p = rng.integers(-1000, 1000, n)
+    p[:3] = p_top
+    k = rng.integers(3, groups, n)
+    k[:3] = [0, 0, 1]
+    return pd.DataFrame({
+        "ts": pd.to_datetime("2022-01-01")
+        + pd.to_timedelta(rng.integers(0, 86400 * 100, n), unit="s"),
+        "k": k.astype(np.int64), "q": rng.integers(1, 51, n),
+        "p": p.astype(np.int64), "w": np.round(rng.random(n) * 50, 4)})
+
+
+def _attempt_spans(eng):
+    return [s["attrs"] for s in _walk_spans(eng)
+            if s["name"] == "sparse-attempt"]
+
+
+def _fallbacks(eng):
+    return sum(
+        float(ln.rsplit(" ", 1)[1])
+        for ln in eng.metrics.render().splitlines()
+        if ln.startswith("tpu_olap_sparse_narrow_fallbacks_total"))
+
+
+NARROW_SQL = "SELECT k, sum(p) AS sp, sum(q) AS sq, count(*) AS n FROM t " \
+             "GROUP BY k"
+
+
+def test_a_plan_whose_sums_fit_runs_narrow_and_says_so():
+    df = _narrow_df(1 << 20)
+    eng = _narrow_engine(df)
+    assert eng.explain(NARROW_SQL)["sum_word_bits"] == 32
+    for _ in range(2):
+        check_query(eng, NARROW_SQL)
+        rec = eng.history[-1]
+        assert rec["reduce_path"] == "sparse" and rec["sparse_attempts"] == 1
+        assert rec["sum_word_bits"] == 32 and "narrow_fallback" not in rec
+        assert [s["attrs"].get("sum_word_bits") for s in _walk_spans(eng)
+                if s["name"] == "dispatch"] == [32]
+        assert all("narrow_fallback" not in a for a in _attempt_spans(eng))
+    assert rec["jit_cache_hit"] and _fallbacks(eng) == 0
+    got = eng.sql(NARROW_SQL)
+    want = df.groupby("k").agg(sp=("p", "sum"), sq=("q", "sum"))
+    assert got["sp"].tolist() == want["sp"].tolist()
+    assert str(got["sp"].dtype) == "int64"
+
+
+@pytest.mark.parametrize("sql", [
+    NARROW_SQL,
+    "SELECT k, sum(p) AS sp, sum(q) AS sq FROM t GROUP BY k "
+    "ORDER BY sq DESC LIMIT 7",
+    "SELECT k, sum(p) AS sp, sum(q) AS sq FROM t GROUP BY k "
+    "HAVING sum(p) > 1000000",
+], ids=["group-by", "device-topn", "device-having"])
+def test_a_group_whose_sum_passes_int32_falls_back_once_and_stays_wide(sql):
+    """Three rows of 2^30 in two groups: a group sums to 2^31 and the
+    narrow program's flag says so. The answer is numpy's int64 sum (the
+    wide program of the same cap runs as one more attempt), the plan's
+    next run starts wide, and the counter moved once."""
+    df = _narrow_df(1 << 30)
+    eng = _narrow_engine(df)
+    assert eng.explain(sql)["sum_word_bits"] == 32
+    check_query(eng, sql)
+    first = eng.history[-1]
+    assert first["reduce_path"] == "sparse" \
+        and "fallback_reason" not in first
+    assert first["sparse_attempts"] == 2 and first["narrow_fallback"] is True
+    assert first["sum_word_bits"] == 64
+    attempts = _attempt_spans(eng)
+    assert [a.get("narrow_fallback") for a in attempts] == [True, None]
+    assert attempts[0]["cap"] == attempts[1]["cap"]
+    assert _fallbacks(eng) == 1
+    assert eng.explain(sql)["sum_word_bits"] == 64
+    check_query(eng, sql)
+    second = eng.history[-1]
+    assert second["sparse_attempts"] == 1 and second["jit_cache_hit"]
+    assert second["sum_word_bits"] == 64 and "narrow_fallback" not in second
+    assert all("narrow_fallback" not in a for a in _attempt_spans(eng))
+    assert _fallbacks(eng) == 1
+    got = eng.sql("SELECT k, sum(p) AS sp FROM t GROUP BY k")
+    want = df.groupby("k")["p"].sum()
+    assert got["sp"].tolist() == want.tolist() and want.max() == 1 << 31
+
+
+@pytest.mark.parametrize("sql,bits", [
+    ("SELECT k, count(*) AS n, min(q) AS lo FROM t GROUP BY k", None),
+    ("SELECT k, sum(w) AS sw FROM t GROUP BY k", None),
+    ("SELECT k, sum(p * (100 - q)) AS rev FROM t GROUP BY k", 64),
+    ("SELECT k, sum(p * (100 - q)) AS rev, sum(q) AS sq FROM t GROUP BY k",
+     64),
+], ids=["no-sum", "float-sum", "virtual-int64-sum",
+        "virtual-sum-beside-a-narrow-one"])
+def test_sum_word_bits_is_said_where_a_plan_has_an_integer_sum(sql, bits):
+    """TPC-H q3 / q10's shape, `sum(a * (1 - b))`, is a virtual column,
+    materialised in int64: no narrow program, the parent's own. EXPLAIN
+    and the record say the same, and nothing where no integer sum is."""
+    eng = _narrow_engine(_narrow_df(1 << 20))
+    assert eng.explain(sql).get("sum_word_bits") == bits
+    check_query(eng, sql)
+    rec = eng.history[-1]
+    assert rec["reduce_path"] == "sparse" and rec["sparse_attempts"] == 1
+    assert rec.get("sum_word_bits") == bits and "narrow_fallback" not in rec
+    assert [s["attrs"].get("sum_word_bits") for s in _walk_spans(eng)
+            if s["name"] == "dispatch"] == [bits]
+    assert _fallbacks(eng) == 0
+    dense = "SELECT sum(q) AS sq FROM t"
+    assert "sum_word_bits" not in eng.explain(dense)
+    eng.sql(dense)
+    assert "sum_word_bits" not in eng.history[-1]
+
+
+def test_a_meshs_sparse_programs_stay_wide():
+    """The mesh's `shard_map` program is the parent's: its sums ride at
+    the accumulator's width and it holds no flag, whatever the columns
+    are stored at."""
+    import jax
+
+    from tpu_olap.executor import sharding as sh
+    eng = _narrow_engine(_narrow_df(1 << 20), num_shards=8)
+    assert eng.explain(NARROW_SQL)["sum_word_bits"] == 64
+    check_query(eng, NARROW_SQL)
+    rec = eng.history[-1]
+    assert rec["sparse"] and rec["num_shards"] == 8
+    assert rec["sum_word_bits"] == 64 and "narrow_fallback" not in rec
+    plan = eng.planner.plan(NARROW_SQL)
+    phys = eng.runner._lower_cached(plan.query, plan.entry.segments)
+    env, valid, seg_mask = eng.runner._prepare(phys, {})
+    consts_dev, seg_arg = eng.runner._args_for(phys, seg_mask,
+                                               eng.runner.mesh)
+    args = (env, valid, seg_arg, consts_dev)
+    program = sh.mesh_sparse_kernel(phys, eng.runner.mesh, 64)
+    assert _sort_operand_dtypes(program, *args) == ["int64"] * 3
+    assert "_narrow_ok" not in jax.eval_shape(program, *args)
+    one_chip = phys.make_sparse_kernel(64, None, None, True)
+    assert "_narrow_ok" in jax.eval_shape(one_chip, *args)
+
+
 def test_sparse_gspmd_spelling_parity(monkeypatch):
     """A mesh that spans processes hands the whole sparse program to
     GSPMD over global shapes (no fan-out, no broker merge): the boundary

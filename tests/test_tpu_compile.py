@@ -303,7 +303,13 @@ def test_sparse_min_max_program_compiles_for_v5e(topo, no_persistent_cache,
         assert ext_word_bits(phys.agg_plans, stored, cap) == 32
     consts_dev, seg_arg = eng.runner._args_for(phys, seg_mask, None)
     one_chip = SingleDeviceSharding(topo.devices[0])
-    kernel = phys.make_sparse_kernel(phys.total_groups, top)
+    # the narrow program, which the runner tries first (PR 41): the sums
+    # of l_quantity (int8) and l_extendedprice (int32) ride as one int32
+    # word each, and `_narrow_ok` says whether every part's sums fit
+    from tpu_olap.kernels.sparse_groupby import sum_word_bits
+    assert env["cols"]["l_extendedprice"].dtype == "int32"
+    assert sum_word_bits(phys.agg_plans, stored, True) == 32
+    kernel = phys.make_sparse_kernel(phys.total_groups, top, None, True)
     lowered = jax.jit(kernel).lower(
         *_scaled((env, valid, seg_arg), 1, one_chip),
         _scaled(consts_dev, 1, one_chip))
@@ -314,7 +320,12 @@ def test_sparse_min_max_program_compiles_for_v5e(topo, no_persistent_cache,
     text = lowered.compile().as_text()
     assert " scatter(" not in text and " sort(" in text
     assert " reduce-window(" in text
+    # the key's two u32 halves, the two sums and l_discount as one s32 each
+    widest = max(re.findall(r"= \((.*?)\) sort\(", text), key=len)
+    assert re.findall(r"([us]\d+)\[", widest) \
+        == ["u32", "u32", "s32", "s32", "s32"], widest
     out = jax.eval_shape(kernel, env, valid, seg_arg, consts_dev)
+    assert out["_narrow_ok"].shape == () and out["sum_price"].dtype == "int64"
     assert {k: (v.shape, str(v.dtype)) for k, v in out.items()
             if "discount" in k} == {
         "min_discount": ((100,), "int64"), "max_discount": ((100,), "int64"),
@@ -361,7 +372,9 @@ def test_sparse_having_program_compiles_for_v5e(topo, no_persistent_cache,
     args = (*_scaled((env, valid, seg_arg), 1, one_chip),
             _scaled(consts_dev, 1, one_chip))
     assert ext_word_bits(phys.agg_plans, stored, 1 << 16) == 64
-    kernel = phys.make_sparse_kernel(1 << 16, None, kept)
+    # the narrow program, which the runner tries first (PR 41): the
+    # tested sum(l_quantity), stored as int8, rides as one int32 word
+    kernel = phys.make_sparse_kernel(1 << 16, None, kept, True)
     lowered = jax.jit(kernel).lower(*args)
     main_sort = max(re.findall(r"stablehlo\.sort\"?\(([^)]*)\)",
                                lowered.as_text()), key=len)
@@ -369,9 +382,15 @@ def test_sparse_having_program_compiles_for_v5e(topo, no_persistent_cache,
     assert len(main_sort.split(",")) == 3, main_sort
     text = lowered.compile().as_text()
     assert " scatter(" not in text and text.count(" sort(") == 3
+    # the tested table's prefix is ONE cap-sized gather of an s32, where
+    # the wide program's int64 prefix is two of a u32 half
+    assert len(re.findall(rf"= s32\[{(1 << 16) + 1}\]\S* gather\(", text)) \
+        == 1 and not re.findall(rf"= u32\[{(1 << 16) + 1}\]\S* gather\(",
+                                text)
     out = jax.eval_shape(kernel, env, valid, seg_arg, consts_dev)
     assert {k: (v.shape, str(v.dtype)) for k, v in out.items()} == {
         "_count": ((), "int32"), "_kept": ((), "int32"),
+        "_narrow_ok": ((), "bool"),
         "_rows": ((kept,), "int32"), "_keys": ((kept,), "int64"),
         "sum_quantity": ((kept,), "int64"),
         "o_totalprice": ((kept,), "int64"),

@@ -17,10 +17,21 @@ ranks first and reads the tables it does not rank at the 100 kept rows
 `sparse_topn_tables_first` is the spelling before PR 39 (every [K] table,
 then the ranking, then the cut): at one N the two differ by N + 1 K-sized
 gathers (the keys' and each table's) against as many kept-row reads.
+`--dtypes int8` sums a column stored as int8 (1-50, TPC-H's quantity) into
+int64, and `--sum-word 32 64` runs the sparse forms as the engine's narrow
+program (every such sum one int32 sort operand, an int32 prefix tree, one
+gather a boundary, and `_narrow_ok`, which the row reports) and as its wide
+one (two u32 of each): over rows, K and `--tables` the two differ by one
+sort operand, the prefix tree's width and a gather a sum (PERF.md section 6,
+PR 41).
 
     python tools/sweep_group_reduce.py --rows 59986052 --ks 2000001 \
         --dtypes int64 --tables 0 3 \
         --forms sparse_topn sparse_topn_tables_first
+
+    python tools/sweep_group_reduce.py --rows 59986052 \
+        --ks 2000001 16777216 --dtypes int8 --tables 0 1 \
+        --sum-word 32 64 --forms sparse_topn
 
     python tools/sweep_group_reduce.py                  # on the chip
     python tools/sweep_group_reduce.py --compile-only   # here, for a
@@ -92,7 +103,7 @@ def _bcast(v, key, k):
                    axis=1, dtype=v.dtype)
 
 
-def _sparse_topn(v, key, k, tables=0, rank_first=True):
+def _sparse_topn(v, key, k, tables=0, rank_first=True, sum_word=64):
     """The other side of `lowering.topn_takes_sparse`: the engine's own
     sparse reduce into a compact table of k slots (one sort whose cost
     does not depend on k, the tables read at the runs' boundaries) with
@@ -103,24 +114,32 @@ def _sparse_topn(v, key, k, tables=0, rank_first=True):
     alone is gathered, the others are read at the 100 kept rows. Without
     it, the spelling that stood until PR 39, kept here to price a
     k-sized gather against a kept-row read: every [k] table, then
-    `top_k`, then each table cut to the kept slots. Returns the ranked
-    sum at the kept rows, their keys, and the other tables' kept rows."""
+    `top_k`, then each table cut to the kept slots. `sum_word` 32 asks
+    for the narrow program: a sum of a column stored in 32 bits or fewer
+    rides as one int32 word. Returns the ranked sum at the kept rows,
+    their keys, the other tables' kept rows and, of a narrow program,
+    `_narrow_ok` last."""
     from tpu_olap.kernels.sparse_groupby import (SENTINEL,
                                                  sparse_group_reduce)
     from tpu_olap.kernels.topk import top_k_groups
     cols = {"v": v, **{f"t{i}": v ^ (i + 1) for i in range(tables)}}
-    plans = [groupby.AggPlan(c, "sum", (c,), v.dtype) for c in cols]
+    # a column stored in fewer than 32 bits is summed into an int64
+    acc = v.dtype if v.dtype.itemsize >= 4 else np.dtype(np.int64)
+    plans = [groupby.AggPlan(c, "sum", (c,), acc) for c in cols]
     top = ("v", 100, False)
     out = sparse_group_reduce(key.astype(jnp.int64),
                               jnp.ones(key.shape, bool),
                               {"cols": cols, "nulls": {}}, plans, k, {},
-                              jnp, top if rank_first else None)
+                              jnp, top if rank_first else None,
+                              narrow=sum_word == 32)
+    ok = (out.pop("_narrow_ok"),) if "_narrow_ok" in out else ()
     if not rank_first:
         order, _ = top_k_groups(out["v"], out["_keys"] != SENTINEL,
                                 *top[1:])
         out = {name: t if name == "_count" else t[order]
                for name, t in out.items()}
-    return (out["v"], out["_keys"]) + tuple(out[c] for c in cols if c != "v")
+    return (out["v"], out["_keys"]) \
+        + tuple(out[c] for c in cols if c != "v") + ok
 
 
 FORMS = {"scatter": _scatter, "compare": _compare, "bcast": _bcast,
@@ -136,6 +155,8 @@ def _inputs(n, dtype, k, seed=7):
     key = rng.integers(0, k, n, dtype=np.int32)
     if dtype == "int64":   # a sum whose rows pass int32
         return rng.integers(0, 1 << 40, n, dtype=np.int64), key
+    if dtype == "int8":    # a quantity of 1 to 50, stored as it is
+        return rng.integers(1, 51, n, dtype=np.int8), key
     return (rng.random(n) < 0.5).astype(np.int32), key   # a filtered count
 
 
@@ -320,6 +341,8 @@ def _measure(fn, spec, inputs, want, reps, rec):
             # (the ranked sum at the rows a top-100 keeps, their keys,
             # ...): by (value descending, key ascending), as the engine's
             # TopN cuts a tie
+            if rec.get("sum_word") == 32 and got[-1].ndim == 0:
+                rec["narrow_ok"] = bool(got[-1])
             got, top = got[:2]
             kept = np.lexsort((np.arange(len(want)), -want))[:len(top)]
             rec["top_equal"] = bool(np.array_equal(np.asarray(top), kept))
@@ -378,7 +401,12 @@ def main():
     ap.add_argument("--forms", nargs="*", default=list(FORMS),
                     choices=list(FORMS))
     ap.add_argument("--dtypes", nargs="*", default=["int64", "int32"],
-                    choices=["int64", "int32"])
+                    choices=["int64", "int32", "int8"])
+    ap.add_argument("--sum-word", type=int, nargs="*", default=[64],
+                    choices=[32, 64],
+                    help="the sparse TopN forms' program: 32 the narrow "
+                         "one (a sum of a column stored in 32 bits or "
+                         "fewer rides as one int32 word), 64 the wide")
     ap.add_argument("--tables", type=int, nargs="*", default=[0],
                     help="the sparse TopN forms' integer sums beside the "
                          "ranked one: a boundary table each")
@@ -423,20 +451,26 @@ def sweep_dense(args, sharding):
                 if not args.compile_only:
                     v_np, key_np = _inputs(n, dtype, k)
                     inputs = (jnp.asarray(v_np), jnp.asarray(key_np))
-                    want = np.zeros(k, v_np.dtype)
+                    want = np.zeros(k, np.int64 if dtype == "int8"
+                                    else v_np.dtype)
                     np.add.at(want, key_np, v_np)
                 spec = [jax.ShapeDtypeStruct((n,), np.dtype(d),
                                              sharding=sharding)
                         for d in (dtype, "int32")]
-                for name, block, tables in [
-                        (f, b, t) for f in args.forms
+                for name, block, tables, word in [
+                        (f, b, t, w) for f in args.forms
                         for b in (args.block_bytes
                                   if f == "compare" else [0])
                         for t in (args.tables
+                                  if f in SPARSE_FORMS else [None])
+                        for w in (args.sum_word
                                   if f in SPARSE_FORMS else [None])]:
+                    if dtype == "int8" and name not in SPARSE_FORMS:
+                        continue   # the dense forms sum at v's own width
                     groupby._CMP_BLOCK_BYTES = block or \
                         groupby._CMP_BLOCK_BYTES
-                    more = {} if tables is None else {"tables": tables}
+                    more = {} if tables is None else {
+                        "tables": tables, "sum_word": word}
                     out.append(_measure(
                         functools.partial(FORMS[name], k=k, **more), spec,
                         inputs, want, args.reps,

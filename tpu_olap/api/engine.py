@@ -843,10 +843,22 @@ class Engine:
         in (the record's `mesh_program`) and, for a GroupBy with a
         HAVING, who decides it (the record's `having_where`: `device`
         where the sparse program cuts the table itself, else `host`;
-        null where lowering finds no device plan for the query)."""
+        null where lowering finds no device plan for the query) and,
+        for an aggregate whose sparse program holds an integer sum, the
+        width that sum would ride its sort at (the record's
+        `sum_word_bits`: 32 | 64)."""
         from tpu_olap.executor.batch import AGG_QUERY_TYPES
         plan = self.planner.plan(query)
         out = plan.explain()
+        if plan.rewritten and plan.entry.is_accelerated \
+                and isinstance(plan.query, AGG_QUERY_TYPES):
+            try:
+                bits = self.runner.sum_word_bits(plan.query,
+                                                 plan.entry.segments)
+            except _UNSUPPORTED:
+                bits = None   # no device plan: the fallback answers it
+            if bits is not None:
+                out["sum_word_bits"] = bits
         if plan.rewritten and plan.entry.is_accelerated \
                 and getattr(plan.query, "having", None) is not None:
             out["having_where"] = self.runner.having_where(

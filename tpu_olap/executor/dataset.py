@@ -215,6 +215,31 @@ class HbmLedger:
                 self.remove(k)
 
 
+def narrow_dtype(table: TableSegments, name: str):
+    """Smallest int dtype (int8/int16/int32/int64) holding every
+    value of a LONG column per the segment manifest's column min/max
+    — 2-8x less HBM residency and scan bandwidth; sums still widen
+    to the accumulator dtype on device. Usually a no-op cast: ingest
+    already stores the narrowed dtype. __time stays int64 (epoch
+    millis exceed int32). None for what is not a LONG column: it is
+    resident at the dtype it is stored at."""
+    if name == TIME_COLUMN or \
+            table.schema.get(name) is not ColumnType.LONG:
+        return None
+    from tpu_olap.segments.ingest import _int_dtype_for
+    lo = hi = None
+    for s in table.segments:
+        mlo = s.meta.column_min.get(name)
+        mhi = s.meta.column_max.get(name)
+        if mlo is None:
+            continue  # empty/all-null segment stores zero fill
+        lo = mlo if lo is None else min(lo, mlo)
+        hi = mhi if hi is None else max(hi, mhi)
+    if lo is None:
+        return np.dtype(np.int8)
+    return _int_dtype_for(lo, hi)
+
+
 class DeviceDataset:
     """Lazy per-column device stacks for one table.
 
@@ -357,27 +382,7 @@ class DeviceDataset:
         return out
 
     def _narrow_dtype(self, name: str):
-        """Smallest int dtype (int8/int16/int32/int64) holding every
-        value of a LONG column per the segment manifest's column min/max
-        — 2-8x less HBM residency and scan bandwidth; sums still widen
-        to the accumulator dtype on device. Usually a no-op cast: ingest
-        already stores the narrowed dtype. __time stays int64 (epoch
-        millis exceed int32)."""
-        if name == TIME_COLUMN or \
-                self.table.schema.get(name) is not ColumnType.LONG:
-            return None
-        from tpu_olap.segments.ingest import _int_dtype_for
-        lo = hi = None
-        for s in self.table.segments:
-            mlo = s.meta.column_min.get(name)
-            mhi = s.meta.column_max.get(name)
-            if mlo is None:
-                continue  # empty/all-null segment stores zero fill
-            lo = mlo if lo is None else min(lo, mlo)
-            hi = mhi if hi is None else max(hi, mhi)
-        if lo is None:
-            return np.dtype(np.int8)
-        return _int_dtype_for(lo, hi)
+        return narrow_dtype(self.table, name)
 
     def _ledger_add(self, kind: str, name: str, arr, pinned):
         if self.ledger is None:

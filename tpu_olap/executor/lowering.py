@@ -573,7 +573,7 @@ def _lower_agg(query, table, config) -> PhysicalPlan:
         from tpu_olap.kernels.sparse_groupby import compile_having
         having = compile_having(query.having, sparse_agg_plans, pool)
 
-    def make_sparse_kernel(cap, top=None, kept=None):
+    def make_sparse_kernel(cap, top=None, kept=None, narrow=False):
         """The sparse program for a compact table of `cap` slots; with
         `top` = (metric, threshold, inverted) the table's rows that a TopN
         keeps, the threshold applied on the device: that program ranks
@@ -581,7 +581,10 @@ def _lower_agg(query, table, config) -> PhysicalPlan:
         (`sparse_group_reduce`); with `kept` the rows the plan's HAVING
         lets through, in a bucket of `kept` rows: the same cut by a
         predicate. `cap` None: the program that counts the groups present
-        and builds no table (`sparse_group_count`)."""
+        and builds no table (`sparse_group_count`). `narrow`: the program
+        whose integer sums of columns stored in 32 bits or fewer ride as
+        one int32 word, and which says in `_narrow_ok` whether every
+        group's sum fits one (the same program where no sum is such)."""
         from tpu_olap.kernels.sparse_groupby import (build_group_key64,
                                                      sparse_group_count,
                                                      sparse_group_reduce)
@@ -595,7 +598,7 @@ def _lower_agg(query, table, config) -> PhysicalPlan:
             return sparse_group_reduce(
                 key.astype(xp.int64), mask, fenv, sparse_agg_plans, cap,
                 consts, xp, top,
-                None if kept is None else having + (kept,))
+                None if kept is None else having + (kept,), narrow)
         return sparse_kernel
 
     def build(sparse: bool) -> PhysicalPlan:

@@ -274,6 +274,10 @@ class QueryRunner:
             "cache_clears_total",
             "Explicit cache clears (CLEAR DRUID CACHE / recovery "
             "purges).", ("scope",))
+        self._m_narrow_fallbacks = m.counter(
+            "sparse_narrow_fallbacks_total",
+            "Sparse dispatches whose narrow program found a group's sum "
+            "past int32 and ran the wide program of the same cap.")
         self._m_recompile = m.counter(
             "recompiles_total",
             "Device executables built (jit-cache misses), by dispatch "
@@ -1967,7 +1971,14 @@ class QueryRunner:
         and the attempt run again, as a cap is. A group space past the
         budget whose count no hint tells is counted first, by a program
         that builds no table, so that the first compact table holds it:
-        each cap is a compile of the sort."""
+        each cap is a compile of the sort. One chip's first attempt of a
+        plan with an integer sum of a column stored in 32 bits or fewer
+        runs the narrow program (`sparse_group_reduce`'s `narrow`: such
+        a sum rides as one int32 word); once the count fits the cap its
+        `_narrow_ok` says whether every group's sum fits one, and where
+        it does not the wide program of the same cap runs as one more
+        attempt and the plan is remembered as wide, as a cap is."""
+        from tpu_olap.kernels import sparse_groupby as sg
         from tpu_olap.kernels.groupby import UnsupportedAggregation
 
         with self._enqueue_lock(metrics):
@@ -1981,7 +1992,7 @@ class QueryRunner:
             metrics["segments_window"] = win[1]
         mesh = self.mesh
         n_shards = mesh.devices.size if mesh else 1
-        base_key = plan.fingerprint() + ("sparse", n_shards)
+        base_key = self._sparse_key(plan, n_shards)
         use_exchange = mesh is not None and n_shards > 1 and \
             self.config.sparse_merge == "exchange"
         budget = self.config.sparse_group_budget
@@ -2014,6 +2025,11 @@ class QueryRunner:
             return min(cap, max(HAVING_KEPT_MIN, _next_pow2(
                 self._cap_hints.get(kept_key, 0))))
 
+        # a mesh's programs stay wide: their chips' partial sums are
+        # merged at the accumulator's width
+        wide_key = base_key + ("wide",)
+        narrow = mesh is None and wide_key not in self._cap_hints \
+            and sg.narrow_sums(plan.agg_plans, stored)
         t0 = time.perf_counter()
         hit = False
         attempts = 0
@@ -2029,7 +2045,7 @@ class QueryRunner:
                     f"{count} present groups exceed sparse budget "
                     f"{cap_limit}")
 
-            def run(cap, kept=None):
+            def run(cap, kept=None, narrow=False):
                 """Build (once a key, a counted compile) and enqueue the
                 program of `cap` (None: the count alone); call under the
                 enqueue lock. -> (its output tree, jit cache hit)"""
@@ -2037,13 +2053,14 @@ class QueryRunner:
                 key = base_key + (cap,) \
                     + ((win[1],) if win else ()) \
                     + (("top",) if top else ()) \
-                    + (("having", kept) if kept else ())
+                    + (("having", kept) if kept else ()) \
+                    + (("narrow",) if narrow else ())
                 jitted = self._jit_cache.get(key)
                 hit = jitted is not None
                 if hit:
                     _cache_lru_hit(self._jit_cache, key)
                 else:
-                    kern = plan.make_sparse_kernel(cap, top, kept)
+                    kern = plan.make_sparse_kernel(cap, top, kept, narrow)
                     if win is not None:
                         jitted = jax.jit(
                             self._window_kernel(kern, win[1]))
@@ -2075,7 +2092,7 @@ class QueryRunner:
                     attempts += 1
                     with _span("sparse-attempt", cap=cap) as sp:
                         with self._enqueue_lock(metrics):
-                            out, hit = run(cap, kept)
+                            out, hit = run(cap, kept, narrow)
                             prev, pin = pin, self._pin_inflight(out)
                         if prev is not None:
                             self._hbm_ledger.unpin_inflight(prev)
@@ -2083,11 +2100,22 @@ class QueryRunner:
                         with _span("count-probe"):
                             count = int(out["_count"])
                         sp.set(present_groups=count, jit_cache_hit=hit)
+                        # a ready scalar: some group's sum may pass int32
+                        too_wide = narrow and count <= cap \
+                            and not bool(out["_narrow_ok"])
+                        if too_wide:
+                            sp.set(narrow_fallback=True)
                     if count > cap:
                         if count > cap_limit:
                             raise over_budget(count)
                         cap = _grown_cap(count, cap_limit)
                         kept = kept_bucket(cap) if having else None
+                        continue
+                    if too_wide:
+                        narrow = False
+                        self._cap_hints[wide_key] = True
+                        self._m_narrow_fallbacks.inc()
+                        metrics["narrow_fallback"] = True
                         continue
                     if having:
                         with _span("having", where="device",
@@ -2100,6 +2128,7 @@ class QueryRunner:
                             kept = kept_bucket(cap)
                             continue
                     break
+                out.pop("_narrow_ok", None)   # read above; not a table
                 with _span("host-transfer", cap=cap):
                     out = self._fetch_tree(out, metrics, pin)
                 pin = None  # consumed (fetch unpins)
@@ -2122,7 +2151,6 @@ class QueryRunner:
             import jax
 
             from tpu_olap.executor import sharding as sh
-            from tpu_olap.kernels import sparse_groupby as sg
             if self.mesh_program == "gspmd":
                 # DCN mesh: remote chips' compact tables are not host-
                 # addressable, so neither the fan-out nor the broker
@@ -2310,13 +2338,21 @@ class QueryRunner:
         metrics["execute_ms"] = (time.perf_counter() - t0) * 1000
         metrics["jit_cache_hit"] = hit
         self._note_sparse(metrics, plan, stored, nullable, attempts, cap,
-                          count, top, plan.having[1] if having else None)
+                          count, top, plan.having[1] if having else None,
+                          narrow)
         return out, count
+
+    @staticmethod
+    def _sparse_key(plan, n_shards: int) -> tuple:
+        """What a plan's sparse programs and hints (`_cap_hints`: the
+        groups last seen, the HAVING's bucket, a plan found too wide for
+        the narrow program) are remembered under."""
+        return plan.fingerprint() + ("sparse", n_shards)
 
     @staticmethod
     def _note_sparse(metrics: dict, plan, stored: dict, nullable,
                      attempts: int, cap: int, count: int, top=None,
-                     having=None):
+                     having=None, narrow=False):
         """The sparse dispatch's counters on the record: how many cap
         attempts ran (1 once the template's hint is warm), the compact
         table's final cap, and the groups present in it; and which
@@ -2325,16 +2361,21 @@ class QueryRunner:
         scatters, the width of the word a min / max is read from, and
         how many [cap] tables it gathers or segment-reduces (every table,
         or with `top` the ranked one, with `having` the tested ones, and
-        what still segment-reduces): the kernel's own functions of the
-        plan's aggregate kinds and dtypes, the columns' stored dtypes
-        (`nullable`: those with a null mask), the cap and the cut, as the
-        dense `reduce_form` is of num_groups."""
+        what still segment-reduces), and the width an integer sum rode
+        the sort, its prefix sum and its boundary gather at (`narrow`:
+        the program that answered was the narrow one): the kernel's own
+        functions of the plan's aggregate kinds and dtypes, the columns'
+        stored dtypes (`nullable`: those with a null mask), the cap and
+        the cut, as the dense `reduce_form` is of num_groups."""
         from tpu_olap.kernels import sparse_groupby as sg
         metrics["reduce_form"] = sg.sparse_reduce_form(plan.agg_plans,
                                                        stored, cap)
         bits = sg.ext_word_bits(plan.agg_plans, stored, cap)
         if bits is not None:
             metrics["ext_word_bits"] = bits
+        bits = sg.sum_word_bits(plan.agg_plans, stored, narrow)
+        if bits is not None:
+            metrics["sum_word_bits"] = bits
         metrics["cap_tables"] = sg.cap_tables(plan.agg_plans, stored, cap,
                                               top, nullable, having)
         metrics["sparse"] = True
@@ -2868,6 +2909,27 @@ class QueryRunner:
             return None
         return "device" if self._device_having(plan) else "host"
 
+    def sum_word_bits(self, query, table) -> int | None:
+        """32 | 64: the width the integer sums of the query's sparse
+        program would ride the sort at on its next run, as its record's
+        `sum_word_bits` says after one (EXPLAIN's line): 32 where each
+        is a column stored in 32 bits or fewer, there is no mesh and no
+        run of the plan has found a group's sum past int32. None where
+        the plan is not sparse or has no integer sum. Raises what
+        lowering raises of a query with no device plan."""
+        from tpu_olap.executor.dataset import narrow_dtype
+        from tpu_olap.kernels import sparse_groupby as sg
+        plan = self._lower_cached_inner(query, table)
+        if not plan.sparse:
+            return None
+        stored = {p.fields[0]: dt for p in plan.agg_plans
+                  if p.kind == "sum"
+                  and (dt := narrow_dtype(table, p.fields[0])) is not None}
+        return sg.sum_word_bits(
+            plan.agg_plans, stored,
+            self.mesh is None and self._sparse_key(plan, 1) + ("wide",)
+            not in self._cap_hints)
+
     def _assemble_topn(self, query, plan, arrays) -> QueryResult:
         return self._emit_topn(query, plan, None, arrays, "host")
 
@@ -3158,11 +3220,12 @@ def _note_form(metrics: dict, plan, num_groups: int):
 def _form_attr(metrics: dict) -> dict:
     """The `dispatch` span's `reduce_form` attribute, where the record of
     the query has one (a generic grouped aggregate on the device), and
-    beside it a sparse min / max's `ext_word_bits`, the sparse program's
-    `cap_tables` and who decides a GroupBy's HAVING (`having_where`)."""
+    beside it a sparse min / max's `ext_word_bits`, a sparse integer
+    sum's `sum_word_bits`, the sparse program's `cap_tables` and who
+    decides a GroupBy's HAVING (`having_where`)."""
     return {k: metrics[k]
-            for k in ("reduce_form", "ext_word_bits", "cap_tables",
-                      "having_where")
+            for k in ("reduce_form", "ext_word_bits", "sum_word_bits",
+                      "cap_tables", "having_where")
             if metrics.get(k) is not None}
 
 

@@ -20,7 +20,15 @@ XLA's sort is fast on TPU and everything stays static-shaped:
   4. the [cap] tables are READ AT THE RUN BOUNDARIES: a row count is
      starts[g+1] - starts[g], a key is the run's first row's, an integer
      sum or count is the difference of an inclusive prefix sum at the
-     run's two ends (wrapping arithmetic: exact), an integer min / max of
+     run's two ends (wrapping arithmetic: exact so long as the run's own
+     sum fits the prefix's word: the accumulator's 64 bits, or, in the
+     narrow program one chip's runner tries first, ONE int32 word for a
+     sum of a column stored in 32 bits or fewer — one sort operand, an
+     int32 prefix tree and one gather a boundary where an int64 is two
+     u32 of each — with `_narrow_ok` saying from the longest run and the
+     column's largest |value| that every group's sum fits; where it
+     does not the runner runs the wide program and remembers the plan
+     as wide: `sum_word_bits` on the record), an integer min / max of
      a column stored in 32 bits or fewer is read at the run's last row
      from a running maximum of the one word (run id << b) | code(value):
      the run ids do not decrease, so the plain maximum is segmented by
@@ -282,8 +290,38 @@ def _narrow_int(col_dtype, acc_dtype) -> bool:
         and np.can_cast(col_dtype, np.int32)
 
 
+def _narrow_sum(p, col_dtype) -> bool:
+    """A sum that may ride as ONE int32 word where its program is asked to
+    (`sparse_group_reduce`'s `narrow`): a 64-bit integer accumulator over
+    a column stored in 32 bits or fewer (`col_dtype` None: a virtual
+    column, materialised in 64 bits)."""
+    return p.kind == "sum" and col_dtype is not None \
+        and np.dtype(p.acc_dtype).itemsize > 4 \
+        and _narrow_int(col_dtype, p.acc_dtype)
+
+
+def narrow_sums(plans, col_dtypes) -> bool:
+    """Whether the narrow program of `plans` differs from the wide one:
+    some sum is one `_narrow_sum` says may ride as int32."""
+    return any(_narrow_sum(p, col_dtypes.get(p.fields[0]))
+               for p in plans if p.kind == "sum")
+
+
+def sum_word_bits(plans, col_dtypes, narrow: bool):
+    """Bits of the widest word an integer sum of `plans` rides the sort,
+    its prefix sum and its boundary gather at, in the program `narrow`
+    asks for: 32 where every one is a column stored in 32 bits or fewer
+    and the program is the narrow one, else the accumulator's 64; None
+    where the plan has no integer sum (a count's prefix is int32
+    whatever it is asked)."""
+    bits = [32 if narrow and _narrow_sum(p, col_dtypes.get(p.fields[0]))
+            else 8 * np.dtype(p.acc_dtype).itemsize
+            for p in plans if p.kind == "sum" and prefix_summed(p)]
+    return max(bits) if bits else None
+
+
 def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
-                        having=None):
+                        having=None, narrow=False):
     """[N] int64 keys + mask -> compacted per-group partials.
 
     Returns {"_keys": [cap] int64 (SENTINEL marks empty slots),
@@ -310,6 +348,18 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
     with a larger bucket where they do not fit, as it does for `cap`),
     and every other table is read at those slots. A row past `_kept`
     holds the SENTINEL key and no rows.
+
+    With `narrow`, an integer sum of a column stored in 32 bits or fewer
+    (`_narrow_sum`) rides the sort as ONE int32 operand, takes its prefix
+    sum in int32 and is read with one gather a boundary, and the tables
+    hold `_narrow_ok`, a scalar: whether the longest run times the largest
+    |value| of each such column is at most 2^31 - 1. Where it is, no
+    group's sum leaves int32, the wrapped int32 difference IS the sum, and
+    the tables (widened to the accumulator a slot) are the wide program's
+    to the bit; where it is not (or `_count` passes `cap`: a run past the
+    cap is not among `_rows`) the caller runs the wide program. A plan
+    with no such sum gives the program it gives without `narrow`, text
+    for text.
     """
     import jax
 
@@ -317,6 +367,7 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
     # where its key is the SENTINEL
     slots = {}
     words = {}   # min / max name -> (its column's operand, word, stored)
+    narrowed = []   # the sums that ride as one int32 word
 
     def carry(name, arr):
         if name not in slots:
@@ -339,8 +390,11 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
                 nulls = env["nulls"].get(p.fields[0])
                 mm = m & ~nulls if nulls is not None else m
                 if p.kind == "sum":
-                    carry(f"v:{p.name}",
-                          xp.where(mm, x, 0).astype(p.acc_dtype))
+                    dt = p.acc_dtype
+                    if narrow and _narrow_sum(p, x.dtype):
+                        dt = np.dtype(np.int32)
+                        narrowed.append(p.name)
+                    carry(f"v:{p.name}", xp.where(mm, x, 0).astype(dt))
                 else:
                     dt = _ext_dtype(x.dtype, p.acc_dtype)
                     word = ext_word_dtype(x.dtype, p.acc_dtype, cap)
@@ -380,21 +434,26 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
         gid, count = _sorted_segments(skey, cap, xp)
         starts = _run_starts(skey, cap, xp)
 
-    def run_sum(v, acc_dtype, at=None):
+    def run_sum(v, acc_dtype, at=None, word=None):
         """Exact integer sum of v over the runs of the slots `at` (None:
-        every slot): the inclusive prefix sum read at the run's last row
-        less its value before the run's first. Wrapping arithmetic makes
-        the difference exact whatever the prefix holds."""
+        every slot), as `acc_dtype`: the inclusive prefix sum read at the
+        run's last row less its value before the run's first. Wrapping
+        arithmetic makes the difference exact whatever the prefix holds,
+        so long as the run's own sum fits the `word` the prefix is taken
+        in (the accumulator's unless given: a narrowed sum's int32, which
+        `_narrow_ok` answers for)."""
         with stage_scope("prefix", xp):
-            prefix = _running(v.astype(acc_dtype), "add")
+            prefix = _running(v.astype(word or acc_dtype), "add")
 
         def before(row):
             return xp.where(row > 0, prefix[xp.maximum(row - 1, 0)], 0)
         with stage_scope("gather", xp):
             if at is None:
                 ends = before(starts)
-                return ends[1:] - ends[:-1]
-            return before(starts[at + 1]) - before(starts[at])
+                total = ends[1:] - ends[:-1]
+            else:
+                total = before(starts[at + 1]) - before(starts[at])
+            return total.astype(acc_dtype)
 
     def run_count(m, at=None):
         # a count is at most N: an int32 prefix holds it
@@ -421,7 +480,8 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
                 run_count(sorted_ops[slots[f"m:{p.name}"]], at)
         v = sorted_ops[slots[f"v:{p.name}"]]
         if prefix_summed(p):
-            return run_sum(v, p.acc_dtype, at)
+            return run_sum(v, p.acc_dtype, at,
+                           np.int32 if p.name in narrowed else None)
         return kept(segment(jax.ops.segment_sum, v), at)
 
     # inside a non-SENTINEL run every row is unmasked: its length is its
@@ -493,6 +553,20 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
             out["_kept"] = n_kept
             out["_rows"] = xp.where(live, out["_rows"], 0)
             out["_keys"] = xp.where(live, out["_keys"], SENTINEL)
+    if narrowed:
+        # |a run's sum| <= its rows x the column's largest |value|: where
+        # that fits int32 for the longest run, every wrapped difference
+        # above is the sum itself. A masked row rides as 0, so the plain
+        # max / min over the sorted operand is the unmasked rows'
+        with stage_scope("prefix", xp):
+            longest = rows.max(initial=0).astype(xp.int64)
+            ok = xp.ones((), bool)
+            for name in narrowed:
+                v = sorted_ops[slots[f"v:{name}"]]
+                largest = xp.maximum(v.max(initial=0).astype(xp.int64),
+                                     -v.min(initial=0).astype(xp.int64))
+                ok = ok & (longest * largest <= np.iinfo(np.int32).max)
+            out["_narrow_ok"] = ok
 
     for p in plans:
         if p.name in whole:
