@@ -401,6 +401,62 @@ def test_sparse_having_program_compiles_for_v5e(topo, no_persistent_cache,
                           seg_arg, consts_dev)["_count"].shape == ()
 
 
+def test_sparse_wide_key_program_compiles_for_v5e(topo, no_persistent_cache,
+                                                  monkeypatch, tmp_path):
+    """`q18p` of `tpch-flat-sf10-widekey-chip`, as the chip runs it: Q18 with
+    the five group columns the specification publishes, a group space past
+    2^62 (compiled on small shapes under a cap of 2^16 slots). The sort
+    compares TWO int64 key words and carries `l_quantity`'s sum as one int32
+    word; `o_totalprice` is a key, so no running maximum is left; three
+    sorts (the rows, `starts`, the passing slots), no row-sized scatter,
+    and a `_keys` table a word at the kept rows. The count probe sorts the
+    two words and builds no table."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    from perfbench.datasets import tpch_flat_widekey
+    from tpu_olap.kernels.sparse_groupby import cap_tables
+    _as_tpu(monkeypatch)
+    rows, seed, cap, kept = 60_000, 2_147_483_659, 1 << 24, 1024
+    data = tpch_flat_widekey.generate(rows, seed, str(tmp_path), workers=1,
+                                      orders_per_chunk=7_000)
+    eng = Engine(EngineConfig(fallback_on_device_failure=False,
+                              sparse_group_budget=cap))
+    tpch_flat_widekey.register(eng, data["paths"], rows, seed)
+    phys = _physical(eng, tpch_flat_widekey.templates()["q18p"])
+    assert phys.query.query_type == "groupBy" and phys.sparse
+    assert phys.total_groups >= 1 << 62 and len(phys.key_words) == 2
+    assert sorted(sum(phys.key_words, ())) == [1, 2, 3, 4, 5]
+    assert eng.runner._device_having(phys)
+    env, valid, seg_mask = eng.runner._prepare(phys, {})
+    stored = {c: a.dtype for c, a in env["cols"].items()}
+    assert cap_tables(phys.agg_plans, stored, cap,
+                      having=phys.having[1]) == 1
+    consts_dev, seg_arg = eng.runner._args_for(phys, seg_mask, None)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    args = (*_scaled((env, valid, seg_arg), 1, one_chip),
+            _scaled(consts_dev, 1, one_chip))
+    kernel = phys.make_sparse_kernel(1 << 16, None, kept, True)
+    lowered = jax.jit(kernel).lower(*args)
+    main_sort = max(re.findall(r"stablehlo\.sort\"?\(([^)]*)\)",
+                               lowered.as_text()), key=len)
+    # two key words and l_quantity's sum operand
+    assert len(main_sort.split(",")) == 3, main_sort
+    text = lowered.compile().as_text()
+    assert " scatter(" not in text and text.count(" sort(") == 3
+    out = jax.eval_shape(kernel, env, valid, seg_arg, consts_dev)
+    assert {k: (v.shape, str(v.dtype)) for k, v in out.items()} == {
+        "_count": ((), "int32"), "_kept": ((), "int32"),
+        "_narrow_ok": ((), "bool"),
+        "_rows": ((kept,), "int32"), "_keys": ((kept,), "int64"),
+        "_keys1": ((kept,), "int64"),
+        "sum_quantity": ((kept,), "int64")}
+    count = jax.jit(phys.make_sparse_kernel(None)).lower(*args).compile()
+    assert count.as_text().count(" sort(") == 1
+    assert jax.eval_shape(phys.make_sparse_kernel(None), env, valid,
+                          seg_arg, consts_dev)["_count"].shape == ()
+
+
 @pytest.mark.parametrize("word", ["int32", "int64"])
 def test_running_max_compiles_at_the_druid_cells_rows(topo,
                                                       no_persistent_cache,

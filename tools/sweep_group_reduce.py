@@ -23,7 +23,11 @@ program (every such sum one int32 sort operand, an int32 prefix tree, one
 gather a boundary, and `_narrow_ok`, which the row reports) and as its wide
 one (two u32 of each): over rows, K and `--tables` the two differ by one
 sort operand, the prefix tree's width and a gather a sum (PERF.md section 6,
-PR 41).
+PR 41). `--key-words 1 2 3` gives the sparse forms' key that many int64
+words (each further word a function of the first, so the groups and their
+order stay): the sort compares them in turn, a run ends where any changes
+and each is one more table read where the keys are; over rows and K the
+price of a key word stands beside a sum's (PERF.md section 6, PR 42).
 
     python tools/sweep_group_reduce.py --rows 59986052 --ks 2000001 \
         --dtypes int64 --tables 0 3 \
@@ -32,6 +36,10 @@ PR 41).
     python tools/sweep_group_reduce.py --rows 59986052 \
         --ks 2000001 16777216 --dtypes int8 --tables 0 1 \
         --sum-word 32 64 --forms sparse_topn
+
+    python tools/sweep_group_reduce.py --rows 59986052 --ks 2000001 \
+        --dtypes int8 --tables 0 1 --sum-word 32 64 --key-words 1 2 3 \
+        --forms sparse_topn
 
     python tools/sweep_group_reduce.py                  # on the chip
     python tools/sweep_group_reduce.py --compile-only   # here, for a
@@ -103,7 +111,8 @@ def _bcast(v, key, k):
                    axis=1, dtype=v.dtype)
 
 
-def _sparse_topn(v, key, k, tables=0, rank_first=True, sum_word=64):
+def _sparse_topn(v, key, k, tables=0, rank_first=True, sum_word=64,
+                 key_words=1):
     """The other side of `lowering.topn_takes_sparse`: the engine's own
     sparse reduce into a compact table of k slots (one sort whose cost
     does not depend on k, the tables read at the runs' boundaries) with
@@ -116,9 +125,11 @@ def _sparse_topn(v, key, k, tables=0, rank_first=True, sum_word=64):
     k-sized gather against a kept-row read: every [k] table, then
     `top_k`, then each table cut to the kept slots. `sum_word` 32 asks
     for the narrow program: a sum of a column stored in 32 bits or fewer
-    rides as one int32 word. Returns the ranked sum at the kept rows,
-    their keys, the other tables' kept rows and, of a narrow program,
-    `_narrow_ok` last."""
+    rides as one int32 word. `key_words` past 1 hands the key as that many
+    int64 words, the further ones functions of the first: the same groups
+    in the same order, sorted by every word. Returns the ranked sum at the
+    kept rows, their keys, the other tables' kept rows and, of a narrow
+    program, `_narrow_ok` last."""
     from tpu_olap.kernels.sparse_groupby import (SENTINEL,
                                                  sparse_group_reduce)
     from tpu_olap.kernels.topk import top_k_groups
@@ -127,8 +138,11 @@ def _sparse_topn(v, key, k, tables=0, rank_first=True, sum_word=64):
     acc = v.dtype if v.dtype.itemsize >= 4 else np.dtype(np.int64)
     plans = [groupby.AggPlan(c, "sum", (c,), acc) for c in cols]
     top = ("v", 100, False)
-    out = sparse_group_reduce(key.astype(jnp.int64),
-                              jnp.ones(key.shape, bool),
+    key = key.astype(jnp.int64)
+    if key_words > 1:
+        key = (key,) + tuple((key << 31) + (key ^ (w * 0x55555))
+                             for w in range(1, key_words))
+    out = sparse_group_reduce(key, jnp.ones(v.shape, bool),
                               {"cols": cols, "nulls": {}}, plans, k, {},
                               jnp, top if rank_first else None,
                               narrow=sum_word == 32)
@@ -407,6 +421,9 @@ def main():
                     help="the sparse TopN forms' program: 32 the narrow "
                          "one (a sum of a column stored in 32 bits or "
                          "fewer rides as one int32 word), 64 the wide")
+    ap.add_argument("--key-words", type=int, nargs="*", default=[1],
+                    help="the sparse TopN forms' key as this many int64 "
+                         "words: sort keys, boundary tests and key tables")
     ap.add_argument("--tables", type=int, nargs="*", default=[0],
                     help="the sparse TopN forms' integer sums beside the "
                          "ranked one: a boundary table each")
@@ -457,20 +474,23 @@ def sweep_dense(args, sharding):
                 spec = [jax.ShapeDtypeStruct((n,), np.dtype(d),
                                              sharding=sharding)
                         for d in (dtype, "int32")]
-                for name, block, tables, word in [
-                        (f, b, t, w) for f in args.forms
+                for name, block, tables, word, words in [
+                        (f, b, t, w, kw) for f in args.forms
                         for b in (args.block_bytes
                                   if f == "compare" else [0])
                         for t in (args.tables
                                   if f in SPARSE_FORMS else [None])
                         for w in (args.sum_word
-                                  if f in SPARSE_FORMS else [None])]:
+                                  if f in SPARSE_FORMS else [None])
+                        for kw in (args.key_words
+                                   if f in SPARSE_FORMS else [None])]:
                     if dtype == "int8" and name not in SPARSE_FORMS:
                         continue   # the dense forms sum at v's own width
                     groupby._CMP_BLOCK_BYTES = block or \
                         groupby._CMP_BLOCK_BYTES
                     more = {} if tables is None else {
-                        "tables": tables, "sum_word": word}
+                        "tables": tables, "sum_word": word,
+                        "key_words": words}
                     out.append(_measure(
                         functools.partial(FORMS[name], k=k, **more), spec,
                         inputs, want, args.reps,

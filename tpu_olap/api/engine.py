@@ -846,7 +846,11 @@ class Engine:
         null where lowering finds no device plan for the query) and,
         for an aggregate whose sparse program holds an integer sum, the
         width that sum would ride its sort at (the record's
-        `sum_word_bits`: 32 | 64)."""
+        `sum_word_bits`: 32 | 64) and, for an aggregate whose plan is
+        the sparse group-by, how many int64 words its key takes and how
+        many bits its dimensions' ids (the record's `key_words`: 1 under
+        a group space of 2^62, and `key_bits`; `key_words` null where
+        lowering finds no device plan for the query)."""
         from tpu_olap.executor.batch import AGG_QUERY_TYPES
         plan = self.planner.plan(query)
         out = plan.explain()
@@ -855,10 +859,15 @@ class Engine:
             try:
                 bits = self.runner.sum_word_bits(plan.query,
                                                  plan.entry.segments)
+                key = self.runner.key_words(plan.query,
+                                            plan.entry.segments)
             except _UNSUPPORTED:
-                bits = None   # no device plan: the fallback answers it
+                bits = key = None   # no device plan: the fallback answers
+                out["key_words"] = None
             if bits is not None:
                 out["sum_word_bits"] = bits
+            if key is not None:
+                out["key_words"], out["key_bits"] = key
         if plan.rewritten and plan.entry.is_accelerated \
                 and getattr(plan.query, "having", None) is not None:
             out["having_where"] = self.runner.having_where(

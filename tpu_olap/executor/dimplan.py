@@ -58,6 +58,10 @@ class DimPlan:
     kind: str          # "codes" | "numeric" | "remap" | "timeformat"
     remap_name: str | None = None   # ConstPool name for remap/offset consts
     offset_name: str | None = None
+    # ConstPool name of `size` where a numeric dimension's bound is to
+    # ride the pool and not the program's text (a wide sparse key:
+    # lowering._lower_agg); None: the bound is the literal
+    size_name: str | None = None
     time_plan: object = None        # BucketPlan for timeformat dims
     # content hash for gather-needing kinds (remap/timeformat): the
     # runner precomputes these id streams ONCE per table as
@@ -86,7 +90,9 @@ class DimPlan:
             # jnp.where as i64 and Mosaic's scalar i64->i32 lowering
             # recurses when this runs inside the Pallas kernel
             z = np.int32(0)
-            i = xp.where((i >= 1) & (i < self.size), i, z)
+            size = self.size if self.size_name is None \
+                else consts[self.size_name]
+            i = xp.where((i >= 1) & (i < size), i, z)
             nm = env["nulls"].get(self.source_col)
             if nm is not None:
                 i = xp.where(nm, z, i)

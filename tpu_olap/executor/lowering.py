@@ -12,8 +12,9 @@ rewritable" -> fallback (SURVEY.md §2 property 2).
 
 from __future__ import annotations
 
+import functools
 import os.path
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 
 import numpy as np
 
@@ -58,6 +59,14 @@ class PhysicalPlan:
     pallas_reason: str | None = "not attempted"  # None = pallas kernel active
     sparse: bool = False       # sort-based path for huge group spaces
     make_sparse_kernel: object = None   # cap -> kernel fn (sparse only)
+    # the group key's words: a tuple a word of the positions of `sizes`
+    # packed into it, ascending, and each position's radix in its word.
+    # One word of every position that carries an id, in the mixed radix
+    # of `sizes`, but for a sparse plan whose group space is 2^62 or
+    # more: its key is several int64 words
+    # (sparse_groupby.pack_key_words, key_radix)
+    key_words: tuple = ()
+    key_radix: tuple = ()
     # (test, names) of a GroupBy's HAVING where the sparse program can
     # decide it (sparse_groupby.compile_having), else None: the host does
     having: object = None
@@ -119,14 +128,21 @@ def lower(query, table, config) -> PhysicalPlan:
 def _sparse_reject_reason(query, total, config) -> str | None:
     """None when the sort-based sparse path can serve this shape, else
     why not — the single source of truth for both the over-budget
-    routing decision and the in-branch rejections (GroupBy and TopN: the
-    timeseries assembler indexes the dense bucket space)."""
+    routing decision and the in-branch rejections. Refused: a timeseries
+    (its assembler indexes the dense bucket space); any shape without
+    64-bit lanes (the key's words are int64); and, on a mesh, a group
+    space of 2^62 or more: one chip packs such a key into several int64
+    words (`sparse_groupby.pack_key_words`), but the chips' tables are
+    merged by a sort of ONE int64 key (`sharding.mesh_merge_kernel`,
+    `merge_device`, the broker's `merge_sparse`)."""
     if not isinstance(query, (GroupByQuerySpec, TopNQuerySpec)):
         return f"{query.query_type} has no sparse path"
-    if total >= (1 << 62):
-        return "the group space overflows the int64 sparse key"
     if not config.enable_x64:
         return "sparse group-by needs int64 keys (enable_x64=False)"
+    if total >= (1 << 62) and _mesh_size(config) > 1:
+        return ("the group space is past 2^62, whose sparse key is more "
+                "than one int64 word, and that needs one chip: the "
+                "mesh's merge sorts one int64 key")
     return None
 
 
@@ -458,6 +474,19 @@ def _lower_agg(query, table, config) -> PhysicalPlan:
     total = 1
     for s in sizes:
         total *= s
+    # the sparse key's words, over the positions of `sizes` that carry an
+    # id (granularity "all" has none): one word under 2^62
+    from tpu_olap.kernels.sparse_groupby import key_radix, pack_key_words
+    id_pos = ([0] if bucket_plan.kind != "all" else []) \
+        + list(range(1, len(sizes)))
+    packed = pack_key_words([sizes[i] for i in id_pos])
+    key_words = tuple(tuple(id_pos[i] for i in w) for w in packed)
+    if len(packed) > 1:
+        # a wide key's program holds each domain's width in bits, not its
+        # size (`pack_key_words`): a numeric dimension's bound rides the
+        # pool beside its offset
+        dim_plans = [replace(dp, size_name=pool.add(dp.size, np.int32))
+                     if dp.kind == "numeric" else dp for dp in dim_plans]
     # sketch aggregates keep [groups × radix] state PER AGGREGATION: at
     # large K their TOTAL dominates memory long before the group COUNT
     # exceeds the dense budget (observed: a 1M-group theta query
@@ -584,20 +613,26 @@ def _lower_agg(query, table, config) -> PhysicalPlan:
         and builds no table (`sparse_group_count`). `narrow`: the program
         whose integer sums of columns stored in 32 bits or fewer ride as
         one int32 word, and which says in `_narrow_ok` whether every
-        group's sum fits one (the same program where no sum is such)."""
+        group's sum fits one (the same program where no sum is such).
+        Where the plan's key is several words (`key_words`) every one of
+        these programs sorts by them all and gives a `_keys` table a
+        word."""
         from tpu_olap.kernels.sparse_groupby import (build_group_key64,
                                                      sparse_group_count,
                                                      sparse_group_reduce)
 
+        key_builder = functools.partial(build_group_key64, words=packed)
+
         def sparse_kernel(env, valid, seg_mask, consts):
             xp = _jnp()
             fenv, mask, key = _masked_key(env, valid, seg_mask, consts,
-                                          build_group_key64)
+                                          key_builder)
+            if not isinstance(key, tuple):
+                key = key.astype(xp.int64)
             if cap is None:
-                return sparse_group_count(key.astype(xp.int64), mask, xp)
+                return sparse_group_count(key, mask, xp)
             return sparse_group_reduce(
-                key.astype(xp.int64), mask, fenv, sparse_agg_plans, cap,
-                consts, xp, top,
+                key, mask, fenv, sparse_agg_plans, cap, consts, xp, top,
                 None if kept is None else having + (kept,), narrow)
         return sparse_kernel
 
@@ -618,6 +653,7 @@ def _lower_agg(query, table, config) -> PhysicalPlan:
             filter_streams=_dedupe_streams(pool),
             sparse=sparse, make_sparse_kernel=make_sparse_kernel if sparse
             else None, having=having,
+            key_words=key_words, key_radix=key_radix(sizes, key_words),
             key_fn=None if sparse else key_fn)
 
     plan = build(sparse)

@@ -278,6 +278,10 @@ class QueryRunner:
             "sparse_narrow_fallbacks_total",
             "Sparse dispatches whose narrow program found a group's sum "
             "past int32 and ran the wide program of the same cap.")
+        self._m_wide_key = m.counter(
+            "sparse_wide_key_queries_total",
+            "Sparse dispatches whose group space is 2^62 or more: the key "
+            "rode the sort as more than one int64 word.")
         self._m_recompile = m.counter(
             "recompiles_total",
             "Device executables built (jit-cache misses), by dispatch "
@@ -1000,6 +1004,10 @@ class QueryRunner:
             if plan.kind != "agg":
                 raise UnsupportedAggregation(
                     f"{query.query_type} has no mergeable partials")
+            if len(plan.key_words) > 1:
+                raise UnsupportedAggregation(
+                    f"a group space of {plan.total_groups} has no flat "
+                    "int64 group id to key mergeable partials by")
             if plan.sparse:
                 from tpu_olap.kernels.sparse_groupby import SENTINEL
                 out, _ = self._dispatch(
@@ -2028,6 +2036,9 @@ class QueryRunner:
         # a mesh's programs stay wide: their chips' partial sums are
         # merged at the accumulator's width
         wide_key = base_key + ("wide",)
+        n_words = len(plan.key_words)
+        if n_words > 1:
+            self._m_wide_key.inc()
         narrow = mesh is None and wide_key not in self._cap_hints \
             and sg.narrow_sums(plan.agg_plans, stored)
         t0 = time.perf_counter()
@@ -2079,7 +2090,7 @@ class QueryRunner:
                     # them before any table is sized (a run of a sort,
                     # so an attempt)
                     attempts += 1
-                    with _span("sparse-count") as sp:
+                    with _span("sparse-count", key_words=n_words) as sp:
                         with self._enqueue_lock(metrics):
                             out, hit = run(None)
                         count = int(out["_count"])
@@ -2090,7 +2101,8 @@ class QueryRunner:
                 kept = kept_bucket(cap) if having else None
                 while True:
                     attempts += 1
-                    with _span("sparse-attempt", cap=cap) as sp:
+                    with _span("sparse-attempt", cap=cap,
+                               key_words=n_words) as sp:
                         with self._enqueue_lock(metrics):
                             out, hit = run(cap, kept, narrow)
                             prev, pin = pin, self._pin_inflight(out)
@@ -2209,7 +2221,8 @@ class QueryRunner:
             try:
                 while True:
                     attempts += 1
-                    with _span("sparse-attempt", cap=cap) as sp:
+                    with _span("sparse-attempt", cap=cap,
+                               key_words=n_words) as sp:
                         with self._enqueue_lock(metrics):
                             consts_dev, seg_arg = self._args_for(
                                 plan, seg_mask, mesh)
@@ -2366,7 +2379,10 @@ class QueryRunner:
         the program that answered was the narrow one): the kernel's own
         functions of the plan's aggregate kinds and dtypes, the columns'
         stored dtypes (`nullable`: those with a null mask), the cap and
-        the cut, as the dense `reduce_form` is of num_groups."""
+        the cut, as the dense `reduce_form` is of num_groups. And the
+        key: how many int64 words it rode the sort as (`key_words`: 1
+        under a group space of 2^62) and the bits its dimensions' ids
+        take together (`key_bits`)."""
         from tpu_olap.kernels import sparse_groupby as sg
         metrics["reduce_form"] = sg.sparse_reduce_form(plan.agg_plans,
                                                        stored, cap)
@@ -2378,6 +2394,8 @@ class QueryRunner:
             metrics["sum_word_bits"] = bits
         metrics["cap_tables"] = sg.cap_tables(plan.agg_plans, stored, cap,
                                               top, nullable, having)
+        metrics["key_words"] = len(plan.key_words)
+        metrics["key_bits"] = sg.key_bits(plan.sizes)
         metrics["sparse"] = True
         metrics["sparse_attempts"] = attempts
         metrics["sparse_cap"] = metrics["result_cap"] = cap
@@ -2436,10 +2454,16 @@ class QueryRunner:
                     eval_post_aggs(arrays, query.post_aggregations)
             names = self._out_names(query)
             # present groups by sentinel mask: compact tables fill the
-            # tail with SENTINEL; exchange slot tables interleave empties
+            # tail with SENTINEL; exchange slot tables interleave empties.
+            # A wide key's further words are read where word 0 is present
             keys = np.asarray(out["_keys"])
             pm = keys != SENTINEL
             present = keys[pm].astype(np.int64)
+            if len(plan.key_words) > 1:
+                from tpu_olap.kernels.sparse_groupby import key_names
+                present = (present,) + tuple(
+                    np.asarray(out[n])[pm]
+                    for n in key_names(len(plan.key_words))[1:])
             sub = {n: np.asarray(arrays[n])[pm] for n in names}
             with self.stages.stage("assemble", metrics), \
                     _span("assemble"):
@@ -2786,17 +2810,21 @@ class QueryRunner:
             sp.set(rows=len(rows))
         return QueryResult(query, rows, druid)
 
-    def _decode_groups(self, plan, idx: np.ndarray):
+    def _decode_groups(self, plan, idx):
         """Present flat group ids -> (bucket ids, {dim name -> dense ids}).
         A dimension's values are `dp.labels[ids]`; the caller looks up
-        only the rows it emits or sorts by."""
-        sizes = plan.sizes
-        rem = idx
-        radix_vals = []
-        for s in sizes[::-1]:
-            radix_vals.append(rem % s)
-            rem = rem // s
-        radix_vals = radix_vals[::-1]  # bucket first, then dims in order
+        only the rows it emits or sorts by. `idx`: one array of ids in
+        the mixed radix of `plan.sizes`, or, of a sparse plan whose key
+        is several words, the tuple of the words' arrays: each position
+        of `sizes` is taken from its word (`plan.key_words`: one word of
+        every position that carries an id, but for such a plan) under
+        its radix there (`plan.key_radix`)."""
+        words = idx if isinstance(idx, tuple) else (idx,)
+        radix_vals = [np.zeros(len(words[0]), np.int64) for _ in plan.sizes]
+        for rem, positions in zip(words, plan.key_words):
+            for i in positions[::-1]:
+                radix_vals[i] = rem % plan.key_radix[i]
+                rem = rem // plan.key_radix[i]
         buckets = radix_vals[0]
         dim_ids = {dp.name: ids
                    for dp, ids in zip(plan.dim_plans, radix_vals[1:])}
@@ -2808,18 +2836,23 @@ class QueryRunner:
     def _decode_present(self, query, plan, present, sub):
         """-> (present, sub, bucket ids, {dim name -> dense ids}) under the
         leaf span `decode-groups`. `present` None: `sub` holds the dense
-        [K] tables, cut here to the groups that have rows."""
+        [K] tables, cut here to the groups that have rows; a tuple: the
+        words of a wide sparse key (`_decode_groups`), of which word 0
+        stands for the groups from here on (their count is all that is
+        read of it)."""
         with _span("decode-groups") as sp:
             if present is None:
                 present = np.nonzero(sub["_rows"] > 0)[0]
                 sub = {n: np.asarray(sub[n])[present]
                        for n in self._out_names(query)}
-            sp.set(groups=len(present))
-            return (present, sub) + self._decode_groups(plan, present)
+            words = present if isinstance(present, tuple) else (present,)
+            sp.set(groups=len(words[0]), key_words=len(words))
+            return (words[0], sub) + self._decode_groups(plan, present)
 
     def _emit_groupby(self, query, plan, present, sub,
                       decided=False) -> QueryResult:
-        """present: flat group ids (any int width); sub: compact per-group
+        """present: flat group ids (any int width; a wide sparse key's
+        words as a tuple); sub: compact per-group
         final values (present None: the dense [K] tables). Shared tail of
         the dense and sparse paths. `decided`: the groups are those the
         device's HAVING let through (`_device_having`), so none is tested
@@ -2908,6 +2941,19 @@ class QueryRunner:
         except (UnsupportedAggregation, UnsupportedFilter):
             return None
         return "device" if self._device_having(plan) else "host"
+
+    def key_words(self, query, table):
+        """(words, bits) of the sparse key the query's plan sorts by, as
+        its record's `key_words` and `key_bits` say after a run
+        (EXPLAIN's lines): the int64 words the key takes (1 under a group
+        space of 2^62) and the bits its dimensions' ids take together.
+        None where the plan is not sparse. Raises what lowering raises of
+        a query with no device plan."""
+        from tpu_olap.kernels import sparse_groupby as sg
+        plan = self._lower_cached_inner(query, table)
+        if not plan.sparse:
+            return None
+        return len(plan.key_words), sg.key_bits(plan.sizes)
 
     def sum_word_bits(self, query, table) -> int | None:
         """32 | 64: the width the integer sums of the query's sparse
@@ -3221,11 +3267,13 @@ def _form_attr(metrics: dict) -> dict:
     """The `dispatch` span's `reduce_form` attribute, where the record of
     the query has one (a generic grouped aggregate on the device), and
     beside it a sparse min / max's `ext_word_bits`, a sparse integer
-    sum's `sum_word_bits`, the sparse program's `cap_tables` and who
-    decides a GroupBy's HAVING (`having_where`)."""
+    sum's `sum_word_bits`, the sparse program's `cap_tables`, who
+    decides a GroupBy's HAVING (`having_where`) and the sparse key's
+    `key_words` and `key_bits`."""
     return {k: metrics[k]
             for k in ("reduce_form", "ext_word_bits", "sum_word_bits",
-                      "cap_tables", "having_where")
+                      "cap_tables", "having_where", "key_words",
+                      "key_bits")
             if metrics.get(k) is not None}
 
 

@@ -7,7 +7,13 @@ answer would be a hash exchange, but sorting is the TPU-idiomatic move —
 XLA's sort is fast on TPU and everything stays static-shaped:
 
   1. mixed-radix key in int64 (the radix product may exceed int32);
-     masked rows get the +inf sentinel so they sort to the tail;
+     masked rows get the +inf sentinel so they sort to the tail. A group
+     space of 2^62 or more takes MORE THAN ONE such word (`pack_key_words`
+     says which dimension lies in which): the sort compares the words in
+     turn (`num_keys`), the SENTINEL stands in word 0 alone, a run ends
+     where any word changes and `_keys` is one table a word (`key_names`).
+     Everything below holds a word for a word; one word is the program
+     it always was, text for text;
   2. one multi-operand `lax.sort` carries the key and every aggregate
      input along (not the mask: a sorted row is masked exactly where its
      key is the sentinel; not stable: no table depends on the order of
@@ -72,21 +78,100 @@ from tpu_olap.kernels.groupby import (UnsupportedAggregation, _hash_fields,
 SENTINEL = np.int64(np.iinfo(np.int64).max)
 
 
-def build_group_key64(ids, sizes, xp):
-    """Mixed-radix combine into int64. Callers guard product < 2^62."""
+# bits of one key word: every word stays under 2^62, so the SENTINEL
+# (2^63 - 1) is never a key and a radix step cannot overflow
+KEY_WORD_BITS = 62
+
+
+def dim_bits(size) -> int:
+    """Bits that hold the ids 0..size-1 of one dimension."""
+    return (int(size) - 1).bit_length()
+
+
+def pack_key_words(sizes) -> tuple:
+    """The positions of `sizes` (the id domains of a group key, in radix
+    order) packed into the fewest int64 words: a tuple a word of positions,
+    ascending. A space under 2^62 is ONE word of every position, the exact
+    mixed radix the sparse key always was. Past it a dimension takes
+    `dim_bits` of a word (a radix of the next power of two: the program
+    then holds the domain's width and not its exact size, so a table whose
+    minimum or maximum moves from load to load keeps its program), and the
+    positions are laid first-fit in descending width: GROUP BY order would
+    strand room (TPC-H Q18's five columns take three words in that order,
+    two by width), and a word costs two u32 sort operands. The word that
+    holds position 0 comes first."""
     total = 1
     for s in sizes:
         total *= int(s)
-    if total >= (1 << 62):
+    if total < (1 << KEY_WORD_BITS):
+        return (tuple(range(len(sizes))),)
+    words = []   # [bits used, positions]
+    for i in sorted(range(len(sizes)), key=lambda i: -dim_bits(sizes[i])):
+        need = dim_bits(sizes[i])
+        for w in words:
+            if w[0] + need <= KEY_WORD_BITS:
+                w[0] += need
+                w[1].append(i)
+                break
+        else:
+            words.append([need, [i]])
+    return tuple(sorted(tuple(sorted(w[1])) for w in words))
+
+
+def key_radix(sizes, words) -> tuple:
+    """The radix each position of `sizes` has in its word: its size where
+    the key is one word, the next power of two where it is several."""
+    if len(words) == 1:
+        return tuple(int(s) for s in sizes)
+    return tuple(1 << dim_bits(s) for s in sizes)
+
+
+def key_bits(sizes) -> int:
+    """Bits the ids of a group key's dimensions take together."""
+    return sum(dim_bits(s) for s in sizes)
+
+
+def key_names(n_words: int) -> tuple:
+    """The compact tables that hold a key's words, word 0 first."""
+    return ("_keys",) + tuple(f"_keys{w}" for w in range(1, n_words))
+
+
+def build_group_key64(ids, sizes, xp, words=None):
+    """Mixed-radix combine into int64. Callers guard product < 2^62, or
+    hand `words` (`pack_key_words` of `sizes`): then a tuple of keys, a
+    word, each the combine of its positions' ids under `key_radix`."""
+    def combine(ids, radix):
+        key = None
+        for i, s in zip(ids, radix):
+            i = i.astype(xp.int64)
+            key = i if key is None else key * xp.int64(s) + i
+        return xp.zeros((), xp.int64) if key is None else key
+
+    total = 1
+    for s in sizes:
+        total *= int(s)
+    if words is not None and len(words) > 1:
+        radix = key_radix(sizes, words)
+        return tuple(combine([ids[i] for i in w], [radix[i] for i in w])
+                     for w in words), total
+    if total >= (1 << KEY_WORD_BITS):
         raise UnsupportedAggregation(
             f"group space {total} overflows the int64 key")
-    key = None
-    for i, s in zip(ids, sizes):
-        i = i.astype(xp.int64)
-        key = i if key is None else key * xp.int64(s) + i
-    if key is None:
-        key = xp.zeros((), xp.int64)
-    return key, total
+    return combine(ids, sizes), total
+
+
+def _key_words(key) -> tuple:
+    """A key as the tuple of its words (one array: one word)."""
+    return tuple(key) if isinstance(key, (tuple, list)) else (key,)
+
+
+def _changes(skeys):
+    """[N-1] bool: where a sorted key differs from the row before it, in
+    any word."""
+    import functools
+    import operator
+    return functools.reduce(operator.or_,
+                            [w[1:] != w[:-1] for w in skeys])
 
 
 def _running(x, kind: str, axis=0):
@@ -113,15 +198,19 @@ def _running(x, kind: str, axis=0):
 
 def _sorted_segments(skey, cap, xp):
     """boundary/gid/count core shared by row reduction and table merge:
-    gid clips into the dropped overflow+sentinel slot `cap`."""
+    gid clips into the dropped overflow+sentinel slot `cap`. `skey`: the
+    sorted key, an array or a tuple of its words (a masked row holds the
+    SENTINEL in word 0; what its other words hold starts runs in the tail
+    that no slot keeps)."""
+    skeys = _key_words(skey)
     boundary = xp.concatenate([
         xp.ones((1,), bool),
-        skey[1:] != skey[:-1],
+        _changes(skeys),
     ])
     flags = boundary.astype(xp.int32)
     gid = (np.cumsum(flags) if xp is np else _running(flags, "add")) - 1
-    count = (boundary & (skey != SENTINEL)).sum(dtype=xp.int32)
-    gid = xp.where((gid < cap) & (skey != SENTINEL), gid, cap)
+    count = (boundary & (skeys[0] != SENTINEL)).sum(dtype=xp.int32)
+    gid = xp.where((gid < cap) & (skeys[0] != SENTINEL), gid, cap)
     return gid, count
 
 
@@ -147,7 +236,8 @@ def _seg_ext(v, gid, cap, kind, xp):
 
 def _run_starts(skey, cap, xp):
     """[cap + 1] int32: starts[g] is the first row of the g-th run of equal
-    keys; for a slot past the last present group (and for g == cap when
+    keys (`skey`: an array, or a tuple of the key's words); for a slot
+    past the last present group (and for g == cap when
     nothing overflows) the row where the SENTINEL tail begins, so an empty
     slot is an empty run. The first rows' positions, every other row
     standing in as the tail's first, sorted: a second, one-operand sort
@@ -156,10 +246,10 @@ def _run_starts(skey, cap, xp):
     slots, 1.2 s at the budget's 2^21), and no row is scattered."""
     import jax
 
-    n = skey.shape[0]
-    valid = skey != SENTINEL
-    first = valid & xp.concatenate([xp.ones((1,), bool),
-                                    skey[1:] != skey[:-1]])
+    skeys = _key_words(skey)
+    n = skeys[0].shape[0]
+    valid = skeys[0] != SENTINEL
+    first = valid & xp.concatenate([xp.ones((1,), bool), _changes(skeys)])
     tail = valid.sum(dtype=xp.int32)
     pos = xp.where(first, xp.arange(n, dtype=xp.int32), tail)
     if n < cap + 1:
@@ -328,6 +418,13 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
              "_count": [] int32 true unique count,
              "_rows": [cap], <agg name>: [cap] or [cap, m], ...}.
 
+    `key` is one [N] int64 array, or the tuple of a wide key's words
+    (`build_group_key64` with `words`): the words ride the sort as its
+    `num_keys` leading operands, a masked row holds the SENTINEL in word 0
+    alone, a run ends where any word changes, and the tables gain one a
+    further word (`key_names`: `_keys1`, ...; what they hold in an empty
+    slot is not defined: `_keys` says which slots are present).
+
     With `top` = (metric, threshold, inverted), the rows of that table a
     TopN by `metric` (a count or a sum of `plans`) keeps, as
     [min(threshold, cap)] tables in rank order: the program ranks first.
@@ -374,8 +471,9 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
             slots[name] = len(operands)
             operands.append(arr)
 
+    kwords = _key_words(key)
     with stage_scope("sort", xp):
-        operands = [xp.where(mask, key, SENTINEL)]
+        operands = [xp.where(mask, kwords[0], SENTINEL), *kwords[1:]]
         for p in plans:
             m = mask
             if p.filter_fn is not None:
@@ -425,14 +523,15 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
         # no table depends on the order of the rows inside a run, so the
         # sort need not be stable: XLA spells stability as one more
         # operand, an iota that breaks ties
-        sorted_ops = list(jax.lax.sort(tuple(operands), num_keys=1,
+        sorted_ops = list(jax.lax.sort(tuple(operands),
+                                       num_keys=len(kwords),
                                        is_stable=False))
 
-    skey = sorted_ops[0]
+    skeys = tuple(sorted_ops[:len(kwords)])
 
     with stage_scope("runs", xp):
-        gid, count = _sorted_segments(skey, cap, xp)
-        starts = _run_starts(skey, cap, xp)
+        gid, count = _sorted_segments(skeys, cap, xp)
+        starts = _run_starts(skeys, cap, xp)
 
     def run_sum(v, acc_dtype, at=None, word=None):
         """Exact integer sum of v over the runs of the slots `at` (None:
@@ -547,12 +646,16 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
     # row is in the SENTINEL tail, or out of bounds
     with stage_scope("gather", xp):
         out = {"_count": count, "_rows": kept(rows, at),
-               "_keys": skey.at[starts[:cap] if at is None else starts[at]]
+               "_keys": skeys[0]
+               .at[starts[:cap] if at is None else starts[at]]
                .get(mode="fill", fill_value=SENTINEL)}
         if live is not None:
             out["_kept"] = n_kept
             out["_rows"] = xp.where(live, out["_rows"], 0)
             out["_keys"] = xp.where(live, out["_keys"], SENTINEL)
+        for name, w in zip(key_names(len(kwords))[1:], skeys[1:]):
+            out[name] = w.at[starts[:cap] if at is None else starts[at]] \
+                .get(mode="fill", fill_value=0)
     if narrowed:
         # |a run's sum| <= its rows x the column's largest |value|: where
         # that fits int32 for the longest run, every wrapped difference
@@ -640,18 +743,22 @@ def cap_tables(plans, col_dtypes, cap, top=None, nullable=(),
 
 def sparse_group_count(key, mask, xp):
     """{"_count": [] int32}: the groups present among the unmasked rows,
-    and nothing else: a one-operand sort of the key and its run
-    boundaries, no table. What the runner asks before it sizes the first
+    and nothing else: a sort of the key alone (one operand, or one a word
+    of a wide key) and its run boundaries, no table. What the runner asks before it sizes the first
     compact table of a group space past the budget, whose count it has no
     hint of: a cap attempt that overflows compiles the whole multi-operand
     sort program only to learn this number."""
     import jax
 
+    words = _key_words(key)
     with stage_scope("sort", xp):
-        skey = jax.lax.sort(xp.where(mask, key, SENTINEL), is_stable=False)
+        skeys = jax.lax.sort(
+            (xp.where(mask, words[0], SENTINEL), *words[1:]),
+            num_keys=len(words), is_stable=False)
     with stage_scope("runs", xp):
-        first = xp.concatenate([xp.ones((1,), bool), skey[1:] != skey[:-1]])
-        return {"_count": (first & (skey != SENTINEL)).sum(dtype=xp.int32)}
+        first = xp.concatenate([xp.ones((1,), bool), _changes(skeys)])
+        return {"_count": (first & (skeys[0] != SENTINEL))
+                .sum(dtype=xp.int32)}
 
 
 def compile_having(spec, plans, pool):
