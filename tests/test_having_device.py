@@ -396,3 +396,86 @@ def test_the_group_budget_refuses_legibly_or_serves(table, budget, serves):
         assert eng.runner.history[-1]["sparse_attempts"] == 1
     finally:
         eng.close()
+
+
+# ------------------------- how the tested table is read (PR 43): the counter
+
+def _boundary_sorted(eng) -> float:
+    return sum(
+        float(ln.rsplit(" ", 1)[1])
+        for ln in eng.metrics.render().splitlines()
+        if ln.startswith("tpu_olap_sparse_boundary_sorted_total"))
+
+
+def _dispatch_attrs(eng) -> dict:
+    return next(s["attrs"] for s in _walk(eng.tracer.last.to_json())
+                if s["name"] == "dispatch")
+
+
+def test_q18s_tested_sum_rides_the_sort_of_the_runs_first_rows(q18):
+    """Q18's compact table is a large share of the rows sorted (one slot
+    to three or four rows, as at SF10): the tested sum's prefix rides
+    `starts`' sort. The record and the `dispatch` span say `sorted`, and
+    the registry counts the dispatch."""
+    _seed, eng, _reference, _df = q18
+    sql = tpch_flat_having.templates()["q18_312"]
+    before = _boundary_sorted(eng)
+    eng.sql(sql)
+    rec = eng.runner.history[-1]
+    assert rec["boundary_read"] == "sorted" and rec["cap_tables"] == 1
+    assert rec["sum_word_bits"] == 32 and "narrow_fallback" not in rec
+    assert _dispatch_attrs(eng)["boundary_read"] == "sorted"
+    assert _boundary_sorted(eng) == before + 1
+
+
+def test_a_small_table_over_many_rows_is_gathered_and_not_counted(table):
+    """A hundred groups over twelve thousand rows: a HAVING's tested table
+    stays a gather after `starts`' one-operand sort, and the counter does
+    not move. A HAVING over the row count alone reads no table at the
+    boundaries and says nothing."""
+    df = table.copy()
+    df["k"] = df["k"] % 100
+    eng = _engine(df)
+    try:
+        sql = "SELECT k, sum(q) AS sq FROM t GROUP BY k HAVING sum(q) > 3000"
+        got = eng.sql(sql)
+        rec = eng.runner.history[-1]
+        want = df.groupby("k")["q"].sum()
+        assert got["sq"].tolist() == want[want > 3000].tolist()
+        assert rec["reduce_path"] == "sparse" \
+            and rec["having_where"] == "device"
+        assert rec["boundary_read"] == "gather"
+        assert _dispatch_attrs(eng)["boundary_read"] == "gather"
+        assert _boundary_sorted(eng) == 0
+        sql = "SELECT k, sum(q) AS sq FROM t GROUP BY k HAVING count(*) > 110"
+        eng.sql(sql)
+        assert "boundary_read" not in eng.runner.history[-1]
+        assert "boundary_read" not in _dispatch_attrs(eng)
+        assert _boundary_sorted(eng) == 0
+    finally:
+        eng.close()
+
+
+def test_the_counted_cap_decides_how_the_table_program_reads(table):
+    """A group space past the budget: the cap is what holds the groups a
+    run counts, so the rule is asked once the count is in. The program
+    the runner keeps for that cap is the one the record names (`sorted`
+    in its key), and a second run of the plan is that program again."""
+    eng = _engine(table, sparse_group_budget=1 << 12)
+    try:
+        sql = SELECT + "HAVING sum(q) > 120"
+        eng.sql(sql)
+        rec = eng.runner.history[-1]
+        # the count, the table, and again where the kept bucket grew
+        assert rec["sparse_attempts"] >= 2
+        assert rec["boundary_read"] == "sorted"
+        tables = [k for k in eng.runner._jit_cache
+                  if "sparse" in k and rec["sparse_cap"] in k]
+        assert tables and all(k[-1] == "sorted" for k in tables)
+        eng.sql(sql)
+        rec = eng.runner.history[-1]
+        assert rec["boundary_read"] == "sorted" and rec["jit_cache_hit"] \
+            and rec["sparse_attempts"] == 1
+        assert _boundary_sorted(eng) == 2
+    finally:
+        eng.close()

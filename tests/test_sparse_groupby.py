@@ -1326,3 +1326,234 @@ def test_sparse_gspmd_spelling_parity(monkeypatch):
     assert rec["reduce_form"] == "scatter"
     assert rec["ext_word_bits"] == (32 if rec["sparse_cap"] < 1 << 14
                                     else 64)
+
+
+# --------------------------------------------------------------------------
+# A whole [cap] table read at the sorted runs' boundaries rides `starts`'
+# sort as one more operand where the cap is a large share of the rows sorted
+# (PR 43): `sorted` against `gather`, table for table, and the rule between
+# them, which two shapes decide.
+
+# name -> (plans, the plan a `top` ranks by, the plan a `having` tests,
+#          narrow): each holds the aggregate that is read whole and a row
+# count; a min / max cannot rank, so a filtered count beside it does
+READ_AGGS = {
+    "narrow-sum": ([_agg("a", "sum", "i8"), _agg("n", "count")],
+                   "a", "a", True),
+    "wide-sum": ([_agg("a", "sum", "v"), _agg("n", "count")],
+                 "a", "a", False),
+    "filtered-count": ([_agg("a", "count", filter_fn=_positive),
+                        _agg("s", "sum", "i8")], "a", "a", False),
+    "word32-min": ([_agg("a", "min", "i8"), _agg("hi", "max", "i8"),
+                    _agg("fc", "count", filter_fn=_positive)],
+                   "fc", "a", False),
+    "word64-max": ([_agg("a", "max", "i32", filter_fn=_positive),
+                    _agg("lo", "min", "i32"),
+                    _agg("fc", "count", filter_fn=_positive)],
+                   "fc", "a", False),
+}
+# name -> (rows, cap, distinct keys, the share of rows the mask keeps)
+READ_SHAPES = {
+    "groups-past-the-cap": (300, 16, 40, 0.9),
+    "rows-under-cap-plus-one": (300, 512, 40, 0.9),
+    "every-row-masked": (300, 16, 9, 0.0),
+    "a-lone-row-at-both-ends": (300, 64, 40, 1.0),
+}
+
+
+def _read_inputs(shape, key_words):
+    n, cap, distinct, keep = READ_SHAPES[shape]
+    rng = np.random.default_rng(43)
+    key = rng.integers(1, distinct, n).astype(np.int64)
+    mask = rng.random(n) < keep
+    if keep == 1.0:
+        # the smallest and the largest key a row each, and no masked
+        # tail: sorted row 0 and sorted row n - 1 are runs of one row
+        key[5], key[77] = 0, distinct
+    env = {"cols": {
+        "i8": rng.integers(-128, 128, n).astype(np.int8),
+        "i32": rng.integers(-(1 << 31), 1 << 31, n).astype(np.int32),
+        "v": rng.integers(-(1 << 40), 1 << 40, n),
+        "f": rng.integers(-3, 4, n)}, "nulls": {}}
+    if key_words == 2:
+        # a run ends where either word changes
+        key = (key // 7, key % 7)
+    return key, mask, env, cap
+
+
+@pytest.mark.parametrize("key_words", [1, 2], ids=["one-word", "two-words"])
+@pytest.mark.parametrize("cut", ["uncut", "top", "having"])
+@pytest.mark.parametrize("shape", sorted(READ_SHAPES))
+@pytest.mark.parametrize("aggs", sorted(READ_AGGS))
+def test_sorted_boundary_reads_equal_the_gathers(aggs, shape, cut,
+                                                 key_words):
+    """Every key of the output, the two spellings side by side: a narrow
+    and a wide integer sum, a filtered count, a 32-bit and a 64-bit min /
+    max word, each read whole (uncut, or tested by a HAVING) or beside the
+    table a TopN ranks by; groups past the cap (the overflowing attempt's
+    tables too), fewer rows than cap + 1, no unmasked row, a run of one
+    row at the first and at the last sorted row; negative values
+    throughout."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_olap.kernels import sparse_groupby as sg
+    EngineConfig().apply_x64()
+    plans, ranked, tested, narrow = READ_AGGS[aggs]
+    key, mask, env, cap = _read_inputs(shape, key_words)
+    top = (ranked, 10, False) if cut == "top" else None
+    having = (lambda t, c: t[tested][0].astype(np.int64) > -5,
+              frozenset({tested}), 32) if cut == "having" else None
+    stored = {c: a.dtype for c, a in env["cols"].items()}
+    # each reads a whole table at the boundaries: the rule has a say
+    assert sg.boundary_read(plans, stored, cap, mask.shape[0], top, (),
+                            having and having[1]) is not None
+    n = mask.shape[0]
+
+    def run(spelling):
+        return jax.device_get(jax.jit(
+            lambda k, m, e: sg.sparse_group_reduce(
+                k, m, e, plans, cap, {}, jnp, top, having, narrow,
+                spelling))(key, mask, env))
+
+    got, want = run("sorted"), run("gather")
+    assert set(got) == set(want)
+    assert (int(want["_count"]) > cap) == (shape == "groups-past-the-cap")
+    for name, table in want.items():
+        assert got[name].dtype == table.dtype, name
+        np.testing.assert_array_equal(got[name], table, err_msg=name)
+    if narrow:
+        assert bool(want["_narrow_ok"])
+    if shape == "a-lone-row-at-both-ends" and cut == "uncut":
+        assert want["_rows"][0] == 1 \
+            and want["_rows"][int(want["_count"]) - 1] == 1
+        assert want["_rows"].sum() == n
+
+
+def _position_sorts(fn, *args):
+    """The operand counts of the sorts by an int32 key anywhere in fn's
+    jaxpr: `starts`' (and a HAVING's compaction), not the main sort,
+    whose first key is an int64 word."""
+    import jax
+
+    def sorts(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "sort" \
+                    and str(eqn.invars[0].aval.dtype) == "int32":
+                assert eqn.params["num_keys"] == 1
+                yield len(eqn.invars)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from sorts(sub)
+    return sorted(sorts(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+def test_the_rule_is_rows_a_slot_and_the_gather_side_keeps_its_program():
+    """`sorted` up to 8 rows a slot of the compact table, `gather` one row
+    past it; a q3-shaped plan (an int64 sum of a virtual column and the row
+    count, no cut, one key word) on the `gather` side lowers to the text it
+    has with no rule at all, with `starts` a one-operand sort: no edit
+    moves the programs of the cells whose shapes keep them there (q3 / q10,
+    the Druid TopNs, `q10p`) unseen. At the rule's edge the sum's int64
+    prefix rides as a second operand and the program gathers no cap-sized
+    table but `_keys`."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_olap.kernels import sparse_groupby as sg
+    EngineConfig().apply_x64()
+    assert sg.BOUNDARY_SORT_MAX_ROWS_PER_SLOT == 8
+    cap = 63
+    assert sg.boundary_spelling(8 * (cap + 1), cap) == "sorted"
+    assert sg.boundary_spelling(8 * (cap + 1) + 1, cap) == "gather"
+    assert sg.boundary_spelling(62_062_592, (1 << 24)) == "sorted"   # Q18
+    assert sg.boundary_spelling(20_450_073, 2_000_001) == "gather"
+    plans = [_agg("revenue", "sum", "v"), _agg("n", "count")]
+    stored = {}   # a virtual column: materialised in 64 bits
+
+    def program(n, ruled=True):
+        env = {"cols": {"v": jnp.arange(n, dtype=jnp.int64)}, "nulls": {}}
+        args = (jnp.arange(n, dtype=jnp.int64) % 50, jnp.ones(n, bool), env)
+        read = sg.boundary_read(plans, stored, cap, n) if ruled else None
+
+        def fn(k, m, e):
+            return sg.sparse_group_reduce(k, m, e, plans, cap, {}, jnp,
+                                          boundary=read)
+        return read, _position_sorts(fn, *args), jax.jit(fn).lower(*args)
+
+    read, sorts, lowered = program(8 * (cap + 1))
+    assert (read, sorts) == ("sorted", [2])
+    assert _cap_sized_gathers(lowered, cap) == 1    # `_keys`, AT `starts`
+    read, sorts, lowered = program(8 * (cap + 1) + 1)
+    assert (read, sorts) == ("gather", [1])
+    assert _cap_sized_gathers(lowered, cap) == 2 \
+        == sg.cap_tables(plans, stored, cap)
+    assert program(8 * (cap + 1) + 1, ruled=False)[2].as_text() \
+        == lowered.as_text()
+
+
+@pytest.mark.parametrize("plans,top,having,nullable,reads", [
+    ([("n", "count")], None, None, (), False),         # `_rows` alone
+    ([("fs", "sum", "w", np.float64)], None, None, (), False),   # scatter
+    ([("lo", "min", "v")], None, None, (), False),     # 64-bit: scatter
+    ([("lo", "min", "v")], None, None, ("v",), True),  # its non-null count
+    ([("lo", "min", "d")], None, None, (), True),      # a running word
+    ([("s", "sum", "v"), ("n", "count")], None, None, (), True),
+    # a cut reads whole what it is decided from, and nothing else
+    ([("s", "sum", "v"), ("n", "count")], ("n", 5, False), None, (), False),
+    ([("s", "sum", "v"), ("n", "count")], ("s", 5, False), None, (), True),
+    ([("s", "sum", "v"), ("n", "count")], None, {"n"}, (), False),
+    ([("s", "sum", "v"), ("lo", "min", "d")], None, {"lo"}, (), True),
+], ids=["count", "float-sum", "int64-min", "nullable-int64-min", "int8-min",
+        "sum", "top-by-rows", "top-by-sum", "having-rows", "having-min"])
+def test_boundary_read_is_none_where_no_whole_table_is_read_there(
+        plans, top, having, nullable, reads):
+    from tpu_olap.kernels.sparse_groupby import boundary_read
+    plans = [_agg(*a) for a in plans]
+    stored = {"v": np.dtype(np.int64), "w": np.dtype(np.float64),
+              "d": np.dtype(np.int8)}
+    for n, spelling in ((100, "sorted"), (100_000, "gather")):
+        assert boundary_read(plans, stored, 64, n, top, nullable,
+                             having) == (spelling if reads else None)
+
+
+@pytest.mark.parametrize("gspmd", [False, True],
+                         ids=["a-chip-each", "gspmd"])
+def test_a_meshs_record_says_how_its_programs_rows_and_cap_read(
+        monkeypatch, gspmd):
+    """On a mesh the rule sees what the program sees: a chip's share of
+    the rows where the one-chip program is mapped over the chips, all of
+    them where GSPMD partitions one program. The record's `boundary_read`
+    is the kernel's own function of those rows and the cap, and the
+    program the runner built and keeps carries a rider on its `starts`
+    sort exactly where the record says `sorted`."""
+    from tpu_olap.executor import sharding as sh
+    from tpu_olap.kernels import sparse_groupby as sg
+    if gspmd:
+        monkeypatch.setattr(sh, "is_multihost", lambda mesh: True)
+    eng = _engine(num_shards=8)
+    check_query(eng, SQL)
+    rec = eng.history[-1]
+    assert rec["sparse"] and rec["num_shards"] == 8
+    plan = eng.planner.plan(SQL)
+    segments, block_rows = eng.runner._dataset(plan.entry.segments).shape
+    rows = segments * block_rows // (1 if gspmd else 8)
+    cap, read = rec["sparse_cap"], rec["boundary_read"]
+    assert read == sg.boundary_spelling(rows, cap)
+    phys = eng.runner._lower_cached(plan.query, plan.entry.segments)
+    env, valid, seg_mask = eng.runner._prepare(phys, {})
+    consts_dev, seg_arg = eng.runner._args_for(phys, seg_mask,
+                                               eng.runner.mesh)
+    key = eng.runner._sparse_key(phys, 8) \
+        + ("gspmd" if gspmd else "mesh", cap) \
+        + (("sorted",) if read == "sorted" else ())
+    sorts = _position_sorts(eng.runner._jit_cache[key], env, valid, seg_arg,
+                            consts_dev)
+    assert (max(sorts) > 1) == (read == "sorted"), sorts
+    if not gspmd:
+        # and the other spelling of the same cap, said to the mapped
+        # program as the runner says it
+        other = "gather" if read == "sorted" else "sorted"
+        sorts = _position_sorts(
+            sh.mesh_sparse_kernel(phys, eng.runner.mesh, cap, other),
+            env, valid, seg_arg, consts_dev)
+        assert (max(sorts) > 1) == (other == "sorted"), sorts

@@ -338,7 +338,10 @@ def test_sparse_having_program_compiles_for_v5e(topo, no_persistent_cache,
     """Q18 of `tpch-flat-sf10-having-chip`, as the chip runs it: the sparse
     program with the HAVING at its end (compiled on small shapes under a
     cap of 2^16 slots; the cell's is 2^24, whose word is checked). The tested
-    sum's prefix is the one table gathered at [cap]; max(o_totalprice)
+    sum's prefix is the one table read at [cap], and at the cell's 3.7 rows
+    a slot (one here) it rides `starts`' sort as a second operand (PR 43):
+    no cap-sized gather is left; with the rule turned off it is ONE gather
+    of an s32. max(o_totalprice)
     rides the sort as int32 and is read from an int64 running maximum (25
     + 32 + 1 bits) at the kept rows; three sorts (the rows, `starts`, the
     passing slots), no scatter, `kept` rows a table out. The count probe
@@ -347,6 +350,7 @@ def test_sparse_having_program_compiles_for_v5e(topo, no_persistent_cache,
     from jax.sharding import SingleDeviceSharding
 
     from perfbench.datasets import tpch_flat_having
+    from tpu_olap.kernels import sparse_groupby as sg
     from tpu_olap.kernels.sparse_groupby import cap_tables, ext_word_bits
     _as_tpu(monkeypatch)
     rows, seed, cap, kept = 60_000, 2_147_483_659, 1 << 24, 1024
@@ -374,19 +378,37 @@ def test_sparse_having_program_compiles_for_v5e(topo, no_persistent_cache,
     assert ext_word_bits(phys.agg_plans, stored, 1 << 16) == 64
     # the narrow program, which the runner tries first (PR 41): the
     # tested sum(l_quantity), stored as int8, rides as one int32 word
-    kernel = phys.make_sparse_kernel(1 << 16, None, kept, True)
+    # as the runner builds it: the rule's answer for these rows and cap
+    read = sg.boundary_read(phys.agg_plans, stored, 1 << 16, valid.size,
+                            having=phys.having[1])
+    assert read == "sorted" == sg.boundary_spelling(62_062_592, cap)
+    kernel = phys.make_sparse_kernel(1 << 16, None, kept, True, read)
     lowered = jax.jit(kernel).lower(*args)
     main_sort = max(re.findall(r"stablehlo\.sort\"?\(([^)]*)\)",
                                lowered.as_text()), key=len)
     # key, l_quantity's sum operand, o_totalprice once
     assert len(main_sort.split(",")) == 3, main_sort
+
+    def sort_operands(lowered):
+        return sorted(len(m.split(",")) for m in re.findall(
+            r"stablehlo\.sort\"?\(([^)]*)\)", lowered.as_text()))
+
+    def cap_gathers(text, dtype):
+        return len(re.findall(
+            rf"= {dtype}\[{(1 << 16) + 1}\]\S* gather\(", text))
+    # the passing slots' sort, `starts` with the tested sum's int32 prefix
+    # as its rider, the rows
+    assert sort_operands(lowered) == [1, 2, 3]
     text = lowered.compile().as_text()
     assert " scatter(" not in text and text.count(" sort(") == 3
-    # the tested table's prefix is ONE cap-sized gather of an s32, where
+    assert not cap_gathers(text, "s32") and not cap_gathers(text, "u32")
+    # said `gather` the prefix is ONE cap-sized gather of an s32, where
     # the wide program's int64 prefix is two of a u32 half
-    assert len(re.findall(rf"= s32\[{(1 << 16) + 1}\]\S* gather\(", text)) \
-        == 1 and not re.findall(rf"= u32\[{(1 << 16) + 1}\]\S* gather\(",
-                                text)
+    gathered = jax.jit(phys.make_sparse_kernel(
+        1 << 16, None, kept, True, "gather")).lower(*args)
+    assert sort_operands(gathered) == [1, 1, 3]
+    text = gathered.compile().as_text()
+    assert cap_gathers(text, "s32") == 1 and not cap_gathers(text, "u32")
     out = jax.eval_shape(kernel, env, valid, seg_arg, consts_dev)
     assert {k: (v.shape, str(v.dtype)) for k, v in out.items()} == {
         "_count": ((), "int32"), "_kept": ((), "int32"),
@@ -408,14 +430,15 @@ def test_sparse_wide_key_program_compiles_for_v5e(topo, no_persistent_cache,
     2^62 (compiled on small shapes under a cap of 2^16 slots). The sort
     compares TWO int64 key words and carries `l_quantity`'s sum as one int32
     word; `o_totalprice` is a key, so no running maximum is left; three
-    sorts (the rows, `starts`, the passing slots), no row-sized scatter,
+    sorts (the rows, `starts` with the tested sum's prefix riding it, the
+    passing slots), no cap-sized gather, no row-sized scatter,
     and a `_keys` table a word at the kept rows. The count probe sorts the
     two words and builds no table."""
     import jax
     from jax.sharding import SingleDeviceSharding
 
     from perfbench.datasets import tpch_flat_widekey
-    from tpu_olap.kernels.sparse_groupby import cap_tables
+    from tpu_olap.kernels.sparse_groupby import boundary_read, cap_tables
     _as_tpu(monkeypatch)
     rows, seed, cap, kept = 60_000, 2_147_483_659, 1 << 24, 1024
     data = tpch_flat_widekey.generate(rows, seed, str(tmp_path), workers=1,
@@ -436,14 +459,22 @@ def test_sparse_wide_key_program_compiles_for_v5e(topo, no_persistent_cache,
     one_chip = SingleDeviceSharding(topo.devices[0])
     args = (*_scaled((env, valid, seg_arg), 1, one_chip),
             _scaled(consts_dev, 1, one_chip))
-    kernel = phys.make_sparse_kernel(1 << 16, None, kept, True)
+    read = boundary_read(phys.agg_plans, stored, 1 << 16, valid.size,
+                         having=phys.having[1])
+    assert read == "sorted"
+    kernel = phys.make_sparse_kernel(1 << 16, None, kept, True, read)
     lowered = jax.jit(kernel).lower(*args)
     main_sort = max(re.findall(r"stablehlo\.sort\"?\(([^)]*)\)",
                                lowered.as_text()), key=len)
     # two key words and l_quantity's sum operand
     assert len(main_sort.split(",")) == 3, main_sort
+    # the passing slots' sort, `starts` with the tested sum's int32 prefix
+    # as its rider (one row a slot here, 3.7 in the cell: PR 43), the rows
+    assert sorted(len(m.split(",")) for m in re.findall(
+        r"stablehlo\.sort\"?\(([^)]*)\)", lowered.as_text())) == [1, 2, 3]
     text = lowered.compile().as_text()
     assert " scatter(" not in text and text.count(" sort(") == 3
+    assert not re.findall(rf"= [su]32\[{(1 << 16) + 1}\]\S* gather\(", text)
     out = jax.eval_shape(kernel, env, valid, seg_arg, consts_dev)
     assert {k: (v.shape, str(v.dtype)) for k, v in out.items()} == {
         "_count": ((), "int32"), "_kept": ((), "int32"),

@@ -28,6 +28,12 @@ words (each further word a function of the first, so the groups and their
 order stay): the sort compares them in turn, a run ends where any changes
 and each is one more table read where the keys are; over rows and K the
 price of a key word stands beside a sum's (PERF.md section 6, PR 42).
+`--boundary-read gather sorted` runs the sparse forms with the ranked sum's
+prefix read at the runs' boundaries by a gather after `starts`' sort and as
+a second operand of it, whatever `sparse_groupby.boundary_spelling` would
+choose (`rule`, the default, leaves it the choice; the row's `boundary` says
+what ran): over `--ks` at fixed rows the two cross where
+`BOUNDARY_SORT_MAX_ROWS_PER_SLOT` rests (PERF.md section 6, PR 43).
 
     python tools/sweep_group_reduce.py --rows 59986052 --ks 2000001 \
         --dtypes int64 --tables 0 3 \
@@ -40,6 +46,10 @@ price of a key word stands beside a sum's (PERF.md section 6, PR 42).
     python tools/sweep_group_reduce.py --rows 59986052 --ks 2000001 \
         --dtypes int8 --tables 0 1 --sum-word 32 64 --key-words 1 2 3 \
         --forms sparse_topn
+
+    python tools/sweep_group_reduce.py --rows 60030976 \
+        --ks 262144 2000001 4194304 16777216 --dtypes int8 --sum-word 32 \
+        --boundary-read gather sorted --forms sparse_topn
 
     python tools/sweep_group_reduce.py                  # on the chip
     python tools/sweep_group_reduce.py --compile-only   # here, for a
@@ -111,8 +121,11 @@ def _bcast(v, key, k):
                    axis=1, dtype=v.dtype)
 
 
+BOUNDARY_READS = ("rule", "gather", "sorted")
+
+
 def _sparse_topn(v, key, k, tables=0, rank_first=True, sum_word=64,
-                 key_words=1):
+                 key_words=1, boundary=None):
     """The other side of `lowering.topn_takes_sparse`: the engine's own
     sparse reduce into a compact table of k slots (one sort whose cost
     does not depend on k, the tables read at the runs' boundaries) with
@@ -127,8 +140,9 @@ def _sparse_topn(v, key, k, tables=0, rank_first=True, sum_word=64,
     for the narrow program: a sum of a column stored in 32 bits or fewer
     rides as one int32 word. `key_words` past 1 hands the key as that many
     int64 words, the further ones functions of the first: the same groups
-    in the same order, sorted by every word. Returns the ranked sum at the
-    kept rows, their keys, the other tables' kept rows and, of a narrow
+    in the same order, sorted by every word. `boundary` is the program's
+    static argument of that name (the runner's `boundary_read`). Returns
+    the ranked sum at the kept rows, their keys, the other tables' kept rows and, of a narrow
     program, `_narrow_ok` last."""
     from tpu_olap.kernels.sparse_groupby import (SENTINEL,
                                                  sparse_group_reduce)
@@ -145,7 +159,7 @@ def _sparse_topn(v, key, k, tables=0, rank_first=True, sum_word=64,
     out = sparse_group_reduce(key, jnp.ones(v.shape, bool),
                               {"cols": cols, "nulls": {}}, plans, k, {},
                               jnp, top if rank_first else None,
-                              narrow=sum_word == 32)
+                              narrow=sum_word == 32, boundary=boundary)
     ok = (out.pop("_narrow_ok"),) if "_narrow_ok" in out else ()
     if not rank_first:
         order, _ = top_k_groups(out["v"], out["_keys"] != SENTINEL,
@@ -280,15 +294,17 @@ class WordTooNarrow(ValueError):
 
 def _cummax_word(v, gid, cap, kind, stored, word):
     """`sparse_group_reduce`'s read of a stored integer min / max (its
-    `_run_ext`), the word's width forced: the running maximum of
-    (run id << b) | code, read at each run's last row."""
+    `_ext_running` and `_ext_value`), the word's width forced: the running
+    maximum of (run id << b) | code, read at each run's last row."""
     b = 8 * np.dtype(stored).itemsize + 1
     if cap.bit_length() + b > 8 * np.dtype(word).itemsize - 1:
         raise WordTooNarrow(f"{cap.bit_length()} bits of run id over {b} "
                             f"of code do not fit an {word} word")
     starts = _starts_sort(gid, cap)
-    table = sparse_groupby._run_ext(v, None, gid, starts, kind, stored,
-                                    np.dtype(word))
+    running = sparse_groupby._ext_running(v, None, gid, kind, stored,
+                                          np.dtype(word))
+    table = sparse_groupby._ext_value(
+        running[jnp.maximum(starts[1:] - 1, 0)], kind, stored)
     return jnp.where(starts[1:] > starts[:-1], table.astype(jnp.int32),
                      groupby._ident(np.dtype(np.int32), kind))
 
@@ -424,6 +440,12 @@ def main():
     ap.add_argument("--key-words", type=int, nargs="*", default=[1],
                     help="the sparse TopN forms' key as this many int64 "
                          "words: sort keys, boundary tests and key tables")
+    ap.add_argument("--boundary-read", nargs="*", default=["rule"],
+                    choices=BOUNDARY_READS,
+                    help="the sparse TopN forms' read of the ranked sum's "
+                         "prefix at the runs' boundaries: a gather after "
+                         "`starts`' sort, a second operand of it, or what "
+                         "the engine's rule picks from rows and k")
     ap.add_argument("--tables", type=int, nargs="*", default=[0],
                     help="the sparse TopN forms' integer sums beside the "
                          "ranked one: a boundary table each")
@@ -474,8 +496,8 @@ def sweep_dense(args, sharding):
                 spec = [jax.ShapeDtypeStruct((n,), np.dtype(d),
                                              sharding=sharding)
                         for d in (dtype, "int32")]
-                for name, block, tables, word, words in [
-                        (f, b, t, w, kw) for f in args.forms
+                for name, block, tables, word, words, read in [
+                        (f, b, t, w, kw, br) for f in args.forms
                         for b in (args.block_bytes
                                   if f == "compare" else [0])
                         for t in (args.tables
@@ -483,6 +505,8 @@ def sweep_dense(args, sharding):
                         for w in (args.sum_word
                                   if f in SPARSE_FORMS else [None])
                         for kw in (args.key_words
+                                   if f in SPARSE_FORMS else [None])
+                        for br in (args.boundary_read
                                    if f in SPARSE_FORMS else [None])]:
                     if dtype == "int8" and name not in SPARSE_FORMS:
                         continue   # the dense forms sum at v's own width
@@ -491,6 +515,9 @@ def sweep_dense(args, sharding):
                     more = {} if tables is None else {
                         "tables": tables, "sum_word": word,
                         "key_words": words}
+                    if read is not None:
+                        more["boundary"] = read if read != "rule" else \
+                            sparse_groupby.boundary_spelling(n, k)
                     out.append(_measure(
                         functools.partial(FORMS[name], k=k, **more), spec,
                         inputs, want, args.reps,

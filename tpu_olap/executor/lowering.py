@@ -602,7 +602,8 @@ def _lower_agg(query, table, config) -> PhysicalPlan:
         from tpu_olap.kernels.sparse_groupby import compile_having
         having = compile_having(query.having, sparse_agg_plans, pool)
 
-    def make_sparse_kernel(cap, top=None, kept=None, narrow=False):
+    def make_sparse_kernel(cap, top=None, kept=None, narrow=False,
+                           boundary=None):
         """The sparse program for a compact table of `cap` slots; with
         `top` = (metric, threshold, inverted) the table's rows that a TopN
         keeps, the threshold applied on the device: that program ranks
@@ -614,6 +615,9 @@ def _lower_agg(query, table, config) -> PhysicalPlan:
         whose integer sums of columns stored in 32 bits or fewer ride as
         one int32 word, and which says in `_narrow_ok` whether every
         group's sum fits one (the same program where no sum is such).
+        `boundary`: the caller's `sparse_groupby.boundary_read` of the
+        cap and the rows the program will sort ("sorted": the whole [cap]
+        tables read at the runs' boundaries ride `starts`' sort).
         Where the plan's key is several words (`key_words`) every one of
         these programs sorts by them all and gives a `_keys` table a
         word."""
@@ -633,7 +637,8 @@ def _lower_agg(query, table, config) -> PhysicalPlan:
                 return sparse_group_count(key, mask, xp)
             return sparse_group_reduce(
                 key, mask, fenv, sparse_agg_plans, cap, consts, xp, top,
-                None if kept is None else having + (kept,), narrow)
+                None if kept is None else having + (kept,), narrow,
+                boundary)
         return sparse_kernel
 
     def build(sparse: bool) -> PhysicalPlan:

@@ -282,6 +282,12 @@ class QueryRunner:
             "sparse_wide_key_queries_total",
             "Sparse dispatches whose group space is 2^62 or more: the key "
             "rode the sort as more than one int64 word.")
+        self._m_boundary_sorted = m.counter(
+            "sparse_boundary_sorted_total",
+            "Sparse dispatches whose program read its whole [cap] tables "
+            "at the sorted runs' boundaries as operands of the sort that "
+            "finds the runs' first rows (boundary_read: sorted), not as "
+            "gathers after it.")
         self._m_recompile = m.counter(
             "recompiles_total",
             "Device executables built (jit-cache misses), by dispatch "
@@ -2023,6 +2029,14 @@ class QueryRunner:
             return min(local_limit, self.config.sparse_group_cap) \
                 if hint is None else _grown_cap(hint, local_limit)
 
+        def boundary(cap, rows):
+            # how the program of `cap` over `rows` sorted rows reads its
+            # whole [cap] tables: the program's static argument and the
+            # record's word are this one answer
+            return sg.boundary_read(
+                plan.agg_plans, stored, cap, rows, top, nullable,
+                plan.having[1] if having else None)
+
         hint = self._cap_hints.get(base_key)
         cap = first_cap(hint)
         # the HAVING's bucket: the power of two that holds the most
@@ -2059,19 +2073,25 @@ class QueryRunner:
             def run(cap, kept=None, narrow=False):
                 """Build (once a key, a counted compile) and enqueue the
                 program of `cap` (None: the count alone); call under the
-                enqueue lock. -> (its output tree, jit cache hit)"""
+                enqueue lock. -> (its output tree, jit cache hit, its
+                `boundary_read`)"""
                 consts_dev, seg_arg = self._args_for(plan, seg_mask, None)
+                read = None if cap is None else boundary(
+                    cap, (win[1] if win else valid.shape[0])
+                    * valid.shape[1])
                 key = base_key + (cap,) \
                     + ((win[1],) if win else ()) \
                     + (("top",) if top else ()) \
                     + (("having", kept) if kept else ()) \
-                    + (("narrow",) if narrow else ())
+                    + (("narrow",) if narrow else ()) \
+                    + (("sorted",) if read == "sorted" else ())
                 jitted = self._jit_cache.get(key)
                 hit = jitted is not None
                 if hit:
                     _cache_lru_hit(self._jit_cache, key)
                 else:
-                    kern = plan.make_sparse_kernel(cap, top, kept, narrow)
+                    kern = plan.make_sparse_kernel(cap, top, kept, narrow,
+                                                   read)
                     if win is not None:
                         jitted = jax.jit(
                             self._window_kernel(kern, win[1]))
@@ -2082,7 +2102,7 @@ class QueryRunner:
                 out = jitted(env, valid, seg_arg, consts_dev,
                              win[0]) if win is not None else \
                     jitted(env, valid, seg_arg, consts_dev)
-                return out, hit
+                return out, hit, read
 
             try:
                 if hint is None and not whole_space:
@@ -2092,7 +2112,7 @@ class QueryRunner:
                     attempts += 1
                     with _span("sparse-count", key_words=n_words) as sp:
                         with self._enqueue_lock(metrics):
-                            out, hit = run(None)
+                            out, hit, _ = run(None)
                         count = int(out["_count"])
                         sp.set(present_groups=count, jit_cache_hit=hit)
                     if count > cap_limit:
@@ -2104,7 +2124,7 @@ class QueryRunner:
                     with _span("sparse-attempt", cap=cap,
                                key_words=n_words) as sp:
                         with self._enqueue_lock(metrics):
-                            out, hit = run(cap, kept, narrow)
+                            out, hit, read = run(cap, kept, narrow)
                             prev, pin = pin, self._pin_inflight(out)
                         if prev is not None:
                             self._hbm_ledger.unpin_inflight(prev)
@@ -2176,14 +2196,17 @@ class QueryRunner:
                         with self._enqueue_lock(metrics):
                             consts_dev, seg_arg = self._args_for(
                                 plan, seg_mask, mesh)
-                            key = base_key + ("gspmd", cap)
+                            read = boundary(cap, valid.size)
+                            key = base_key + ("gspmd", cap) \
+                                + (("sorted",) if read == "sorted" else ())
                             jitted = self._jit_cache.get(key)
                             hit = jitted is not None
                             if hit:
                                 _cache_lru_hit(self._jit_cache, key)
                             else:
                                 jitted = jax.jit(
-                                    plan.make_sparse_kernel(cap),
+                                    plan.make_sparse_kernel(
+                                        cap, boundary=read),
                                     out_shardings=sh.replicated_spec(
                                         mesh))
                                 self._jit_cache[key] = jitted
@@ -2212,7 +2235,7 @@ class QueryRunner:
                     (time.perf_counter() - t0) * 1000
                 metrics["jit_cache_hit"] = hit
                 self._note_sparse(metrics, plan, stored, nullable, attempts,
-                                  cap, count)
+                                  cap, count, read)
                 return out, count
             lhint = self._cap_hints.get(base_key + ("local",))
             if lhint is not None:
@@ -2226,14 +2249,17 @@ class QueryRunner:
                         with self._enqueue_lock(metrics):
                             consts_dev, seg_arg = self._args_for(
                                 plan, seg_mask, mesh)
-                            key = base_key + ("mesh", cap)
+                            # a chip sorts its own share of the rows
+                            read = boundary(cap, valid.size // n_shards)
+                            key = base_key + ("mesh", cap) \
+                                + (("sorted",) if read == "sorted" else ())
                             jitted = self._jit_cache.get(key)
                             hit = jitted is not None
                             if hit:
                                 _cache_lru_hit(self._jit_cache, key)
                             else:
-                                jitted = sh.mesh_sparse_kernel(plan, mesh,
-                                                               cap)
+                                jitted = sh.mesh_sparse_kernel(
+                                    plan, mesh, cap, read)
                                 self._jit_cache[key] = jitted
                                 self._note_compile("sparse", metrics)
                             out = jitted(env, valid, seg_arg, consts_dev)
@@ -2351,8 +2377,8 @@ class QueryRunner:
         metrics["execute_ms"] = (time.perf_counter() - t0) * 1000
         metrics["jit_cache_hit"] = hit
         self._note_sparse(metrics, plan, stored, nullable, attempts, cap,
-                          count, top, plan.having[1] if having else None,
-                          narrow)
+                          count, read, top,
+                          plan.having[1] if having else None, narrow)
         return out, count
 
     @staticmethod
@@ -2362,10 +2388,9 @@ class QueryRunner:
         the narrow program) are remembered under."""
         return plan.fingerprint() + ("sparse", n_shards)
 
-    @staticmethod
-    def _note_sparse(metrics: dict, plan, stored: dict, nullable,
-                     attempts: int, cap: int, count: int, top=None,
-                     having=None, narrow=False):
+    def _note_sparse(self, metrics: dict, plan, stored: dict, nullable,
+                     attempts: int, cap: int, count: int, read,
+                     top=None, having=None, narrow=False):
         """The sparse dispatch's counters on the record: how many cap
         attempts ran (1 once the template's hint is warm), the compact
         table's final cap, and the groups present in it; and which
@@ -2379,7 +2404,11 @@ class QueryRunner:
         the program that answered was the narrow one): the kernel's own
         functions of the plan's aggregate kinds and dtypes, the columns'
         stored dtypes (`nullable`: those with a null mask), the cap and
-        the cut, as the dense `reduce_form` is of num_groups. And the
+        the cut, as the dense `reduce_form` is of num_groups; and
+        `read`, what the program was built with (`boundary_read`: its
+        whole [cap] tables at the runs' boundaries as operands of
+        `starts`' sort, `sorted`, which the registry counts, or `gather`;
+        None, and absent, where it read none there). And the
         key: how many int64 words it rode the sort as (`key_words`: 1
         under a group space of 2^62) and the bits its dimensions' ids
         take together (`key_bits`)."""
@@ -2394,6 +2423,10 @@ class QueryRunner:
             metrics["sum_word_bits"] = bits
         metrics["cap_tables"] = sg.cap_tables(plan.agg_plans, stored, cap,
                                               top, nullable, having)
+        if read is not None:
+            metrics["boundary_read"] = read
+        if read == "sorted":
+            self._m_boundary_sorted.inc()
         metrics["key_words"] = len(plan.key_words)
         metrics["key_bits"] = sg.key_bits(plan.sizes)
         metrics["sparse"] = True
@@ -3267,13 +3300,14 @@ def _form_attr(metrics: dict) -> dict:
     """The `dispatch` span's `reduce_form` attribute, where the record of
     the query has one (a generic grouped aggregate on the device), and
     beside it a sparse min / max's `ext_word_bits`, a sparse integer
-    sum's `sum_word_bits`, the sparse program's `cap_tables`, who
+    sum's `sum_word_bits`, the sparse program's `cap_tables` and
+    `boundary_read`, who
     decides a GroupBy's HAVING (`having_where`) and the sparse key's
     `key_words` and `key_bits`."""
     return {k: metrics[k]
             for k in ("reduce_form", "ext_word_bits", "sum_word_bits",
-                      "cap_tables", "having_where", "key_words",
-                      "key_bits")
+                      "cap_tables", "boundary_read", "having_where",
+                      "key_words", "key_bits")
             if metrics.get(k) is not None}
 
 

@@ -151,15 +151,16 @@ def replicate_put(arr, mesh: Mesh):
     return jax.device_put(arr, replicated_spec(mesh))
 
 
-def mesh_sparse_kernel(plan, mesh: Mesh, cap: int):
+def mesh_sparse_kernel(plan, mesh: Mesh, cap: int, boundary=None):
     """Jitted sparse group-by over the mesh: the one-chip program
-    (`plan.make_sparse_kernel(cap)`: key, sort, the [cap] tables read at
-    the runs' boundaries) `jax.shard_map`ped over the chip axis, as
+    (`plan.make_sparse_kernel(cap, boundary=boundary)`: key, sort, the
+    [cap] tables read at the runs' boundaries, `boundary` said of a chip's
+    share of the rows) `jax.shard_map`ped over the chip axis, as
     mesh_agg_kernel maps the dense one. Each chip compacts its own rows;
     out_specs=P(chips) lays the D tables end to end as [D·cap, ...] and
     the D true counts as `_count` [D], a chip each. No collective is in
     the program, and it compiles once a cap whatever the mesh's size."""
-    local = plan.make_sparse_kernel(cap)
+    local = plan.make_sparse_kernel(cap, boundary=boundary)
 
     def per_chip(env, valid, seg_mask, consts):
         out = local(env, valid, seg_mask, consts)

@@ -22,7 +22,13 @@ XLA's sort is fast on TPU and everything stays static-shaped:
      [0, n_unique); ids clip to a `cap` slot table (+1 overflow slot that
      also swallows the sentinel tail). After the sort a group is a
      contiguous run: `starts[g]`, the run's first row, comes from a
-     second, one-operand sort of the first rows' positions;
+     second, one-operand sort of the first rows' positions. Where the
+     compact table is a large share of the rows sorted
+     (`boundary_spelling`: up to 8 rows a slot), the prefix sums and
+     running words that step 4 reads a WHOLE [cap] table from ride that
+     sort as further operands, and its first cap + 1 outputs are the
+     read: a sort operand is paid by the row, a gather by the index
+     (`boundary_read` on the record says which);
   4. the [cap] tables are READ AT THE RUN BOUNDARIES: a row count is
      starts[g+1] - starts[g], a key is the run's first row's, an integer
      sum or count is the difference of an inclusive prefix sum at the
@@ -234,8 +240,9 @@ def _seg_ext(v, gid, cap, kind, xp):
     return f(v, gid, num_segments=cap + 1)[:cap]
 
 
-def _run_starts(skey, cap, xp):
-    """[cap + 1] int32: starts[g] is the first row of the g-th run of equal
+def _run_starts(skey, cap, xp, riders=()):
+    """-> (starts, rode). starts: [cap + 1] int32, starts[g] the first row
+    of the g-th run of equal
     keys (`skey`: an array, or a tuple of the key's words); for a slot
     past the last present group (and for g == cap when
     nothing overflows) the row where the SENTINEL tail begins, so an empty
@@ -243,7 +250,20 @@ def _run_starts(skey, cap, xp):
     standing in as the tail's first, sorted: a second, one-operand sort
     whose cost does not depend on cap (a binary search of the slot
     numbers in the run ids costs 21 ns a slot a round: less up to 2^16
-    slots, 1.2 s at the budget's 2^21), and no row is scattered."""
+    slots, 1.2 s at the budget's 2^21), and no row is scattered.
+
+    rode: what `riders` hold there, [N] arrays a whole [cap] table is
+    read from at the runs' boundaries (a prefix sum, a running word),
+    each as its [cap + 1] values at the row before each of `starts`, 0
+    before row 0. A gather at ascending indices that a sort has just
+    compacted IS a compaction, so each rides that sort as one more
+    operand: shifted by
+    one row, and on every row that is not a run's first (and in the
+    padding) its value before the tail's first row, which is what a slot
+    past the present groups reads. Rows that tie on the position carry
+    the same fill, so the order an unstable sort leaves them in cannot
+    show. No index is paid for; an operand over the rows sorted is
+    (`BOUNDARY_SORT_MAX_ROWS_PER_SLOT`)."""
     import jax
 
     skeys = _key_words(skey)
@@ -254,7 +274,46 @@ def _run_starts(skey, cap, xp):
     pos = xp.where(first, xp.arange(n, dtype=xp.int32), tail)
     if n < cap + 1:
         pos = xp.concatenate([pos, xp.broadcast_to(tail, (cap + 1 - n,))])
-    return jax.lax.sort(pos, is_stable=False)[:cap + 1]
+    if not riders:
+        return jax.lax.sort(pos, is_stable=False)[:cap + 1], []
+
+    def ride(x):
+        fill = xp.where(tail > 0, x[xp.maximum(tail - 1, 0)], 0)
+        before = xp.concatenate([xp.zeros((1,), x.dtype), x[:-1]])
+        x = xp.where(first, before, fill)
+        if n < cap + 1:
+            x = xp.concatenate([x, xp.broadcast_to(fill, (cap + 1 - n,))])
+        return x
+    with stage_scope("prefix", xp):
+        riders = [ride(x) for x in riders]
+    starts, *rode = jax.lax.sort((pos, *riders), num_keys=1,
+                                 is_stable=False)
+    return starts[:cap + 1], [x[:cap + 1] for x in rode]
+
+
+# Rows sorted a slot of the compact table (n / (cap + 1)) up to which a
+# whole [cap] table read at the runs' boundaries rides `starts`' sort
+# (`_run_starts`' riders) and past which it is a gather after it. A gather
+# is paid by the index, a sort operand by the row sorted. Measured on a
+# v5e over 60,030,976 rows, a narrow sum ranked (`tools/
+# sweep_group_reduce.py --boundary-read gather sorted`, PERF.md section 6,
+# PR 43), `sorted` less `gather`: +64.9 ms at 2^18 slots (229 rows a slot),
+# +39.3 at 2,000,001 (30), -0.2 at 2^22 (14.3), -238.3 at 2^24 (3.6): the
+# operand costs ~68 ms whatever the cap and the two cross at 14 rows a
+# slot (an int64 table is two u32 halves gathered against two operands:
+# the same ratio by PR 41's prices, not swept). 8 leaves a margin of 1.8:
+# TPC-H Q18's 2^24 slots over 62M padded rows (3.7) ride; the Druid TopNs'
+# 2,000,001 (10.2 and 31) and q3 / q10's 2^18 (59-150) keep the gather
+# and the program they had
+BOUNDARY_SORT_MAX_ROWS_PER_SLOT = 8
+
+
+def boundary_spelling(n: int, cap: int) -> str:
+    """"sorted" | "gather": how a program over `n` sorted rows reads a
+    whole [cap] table at the runs' boundaries. Two shapes, and nothing
+    else."""
+    return "sorted" if n <= BOUNDARY_SORT_MAX_ROWS_PER_SLOT * (cap + 1) \
+        else "gather"
 
 
 # rows a block of the 64-bit running maximum: XLA:TPU compiles a
@@ -286,26 +345,29 @@ def _running_max(word):
     return jnp.maximum(inner, before[:, None]).reshape(-1)[:n]
 
 
-def _run_ext(v, counted, gid, starts, kind, col_dtype, word, at=None):
-    """Exact min / max of v over the sorted runs of the slots `at` (None:
-    every slot, [cap] values), of dtype `word` (garbage in an empty run):
-    the running maximum of (run id << b) | code(v), read at the run's last
-    row. gid does not decrease, so at that row the maximum holds the run's
-    own id above the largest code seen in the run; `counted` (None: every
-    row) is False on the rows the aggregator leaves out, which code as 0."""
+def _ext_running(v, counted, gid, kind, col_dtype, word):
+    """[N] `word`s an exact min / max of v over the sorted runs is read
+    from at a run's last row: the running maximum of
+    (run id << b) | code(v). gid does not decrease, so at that row the
+    maximum holds the run's own id above the largest code seen in the
+    run; `counted` (None: every row) is False on the rows the aggregator
+    leaves out, which code as 0."""
     import jax.numpy as jnp
 
     lim, b = np.iinfo(col_dtype), np.iinfo(col_dtype).bits + 1
-    with stage_scope("prefix", jnp):
-        v = v.astype(word)
-        code = v - lim.min + 1 if kind == "max" else lim.max - v + 1
-        if counted is not None:
-            code = jnp.where(counted, code, 0)
-        running = _running_max((gid.astype(word) << b) | code)
-    with stage_scope("gather", jnp):
-        ends = starts[1:] if at is None else starts[at + 1]
-        code = running[jnp.maximum(ends - 1, 0)] & ((1 << b) - 1)
-        return code - 1 + lim.min if kind == "max" else lim.max + 1 - code
+    v = v.astype(word)
+    code = v - lim.min + 1 if kind == "max" else lim.max - v + 1
+    if counted is not None:
+        code = jnp.where(counted, code, 0)
+    return _running_max((gid.astype(word) << b) | code)
+
+
+def _ext_value(running, kind, col_dtype):
+    """The min / max under `_ext_running`'s words as they stand at runs'
+    last rows (garbage of an empty run's)."""
+    lim, b = np.iinfo(col_dtype), np.iinfo(col_dtype).bits + 1
+    code = running & ((1 << b) - 1)
+    return code - 1 + lim.min if kind == "max" else lim.max + 1 - code
 
 
 def sparse_reduce_form(plans, col_dtypes, cap) -> str:
@@ -411,7 +473,7 @@ def sum_word_bits(plans, col_dtypes, narrow: bool):
 
 
 def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
-                        having=None, narrow=False):
+                        having=None, narrow=False, boundary=None):
     """[N] int64 keys + mask -> compacted per-group partials.
 
     Returns {"_keys": [cap] int64 (SENTINEL marks empty slots),
@@ -457,14 +519,20 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
     cap is not among `_rows`) the caller runs the wide program. A plan
     with no such sum gives the program it gives without `narrow`, text
     for text.
+
+    `boundary` is the caller's `boundary_read` of this program's plans,
+    cap and rows: with "sorted" the prefix sums and running words a whole
+    [cap] table is read from ride `starts`' sort (`_run_starts`' riders);
+    else each is a gather after it. The tables are the same to the bit.
     """
     import jax
 
     # the mask does not ride the sort: a sorted row is masked exactly
     # where its key is the SENTINEL
     slots = {}
-    words = {}   # min / max name -> (its column's operand, word, stored)
+    words = {}   # "w:" + a min / max's name -> (operand, word, stored, p)
     narrowed = []   # the sums that ride as one int32 word
+    prefixed = {}   # operand read as a prefix sum -> the prefix's word
 
     def carry(name, arr):
         if name not in slots:
@@ -482,6 +550,8 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
             if p.kind == "count":
                 if p.filter_fn is not None:
                     carry(f"m:{p.name}", m)
+                    # a count is at most N: an int32 prefix holds it
+                    prefixed[f"m:{p.name}"] = np.int32
                 continue
             if p.kind in ("sum", "min", "max"):
                 x = env["cols"][p.fields[0]]
@@ -493,6 +563,8 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
                         dt = np.dtype(np.int32)
                         narrowed.append(p.name)
                     carry(f"v:{p.name}", xp.where(mm, x, 0).astype(dt))
+                    if prefix_summed(p):
+                        prefixed[f"v:{p.name}"] = dt
                 else:
                     dt = _ext_dtype(x.dtype, p.acc_dtype)
                     word = ext_word_dtype(x.dtype, p.acc_dtype, cap)
@@ -501,7 +573,8 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
                         # out codes as 0: the column rides unfilled, once
                         # for every min and max of it
                         operand = f"x:{p.fields[0]}:{dt}"
-                        words[p.name] = (operand, word, np.dtype(x.dtype))
+                        words[f"w:{p.name}"] = (operand, word,
+                                                np.dtype(x.dtype), p)
                         carry(operand, x.astype(dt))
                     else:
                         carry(f"v:{p.name}",
@@ -512,6 +585,7 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
                         # _rows, so skip both the sort operand and the
                         # reduction
                         carry(f"nn:{p.name}", mm)
+                        prefixed[f"nn:{p.name}"] = np.int32
             elif p.kind in ("hll", "theta"):
                 h, valid = _hash_fields(env, p, m, xp, consts)
                 carry(f"h:{p.name}", h)
@@ -531,32 +605,78 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
 
     with stage_scope("runs", xp):
         gid, count = _sorted_segments(skeys, cap, xp)
-        starts = _run_starts(skeys, cap, xp)
 
-    def run_sum(v, acc_dtype, at=None, word=None):
-        """Exact integer sum of v over the runs of the slots `at` (None:
-        every slot), as `acc_dtype`: the inclusive prefix sum read at the
-        run's last row less its value before the run's first. Wrapping
-        arithmetic makes the difference exact whatever the prefix holds,
-        so long as the run's own sum fits the `word` the prefix is taken
-        in (the accumulator's unless given: a narrowed sum's int32, which
-        `_narrow_ok` answers for)."""
-        with stage_scope("prefix", xp):
-            prefix = _running(v.astype(word or acc_dtype), "add")
+    scans = {}   # name -> the [N] prefix sum or running word, built once
 
-        def before(row):
-            return xp.where(row > 0, prefix[xp.maximum(row - 1, 0)], 0)
+    def scan(name):
+        """What a table is read from at the runs' boundaries, built once:
+        the inclusive prefix sum of the sorted operand `name` in its word
+        (`prefixed`: the accumulator's, or a narrowed sum's and a count's
+        int32), or the running word of a min / max (`words`)."""
+        if name not in scans:
+            with stage_scope("prefix", xp):
+                if name in prefixed:
+                    scans[name] = _running(sorted_ops[slots[name]]
+                                           .astype(prefixed[name]), "add")
+                else:
+                    operand, word, col_dtype, p = words[name]
+                    scans[name] = _ext_running(
+                        sorted_ops[slots[operand]], counted(p), gid,
+                        p.kind, col_dtype, word)
+        return scans[name]
+
+    def counted(p):
+        # the rows a min / max leaves out; None: none
+        return sorted_ops[slots[f"nn:{p.name}"]] \
+            if f"nn:{p.name}" in slots else None
+
+    def reads(p):
+        """The scans the table of p (and its non-null count) is read
+        from; none where it is `_rows` or a segment reduce."""
+        names = (f"nn:{p.name}", f"w:{p.name}") \
+            if p.kind in ("min", "max") else \
+            (f"{'m' if p.kind == 'count' else 'v'}:{p.name}",)
+        return [n for n in names if n in prefixed or n in words]
+
+    # the scans of the tables that are read at every slot ride `starts`'
+    # sort where the caller's rule says so
+    riders = {name: scan(name)
+              for p in _decides(plans, top, having and having[1])
+              for name in reads(p)} if boundary == "sorted" else {}
+    with stage_scope("runs", xp):
+        starts, rode = _run_starts(skeys, cap, xp, list(riders.values()))
+    rode = dict(zip(riders, rode))
+
+    def before(name, row=None):
+        """The scan `name` at the row before each of `row` (None: every
+        one of `starts`, [cap + 1] values), 0 before row 0: what rode
+        `starts`' sort, else a gather."""
+        if row is None and name in rode:
+            return rode[name]
+        x, row = scans[name], starts if row is None else row
+        return xp.where(row > 0, x[xp.maximum(row - 1, 0)], 0)
+
+    def run_sum(name, acc_dtype, at=None):
+        """Exact integer sum of the sorted operand `name` over the runs
+        of the slots `at` (None: every slot), as `acc_dtype`: the
+        inclusive prefix sum read at the run's last row less its value
+        before the run's first. Wrapping arithmetic makes the difference
+        exact whatever the prefix holds, so long as the run's own sum
+        fits the word the prefix is taken in (`prefixed`: the
+        accumulator's, or a narrowed sum's int32, which `_narrow_ok`
+        answers for)."""
+        scan(name)
         with stage_scope("gather", xp):
             if at is None:
-                ends = before(starts)
+                ends = before(name)
                 total = ends[1:] - ends[:-1]
             else:
-                total = before(starts[at + 1]) - before(starts[at])
+                total = before(name, starts[at + 1]) \
+                    - before(name, starts[at])
             return total.astype(acc_dtype)
 
-    def run_count(m, at=None):
-        # a count is at most N: an int32 prefix holds it
-        return run_sum(m, np.int32, at)
+    def run_count(name, at=None):
+        return run_sum(name, np.int32, at)
 
     def segment(f, v):
         # what neither gives: XLA's segment reduce, told that the ids are
@@ -576,12 +696,11 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
         """A count's or a sum's table at the slots `at`."""
         if p.kind == "count":
             return kept(rows, at) if p.filter_fn is None else \
-                run_count(sorted_ops[slots[f"m:{p.name}"]], at)
-        v = sorted_ops[slots[f"v:{p.name}"]]
+                run_count(f"m:{p.name}", at)
         if prefix_summed(p):
-            return run_sum(v, p.acc_dtype, at,
-                           np.int32 if p.name in narrowed else None)
-        return kept(segment(jax.ops.segment_sum, v), at)
+            return run_sum(f"v:{p.name}", p.acc_dtype, at)
+        return kept(segment(jax.ops.segment_sum,
+                            sorted_ops[slots[f"v:{p.name}"]]), at)
 
     # inside a non-SENTINEL run every row is unmasked: its length is its
     # row count, and a slot is present exactly where its run is not empty
@@ -591,14 +710,20 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
     def extreme(p, rows_at, at=None):
         """A min's or a max's table at the slots `at` and its non-null
         count there (`rows_at`, the slots' row counts, where the
-        aggregator leaves no row out)."""
-        counted = sorted_ops[slots[f"nn:{p.name}"]] \
-            if f"nn:{p.name}" in slots else None
-        nn = rows_at if counted is None else run_count(counted, at)
-        if p.name in words:
-            operand, word, col_dtype = words[p.name]
-            v = _run_ext(sorted_ops[slots[operand]], counted, gid,
-                         starts, p.kind, col_dtype, word, at)
+        aggregator leaves no row out): of a column stored in 32 bits or
+        fewer the running word (`_ext_running`) at the runs' last rows,
+        garbage in an empty run."""
+        nn = rows_at if counted(p) is None else \
+            run_count(f"nn:{p.name}", at)
+        word = f"w:{p.name}"
+        if word in words:
+            running = scan(word)
+            with stage_scope("gather", xp):
+                ends = starts[1:] if at is None else starts[at + 1]
+                v = _ext_value(
+                    rode[word][1:] if at is None and word in rode else
+                    running[xp.maximum(ends - 1, 0)],
+                    p.kind, words[word][2])
         else:
             v = kept(segment(
                 jax.ops.segment_min if p.kind == "min" else
@@ -709,6 +834,31 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
     return out
 
 
+def _gathered(p) -> bool:
+    # a table of its own, not read off `_rows`
+    return p.kind != "count" or p.filter_fn is not None
+
+
+def _segment_reduced(p, col_dtypes, cap) -> bool:
+    return not prefix_summed(p) and _ext_word(p, col_dtypes, cap) is None
+
+
+def _own_count(p, nullable) -> bool:
+    # a min / max that leaves rows out counts the rest itself
+    return p.kind in ("min", "max") and (
+        p.filter_fn is not None or p.fields[0] in nullable)
+
+
+def _decides(plans, top, having) -> list:
+    """The plans whose [cap] tables a program builds whole: what a cut is
+    decided from (`top`'s ranked metric, the names a `having` reads),
+    every one where there is none."""
+    if top is None and having is None:
+        return list(plans)
+    names = {top[0]} if top is not None else set(having)
+    return [p for p in plans if p.name in names]
+
+
 def cap_tables(plans, col_dtypes, cap, top=None, nullable=(),
                having=None) -> int:
     """How many [cap]-sized tables `sparse_group_reduce`'s program gathers
@@ -721,24 +871,30 @@ def cap_tables(plans, col_dtypes, cap, top=None, nullable=(),
     `top` the ranked metric's alone, with `having` (the names its
     predicate reads) the tested aggregates', beside what still
     segment-reduces (a float sum, a 64-bit min / max, a sketch): the
-    others are read at the kept rows."""
-    def gathered(p):
-        # not read off `_rows`
-        return p.kind != "count" or p.filter_fn is not None
-
-    def segment_reduced(p):
-        return not prefix_summed(p) \
-            and _ext_word(p, col_dtypes, cap) is None
-
-    def own_count(p):
-        return p.kind in ("min", "max") and (
-            p.filter_fn is not None or p.fields[0] in nullable)
-
+    others are read at the kept rows. A table read at the boundaries
+    counts whichever way it is (`boundary_read`)."""
     if top is not None or having is not None:
-        decides = {top[0]} if top is not None else set(having)
-        return sum(1 + own_count(p) if p.name in decides and gathered(p)
-                   else int(segment_reduced(p)) for p in plans)
-    return 1 + sum(gathered(p) + own_count(p) for p in plans)
+        decides = {p.name for p in _decides(plans, top, having)}
+        return sum(1 + _own_count(p, nullable)
+                   if p.name in decides and _gathered(p)
+                   else int(_segment_reduced(p, col_dtypes, cap))
+                   for p in plans)
+    return 1 + sum(_gathered(p) + _own_count(p, nullable) for p in plans)
+
+
+def boundary_read(plans, col_dtypes, cap, n, top=None, nullable=(),
+                  having=None):
+    """"sorted" | "gather" | None: how `sparse_group_reduce`'s program
+    over `n` sorted rows reads its whole [cap] tables at the runs'
+    boundaries, from `cap_tables`' static facts and `n`: as operands of
+    `starts`' sort or as gathers after it (`boundary_spelling`). None
+    where it reads none there: every table it builds whole is `_rows`, a
+    segment reduce or (`_keys`) a read AT `starts`, which stays a
+    gather."""
+    whole = any(_own_count(p, nullable) or (
+        _gathered(p) and not _segment_reduced(p, col_dtypes, cap))
+        for p in _decides(plans, top, having))
+    return boundary_spelling(n, cap) if whole else None
 
 
 def sparse_group_count(key, mask, xp):
