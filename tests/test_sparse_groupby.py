@@ -1190,11 +1190,15 @@ def _attempt_spans(eng):
             if s["name"] == "sparse-attempt"]
 
 
-def _fallbacks(eng):
+def _counter(eng, name):
     return sum(
         float(ln.rsplit(" ", 1)[1])
         for ln in eng.metrics.render().splitlines()
-        if ln.startswith("tpu_olap_sparse_narrow_fallbacks_total"))
+        if ln.startswith(f"tpu_olap_{name}"))
+
+
+def _fallbacks(eng):
+    return _counter(eng, "sparse_narrow_fallbacks_total")
 
 
 NARROW_SQL = "SELECT k, sum(p) AS sp, sum(q) AS sq, count(*) AS n FROM t " \
@@ -1304,7 +1308,9 @@ def test_a_meshs_sparse_programs_stay_wide():
                                                eng.runner.mesh)
     args = (env, valid, seg_arg, consts_dev)
     program = sh.mesh_sparse_kernel(phys, eng.runner.mesh, 64)
-    assert _sort_operand_dtypes(program, *args) == ["int64"] * 3
+    # the sums ride wide; the key's one word (a space of a few thousand
+    # groups) rides as int32 on a mesh as on one chip (`key_word_dtypes`)
+    assert _sort_operand_dtypes(program, *args) == ["int32"] + ["int64"] * 2
     assert "_narrow_ok" not in jax.eval_shape(program, *args)
     one_chip = phys.make_sparse_kernel(64, None, None, True)
     assert "_narrow_ok" in jax.eval_shape(one_chip, *args)
@@ -1432,19 +1438,24 @@ def test_sorted_boundary_reads_equal_the_gathers(aggs, shape, cut,
 
 def _position_sorts(fn, *args):
     """The operand counts of the sorts by an int32 key anywhere in fn's
-    jaxpr: `starts`' (and a HAVING's compaction), not the main sort,
-    whose first key is an int64 word."""
+    jaxpr after the main sort, the program's first (whose first key is an
+    int64 word, or an int32 one where the key fits 31 bits): `starts`'
+    (and a HAVING's compaction)."""
     import jax
 
     def sorts(jaxpr):
         for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "sort" \
-                    and str(eqn.invars[0].aval.dtype) == "int32":
-                assert eqn.params["num_keys"] == 1
-                yield len(eqn.invars)
+            if eqn.primitive.name == "sort":
+                yield eqn
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 yield from sorts(sub)
-    return sorted(sorts(jax.make_jaxpr(fn)(*args).jaxpr))
+
+    later = list(sorts(jax.make_jaxpr(fn)(*args).jaxpr))[1:]
+    for eqn in later:
+        assert str(eqn.invars[0].aval.dtype) != "int32" \
+            or eqn.params["num_keys"] == 1
+    return sorted(len(eqn.invars) for eqn in later
+                  if str(eqn.invars[0].aval.dtype) == "int32")
 
 
 def test_the_rule_is_rows_a_slot_and_the_gather_side_keeps_its_program():
@@ -1557,3 +1568,199 @@ def test_a_meshs_record_says_how_its_programs_rows_and_cap_read(
             sh.mesh_sparse_kernel(phys, eng.runner.mesh, cap, other),
             env, valid, seg_arg, consts_dev)
         assert (max(sorts) > 1) == (other == "sorted"), sorts
+
+
+# --------------------------------------------------------------------------
+# A key word whose ids fit 31 bits rides the sort as ONE int32 operand
+# (PR 44): the width follows the domains' sizes where the program is built,
+# and the tables are the int64 program's to the bit.
+
+I32_TOP = (1 << 31) - 1
+
+
+@pytest.mark.parametrize("sizes,bits", [
+    ((I32_TOP - 1,), [32]),             # ids up to 2^31 - 3
+    ((I32_TOP,), [32]),                 # up to 2^31 - 2: the sentinel free
+    ((I32_TOP + 1,), [64]),             # an id would BE the sentinel
+    ((1, 2_000_000), [32]),
+    ((3, 715_827_882), [32]),           # a product of 2^31 - 2
+    ((2, 1 << 30), [64]),               # of 2^31
+    ((1 << 40, 1 << 22), [64]),
+    ((), [32]),                         # no dimension: the one key 0
+], ids=["2^31-2", "2^31-1", "2^31", "druid-partkey", "product-under",
+        "product-at", "62-bits", "no-ids"])
+def test_key_word_dtypes_of_a_one_word_key_is_the_exact_product(sizes, bits):
+    from tpu_olap.kernels import sparse_groupby as sg
+    words = sg.pack_key_words(sizes)
+    assert len(words) == 1
+    assert sg.key_sort_bits(sizes, words) == bits
+    assert [8 * d.itemsize for d in sg.key_word_dtypes(sizes, words)] == bits
+
+
+@pytest.mark.parametrize("sizes,words,bits", [
+    # a later word holds no sentinel: 31 bits ride as int32, 32 do not
+    ((1 << 40, 1 << 22, 1 << 31), ((0, 1), (2,)), [64, 32]),
+    ((1 << 40, 1 << 22, 1 << 32), ((0, 1), (2,)), [64, 64]),
+    ((1 << 40, 1 << 22, 1 << 20, 1 << 11), ((0, 1), (2, 3)), [64, 32]),
+    ((1 << 40, 1 << 22, 1 << 20, 1 << 12), ((0, 1), (2, 3)), [64, 64]),
+    # word 0 holds the sentinel: its largest value must stay under it,
+    # by the exact sizes and not by the bits alone
+    ((I32_TOP, 1 << 62), ((0,), (1,)), [32, 64]),
+    ((I32_TOP + 1, 1 << 62), ((0,), (1,)), [64, 64]),
+    ((1 << 30, 1 << 62), ((0,), (1,)), [32, 64]),
+    ((1 << 15, 1 << 62, (1 << 16) - 1), ((0, 2), (1,)), [32, 64]),
+    ((1 << 15, 1 << 62, 1 << 16), ((0, 2), (1,)), [64, 64]),
+    # q10p's shape: 21 + 20 + 21 fill word 0, c_nation's 5 bits word 1
+    ((1_499_999, 1_000_000, 1_099_999, 25), ((0, 1, 2), (3,)), [64, 32]),
+], ids=["later-31", "later-32", "later-20+11", "later-20+12", "word0-top",
+        "word0-sentinel", "word0-30", "word0-two-dims-under",
+        "word0-two-dims-at", "q10p"])
+def test_key_word_dtypes_of_a_wide_key_a_word(sizes, words, bits):
+    from tpu_olap.kernels import sparse_groupby as sg
+    assert sg.pack_key_words(sizes) == words
+    assert sg.key_sort_bits(sizes, words) == bits
+
+
+# cell -> template -> (the id domains of its group key at the published
+# scale, the time bucket first; what its records' `key_sort_bits` say).
+# Both SSB cells (`ssb-sf100-chip`, `ssb-sf4-mesh4`) run no sparse program.
+CELL_KEYS = {
+    "tpch-flat-sf10-chip": {
+        "q3": ((1, 59_999_969, 2406, 1), [64]),
+        "q10": ((1, 1_499_999, 1_000_000, 25), [64])},
+    "tpch-flat-sf10-mesh4": {
+        "q3": ((1, 59_999_969, 2406, 1), [64]),
+        "q10": ((1, 1_499_999, 1_000_000, 25), [64])},
+    "tpch-flat-sf10-having-chip": {
+        "q18": ((1, 1_499_999, 59_999_969, 2406), [64])},
+    "tpch-flat-sf10-widekey-chip": {
+        "q10p": ((1, 1_499_999, 1_000_000, 1_099_999, 25), [64, 32]),
+        "q18p": ((1, 1_000_000, 1_499_999, 59_999_969, 2406, 55_000_000),
+                 [64, 64])},
+    "druid-lineitem-sf100-chip": {
+        "top_100_parts": ((1, 2_000_000), [32]),
+        "top_100_parts_details": ((1, 2_000_000), [32]),
+        "top_100_parts_filter": ((1, 2_000_000), [32])},
+}
+
+
+@pytest.mark.parametrize("cell,template", [
+    (c, t) for c, ts in CELL_KEYS.items() for t in ts])
+def test_key_sort_bits_of_the_benchmarks_sparse_templates(cell, template):
+    """The Druid TopNs' 21-bit `l_partkey` and `q10p`'s second word are
+    the accepted cells' narrow words; every other sparse template keeps
+    the int64 program."""
+    from tpu_olap.kernels import sparse_groupby as sg
+    sizes, bits = CELL_KEYS[cell][template]
+    # lowering packs the positions that carry an id: the bucket of
+    # granularity "all" carries none
+    words = tuple(tuple(i + 1 for i in w)
+                  for w in sg.pack_key_words(sizes[1:]))
+    assert sg.key_sort_bits(sizes, words) == bits
+    assert len(words) == len(bits)
+
+
+def test_build_group_key64_combines_a_narrow_word_in_int32():
+    import jax.numpy as jnp
+
+    from tpu_olap.kernels import sparse_groupby as sg
+    EngineConfig().apply_x64()
+    rng = np.random.default_rng(44)
+    for xp in (np, jnp):
+        # one word under 2^31 - 1: int32; without `words`, int64 as ever
+        sizes = (3, 715_827_882)
+        ids = [rng.integers(0, s, 200).astype(np.int32) for s in sizes]
+        ids[0][0], ids[1][0] = 2, sizes[1] - 1      # the largest key
+        words = sg.pack_key_words(sizes)
+        key, total = sg.build_group_key64([xp.asarray(i) for i in ids],
+                                          sizes, xp, words)
+        assert key.dtype == np.int32 and total == I32_TOP - 1
+        wide, _ = sg.build_group_key64([xp.asarray(i) for i in ids], sizes,
+                                       xp)
+        assert wide.dtype == np.int64
+        np.testing.assert_array_equal(np.asarray(key), np.asarray(wide))
+        assert int(np.asarray(key)[0]) == I32_TOP - 2
+        # q10p's shape: (int64, int32), each word the int64 combine's
+        sizes = (1_499_999, 1_000_000, 1_099_999, 25)
+        ids = [rng.integers(0, s, 200).astype(np.int32) for s in sizes]
+        words = sg.pack_key_words(sizes)
+        keys, _ = sg.build_group_key64([xp.asarray(i) for i in ids], sizes,
+                                       xp, words)
+        assert [str(k.dtype) for k in keys] == ["int64", "int32"]
+        np.testing.assert_array_equal(np.asarray(keys[1]), ids[3])
+        radix = sg.key_radix(sizes, words)
+        want = (ids[0].astype(np.int64) * radix[1] + ids[1]) * radix[2] \
+            + ids[2]
+        np.testing.assert_array_equal(np.asarray(keys[0]), want)
+
+
+def _narrow_keys(eng):
+    return _counter(eng, "sparse_narrow_key_queries_total")
+
+
+@pytest.mark.parametrize("sql", [
+    NARROW_SQL,
+    "SELECT k, sum(p) AS sp, min(q) AS lo FROM t GROUP BY k "
+    "ORDER BY sp DESC LIMIT 7",
+    "SELECT k, sum(q) AS sq, count(*) AS n FROM t GROUP BY k "
+    "HAVING sum(q) > 150",
+], ids=["group-by", "ordered-limit", "having"])
+def test_record_span_explain_and_counter_say_key_sort_bits(sql):
+    """A group space of 400: the record, the `dispatch` span and `explain`
+    say `key_sort_bits: [32]` beside `key_words` / `key_bits`, the registry
+    counts the dispatch, and the answer is pandas'."""
+    eng = _narrow_engine(_narrow_df(1 << 20))
+    said = eng.explain(sql)
+    assert said["key_words"] == 1 and said["key_sort_bits"] == [32]
+    before = _narrow_keys(eng)
+    check_query(eng, sql)
+    rec = eng.history[-1]
+    assert rec["reduce_path"] == "sparse" and "fallback_reason" not in rec
+    assert rec["key_sort_bits"] == [32] and rec["key_words"] == 1
+    assert [s["attrs"].get("key_sort_bits") for s in _walk_spans(eng)
+            if s["name"] == "dispatch"] == ["[32]"]   # a span's attribute
+    # is a scalar or a string
+    assert _narrow_keys(eng) == before + 1
+    # a dense plan says nothing of a key
+    dense = "SELECT sum(q) AS sq FROM t"
+    assert "key_sort_bits" not in eng.explain(dense)
+    eng.sql(dense)
+    assert "key_sort_bits" not in eng.history[-1]
+    assert _narrow_keys(eng) == before + 1
+    eng.close()
+
+
+def test_a_key_past_31_bits_keeps_the_int64_word_and_the_counter_rests():
+    """`k` times a second dimension 2^20 wide: 2^29 x 400 groups do not
+    fit 31 bits, so the key is the int64 word it was."""
+    df = _narrow_df(1 << 20)
+    df["j"] = np.where(np.arange(len(df)) % 2 == 0, 5, 5 + (1 << 23))
+    eng = _narrow_engine(df)
+    sql = "SELECT k, j, sum(q) AS sq FROM t GROUP BY k, j"
+    assert eng.explain(sql)["key_sort_bits"] == [64]
+    check_query(eng, sql)
+    rec = eng.history[-1]
+    assert rec["reduce_path"] == "sparse" and rec["key_sort_bits"] == [64]
+    assert rec["key_bits"] > 31 and _narrow_keys(eng) == 0
+    eng.close()
+
+
+def test_a_mesh_of_four_merges_a_narrow_key_to_the_one_chip_answer():
+    """The mesh's `shard_map` program takes the same rule (its chips sort
+    an int32 key) and hands `merge_device` the int64 `_keys` it always
+    did: four chips' answer is one chip's, row for row."""
+    df = _narrow_df(1 << 20)
+    one, four = _narrow_engine(df), _narrow_engine(df, num_shards=4)
+    for sql in (NARROW_SQL,
+                "SELECT k, sum(p) AS sp, min(q) AS lo, max(q) AS hi "
+                "FROM t GROUP BY k ORDER BY sp DESC, k LIMIT 25"):
+        check_query(four, sql)
+        rec = four.history[-1]
+        assert rec["sparse"] and rec["num_shards"] == 4
+        assert rec["key_sort_bits"] == [32] \
+            and "fallback_reason" not in rec
+        pd.testing.assert_frame_equal(four.sql(sql), one.sql(sql))
+        assert one.history[-1]["key_sort_bits"] == [32]
+    assert _narrow_keys(four) >= 2
+    one.close()
+    four.close()

@@ -320,10 +320,14 @@ def test_sparse_min_max_program_compiles_for_v5e(topo, no_persistent_cache,
     text = lowered.compile().as_text()
     assert " scatter(" not in text and " sort(" in text
     assert " reduce-window(" in text
-    # the key's two u32 halves, the two sums and l_discount as one s32 each
+    # the key (21 bits of part keys: an int32 word since PR 44, where an
+    # int64 word was two u32 halves), the two sums and l_discount as one
+    # s32 each
+    from tpu_olap.kernels.sparse_groupby import key_sort_bits
+    assert key_sort_bits(phys.sizes, phys.key_words) == [32]
+    assert key_sort_bits((1, 2_000_001), ((1,),)) == [32]
     widest = max(re.findall(r"= \((.*?)\) sort\(", text), key=len)
-    assert re.findall(r"([us]\d+)\[", widest) \
-        == ["u32", "u32", "s32", "s32", "s32"], widest
+    assert re.findall(r"([us]\d+)\[", widest) == ["s32"] * 4, widest
     out = jax.eval_shape(kernel, env, valid, seg_arg, consts_dev)
     assert out["_narrow_ok"].shape == () and out["sum_price"].dtype == "int64"
     assert {k: (v.shape, str(v.dtype)) for k, v in out.items()

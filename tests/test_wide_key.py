@@ -312,11 +312,15 @@ def wide(request):
     eng.close()
 
 
-def _wide_key_queries(eng):
+def _counter(eng, name):
     return sum(
         float(ln.rsplit(" ", 1)[1])
         for ln in eng.metrics.render().splitlines()
-        if ln.startswith("tpu_olap_sparse_wide_key_queries_total"))
+        if ln.startswith(f"tpu_olap_{name}"))
+
+
+def _wide_key_queries(eng):
+    return _counter(eng, "sparse_wide_key_queries_total")
 
 
 def _dims(n_dims):
@@ -341,6 +345,8 @@ def test_engine_group_by_equals_pandas(wide):
     assert rec["reduce_path"] == "sparse" and "fallback_reason" not in rec
     assert rec["key_words"] == n_dims // 2
     assert rec["key_bits"] == 21 * n_dims
+    # every word holds two dimensions' 42 bits: none rides as int32
+    assert rec["key_sort_bits"] == [64] * (n_dims // 2)
     want = df.groupby(dims, as_index=False).agg(
         sv=("v", "sum"), n=("v", "size"), lo=("q", "min"), hi=("q", "max"))
     assert len(got) == len(want) == rec["present_groups"] > 1000
@@ -469,6 +475,264 @@ def test_a_domain_that_moves_keeps_the_wide_program(wide):
     other.close()
     assert texts[0] == texts[1]
     assert sizes[1] == WIDE + 5 + 1   # the moved table's own domain
+
+
+# ------------------------------------- a word that fits 31 bits (PR 44)
+
+I32_TOP = (1 << 31) - 1
+
+
+def _sort_operand_dtypes(fn, *args):
+    """The operand dtypes of the widest sort anywhere in fn's jaxpr."""
+    import jax
+
+    def sorts(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "sort":
+                yield [str(v.aval.dtype) for v in eqn.invars]
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from sorts(sub)
+    return max(sorts(jax.make_jaxpr(fn)(*args).jaxpr), key=len)
+
+
+# name -> the words of a key as they ride (word dtypes), from one int64
+# key below 2^31 - 1: each shape holds the largest value its width allows
+KEY_SHAPES = {
+    "one-word-int32": lambda k: (k.astype(np.int32),),
+    "narrow-second-word": lambda k: (
+        (k // 7) + (1 << 40), np.where(k % 7 == 6, I32_TOP,
+                                       k % 7).astype(np.int32)),
+    "narrow-word-0": lambda k: (
+        np.where(k == I32_TOP - 1, k, k // 7).astype(np.int32),
+        (k % 7) + (1 << 40)),
+}
+# name -> (distinct keys, the share of rows the mask keeps): 300 rows into
+# 16 slots each, so one program a test serves them all
+KEY_DATA = {
+    "cap-overflow-and-masked-tail": (40, 0.9),
+    "every-row-masked": (9, 0.0),
+    "fits-and-no-tail": (12, 1.0),
+}
+KEY_PLANS = [_agg("a", "sum", "i8"), _agg("lo", "min", "i8"),
+             _agg("hi", "max", "i32", filter_fn=_positive),
+             _agg("fc", "count", filter_fn=_positive), _agg("n", "count")]
+_key_programs = {}
+
+
+def _key_program(shape, cut, read, narrow, wide):
+    """The jitted program of KEY_PLANS over 300 rows and 16 slots (built
+    once a parameter set): the words as `shape` rides them, or every word
+    as int64 (`wide`: the program that stood before)."""
+    import jax
+    import jax.numpy as jnp
+
+    EngineConfig().apply_x64()
+    name = (shape, cut, read, narrow, wide)
+    if name not in _key_programs:
+        top = ("a", 10, False) if cut == "top" else None
+        having = (lambda t, c: t["a"][0].astype(np.int64) > -5,
+                  frozenset({"a"}), 32) if cut == "having" else None
+
+        def program(words, m, e):
+            if wide:
+                words = tuple(w.astype(jnp.int64) for w in words)
+            key = words if len(words) > 1 else words[0]
+            return sg.sparse_group_reduce(key, m, e, KEY_PLANS, 16, {}, jnp,
+                                          top, having, narrow, read)
+        _key_programs[name] = jax.jit(program)
+    return _key_programs[name]
+
+
+def _key_inputs(shape, data, n=300):
+    distinct, keep = KEY_DATA[data]
+    rng = np.random.default_rng(44)
+    # the largest key an int32 word 0 may hold, and the smallest
+    key = rng.integers(1, distinct, n).astype(np.int64)
+    key = key * 7 + key % 7        # both words of a wide shape vary
+    key[5], key[77] = 0, I32_TOP - 1
+    mask = rng.random(n) < keep
+    env = {"cols": {
+        "i8": rng.integers(-128, 128, n).astype(np.int8),
+        "i32": rng.integers(-(1 << 31), 1 << 31, n).astype(np.int32),
+        "f": rng.integers(-3, 4, n)}, "nulls": {}}
+    return KEY_SHAPES[shape](key), mask, env
+
+
+@pytest.mark.parametrize("narrow", [True, False], ids=["narrow", "wide-sums"])
+@pytest.mark.parametrize("read", ["gather", "sorted"])
+@pytest.mark.parametrize("cut", ["uncut", "top", "having"])
+@pytest.mark.parametrize("data", sorted(KEY_DATA))
+@pytest.mark.parametrize("shape", sorted(KEY_SHAPES))
+def test_a_narrow_key_words_tables_are_the_int64_programs(shape, data, cut,
+                                                          read, narrow):
+    """A one-word int32 key, a wide key whose second word is int32 and one
+    whose word 0 is: every table of the program equal, dtype and bit, to
+    the program whose words all ride as int64; `_keys` (and `_keys1`)
+    int64, the SENTINEL in the empty slots. A masked tail with more groups
+    than slots, no unmasked row (the empty input), no masked row; uncut,
+    under a TopN's threshold, under a HAVING; the whole tables gathered and riding
+    `starts`' sort; the sum as one int32 word and as int64. Word 0 holds
+    2^31 - 2 (one under its sentinel), a later word 2^31 - 1."""
+    import jax
+
+    words, mask, env = _key_inputs(shape, data)
+    got, want = (jax.device_get(_key_program(shape, cut, read, narrow, wide)(
+        words, mask, env)) for wide in (False, True))
+    assert set(got) == set(want)
+    for name, table in want.items():
+        assert got[name].dtype == table.dtype, name
+        np.testing.assert_array_equal(got[name], table, err_msg=name)
+    count = int(got["_count"])
+    assert (count > 16) == (data == "cap-overflow-and-masked-tail")
+    assert count == (0 if data == "every-row-masked" else
+                     len(np.unique(np.stack([w.astype(np.int64)
+                                             for w in words])[:, mask],
+                                   axis=1).T))
+    for name in sg.key_names(len(words)):
+        assert got[name].dtype == np.int64
+    present = got["_rows"] > 0
+    assert (got["_keys"][~present] == sg.SENTINEL).all()
+    assert (got["_keys"][present] < I32_TOP + (1 << 41)).all()
+    if cut == "uncut":
+        assert present.sum() == min(count, 16)
+        if data == "fits-and-no-tail":
+            # against numpy's own group-by, every word
+            ref = np.unique(np.stack([w.astype(np.int64) for w in words]),
+                            axis=1)
+            for w, name in enumerate(sg.key_names(len(words))):
+                np.testing.assert_array_equal(got[name][:count], ref[w])
+            # the last group's word 0 is one under an int32 sentinel
+            assert got["_keys"][count - 1] == I32_TOP - 1 \
+                or shape == "narrow-second-word"
+            assert got["_rows"].sum() == 300
+
+
+@pytest.mark.parametrize("data", sorted(KEY_DATA))
+@pytest.mark.parametrize("shape", sorted(KEY_SHAPES))
+def test_a_narrow_keys_count_program_counts_numpys_groups(shape, data):
+    """`sparse_group_count` over the words as they ride: numpy's distinct
+    keys among the unmasked rows, none where every row is masked."""
+    import jax
+    import jax.numpy as jnp
+
+    EngineConfig().apply_x64()
+    words, mask, _env = _key_inputs(shape, data)
+    key = words if len(words) > 1 else words[0]
+    got = int(jax.jit(lambda k, m: sg.sparse_group_count(k, m, jnp))(
+        key, mask)["_count"])
+    assert got == np.unique(np.stack(
+        [w.astype(np.int64) for w in words])[:, mask], axis=1).shape[1]
+    assert (got == 0) == (data == "every-row-masked")
+
+
+@pytest.mark.parametrize("shape", sorted(KEY_SHAPES))
+def test_words_ride_the_sort_and_the_key_gather_in_their_own_width(shape):
+    """The main sort's leading operands are the words' own dtypes, the
+    count program's too, and an int32 word's table is ONE gather of s32
+    where an int64's is emulated as two u32 on the chip."""
+    import jax.numpy as jnp
+
+    EngineConfig().apply_x64()
+    words, mask, env = _key_inputs(shape, "fits-and-no-tail")
+    key = words if len(words) > 1 else words[0]
+    dtypes = [str(w.dtype) for w in words]
+    assert sorted(set(dtypes)) in (["int32"], ["int32", "int64"])
+    ops = _sort_operand_dtypes(
+        lambda k, m, e: sg.sparse_group_reduce(k, m, e, KEY_PLANS, 16, {},
+                                               jnp), key, mask, env)
+    assert ops[:len(words)] == dtypes
+    assert _sort_operand_dtypes(
+        lambda k, m: sg.sparse_group_count(k, m, jnp), key, mask) == dtypes
+
+
+
+def _narrow_key_queries(eng):
+    return _counter(eng, "sparse_narrow_key_queries_total")
+
+
+def _odd_dims(n_dims):
+    """GROUP BY columns whose last 21-bit dimension is alone in its word:
+    three of them and the 6 bits of `q` (a product past 2^62: `q` joins
+    word 0) on the four-dimension table, five on the six-dimension one."""
+    return _dims(n_dims)[:3] + ["q"] if n_dims == 4 else _dims(n_dims)[:5]
+
+
+@pytest.mark.parametrize("cut", ["uncut", "ordered-limit", "having"])
+def test_an_odd_dimension_rides_alone_as_an_int32_word(wide, cut):
+    """Three dimensions of 21 bits and one of 6 (five of 21): the last
+    wide one is alone in its word, which rides the sort as int32
+    (`key_sort_bits` [64, 32] / [64, 64, 32]) and comes back as the int64
+    `_keys1` (`_keys2`) the host decodes: pandas' answer, whole, under an
+    ordered LIMIT and under a HAVING the device decides."""
+    n_dims, eng, df = wide
+    dims = _odd_dims(n_dims)
+    grouped = len(dims)
+    cols = ", ".join(dims)
+    bits = [64, 32] if n_dims == 4 else [64, 64, 32]
+    sql = {"uncut": f"SELECT {cols}, sum(v) AS sv, sum(q) AS sq, "
+                    f"min(v) AS lo FROM t GROUP BY {cols}",
+           "ordered-limit": f"SELECT {cols}, sum(v) AS sv FROM t "
+                            f"WHERE q > 10 GROUP BY {cols} "
+                            f"ORDER BY sv DESC, {cols} LIMIT 25",
+           "having": f"SELECT {cols}, sum(q) AS sq FROM t GROUP BY {cols} "
+                     f"HAVING sum(q) > 60 ORDER BY sq DESC, {cols}"}[cut]
+    said = eng.explain(sql)
+    assert said["key_words"] == len(bits) and said["key_sort_bits"] == bits
+    narrow, wide_keys = _narrow_key_queries(eng), _wide_key_queries(eng)
+    got = eng.sql(sql)
+    rec = eng.runner.history[-1]
+    assert rec["reduce_path"] == "sparse" and "fallback_reason" not in rec
+    assert rec["key_sort_bits"] == bits and rec["key_words"] == len(bits)
+    assert rec["key_bits"] == (69 if n_dims == 4 else 105)
+    assert [s["attrs"]["key_sort_bits"] for s in _spans(eng)
+            if s["name"] == "dispatch"] == [str(bits)]
+    assert _narrow_key_queries(eng) == narrow + 1
+    assert _wide_key_queries(eng) == wide_keys + 1
+    if cut == "uncut":
+        want = df.groupby(dims, as_index=False).agg(
+            sv=("v", "sum"), sq=("q", "sum"), lo=("v", "min"))
+        got = got.sort_values(dims).reset_index(drop=True)
+        want = want.sort_values(dims).reset_index(drop=True)
+    elif cut == "ordered-limit":
+        want = df[df.q > 10].groupby(dims, as_index=False) \
+            .agg(sv=("v", "sum")).sort_values(
+                ["sv"] + dims, ascending=[False] + [True] * grouped) \
+            .head(25).reset_index(drop=True)
+    else:
+        assert rec["having_where"] == "device"
+        want = df.groupby(dims, as_index=False).agg(sq=("q", "sum"))
+        want = want[want.sq > 60].sort_values(
+            ["sq"] + dims, ascending=[False] + [True] * grouped) \
+            .reset_index(drop=True)
+    assert len(got) == len(want) > 0
+    for c in want.columns:
+        np.testing.assert_array_equal(got[c].to_numpy(np.int64),
+                                      want[c].to_numpy(np.int64), err_msg=c)
+
+
+def test_the_narrow_word_program_sorts_by_int64_words_then_int32(wide):
+    """The program's own text: the main sort's two leading operands are
+    the words as `key_word_dtypes` says, and the tables leave as int64."""
+    import jax
+
+    n_dims, eng, _df = wide
+    cols = ", ".join(_odd_dims(n_dims))
+    plan = eng.planner.plan(f"SELECT {cols}, sum(v) AS sv FROM t "
+                            f"GROUP BY {cols}")
+    phys = eng.runner._lower_cached(plan.query, plan.entry.segments)
+    dtypes = [str(d) for d in
+              sg.key_word_dtypes(phys.sizes, phys.key_words)]
+    assert dtypes == ["int64"] * (len(dtypes) - 1) + ["int32"]
+    env, valid, seg_mask = eng.runner._prepare(phys, {})
+    consts_dev, seg_arg = eng.runner._args_for(phys, seg_mask, None)
+    args = (env, valid, seg_arg, consts_dev)
+
+    for cap in (4096, None):
+        kernel = phys.make_sparse_kernel(cap)
+        assert _sort_operand_dtypes(kernel, *args)[:len(dtypes)] == dtypes
+        out = jax.eval_shape(kernel, *args)
+        assert cap is None or all(
+            out[n].dtype == np.int64 for n in sg.key_names(len(dtypes)))
 
 
 # ------------------------------------------------------- what stays refused

@@ -8,6 +8,18 @@ where the tree has `sparse_groupby.boundary_read` its answer for the rows
 and the cap is passed on (at these rows and caps every program is past 8
 rows a slot: the `gather` side).
 
+A key's width moves a program (PR 44: `sparse_groupby.key_word_dtypes`, a
+word whose ids fit 31 bits rides as int32): the three Druid TopNs, their
+count program and `q10p` are the accepted cells' programs it moves, and no
+other. At these rows every domain is narrower than its cell's, so each
+template is lowered on the path its cell takes: `CELL_SORT_BITS` (what the
+cells' records say, `key_sort_bits`) stands in for the rule wherever the
+small plan has as many words as the cell's, and the tag ends in those bits
+(`proxy-bits`, with the rule's own answer, where it has not: `q10p` is one
+word under 2^62 here). `key-shape:*` are the programs of the same key
+builder and reduce over the CELLS' OWN domains (`CELL_SIZES`, the published
+SF10's), with no dataset: there the rule answers for itself on either tree.
+
     python tools/lowered_sha.py . > /root/scratch/change.json
     python tools/lowered_sha.py /root/scratch/parent > /root/scratch/parent.json
 """
@@ -28,11 +40,30 @@ import jax  # noqa: E402
 ROWS = 60000
 ROWS_OF = {"druid-lineitem-sf100-chip": 600000}
 CELLS = {"tpch-flat-sf10-chip": ["q3", "q10"],
-         "druid-lineitem-sf100-chip": ["top_100_parts", "top_100_parts_details",
-                                       "top_100_parts_filter"],
+         "druid-lineitem-sf100-chip": [
+             "top_100_parts", "top_100_parts_details",
+             "top_100_parts_filter"],
          "tpch-flat-sf10-having-chip": ["q18"],
          "tpch-flat-sf10-widekey-chip": ["q10p", "q18p"],
          "tpch-flat-sf10-mesh4": ["q3", "q10"]}
+
+
+# template -> the id domains of its cell's group key in radix order, the
+# time bucket first (the published SF10: 15M orders on sparse keys, 1.5M
+# customers of whom a million have orders, 2M parts, 2,406 order dates, 25
+# nations; `c_acctbal` and `o_totalprice` in cents)
+CELL_SIZES = {"q3": (1, 59_999_969, 2406, 1),
+              "q10": (1, 1_499_999, 1_000_000, 25),
+              "q18": (1, 1_499_999, 59_999_969, 2406),
+              "q10p": (1, 1_499_999, 1_000_000, 1_099_999, 25),
+              "q18p": (1, 1_000_000, 1_499_999, 59_999_969, 2406,
+                       55_000_000),
+              "top_100_parts": (1, 2_000_000)}
+# template -> `key_sort_bits` on its cell's records
+CELL_SORT_BITS = {"q3": [64], "q10": [64], "q18": [64], "q10p": [64, 32],
+                  "q18p": [64, 64], "top_100_parts": [32],
+                  "top_100_parts_details": [32],
+                  "top_100_parts_filter": [32]}
 
 
 def sha(lowered):
@@ -60,6 +91,56 @@ def uncut_min_max(sg):
         for narrow in (False, True)}
 
 
+def key_shapes(sg):
+    """The count program and a table program (an int64 sum of an int32
+    column and the row count, 1,024 slots) over a key built from zero ids
+    of `CELL_SIZES`' domains, as `make_sparse_kernel` builds it."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpu_olap.kernels.groupby import AggPlan
+    plans = [AggPlan("s", "sum", ("v",), np.dtype(np.int64)),
+             AggPlan("n", "count", (), np.dtype(np.int64))]
+    env = {"cols": {"v": jnp.zeros(ROWS, jnp.int32)}, "nulls": {}}
+    mask = jnp.ones(ROWS, bool)
+    out = {}
+    for name, sizes in CELL_SIZES.items():
+        sizes = sizes[1:]     # granularity "all": the bucket carries no id
+        words = sg.pack_key_words(sizes)
+        ids = [jnp.zeros(ROWS, jnp.int32) for _ in sizes]
+
+        def key(ids):
+            return sg.build_group_key64(ids, sizes, jnp, words=words)[0]
+        bits = sg.key_sort_bits(sizes, words) \
+            if hasattr(sg, "key_sort_bits") else [64] * len(words)
+        out[f"key-shape:{name}:words{len(words)}:count"] = sha(jax.jit(
+            lambda ids, m: sg.sparse_group_count(key(ids), m, jnp))
+            .lower(ids, mask))
+        out[f"key-shape:{name}:words{len(words)}:table"] = sha(jax.jit(
+            lambda ids, m, e: sg.sparse_group_reduce(
+                key(ids), m, e, plans, 1024, {}, jnp)).lower(ids, mask, env))
+        out[f"key-shape:{name}:rule-bits"] = bits
+    return out
+
+
+def on_the_cells_path(sg, rule, name, phys):
+    """Make `sg.key_word_dtypes` (`rule`, the tree's own; None on a tree
+    before PR 44, whose words are all int64) answer with the widths
+    template `name`'s cell sorts at where the small plan has as many
+    words, and -> the tag's tail."""
+    import numpy as np
+    if rule is None:
+        return ""
+    own = [8 * d.itemsize for d in rule(phys.sizes, phys.key_words)]
+    real = CELL_SORT_BITS[name]
+    if len(own) != len(real):
+        sg.key_word_dtypes = rule
+        return f":proxy-bits{own}"
+    sg.key_word_dtypes = lambda sizes, words: tuple(
+        np.dtype(f"int{b}") for b in real)
+    return ""
+
+
 def main():
     import importlib
 
@@ -67,6 +148,7 @@ def main():
     from tpu_olap.executor import EngineConfig
     from tpu_olap.kernels import sparse_groupby as sg
     out = {}
+    rule = getattr(sg, "key_word_dtypes", None)
     for cfg_name, names in CELLS.items():
         with open(f"perfbench/configs/{cfg_name}.json") as f:
             cfg = json.load(f)
@@ -86,6 +168,7 @@ def main():
             n = int(valid.size)
             consts_dev, seg_arg = r._args_for(phys, seg_mask, r.mesh)
             tag = f"{cfg_name}:{name}"
+            tail = on_the_cells_path(sg, rule, name, phys)
             if r.mesh is not None:
                 from tpu_olap.executor import sharding as sh
                 for cap in (1024,):
@@ -121,14 +204,17 @@ def main():
                         env, valid, seg_arg, consts_dev, win[0])
                 return jax.jit(kern).lower(env, valid, seg_arg, consts_dev)
 
-            out[f"{tag}:count"] = sha(lower(None, False))
+            out[f"{tag}:count{tail}"] = sha(lower(None, False))
             for cap in (1024, 4096):
                 for narrow in (False, True):
                     out[f"{tag}:cap{cap}:{'narrow' if narrow else 'wide'}"
-                        f":n{n}:win{win}:top{bool(top)}:kept{kept}"] = \
+                        f":n{n}:win{win}:top{bool(top)}:kept{kept}{tail}"] = \
                         sha(lower(cap, narrow))
         eng.close()
+    if rule is not None:
+        sg.key_word_dtypes = rule
     out.update(uncut_min_max(sg))
+    out.update(key_shapes(sg))
     json.dump(out, sys.stdout, indent=1, sort_keys=True)
 
 

@@ -34,6 +34,14 @@ a second operand of it, whatever `sparse_groupby.boundary_spelling` would
 choose (`rule`, the default, leaves it the choice; the row's `boundary` says
 what ran): over `--ks` at fixed rows the two cross where
 `BOUNDARY_SORT_MAX_ROWS_PER_SLOT` rests (PERF.md section 6, PR 43).
+`--key-bits 32 64` hands the sparse forms' key (k slots: ids under 2^31) as
+an int32 word or an int64 one, as `sparse_groupby.key_word_dtypes` would
+of a domain that fits 31 bits and of one that does not; under
+`--key-words 2` the width is the SECOND word's (5 bits of the first, as
+`q10p`'s `c_nation`; word 0 stays int64): a sort whose key words differ in
+width. Over rows at one k the rows differ by one u32 sort operand that is
+also compared, the boundary test's bytes and a gather of the keys' table
+(PERF.md section 6, PR 44).
 
     python tools/sweep_group_reduce.py --rows 59986052 --ks 2000001 \
         --dtypes int64 --tables 0 3 \
@@ -50,6 +58,10 @@ what ran): over `--ks` at fixed rows the two cross where
     python tools/sweep_group_reduce.py --rows 60030976 \
         --ks 262144 2000001 4194304 16777216 --dtypes int8 --sum-word 32 \
         --boundary-read gather sorted --forms sparse_topn
+
+    python tools/sweep_group_reduce.py --rows 59986052 --ks 2000001 \
+        --dtypes int8 --tables 0 1 --sum-word 32 --key-bits 32 64 \
+        --key-words 1 2 --forms sparse_topn
 
     python tools/sweep_group_reduce.py                  # on the chip
     python tools/sweep_group_reduce.py --compile-only   # here, for a
@@ -125,7 +137,7 @@ BOUNDARY_READS = ("rule", "gather", "sorted")
 
 
 def _sparse_topn(v, key, k, tables=0, rank_first=True, sum_word=64,
-                 key_words=1, boundary=None):
+                 key_words=1, boundary=None, key_bits=64):
     """The other side of `lowering.topn_takes_sparse`: the engine's own
     sparse reduce into a compact table of k slots (one sort whose cost
     does not depend on k, the tables read at the runs' boundaries) with
@@ -140,7 +152,9 @@ def _sparse_topn(v, key, k, tables=0, rank_first=True, sum_word=64,
     for the narrow program: a sum of a column stored in 32 bits or fewer
     rides as one int32 word. `key_words` past 1 hands the key as that many
     int64 words, the further ones functions of the first: the same groups
-    in the same order, sorted by every word. `boundary` is the program's
+    in the same order, sorted by every word. `key_bits` 32 hands the key
+    as an int32 word; with two words the second, then the first's low 5
+    bits, and word 0 stays int64. `boundary` is the program's
     static argument of that name (the runner's `boundary_read`). Returns
     the ranked sum at the kept rows, their keys, the other tables' kept rows and, of a narrow
     program, `_narrow_ok` last."""
@@ -152,10 +166,14 @@ def _sparse_topn(v, key, k, tables=0, rank_first=True, sum_word=64,
     acc = v.dtype if v.dtype.itemsize >= 4 else np.dtype(np.int64)
     plans = [groupby.AggPlan(c, "sum", (c,), acc) for c in cols]
     top = ("v", 100, False)
-    key = key.astype(jnp.int64)
+    narrow_key = key_bits == 32
+    key = key.astype(jnp.int32 if narrow_key and key_words == 1
+                     else jnp.int64)
     if key_words > 1:
-        key = (key,) + tuple((key << 31) + (key ^ (w * 0x55555))
-                             for w in range(1, key_words))
+        key = (key,) + tuple(
+            (key & 31).astype(jnp.int32) if narrow_key and w == 1
+            else (key << 31) + (key ^ (w * 0x55555))
+            for w in range(1, key_words))
     out = sparse_group_reduce(key, jnp.ones(v.shape, bool),
                               {"cols": cols, "nulls": {}}, plans, k, {},
                               jnp, top if rank_first else None,
@@ -440,6 +458,10 @@ def main():
     ap.add_argument("--key-words", type=int, nargs="*", default=[1],
                     help="the sparse TopN forms' key as this many int64 "
                          "words: sort keys, boundary tests and key tables")
+    ap.add_argument("--key-bits", type=int, nargs="*", default=[64],
+                    choices=[32, 64],
+                    help="the sparse TopN forms' key word as int32 or "
+                         "int64 (with --key-words 2: the second word)")
     ap.add_argument("--boundary-read", nargs="*", default=["rule"],
                     choices=BOUNDARY_READS,
                     help="the sparse TopN forms' read of the ranked sum's "
@@ -496,8 +518,8 @@ def sweep_dense(args, sharding):
                 spec = [jax.ShapeDtypeStruct((n,), np.dtype(d),
                                              sharding=sharding)
                         for d in (dtype, "int32")]
-                for name, block, tables, word, words, read in [
-                        (f, b, t, w, kw, br) for f in args.forms
+                for name, block, tables, word, words, read, kbits in [
+                        (f, b, t, w, kw, br, kb) for f in args.forms
                         for b in (args.block_bytes
                                   if f == "compare" else [0])
                         for t in (args.tables
@@ -507,6 +529,8 @@ def sweep_dense(args, sharding):
                         for kw in (args.key_words
                                    if f in SPARSE_FORMS else [None])
                         for br in (args.boundary_read
+                                   if f in SPARSE_FORMS else [None])
+                        for kb in (args.key_bits
                                    if f in SPARSE_FORMS else [None])]:
                     if dtype == "int8" and name not in SPARSE_FORMS:
                         continue   # the dense forms sum at v's own width
@@ -514,7 +538,7 @@ def sweep_dense(args, sharding):
                         groupby._CMP_BLOCK_BYTES
                     more = {} if tables is None else {
                         "tables": tables, "sum_word": word,
-                        "key_words": words}
+                        "key_words": words, "key_bits": kbits}
                     if read is not None:
                         more["boundary"] = read if read != "rule" else \
                             sparse_groupby.boundary_spelling(n, k)

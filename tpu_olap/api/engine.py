@@ -850,7 +850,9 @@ class Engine:
         the sparse group-by, how many int64 words its key takes and how
         many bits its dimensions' ids (the record's `key_words`: 1 under
         a group space of 2^62, and `key_bits`; `key_words` null where
-        lowering finds no device plan for the query)."""
+        lowering finds no device plan for the query) and the width each
+        word rides the sort at (`key_sort_bits`: 32 a word whose ids fit
+        31 bits, else 64)."""
         from tpu_olap.executor.batch import AGG_QUERY_TYPES
         plan = self.planner.plan(query)
         out = plan.explain()
@@ -867,7 +869,8 @@ class Engine:
             if bits is not None:
                 out["sum_word_bits"] = bits
             if key is not None:
-                out["key_words"], out["key_bits"] = key
+                out["key_words"], out["key_bits"], \
+                    out["key_sort_bits"] = key
         if plan.rewritten and plan.entry.is_accelerated \
                 and getattr(plan.query, "having", None) is not None:
             out["having_where"] = self.runner.having_where(

@@ -620,7 +620,9 @@ def _lower_agg(query, table, config) -> PhysicalPlan:
         tables read at the runs' boundaries ride `starts`' sort).
         Where the plan's key is several words (`key_words`) every one of
         these programs sorts by them all and gives a `_keys` table a
-        word."""
+        word. A word rides in the dtype `sparse_groupby.key_word_dtypes`
+        gives it from `sizes` (int32 where its ids fit 31 bits); the
+        tables are int64 either way."""
         from tpu_olap.kernels.sparse_groupby import (build_group_key64,
                                                      sparse_group_count,
                                                      sparse_group_reduce)
@@ -631,8 +633,6 @@ def _lower_agg(query, table, config) -> PhysicalPlan:
             xp = _jnp()
             fenv, mask, key = _masked_key(env, valid, seg_mask, consts,
                                           key_builder)
-            if not isinstance(key, tuple):
-                key = key.astype(xp.int64)
             if cap is None:
                 return sparse_group_count(key, mask, xp)
             return sparse_group_reduce(

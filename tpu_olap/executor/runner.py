@@ -282,6 +282,11 @@ class QueryRunner:
             "sparse_wide_key_queries_total",
             "Sparse dispatches whose group space is 2^62 or more: the key "
             "rode the sort as more than one int64 word.")
+        self._m_narrow_key = m.counter(
+            "sparse_narrow_key_queries_total",
+            "Sparse dispatches with a key word whose ids fit 31 bits: "
+            "that word rode the sort as ONE int32 operand "
+            "(key_sort_bits holds a 32).")
         self._m_boundary_sorted = m.counter(
             "sparse_boundary_sorted_total",
             "Sparse dispatches whose program read its whole [cap] tables "
@@ -2053,6 +2058,8 @@ class QueryRunner:
         n_words = len(plan.key_words)
         if n_words > 1:
             self._m_wide_key.inc()
+        if 32 in sg.key_sort_bits(plan.sizes, plan.key_words):
+            self._m_narrow_key.inc()
         narrow = mesh is None and wide_key not in self._cap_hints \
             and sg.narrow_sums(plan.agg_plans, stored)
         t0 = time.perf_counter()
@@ -2410,8 +2417,10 @@ class QueryRunner:
         `starts`' sort, `sorted`, which the registry counts, or `gather`;
         None, and absent, where it read none there). And the
         key: how many int64 words it rode the sort as (`key_words`: 1
-        under a group space of 2^62) and the bits its dimensions' ids
-        take together (`key_bits`)."""
+        under a group space of 2^62), the bits its dimensions' ids
+        take together (`key_bits`) and the width each word rode the sort
+        at (`key_sort_bits`, a list a word: 32 where the word's ids fit
+        31 bits, `sparse_groupby.key_word_dtypes`)."""
         from tpu_olap.kernels import sparse_groupby as sg
         metrics["reduce_form"] = sg.sparse_reduce_form(plan.agg_plans,
                                                        stored, cap)
@@ -2429,6 +2438,8 @@ class QueryRunner:
             self._m_boundary_sorted.inc()
         metrics["key_words"] = len(plan.key_words)
         metrics["key_bits"] = sg.key_bits(plan.sizes)
+        metrics["key_sort_bits"] = sg.key_sort_bits(plan.sizes,
+                                                    plan.key_words)
         metrics["sparse"] = True
         metrics["sparse_attempts"] = attempts
         metrics["sparse_cap"] = metrics["result_cap"] = cap
@@ -2976,17 +2987,19 @@ class QueryRunner:
         return "device" if self._device_having(plan) else "host"
 
     def key_words(self, query, table):
-        """(words, bits) of the sparse key the query's plan sorts by, as
-        its record's `key_words` and `key_bits` say after a run
-        (EXPLAIN's lines): the int64 words the key takes (1 under a group
-        space of 2^62) and the bits its dimensions' ids take together.
-        None where the plan is not sparse. Raises what lowering raises of
-        a query with no device plan."""
+        """(words, bits, sort bits) of the sparse key the query's plan
+        sorts by, as its record's `key_words`, `key_bits` and
+        `key_sort_bits` say after a run (EXPLAIN's lines): the words the
+        key takes (1 under a group space of 2^62), the bits its
+        dimensions' ids take together and the width each word rides the
+        sort at (32 | 64 a word). None where the plan is not sparse.
+        Raises what lowering raises of a query with no device plan."""
         from tpu_olap.kernels import sparse_groupby as sg
         plan = self._lower_cached_inner(query, table)
         if not plan.sparse:
             return None
-        return len(plan.key_words), sg.key_bits(plan.sizes)
+        return (len(plan.key_words), sg.key_bits(plan.sizes),
+                sg.key_sort_bits(plan.sizes, plan.key_words))
 
     def sum_word_bits(self, query, table) -> int | None:
         """32 | 64: the width the integer sums of the query's sparse
@@ -3303,11 +3316,11 @@ def _form_attr(metrics: dict) -> dict:
     sum's `sum_word_bits`, the sparse program's `cap_tables` and
     `boundary_read`, who
     decides a GroupBy's HAVING (`having_where`) and the sparse key's
-    `key_words` and `key_bits`."""
+    `key_words`, `key_bits` and `key_sort_bits`."""
     return {k: metrics[k]
             for k in ("reduce_form", "ext_word_bits", "sum_word_bits",
                       "cap_tables", "boundary_read", "having_where",
-                      "key_words", "key_bits")
+                      "key_words", "key_bits", "key_sort_bits")
             if metrics.get(k) is not None}
 
 

@@ -6,8 +6,11 @@ space [K] and stops at the dense budget; beyond it the reference-shaped
 answer would be a hash exchange, but sorting is the TPU-idiomatic move —
 XLA's sort is fast on TPU and everything stays static-shaped:
 
-  1. mixed-radix key in int64 (the radix product may exceed int32);
-     masked rows get the +inf sentinel so they sort to the tail. A group
+  1. mixed-radix key in int64 (the radix product may exceed int32; where
+     it does not, or a word of a several-word key holds 31 bits or fewer,
+     that word is int32: `key_word_dtypes`, one u32 sort operand in place
+     of two); masked rows get the +inf sentinel (the maximum of word 0's
+     dtype) so they sort to the tail. A group
      space of 2^62 or more takes MORE THAN ONE such word (`pack_key_words`
      says which dimension lies in which): the sort compares the words in
      turn (`num_keys`), the SENTINEL stands in word 0 alone, a run ends
@@ -142,28 +145,66 @@ def key_names(n_words: int) -> tuple:
     return ("_keys",) + tuple(f"_keys{w}" for w in range(1, n_words))
 
 
+def key_word_dtypes(sizes, words) -> tuple:
+    """The dtype each word of a group key rides the sort in, a word: int32
+    where every value the word can hold fits one, else int64. Known where
+    the program is built: `sizes` are the id domains (radix order), `words`
+    their `pack_key_words`. The bound is exact: the largest value of a word
+    is its positions' largest ids (size - 1) combined under `key_radix`
+    (for a one-word key that is the radix product less one). Word 0 also
+    carries the sentinel of the masked rows, the maximum of its dtype, so
+    its values must stay BELOW 2^31 - 1 (a one-word space of up to
+    2^31 - 1 groups; 31 bits of a several-word key only where the exact
+    sizes leave the last value free); a later word carries none and may
+    reach 2^31 - 1 (31 bits). An id is below its domain's size by
+    construction, which the int64 key rests on as well. Which dimension
+    sits in which word is `pack_key_words`' alone: this rule never moves
+    one."""
+    radix = key_radix(sizes, words)
+    out = []
+    for w, positions in enumerate(words):
+        top = 0
+        for i in positions:
+            top = top * radix[i] + int(sizes[i]) - 1
+        limit = np.iinfo(np.int32).max - (1 if w == 0 else 0)
+        out.append(np.dtype(np.int32 if top <= limit else np.int64))
+    return tuple(out)
+
+
+def key_sort_bits(sizes, words) -> list:
+    """`key_word_dtypes` as the record says it: bits a word, `[32]`,
+    `[64]`, `[64, 32]`."""
+    return [8 * dt.itemsize for dt in key_word_dtypes(sizes, words)]
+
+
 def build_group_key64(ids, sizes, xp, words=None):
-    """Mixed-radix combine into int64. Callers guard product < 2^62, or
-    hand `words` (`pack_key_words` of `sizes`): then a tuple of keys, a
-    word, each the combine of its positions' ids under `key_radix`."""
-    def combine(ids, radix):
+    """Mixed-radix combine into one key word, int64, or with `words`
+    (`pack_key_words` of `sizes`) into each word's `key_word_dtypes`: a
+    word whose values fit 31 bits is combined in int32 (no emulated
+    64-bit multiply-add a row), any other in int64. One word: the array;
+    several: a tuple, each the combine of its positions' ids under
+    `key_radix`. Callers without `words` guard product < 2^62."""
+    def combine(ids, radix, dtype):
+        word = getattr(xp, dtype.name)
         key = None
         for i, s in zip(ids, radix):
-            i = i.astype(xp.int64)
-            key = i if key is None else key * xp.int64(s) + i
-        return xp.zeros((), xp.int64) if key is None else key
+            i = i.astype(word)
+            key = i if key is None else key * word(s) + i
+        return xp.zeros((), word) if key is None else key
 
     total = 1
     for s in sizes:
         total *= int(s)
-    if words is not None and len(words) > 1:
+    dtypes = (np.dtype(np.int64),) if words is None \
+        else key_word_dtypes(sizes, words)
+    if len(dtypes) > 1:
         radix = key_radix(sizes, words)
-        return tuple(combine([ids[i] for i in w], [radix[i] for i in w])
-                     for w in words), total
+        return tuple(combine([ids[i] for i in w], [radix[i] for i in w], dt)
+                     for w, dt in zip(words, dtypes)), total
     if total >= (1 << KEY_WORD_BITS):
         raise UnsupportedAggregation(
             f"group space {total} overflows the int64 key")
-    return combine(ids, sizes), total
+    return combine(ids, sizes, dtypes[0]), total
 
 
 def _key_words(key) -> tuple:
@@ -171,9 +212,17 @@ def _key_words(key) -> tuple:
     return tuple(key) if isinstance(key, (tuple, list)) else (key,)
 
 
+def _sentinel(word):
+    """What a masked row holds in word 0 of the key, and an empty slot's
+    key inside the program: the maximum of the word's dtype (of an int64
+    word the SENTINEL)."""
+    dtype = np.dtype(word.dtype)
+    return dtype.type(np.iinfo(dtype).max)
+
+
 def _changes(skeys):
     """[N-1] bool: where a sorted key differs from the row before it, in
-    any word."""
+    any word (each compared in its own width)."""
     import functools
     import operator
     return functools.reduce(operator.or_,
@@ -206,17 +255,18 @@ def _sorted_segments(skey, cap, xp):
     """boundary/gid/count core shared by row reduction and table merge:
     gid clips into the dropped overflow+sentinel slot `cap`. `skey`: the
     sorted key, an array or a tuple of its words (a masked row holds the
-    SENTINEL in word 0; what its other words hold starts runs in the tail
-    that no slot keeps)."""
+    sentinel of word 0's dtype there; what its other words hold starts
+    runs in the tail that no slot keeps)."""
     skeys = _key_words(skey)
+    sentinel = _sentinel(skeys[0])
     boundary = xp.concatenate([
         xp.ones((1,), bool),
         _changes(skeys),
     ])
     flags = boundary.astype(xp.int32)
     gid = (np.cumsum(flags) if xp is np else _running(flags, "add")) - 1
-    count = (boundary & (skeys[0] != SENTINEL)).sum(dtype=xp.int32)
-    gid = xp.where((gid < cap) & (skeys[0] != SENTINEL), gid, cap)
+    count = (boundary & (skeys[0] != sentinel)).sum(dtype=xp.int32)
+    gid = xp.where((gid < cap) & (skeys[0] != sentinel), gid, cap)
     return gid, count
 
 
@@ -268,7 +318,7 @@ def _run_starts(skey, cap, xp, riders=()):
 
     skeys = _key_words(skey)
     n = skeys[0].shape[0]
-    valid = skeys[0] != SENTINEL
+    valid = skeys[0] != _sentinel(skeys[0])
     first = valid & xp.concatenate([xp.ones((1,), bool), _changes(skeys)])
     tail = valid.sum(dtype=xp.int32)
     pos = xp.where(first, xp.arange(n, dtype=xp.int32), tail)
@@ -474,18 +524,24 @@ def sum_word_bits(plans, col_dtypes, narrow: bool):
 
 def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
                         having=None, narrow=False, boundary=None):
-    """[N] int64 keys + mask -> compacted per-group partials.
+    """[N] keys + mask -> compacted per-group partials.
 
     Returns {"_keys": [cap] int64 (SENTINEL marks empty slots),
              "_count": [] int32 true unique count,
              "_rows": [cap], <agg name>: [cap] or [cap, m], ...}.
 
-    `key` is one [N] int64 array, or the tuple of a wide key's words
+    `key` is one [N] array, or the tuple of a wide key's words
     (`build_group_key64` with `words`): the words ride the sort as its
-    `num_keys` leading operands, a masked row holds the SENTINEL in word 0
+    `num_keys` leading operands, a masked row holds the sentinel in word 0
     alone, a run ends where any word changes, and the tables gain one a
     further word (`key_names`: `_keys1`, ...; what they hold in an empty
-    slot is not defined: `_keys` says which slots are present).
+    slot is not defined: `_keys` says which slots are present). A word is
+    int64 or int32 (`key_word_dtypes`: the dtype follows the bits the
+    word holds) and rides the sort, the boundary test and the gather of
+    its table in that width; the sentinel is the maximum of word 0's
+    dtype. The tables leave as int64 whatever the words rode as, an empty
+    slot's `_keys` the int64 SENTINEL: they are the int64 program's to
+    the bit.
 
     With `top` = (metric, threshold, inverted), the rows of that table a
     TopN by `metric` (a count or a sum of `plans`) keeps, as
@@ -541,7 +597,8 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
 
     kwords = _key_words(key)
     with stage_scope("sort", xp):
-        operands = [xp.where(mask, kwords[0], SENTINEL), *kwords[1:]]
+        operands = [xp.where(mask, kwords[0], _sentinel(kwords[0])),
+                    *kwords[1:]]
         for p in plans:
             m = mask
             if p.filter_fn is not None:
@@ -770,17 +827,17 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
     # The key of slot g is its first row's; past the present groups that
     # row is in the SENTINEL tail, or out of bounds
     with stage_scope("gather", xp):
+        def first_rows():
+            # taken anew a table: the text a wide key's program always had
+            return starts[:cap] if at is None else starts[at]
         out = {"_count": count, "_rows": kept(rows, at),
-               "_keys": skeys[0]
-               .at[starts[:cap] if at is None else starts[at]]
-               .get(mode="fill", fill_value=SENTINEL)}
+               "_keys": _key_table(skeys[0], first_rows(), 0, xp)}
         if live is not None:
             out["_kept"] = n_kept
             out["_rows"] = xp.where(live, out["_rows"], 0)
             out["_keys"] = xp.where(live, out["_keys"], SENTINEL)
-        for name, w in zip(key_names(len(kwords))[1:], skeys[1:]):
-            out[name] = w.at[starts[:cap] if at is None else starts[at]] \
-                .get(mode="fill", fill_value=0)
+        for w, name in enumerate(key_names(len(kwords))[1:], 1):
+            out[name] = _key_table(skeys[w], first_rows(), w, xp)
     if narrowed:
         # |a run's sum| <= its rows x the column's largest |value|: where
         # that fits int32 for the longest run, every wrapped difference
@@ -832,6 +889,21 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
             out[p.name] = kept(t[:cap], at)
             continue
     return out
+
+
+def _key_table(word, first_rows, w, xp):
+    """The table of word `w` of the key: the sorted `word` at the runs'
+    `first_rows`, read in the word's own width (an int32 word is ONE u32
+    gather where an int64 is two) and widened to the int64 every caller
+    holds. Past the present groups the row is in the sentinel tail or out
+    of bounds: word 0 holds the int64 SENTINEL there, a later word 0 or
+    what the tail holds (`_keys` says which slots are present)."""
+    fill = _sentinel(word) if w == 0 else 0
+    table = word.at[first_rows].get(mode="fill", fill_value=fill)
+    if table.dtype == np.int64:
+        return table
+    wide = table.astype(np.int64)
+    return xp.where(table == fill, SENTINEL, wide) if w == 0 else wide
 
 
 def _gathered(p) -> bool:
@@ -907,13 +979,14 @@ def sparse_group_count(key, mask, xp):
     import jax
 
     words = _key_words(key)
+    sentinel = _sentinel(words[0])
     with stage_scope("sort", xp):
         skeys = jax.lax.sort(
-            (xp.where(mask, words[0], SENTINEL), *words[1:]),
+            (xp.where(mask, words[0], sentinel), *words[1:]),
             num_keys=len(words), is_stable=False)
     with stage_scope("runs", xp):
         first = xp.concatenate([xp.ones((1,), bool), _changes(skeys)])
-        return {"_count": (first & (skeys[0] != SENTINEL))
+        return {"_count": (first & (skeys[0] != sentinel))
                 .sum(dtype=xp.int32)}
 
 
