@@ -18,7 +18,7 @@ import pytest
 from perfbench.datasets import tpch_flat_having
 from tpu_olap import Engine
 from tpu_olap.executor import EngineConfig
-from tpu_olap.executor import runner as runner_mod
+from tpu_olap.executor import sparse_dispatch
 
 Q18_ROWS = 400_000
 # (seed, orders past 300 / 312 / 315): the second seed leaves q18_315 empty
@@ -75,7 +75,7 @@ def test_q18_equals_the_plain_reference_and_pandas(q18, template):
     assert rec["sum_word_bits"] == 32 and "narrow_fallback" not in rec
     assert rec["having_groups_in"] == reference["n_orders"] \
         == rec["present_groups"]
-    assert rec["having_rows_fetched"] == runner_mod.HAVING_KEPT_MIN
+    assert rec["having_rows_fetched"] == sparse_dispatch.HAVING_KEPT_MIN
 
 
 def test_q18s_three_literals_are_one_program(q18):
@@ -133,8 +133,11 @@ def device(table):
 @pytest.fixture(scope="module")
 def host(table):
     """The same engine with the rule turned off: the parent's path."""
+    import dataclasses
     eng = _engine(table)
-    eng.runner._device_having = lambda plan: False
+    lower = eng.runner._lower_cached_inner
+    eng.runner._lower_cached_inner = lambda query, t: dataclasses.replace(
+        lower(query, t), having=None)   # nothing the device can decide
     yield eng
     eng.close()
 
@@ -172,7 +175,7 @@ def test_every_form_device_against_host(device, host, case, having):
         == on_dev["present_groups"]
     # the device brings the kept bucket, the host the compact table
     kept = on_dev["having_rows_fetched"]
-    assert kept >= max(runner_mod.HAVING_KEPT_MIN, len(got)) \
+    assert kept >= max(sparse_dispatch.HAVING_KEPT_MIN, len(got)) \
         and kept & (kept - 1) == 0
     assert on_host["having_rows_fetched"] == on_host["sparse_cap"] > kept
     # only the tested aggregates' tables are built at [cap]
@@ -470,8 +473,8 @@ def test_the_counted_cap_decides_how_the_table_program_reads(table):
         assert rec["sparse_attempts"] >= 2
         assert rec["boundary_read"] == "sorted"
         tables = [k for k in eng.runner._jit_cache
-                  if "sparse" in k and rec["sparse_cap"] in k]
-        assert tables and all(k[-1] == "sorted" for k in tables)
+                  if "sparse" in k and k[-1].cap == rec["sparse_cap"]]
+        assert tables and all(k[-1].boundary == "sorted" for k in tables)
         eng.sql(sql)
         rec = eng.runner.history[-1]
         assert rec["boundary_read"] == "sorted" and rec["jit_cache_hit"] \

@@ -11,8 +11,19 @@ import pytest
 
 from tpu_olap import Engine
 from tpu_olap.bench.parity import check_query
-from tpu_olap.executor import EngineConfig
+from tpu_olap.executor import EngineConfig, sparse_dispatch
 from tpu_olap.executor.lowering import lower
+from tpu_olap.kernels.sparse_groupby import SparseProgram, sparse_group_reduce
+
+
+def _reduce(key, mask, env, plans, cap, top=None, having=None, narrow=False,
+            boundary=None):
+    """`sparse_group_reduce`'s tables of the program a case's cut and
+    spellings name; `having`: (test, names, kept)."""
+    return sparse_group_reduce(
+        key, mask, env, plans, {},
+        SparseProgram(cap, top, having and having[2], narrow, boundary),
+        having and having[:2])
 
 
 def _df(n=6000, seed=23):
@@ -111,7 +122,7 @@ def test_merge_propagates_local_overflow():
     def chip(n):  # a chip's compact table, fetched as the broker does
         return jax.device_get(sparse_group_reduce(
             jnp.arange(n, dtype=jnp.int64), jnp.ones(n, bool), env, plans,
-            cap, {}, jnp))
+            {}, SparseProgram(cap)))
     # chip A: 65 distinct keys -> local overflow drops one
     out_a = chip(65)
     assert int(out_a["_count"]) == 65  # local overflow signalled
@@ -434,7 +445,8 @@ def test_compact_tables_equal_the_numpy_reference(case):
 
     @jax.jit
     def run(key, mask, env):
-        return sparse_group_reduce(key, mask, env, plans, cap, {}, jnp)
+        return sparse_group_reduce(key, mask, env, plans, {},
+                                   SparseProgram(cap))
 
     got = jax.device_get(run(key, mask, env))
     want = _numpy_tables(key, mask, env, plans, cap)
@@ -525,7 +537,7 @@ def test_integer_min_max_tables_equal_the_numpy_reference(stored, aggs,
     got = jax.device_get(sparse_group_reduce(
         jnp.asarray(key), jnp.asarray(mask),
         {k: {c: jnp.asarray(a) for c, a in d.items()}
-         for k, d in env.items()}, plans, cap, {}, jnp))
+         for k, d in env.items()}, plans, {}, SparseProgram(cap)))
     want = _numpy_tables(key, mask, env, plans, cap)
     assert int(got["_count"]) == int(want["_count"])
     if want["_count"] > cap:
@@ -579,7 +591,7 @@ def test_a_narrow_min_max_program_holds_no_scatter_and_an_int64_ones_does():
         """(the program's text, the operands of its widest sort)"""
         env = {"cols": {"v": jnp.arange(n).astype(dtype)}, "nulls": {}}
         jaxpr = jax.make_jaxpr(lambda k, m, e: sparse_group_reduce(
-            k, m, e, plans, cap, {}, jnp))(
+            k, m, e, plans, {}, SparseProgram(cap)))(
             jnp.arange(n, dtype=jnp.int64) % 50, jnp.ones(n, bool), env)
         return str(jaxpr), max(len(e.invars) for e in jaxpr.jaxpr.eqns
                                if e.primitive.name == "sort")
@@ -643,7 +655,7 @@ def test_no_scatter_in_a_sum_and_count_program_and_a_sketch_says_so():
     env = {"cols": {"v": jnp.arange(n, dtype=jnp.int64),
                     "f": jnp.arange(n, dtype=jnp.int64) - 7}, "nulls": {}}
     jaxpr = jax.make_jaxpr(lambda k, m, e: sparse_group_reduce(
-        k, m, e, plans, 64, {}, jnp))(
+        k, m, e, plans, {}, SparseProgram(64)))(
         jnp.arange(n, dtype=jnp.int64) % 50, jnp.ones(n, bool), env)
     assert "scatter" not in str(jaxpr)
     assert "sort" in str(jaxpr)
@@ -779,10 +791,11 @@ def test_kept_rows_equal_every_table_cut_after_the_ranking(case):
 
     @jax.jit
     def run(key, mask, env):
-        return (sparse_group_reduce(key, mask, env, plans, cap, {}, jnp,
-                                    top),
+        return (sparse_group_reduce(key, mask, env, plans, {},
+                                    SparseProgram(cap, top)),
                 _all_tables_then_cut(
-                    sparse_group_reduce(key, mask, env, plans, cap, {}, jnp),
+                    sparse_group_reduce(key, mask, env, plans, {},
+                                        SparseProgram(cap)),
                     *top))
 
     got, want = jax.device_get(run(key, mask, env))
@@ -838,7 +851,7 @@ def _lowered_sparse(plans, cap, top, dtype=np.int8, nulls=False):
                     "d": jnp.arange(n).astype(dtype)},
            "nulls": {"d": jnp.arange(n) % 3 == 0} if nulls else {}}
     return jax.jit(lambda k, m, e: sparse_group_reduce(
-        k, m, e, plans, cap, {}, jnp, top)).lower(
+        k, m, e, plans, {}, SparseProgram(cap, top))).lower(
         jnp.arange(n, dtype=jnp.int64) % 50, jnp.ones(n, bool), env), \
         {c: a.dtype for c, a in env["cols"].items()}, set(env["nulls"])
 
@@ -992,8 +1005,7 @@ def test_narrow_sums_equal_the_wide_programs_tables(case):
 
     @jax.jit
     def run(key, mask, env):
-        return [sparse_group_reduce(key, mask, env, plans, cap, {}, jnp,
-                                    top, having, narrow)
+        return [_reduce(key, mask, env, plans, cap, top, having, narrow)
                 for narrow in (True, False)]
 
     got, want = jax.device_get(run(key, mask, env))
@@ -1052,8 +1064,8 @@ def test_narrow_ok_holds_exactly_where_rows_times_the_largest_value_fit(
     env = {"cols": {"x": x}, "nulls": {}}
     plans = [_agg("s", "sum", "x")]
     got = {narrow: jax.device_get(jax.jit(
-        lambda k, m, e: sparse_group_reduce(k, m, e, plans, 8, {}, jnp,
-                                            None, None, narrow))(
+        lambda k, m, e: sparse_group_reduce(
+            k, m, e, plans, {}, SparseProgram(8, narrow=narrow)))(
         key, mask, env)) for narrow in (True, False)}
     assert bool(got[True]["_narrow_ok"]) == ok
     want = _numpy_tables(key, mask, env, plans, 8)["s"]
@@ -1092,8 +1104,8 @@ def _lowered_text(plans, narrow, top=None, having=None):
     from tpu_olap.kernels.sparse_groupby import sparse_group_reduce
     EngineConfig().apply_x64()
     env, key, mask = _narrow_env()
-    return jax.jit(lambda k, m, e: sparse_group_reduce(
-        k, m, e, plans, 64, {}, jnp, top, having, narrow)).lower(
+    return jax.jit(lambda k, m, e: _reduce(
+        k, m, e, plans, 64, top, having, narrow)).lower(
         key, mask, env).as_text()
 
 
@@ -1144,7 +1156,7 @@ def test_a_narrow_sum_rides_the_sort_as_one_int32_operand():
 
     def program(narrow):
         return lambda k, m, e: sparse_group_reduce(
-            k, m, e, plans, 64, {}, jnp, None, None, narrow)
+            k, m, e, plans, {}, SparseProgram(64, narrow=narrow))
 
     assert _sort_operand_dtypes(program(True), key, mask, env) \
         == ["int64", "int32", "int32", "int64"]
@@ -1307,12 +1319,13 @@ def test_a_meshs_sparse_programs_stay_wide():
     consts_dev, seg_arg = eng.runner._args_for(phys, seg_mask,
                                                eng.runner.mesh)
     args = (env, valid, seg_arg, consts_dev)
-    program = sh.mesh_sparse_kernel(phys, eng.runner.mesh, 64)
+    program = sh.mesh_sparse_kernel(phys, eng.runner.mesh,
+                                    SparseProgram(64))
     # the sums ride wide; the key's one word (a space of a few thousand
     # groups) rides as int32 on a mesh as on one chip (`key_word_dtypes`)
     assert _sort_operand_dtypes(program, *args) == ["int32"] + ["int64"] * 2
     assert "_narrow_ok" not in jax.eval_shape(program, *args)
-    one_chip = phys.make_sparse_kernel(64, None, None, True)
+    one_chip = phys.make_sparse_kernel(SparseProgram(64, narrow=True))
     assert "_narrow_ok" in jax.eval_shape(one_chip, *args)
 
 
@@ -1418,9 +1431,9 @@ def test_sorted_boundary_reads_equal_the_gathers(aggs, shape, cut,
 
     def run(spelling):
         return jax.device_get(jax.jit(
-            lambda k, m, e: sg.sparse_group_reduce(
-                k, m, e, plans, cap, {}, jnp, top, having, narrow,
-                spelling))(key, mask, env))
+            lambda k, m, e: _reduce(
+                k, m, e, plans, cap, top, having, narrow, spelling))(
+            key, mask, env))
 
     got, want = run("sorted"), run("gather")
     assert set(got) == set(want)
@@ -1487,8 +1500,8 @@ def test_the_rule_is_rows_a_slot_and_the_gather_side_keeps_its_program():
         read = sg.boundary_read(plans, stored, cap, n) if ruled else None
 
         def fn(k, m, e):
-            return sg.sparse_group_reduce(k, m, e, plans, cap, {}, jnp,
-                                          boundary=read)
+            return sg.sparse_group_reduce(
+                k, m, e, plans, {}, sg.SparseProgram(cap, boundary=read))
         return read, _position_sorts(fn, *args), jax.jit(fn).lower(*args)
 
     read, sorts, lowered = program(8 * (cap + 1))
@@ -1554,9 +1567,9 @@ def test_a_meshs_record_says_how_its_programs_rows_and_cap_read(
     env, valid, seg_mask = eng.runner._prepare(phys, {})
     consts_dev, seg_arg = eng.runner._args_for(phys, seg_mask,
                                                eng.runner.mesh)
-    key = eng.runner._sparse_key(phys, 8) \
-        + ("gspmd" if gspmd else "mesh", cap) \
-        + (("sorted",) if read == "sorted" else ())
+    key = sparse_dispatch.sparse_key(phys, 8) \
+        + ("gspmd" if gspmd else "mesh",
+           sg.SparseProgram(cap, boundary=read))
     sorts = _position_sorts(eng.runner._jit_cache[key], env, valid, seg_arg,
                             consts_dev)
     assert (max(sorts) > 1) == (read == "sorted"), sorts
@@ -1565,7 +1578,8 @@ def test_a_meshs_record_says_how_its_programs_rows_and_cap_read(
         # program as the runner says it
         other = "gather" if read == "sorted" else "sorted"
         sorts = _position_sorts(
-            sh.mesh_sparse_kernel(phys, eng.runner.mesh, cap, other),
+            sh.mesh_sparse_kernel(phys, eng.runner.mesh,
+                                  sg.SparseProgram(cap, boundary=other)),
             env, valid, seg_arg, consts_dev)
         assert (max(sorts) > 1) == (other == "sorted"), sorts
 
@@ -1666,32 +1680,30 @@ def test_build_group_key64_combines_a_narrow_word_in_int32():
     from tpu_olap.kernels import sparse_groupby as sg
     EngineConfig().apply_x64()
     rng = np.random.default_rng(44)
-    for xp in (np, jnp):
-        # one word under 2^31 - 1: int32; without `words`, int64 as ever
-        sizes = (3, 715_827_882)
-        ids = [rng.integers(0, s, 200).astype(np.int32) for s in sizes]
-        ids[0][0], ids[1][0] = 2, sizes[1] - 1      # the largest key
-        words = sg.pack_key_words(sizes)
-        key, total = sg.build_group_key64([xp.asarray(i) for i in ids],
-                                          sizes, xp, words)
-        assert key.dtype == np.int32 and total == I32_TOP - 1
-        wide, _ = sg.build_group_key64([xp.asarray(i) for i in ids], sizes,
-                                       xp)
-        assert wide.dtype == np.int64
-        np.testing.assert_array_equal(np.asarray(key), np.asarray(wide))
-        assert int(np.asarray(key)[0]) == I32_TOP - 2
-        # q10p's shape: (int64, int32), each word the int64 combine's
-        sizes = (1_499_999, 1_000_000, 1_099_999, 25)
-        ids = [rng.integers(0, s, 200).astype(np.int32) for s in sizes]
-        words = sg.pack_key_words(sizes)
-        keys, _ = sg.build_group_key64([xp.asarray(i) for i in ids], sizes,
-                                       xp, words)
-        assert [str(k.dtype) for k in keys] == ["int64", "int32"]
-        np.testing.assert_array_equal(np.asarray(keys[1]), ids[3])
-        radix = sg.key_radix(sizes, words)
-        want = (ids[0].astype(np.int64) * radix[1] + ids[1]) * radix[2] \
-            + ids[2]
-        np.testing.assert_array_equal(np.asarray(keys[0]), want)
+    # one word under 2^31 - 1: int32; without `words`, int64 as ever
+    sizes = (3, 715_827_882)
+    ids = [rng.integers(0, s, 200).astype(np.int32) for s in sizes]
+    ids[0][0], ids[1][0] = 2, sizes[1] - 1      # the largest key
+    words = sg.pack_key_words(sizes)
+    key, total = sg.build_group_key64([jnp.asarray(i) for i in ids],
+                                      sizes, words)
+    assert key.dtype == np.int32 and total == I32_TOP - 1
+    wide, _ = sg.build_group_key64([jnp.asarray(i) for i in ids], sizes)
+    assert wide.dtype == np.int64
+    np.testing.assert_array_equal(np.asarray(key), np.asarray(wide))
+    assert int(np.asarray(key)[0]) == I32_TOP - 2
+    # q10p's shape: (int64, int32), each word the int64 combine's
+    sizes = (1_499_999, 1_000_000, 1_099_999, 25)
+    ids = [rng.integers(0, s, 200).astype(np.int32) for s in sizes]
+    words = sg.pack_key_words(sizes)
+    keys, _ = sg.build_group_key64([jnp.asarray(i) for i in ids], sizes,
+                                   words)
+    assert [str(k.dtype) for k in keys] == ["int64", "int32"]
+    np.testing.assert_array_equal(np.asarray(keys[1]), ids[3])
+    radix = sg.key_radix(sizes, words)
+    want = (ids[0].astype(np.int64) * radix[1] + ids[1]) * radix[2] \
+        + ids[2]
+    np.testing.assert_array_equal(np.asarray(keys[0]), want)
 
 
 def _narrow_keys(eng):

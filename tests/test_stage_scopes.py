@@ -27,7 +27,7 @@ import pytest
 from tpu_olap import Engine
 from tpu_olap.bench import QUERIES
 from tpu_olap.bench.ssb import generate_tables, register_ssb
-from tpu_olap.executor import EngineConfig
+from tpu_olap.executor import EngineConfig, sparse_dispatch
 from tpu_olap.kernels.groupby import STAGES, stage_scope
 
 ROWS = 20_000
@@ -191,12 +191,12 @@ def _sparse(ssb_tables, sql, form, top=False, having=False, **cfg):
     args = _dispatch_args(eng, phys)
     stored = {c: a.dtype for c, a in args[0]["cols"].items()}
     assert sparse_reduce_form(phys.agg_plans, stored, 4096) == form
-    threshold = eng.runner._device_threshold(phys.query, phys) \
-        if top else None
-    assert (threshold is not None) == top
-    assert eng.runner._device_having(phys) == having
-    return jax.jit(phys.make_sparse_kernel(
-        4096, threshold, 1024 if having else None)).lower(*args)
+    program = sparse_dispatch.choose_program(
+        eng.runner, phys, stored, frozenset(args[0]["nulls"]), args[1].size,
+        4096)
+    assert (program.top is not None) == top
+    assert (program.kept == 1024) == having == (program.kept is not None)
+    return jax.jit(phys.make_sparse_kernel(program)).lower(*args)
 
 
 def _mesh(ssb_tables, which):
@@ -208,7 +208,8 @@ def _mesh(ssb_tables, which):
     mesh = eng.runner.mesh
     phys = _physical(eng, SPARSE_BOUNDARY_SQL)
     args = _dispatch_args(eng, phys, mesh)
-    sort = sh.mesh_sparse_kernel(phys, mesh, 4096)
+    from tpu_olap.kernels.sparse_groupby import SparseProgram
+    sort = sh.mesh_sparse_kernel(phys, mesh, SparseProgram(4096))
     if which == "sort":
         return sort.lower(*args)
     tables = {k: v for k, v in jax.eval_shape(sort, *args).items()

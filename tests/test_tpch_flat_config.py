@@ -201,8 +201,8 @@ def test_four_chips_tables_merge_to_the_one_chip_table():
     import numpy as np
 
     from tpu_olap.kernels.groupby import AggPlan
-    from tpu_olap.kernels.sparse_groupby import (SENTINEL, merge_device,
-                                                 merge_sparse,
+    from tpu_olap.kernels.sparse_groupby import (SENTINEL, SparseProgram,
+                                                 merge_device, merge_sparse,
                                                  sparse_group_reduce)
 
     cap = 64
@@ -221,7 +221,8 @@ def test_four_chips_tables_merge_to_the_one_chip_table():
     def table(keys, mask, v, cap):
         env = {"cols": {"v": jnp.asarray(v)}, "nulls": {}}
         return jax.device_get(sparse_group_reduce(
-            jnp.asarray(keys), jnp.asarray(mask), env, plans, cap, {}, jnp))
+            jnp.asarray(keys), jnp.asarray(mask), env, plans, {},
+            SparseProgram(cap)))
 
     parts = [table(*c, cap) for c in chips]
     counts = [int(p["_count"]) for p in parts]
@@ -241,7 +242,7 @@ def test_four_chips_tables_merge_to_the_one_chip_table():
     # broker's table slot for slot, the reduces' identities past it
     laid = {k: jnp.concatenate([jnp.asarray(p[k]) for p in parts])
             for k in parts[0] if k != "_count"}
-    on_device = jax.device_get(merge_device(laid, plans, len(parts), jnp))
+    on_device = jax.device_get(merge_device(laid, plans, len(parts)))
     assert int(on_device["_count"]) == n
     assert set(on_device) == set(merged)
     for name, table in merged.items():

@@ -10,6 +10,7 @@ lives in a fixture (one xdist worker loads libtpu, the others never do),
 never at import, in a skipif, in parametrize arguments, or in conftest.
 """
 
+import dataclasses
 import math
 import os
 import re
@@ -19,7 +20,7 @@ import pytest
 from tpu_olap import Engine
 from tpu_olap.bench import QUERIES
 from tpu_olap.bench.ssb import generate_tables, register_ssb
-from tpu_olap.executor import EngineConfig
+from tpu_olap.executor import EngineConfig, sparse_dispatch
 
 ROWS = 120_000
 ROWS_75M = 75_000_000
@@ -123,6 +124,14 @@ def _scaled(tree, seg_factor, sharding):
         return jax.ShapeDtypeStruct(shape, x.dtype, sharding=sharding)
 
     return jax.tree_util.tree_map(leaf, tree)
+
+
+def _chosen(eng, phys, env, rows, cap):
+    """The sparse program of `cap` slots over `rows` sorted rows, as the
+    dispatch chooses it (`sparse_dispatch.choose_program`)."""
+    return sparse_dispatch.choose_program(
+        eng.runner, phys, {c: a.dtype for c, a in env["cols"].items()},
+        frozenset(env["nulls"]), rows, cap)
 
 
 def compile_dispatch(eng, phys, sharding, rows=None):
@@ -252,18 +261,21 @@ def test_sparse_topn_program_compiles_for_v5e(topo, no_persistent_cache,
     assert phys.pallas_reason is not None
     assert COMPARE_MAX_GROUPS < phys.total_groups \
         <= eng.config.sparse_group_budget
-    top = eng.runner._device_threshold(phys.query, phys)
-    assert top == ("qty", 100, False)
     env, valid, seg_mask = eng.runner._prepare(phys, {})
+    # the wide program of the runner's choice (the narrow one is the
+    # Druid cell's test below)
+    program = dataclasses.replace(
+        _chosen(eng, phys, env, valid.size, phys.total_groups), narrow=False)
+    assert program.top == ("qty", 100, False) and program.kept is None
     consts_dev, seg_arg = eng.runner._args_for(phys, seg_mask, None)
     one_chip = SingleDeviceSharding(topo.devices[0])
     import jax
-    compiled = jax.jit(phys.make_sparse_kernel(phys.total_groups, top)) \
+    compiled = jax.jit(phys.make_sparse_kernel(program)) \
         .lower(*_scaled((env, valid, seg_arg), 1, one_chip),
                _scaled(consts_dev, 1, one_chip)).compile()
     text = compiled.as_text()
     assert " scatter(" not in text and " sort(" in text
-    out = jax.eval_shape(phys.make_sparse_kernel(phys.total_groups, top),
+    out = jax.eval_shape(phys.make_sparse_kernel(program),
                          env, valid, seg_arg, consts_dev)
     assert {k: v.shape for k, v in out.items()} == {
         "_count": (), "_rows": (100,), "_keys": (100,), "qty": (100,),
@@ -293,8 +305,6 @@ def test_sparse_min_max_program_compiles_for_v5e(topo, no_persistent_cache,
     phys = _physical(eng,
                      druid_lineitem.templates()["top_100_parts_details"])
     assert phys.query.query_type == "topN" and phys.sparse
-    top = eng.runner._device_threshold(phys.query, phys)
-    assert top == ("sum_quantity", 100, False)
     env, valid, seg_mask = eng.runner._prepare(phys, {})
     assert env["cols"]["l_discount"].dtype == "int8"
     stored = {c: a.dtype for c, a in env["cols"].items()}
@@ -309,7 +319,13 @@ def test_sparse_min_max_program_compiles_for_v5e(topo, no_persistent_cache,
     from tpu_olap.kernels.sparse_groupby import sum_word_bits
     assert env["cols"]["l_extendedprice"].dtype == "int32"
     assert sum_word_bits(phys.agg_plans, stored, True) == 32
-    kernel = phys.make_sparse_kernel(phys.total_groups, top, None, True)
+    # at the cell's 30 rows a slot the ranked table is a gather
+    program = _chosen(eng, phys, env, 59_986_052, phys.total_groups)
+    from tpu_olap.kernels.sparse_groupby import SparseProgram
+    assert program == SparseProgram(
+        phys.total_groups, ("sum_quantity", 100, False), None, True,
+        "gather")
+    kernel = phys.make_sparse_kernel(program)
     lowered = jax.jit(kernel).lower(
         *_scaled((env, valid, seg_arg), 1, one_chip),
         _scaled(consts_dev, 1, one_chip))
@@ -366,7 +382,7 @@ def test_sparse_having_program_compiles_for_v5e(topo, no_persistent_cache,
     phys = _physical(eng, tpch_flat_having.templates()["q18"])
     assert phys.query.query_type == "groupBy" and phys.sparse
     assert phys.total_groups > cap
-    assert eng.runner._device_having(phys)
+    assert sparse_dispatch.device_having(eng.runner.mesh, phys)
     assert phys.having[1] == {"sum_quantity"}
     env, valid, seg_mask = eng.runner._prepare(phys, {})
     assert env["cols"]["o_totalprice"].dtype == "int32"
@@ -382,11 +398,11 @@ def test_sparse_having_program_compiles_for_v5e(topo, no_persistent_cache,
     assert ext_word_bits(phys.agg_plans, stored, 1 << 16) == 64
     # the narrow program, which the runner tries first (PR 41): the
     # tested sum(l_quantity), stored as int8, rides as one int32 word
-    # as the runner builds it: the rule's answer for these rows and cap
-    read = sg.boundary_read(phys.agg_plans, stored, 1 << 16, valid.size,
-                            having=phys.having[1])
-    assert read == "sorted" == sg.boundary_spelling(62_062_592, cap)
-    kernel = phys.make_sparse_kernel(1 << 16, None, kept, True, read)
+    # as the runner builds it: the rules' answers for these rows and cap
+    program = _chosen(eng, phys, env, valid.size, 1 << 16)
+    assert program == sg.SparseProgram(1 << 16, None, kept, True, "sorted")
+    assert sg.boundary_spelling(62_062_592, cap) == "sorted"
+    kernel = phys.make_sparse_kernel(program)
     lowered = jax.jit(kernel).lower(*args)
     main_sort = max(re.findall(r"stablehlo\.sort\"?\(([^)]*)\)",
                                lowered.as_text()), key=len)
@@ -408,8 +424,8 @@ def test_sparse_having_program_compiles_for_v5e(topo, no_persistent_cache,
     assert not cap_gathers(text, "s32") and not cap_gathers(text, "u32")
     # said `gather` the prefix is ONE cap-sized gather of an s32, where
     # the wide program's int64 prefix is two of a u32 half
-    gathered = jax.jit(phys.make_sparse_kernel(
-        1 << 16, None, kept, True, "gather")).lower(*args)
+    gathered = jax.jit(phys.make_sparse_kernel(dataclasses.replace(
+        program, boundary="gather"))).lower(*args)
     assert sort_operands(gathered) == [1, 1, 3]
     text = gathered.compile().as_text()
     assert cap_gathers(text, "s32") == 1 and not cap_gathers(text, "u32")
@@ -421,10 +437,12 @@ def test_sparse_having_program_compiles_for_v5e(topo, no_persistent_cache,
         "sum_quantity": ((kept,), "int64"),
         "o_totalprice": ((kept,), "int64"),
         "_nn_o_totalprice": ((kept,), "int32")}
-    count = jax.jit(phys.make_sparse_kernel(None)).lower(*args).compile()
+    counts = phys.make_sparse_kernel(
+        _chosen(eng, phys, env, valid.size, None))
+    count = jax.jit(counts).lower(*args).compile()
     assert count.as_text().count(" sort(") == 1
-    assert jax.eval_shape(phys.make_sparse_kernel(None), env, valid,
-                          seg_arg, consts_dev)["_count"].shape == ()
+    assert jax.eval_shape(counts, env, valid, seg_arg,
+                          consts_dev)["_count"].shape == ()
 
 
 def test_sparse_wide_key_program_compiles_for_v5e(topo, no_persistent_cache,
@@ -442,7 +460,7 @@ def test_sparse_wide_key_program_compiles_for_v5e(topo, no_persistent_cache,
     from jax.sharding import SingleDeviceSharding
 
     from perfbench.datasets import tpch_flat_widekey
-    from tpu_olap.kernels.sparse_groupby import boundary_read, cap_tables
+    from tpu_olap.kernels.sparse_groupby import SparseProgram, cap_tables
     _as_tpu(monkeypatch)
     rows, seed, cap, kept = 60_000, 2_147_483_659, 1 << 24, 1024
     data = tpch_flat_widekey.generate(rows, seed, str(tmp_path), workers=1,
@@ -454,7 +472,7 @@ def test_sparse_wide_key_program_compiles_for_v5e(topo, no_persistent_cache,
     assert phys.query.query_type == "groupBy" and phys.sparse
     assert phys.total_groups >= 1 << 62 and len(phys.key_words) == 2
     assert sorted(sum(phys.key_words, ())) == [1, 2, 3, 4, 5]
-    assert eng.runner._device_having(phys)
+    assert sparse_dispatch.device_having(eng.runner.mesh, phys)
     env, valid, seg_mask = eng.runner._prepare(phys, {})
     stored = {c: a.dtype for c, a in env["cols"].items()}
     assert cap_tables(phys.agg_plans, stored, cap,
@@ -463,10 +481,9 @@ def test_sparse_wide_key_program_compiles_for_v5e(topo, no_persistent_cache,
     one_chip = SingleDeviceSharding(topo.devices[0])
     args = (*_scaled((env, valid, seg_arg), 1, one_chip),
             _scaled(consts_dev, 1, one_chip))
-    read = boundary_read(phys.agg_plans, stored, 1 << 16, valid.size,
-                         having=phys.having[1])
-    assert read == "sorted"
-    kernel = phys.make_sparse_kernel(1 << 16, None, kept, True, read)
+    program = _chosen(eng, phys, env, valid.size, 1 << 16)
+    assert program == SparseProgram(1 << 16, None, kept, True, "sorted")
+    kernel = phys.make_sparse_kernel(program)
     lowered = jax.jit(kernel).lower(*args)
     main_sort = max(re.findall(r"stablehlo\.sort\"?\(([^)]*)\)",
                                lowered.as_text()), key=len)
@@ -486,10 +503,12 @@ def test_sparse_wide_key_program_compiles_for_v5e(topo, no_persistent_cache,
         "_rows": ((kept,), "int32"), "_keys": ((kept,), "int64"),
         "_keys1": ((kept,), "int64"),
         "sum_quantity": ((kept,), "int64")}
-    count = jax.jit(phys.make_sparse_kernel(None)).lower(*args).compile()
+    counts = phys.make_sparse_kernel(
+        _chosen(eng, phys, env, valid.size, None))
+    count = jax.jit(counts).lower(*args).compile()
     assert count.as_text().count(" sort(") == 1
-    assert jax.eval_shape(phys.make_sparse_kernel(None), env, valid,
-                          seg_arg, consts_dev)["_count"].shape == ()
+    assert jax.eval_shape(counts, env, valid, seg_arg,
+                          consts_dev)["_count"].shape == ()
 
 
 @pytest.mark.parametrize("word", ["int32", "int64"])
@@ -656,7 +675,8 @@ def test_sparse_program_maps_over_four_chips_as_one_program(
     args = (jax.tree_util.tree_map(lambda x: struct(x, seg), env),
             struct(valid, seg), struct(seg_mask, seg),
             {k: struct(v, P()) for k, v in phys.pool.consts.items()})
-    fn = sh.mesh_sparse_kernel(phys, mesh, cap)
+    from tpu_olap.kernels.sparse_groupby import SparseProgram
+    fn = sh.mesh_sparse_kernel(phys, mesh, SparseProgram(cap))
     text = fn.lower(*args).compile().as_text()
     assert " sort(" in text and " scatter(" not in text
     assert not [c for c in COLLECTIVES if c in text]

@@ -22,6 +22,16 @@ from tpu_olap.kernels import sparse_groupby as sg
 from tpu_olap.kernels.groupby import AggPlan, _ident
 
 
+def _reduce(key, mask, env, plans, cap, top=None, having=None, narrow=False,
+            boundary=None):
+    """`sparse_group_reduce`'s tables of the program a case's cut and
+    spellings name; `having`: (test, names, kept)."""
+    return sg.sparse_group_reduce(
+        key, mask, env, plans, {},
+        sg.SparseProgram(cap, top, having and having[2], narrow, boundary),
+        having and having[:2])
+
+
 def _agg(name, kind, field=None, acc=np.int64, filter_fn=None):
     return AggPlan(name, kind, (field,) if field else (), acc, filter_fn)
 
@@ -60,11 +70,15 @@ def test_words_grow_with_the_space(n_dims, n_words):
 
 
 def test_build_group_key64_words_round_trip():
+    import jax.numpy as jnp
+    EngineConfig().apply_x64()
     sizes = (1_000_001, 1_500_000, 59_986_000, 2407, 55_000_000)
     rng = np.random.default_rng(7)
     ids = [rng.integers(0, s, 500).astype(np.int32) for s in sizes]
     words = sg.pack_key_words(sizes)
-    keys, total = sg.build_group_key64(ids, sizes, np, words)
+    keys, total = sg.build_group_key64([jnp.asarray(i) for i in ids], sizes,
+                                       words)
+    keys = [np.asarray(k) for k in keys]
     assert total == int(np.prod([float(s) for s in sizes])) or total > 1 << 62
     radix = sg.key_radix(sizes, words)
     for key, positions in zip(keys, words):
@@ -75,7 +89,7 @@ def test_build_group_key64_words_round_trip():
             key = key // radix[i]
     # one word: the caller's guard still stands
     with pytest.raises(Exception, match="overflows the int64 key"):
-        sg.build_group_key64(ids, sizes, np)
+        sg.build_group_key64(ids, sizes)
 
 
 # ------------------------------------------------- the kernel against numpy
@@ -195,8 +209,8 @@ def test_wide_key_tables_equal_the_numpy_group_by(case):
 
     @jax.jit
     def run(words, mask, env):
-        return sg.sparse_group_reduce(tuple(words), mask, env, plans, cap,
-                                      {}, jnp, None, having, narrow)
+        return _reduce(tuple(words), mask, env, plans, cap, None, having,
+                       narrow)
 
     got = jax.device_get(run(words, mask, env))
     want = _numpy_tables(words, mask, env, plans, cap)
@@ -243,7 +257,7 @@ def test_count_program_counts_the_groups_of_every_word(k):
              for _ in range(k)]
     mask = rng.random(n) < 0.7
     got = jax.jit(lambda w, m: sg.sparse_group_count(
-        tuple(w) if k > 1 else w[0], m, jnp))(words, mask)
+        tuple(w) if k > 1 else w[0], m))(words, mask)
     assert int(got["_count"]) \
         == np.unique(np.stack(words)[:, mask], axis=1).shape[1]
 
@@ -264,7 +278,7 @@ def test_one_word_is_the_program_it_was():
 
     def program(k):
         return lambda key, mask, env: sg.sparse_group_reduce(
-            k(key), mask, env, plans, 64, {}, jnp)
+            k(key), mask, env, plans, {}, sg.SparseProgram(64))
 
     array = jax.jit(program(lambda k: k)).lower(key, mask, env).as_text()
     one = jax.jit(program(lambda k: (k,))).lower(key, mask, env).as_text()
@@ -468,7 +482,8 @@ def test_a_domain_that_moves_keeps_the_wide_program(wide):
         phys = e.runner._lower_cached(plan.query, plan.entry.segments)
         env, valid, seg_mask = e.runner._prepare(phys, {})
         consts_dev, seg_arg = e.runner._args_for(phys, seg_mask, None)
-        texts.append(hashlib.sha256(jax.jit(phys.make_sparse_kernel(4096))
+        texts.append(hashlib.sha256(jax.jit(phys.make_sparse_kernel(
+            sg.SparseProgram(4096)))
                      .lower(env, valid, seg_arg, consts_dev).as_text()
                      .encode()).hexdigest())
         sizes = phys.sizes
@@ -537,8 +552,8 @@ def _key_program(shape, cut, read, narrow, wide):
             if wide:
                 words = tuple(w.astype(jnp.int64) for w in words)
             key = words if len(words) > 1 else words[0]
-            return sg.sparse_group_reduce(key, m, e, KEY_PLANS, 16, {}, jnp,
-                                          top, having, narrow, read)
+            return _reduce(key, m, e, KEY_PLANS, 16, top, having, narrow,
+                           read)
         _key_programs[name] = jax.jit(program)
     return _key_programs[name]
 
@@ -618,8 +633,7 @@ def test_a_narrow_keys_count_program_counts_numpys_groups(shape, data):
     EngineConfig().apply_x64()
     words, mask, _env = _key_inputs(shape, data)
     key = words if len(words) > 1 else words[0]
-    got = int(jax.jit(lambda k, m: sg.sparse_group_count(k, m, jnp))(
-        key, mask)["_count"])
+    got = int(jax.jit(sg.sparse_group_count)(key, mask)["_count"])
     assert got == np.unique(np.stack(
         [w.astype(np.int64) for w in words])[:, mask], axis=1).shape[1]
     assert (got == 0) == (data == "every-row-masked")
@@ -638,11 +652,11 @@ def test_words_ride_the_sort_and_the_key_gather_in_their_own_width(shape):
     dtypes = [str(w.dtype) for w in words]
     assert sorted(set(dtypes)) in (["int32"], ["int32", "int64"])
     ops = _sort_operand_dtypes(
-        lambda k, m, e: sg.sparse_group_reduce(k, m, e, KEY_PLANS, 16, {},
-                                               jnp), key, mask, env)
+        lambda k, m, e: sg.sparse_group_reduce(
+            k, m, e, KEY_PLANS, {}, sg.SparseProgram(16)), key, mask, env)
     assert ops[:len(words)] == dtypes
     assert _sort_operand_dtypes(
-        lambda k, m: sg.sparse_group_count(k, m, jnp), key, mask) == dtypes
+        sg.sparse_group_count, key, mask) == dtypes
 
 
 
@@ -728,7 +742,7 @@ def test_the_narrow_word_program_sorts_by_int64_words_then_int32(wide):
     args = (env, valid, seg_arg, consts_dev)
 
     for cap in (4096, None):
-        kernel = phys.make_sparse_kernel(cap)
+        kernel = phys.make_sparse_kernel(sg.SparseProgram(cap))
         assert _sort_operand_dtypes(kernel, *args)[:len(dtypes)] == dtypes
         out = jax.eval_shape(kernel, *args)
         assert cap is None or all(

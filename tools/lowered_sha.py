@@ -3,10 +3,12 @@ run (q3, q10, `q10p`, Q18, `q18p`, the three Druid TopNs: the count program,
 the narrow and the wide table programs at two caps; the mesh's sort and merge
 programs), on the tree given as argv[1], lowered here on the CPU at `ROWS`
 rows: what a PR that says "the other cells' programs are the parent's text"
-compares (PRs 41-43). Each table program is built as the runner builds it:
-where the tree has `sparse_groupby.boundary_read` its answer for the rows
-and the cap is passed on (at these rows and caps every program is past 8
-rows a slot: the `gather` side).
+compares (PRs 41-43). Each table program is the dispatch's own choice for
+the rows and the cap (`sparse_dispatch.choose_program`, made by
+`make_sparse_kernel(program)`; the wide program beside the narrow one by
+`dataclasses.replace`): at these rows and caps every program is past 8 rows
+a slot, the `gather` side. On a tree before PR 45 use that tree's copy of
+this tool.
 
 A key's width moves a program (PR 44: `sparse_groupby.key_word_dtypes`, a
 word whose ids fit 31 bits rides as int32): the three Druid TopNs, their
@@ -23,6 +25,7 @@ SF10's), with no dataset: there the rule answers for itself on either tree.
     python tools/lowered_sha.py . > /root/scratch/change.json
     python tools/lowered_sha.py /root/scratch/parent > /root/scratch/parent.json
 """
+import dataclasses
 import hashlib
 import json
 import os
@@ -86,7 +89,7 @@ def uncut_min_max(sg):
                     "e": jnp.zeros(ROWS, jnp.int32)}, "nulls": {}}
     return {f"uncut-min-max:{'narrow' if narrow else 'wide'}": sha(jax.jit(
         lambda k, m, e: sg.sparse_group_reduce(
-            k, m, e, plans, 1024, {}, jnp, None, None, narrow)).lower(
+            k, m, e, plans, {}, sg.SparseProgram(1024, narrow=narrow))).lower(
         jnp.zeros(ROWS, jnp.int64), jnp.ones(ROWS, bool), env))
         for narrow in (False, True)}
 
@@ -110,27 +113,23 @@ def key_shapes(sg):
         ids = [jnp.zeros(ROWS, jnp.int32) for _ in sizes]
 
         def key(ids):
-            return sg.build_group_key64(ids, sizes, jnp, words=words)[0]
-        bits = sg.key_sort_bits(sizes, words) \
-            if hasattr(sg, "key_sort_bits") else [64] * len(words)
+            return sg.build_group_key64(ids, sizes, words=words)[0]
         out[f"key-shape:{name}:words{len(words)}:count"] = sha(jax.jit(
-            lambda ids, m: sg.sparse_group_count(key(ids), m, jnp))
+            lambda ids, m: sg.sparse_group_count(key(ids), m))
             .lower(ids, mask))
         out[f"key-shape:{name}:words{len(words)}:table"] = sha(jax.jit(
             lambda ids, m, e: sg.sparse_group_reduce(
-                key(ids), m, e, plans, 1024, {}, jnp)).lower(ids, mask, env))
-        out[f"key-shape:{name}:rule-bits"] = bits
+                key(ids), m, e, plans, {}, sg.SparseProgram(1024)))
+            .lower(ids, mask, env))
+        out[f"key-shape:{name}:rule-bits"] = sg.key_sort_bits(sizes, words)
     return out
 
 
 def on_the_cells_path(sg, rule, name, phys):
-    """Make `sg.key_word_dtypes` (`rule`, the tree's own; None on a tree
-    before PR 44, whose words are all int64) answer with the widths
-    template `name`'s cell sorts at where the small plan has as many
-    words, and -> the tag's tail."""
+    """Make `sg.key_word_dtypes` (`rule`, the tree's own) answer with the
+    widths template `name`'s cell sorts at where the small plan has as
+    many words, and -> the tag's tail."""
     import numpy as np
-    if rule is None:
-        return ""
     own = [8 * d.itemsize for d in rule(phys.sizes, phys.key_words)]
     real = CELL_SORT_BITS[name]
     if len(own) != len(real):
@@ -145,10 +144,11 @@ def main():
     import importlib
 
     from tpu_olap import Engine
-    from tpu_olap.executor import EngineConfig
+    from tpu_olap.executor import EngineConfig, sparse_dispatch
+    from tpu_olap.executor import sharding as sh
     from tpu_olap.kernels import sparse_groupby as sg
     out = {}
-    rule = getattr(sg, "key_word_dtypes", None)
+    rule = sg.key_word_dtypes
     for cfg_name, names in CELLS.items():
         with open(f"perfbench/configs/{cfg_name}.json") as f:
             cfg = json.load(f)
@@ -169,50 +169,45 @@ def main():
             consts_dev, seg_arg = r._args_for(phys, seg_mask, r.mesh)
             tag = f"{cfg_name}:{name}"
             tail = on_the_cells_path(sg, rule, name, phys)
-            if r.mesh is not None:
-                from tpu_olap.executor import sharding as sh
+            win = r._segment_window(phys, len(seg_mask))
+            mesh = r.mesh
+            stored = {c: a.dtype for c, a in env["cols"].items()}
+            rows = ((win[1] * valid.shape[1]) if win else n) \
+                // (mesh.devices.size if mesh else 1)
+
+            def chosen(cap):
+                return sparse_dispatch.choose_program(
+                    r, phys, stored, frozenset(env["nulls"]), rows, cap,
+                    window=win[1] if win else None)
+
+            if mesh is not None:
                 for cap in (1024,):
+                    sort = sh.mesh_sparse_kernel(phys, mesh, chosen(cap))
                     out[f"{tag}:mesh-sort:{cap}"] = sha(
-                        sh.mesh_sparse_kernel(phys, r.mesh, cap).lower(
-                            env, valid, seg_arg, consts_dev))
-                    tables = jax.eval_shape(
-                        sh.mesh_sparse_kernel(phys, r.mesh, cap), env, valid,
-                        seg_arg, consts_dev)
+                        sort.lower(env, valid, seg_arg, consts_dev))
+                    tables = jax.eval_shape(sort, env, valid, seg_arg,
+                                            consts_dev)
                     tables = {k: v for k, v in tables.items()
                               if k != "_count"}
                     out[f"{tag}:mesh-merge:{cap}"] = sha(
-                        sh.mesh_merge_kernel(phys, r.mesh, 256).lower(tables))
+                        sh.mesh_merge_kernel(phys, mesh, 256).lower(tables))
                 continue
-            top = r._device_threshold(plan.query, phys) \
-                if type(plan.query).__name__.startswith("TopN") else None
-            kept = 1024 if r._device_having(phys) else None
-            win = r._segment_window(phys, len(seg_mask))
 
-            stored = {c: a.dtype for c, a in env["cols"].items()}
-            rows = (win[1] * valid.shape[1]) if win else n
+            def lower(program):
+                return sparse_dispatch.jit_whole(r, phys, program).lower(
+                    env, valid, seg_arg, consts_dev,
+                    *([win[0]] if win else []))
 
-            def lower(cap, narrow):
-                read = getattr(sg, "boundary_read", None) if cap else None
-                kern = phys.make_sparse_kernel(
-                    cap, top, kept if cap else None, narrow,
-                    *([read(phys.agg_plans, stored, cap, rows, top,
-                            frozenset(env["nulls"]),
-                            phys.having[1] if kept else None)]
-                      if read else []))
-                if win is not None:
-                    return jax.jit(r._window_kernel(kern, win[1])).lower(
-                        env, valid, seg_arg, consts_dev, win[0])
-                return jax.jit(kern).lower(env, valid, seg_arg, consts_dev)
-
-            out[f"{tag}:count{tail}"] = sha(lower(None, False))
+            out[f"{tag}:count{tail}"] = sha(lower(chosen(None)))
             for cap in (1024, 4096):
+                program = chosen(cap)
                 for narrow in (False, True):
                     out[f"{tag}:cap{cap}:{'narrow' if narrow else 'wide'}"
-                        f":n{n}:win{win}:top{bool(top)}:kept{kept}{tail}"] = \
-                        sha(lower(cap, narrow))
+                        f":n{n}:win{win}:top{bool(program.top)}"
+                        f":kept{program.kept}{tail}"] = sha(lower(
+                            dataclasses.replace(program, narrow=narrow)))
         eng.close()
-    if rule is not None:
-        sg.key_word_dtypes = rule
+    sg.key_word_dtypes = rule
     out.update(uncut_min_max(sg))
     out.update(key_shapes(sg))
     json.dump(out, sys.stdout, indent=1, sort_keys=True)

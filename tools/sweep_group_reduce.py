@@ -154,11 +154,11 @@ def _sparse_topn(v, key, k, tables=0, rank_first=True, sum_word=64,
     int64 words, the further ones functions of the first: the same groups
     in the same order, sorted by every word. `key_bits` 32 hands the key
     as an int32 word; with two words the second, then the first's low 5
-    bits, and word 0 stays int64. `boundary` is the program's
-    static argument of that name (the runner's `boundary_read`). Returns
+    bits, and word 0 stays int64. `boundary` is the `SparseProgram`'s
+    field of that name (the dispatch's `boundary_read`). Returns
     the ranked sum at the kept rows, their keys, the other tables' kept rows and, of a narrow
     program, `_narrow_ok` last."""
-    from tpu_olap.kernels.sparse_groupby import (SENTINEL,
+    from tpu_olap.kernels.sparse_groupby import (SENTINEL, SparseProgram,
                                                  sparse_group_reduce)
     from tpu_olap.kernels.topk import top_k_groups
     cols = {"v": v, **{f"t{i}": v ^ (i + 1) for i in range(tables)}}
@@ -174,10 +174,10 @@ def _sparse_topn(v, key, k, tables=0, rank_first=True, sum_word=64,
             (key & 31).astype(jnp.int32) if narrow_key and w == 1
             else (key << 31) + (key ^ (w * 0x55555))
             for w in range(1, key_words))
-    out = sparse_group_reduce(key, jnp.ones(v.shape, bool),
-                              {"cols": cols, "nulls": {}}, plans, k, {},
-                              jnp, top if rank_first else None,
-                              narrow=sum_word == 32, boundary=boundary)
+    out = sparse_group_reduce(
+        key, jnp.ones(v.shape, bool), {"cols": cols, "nulls": {}}, plans,
+        {}, SparseProgram(k, top if rank_first else None,
+                          narrow=sum_word == 32, boundary=boundary))
     ok = (out.pop("_narrow_ok"),) if "_narrow_ok" in out else ()
     if not rank_first:
         order, _ = top_k_groups(out["v"], out["_keys"] != SENTINEL,

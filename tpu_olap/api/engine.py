@@ -839,46 +839,28 @@ class Engine:
     def explain(self, query: str) -> dict:
         """EXPLAIN DRUID REWRITE analog: the chosen QuerySpec (or the
         fallback reason) without executing (SURVEY.md §4.5), plus, for
-        an aggregate served over a mesh, the spelling the mesh runs it
-        in (the record's `mesh_program`) and, for a GroupBy with a
-        HAVING, who decides it (the record's `having_where`: `device`
-        where the sparse program cuts the table itself, else `host`;
-        null where lowering finds no device plan for the query) and,
-        for an aggregate whose sparse program holds an integer sum, the
-        width that sum would ride its sort at (the record's
-        `sum_word_bits`: 32 | 64) and, for an aggregate whose plan is
-        the sparse group-by, how many int64 words its key takes and how
-        many bits its dimensions' ids (the record's `key_words`: 1 under
-        a group space of 2^62, and `key_bits`; `key_words` null where
-        lowering finds no device plan for the query) and the width each
-        word rides the sort at (`key_sort_bits`: 32 a word whose ids fit
-        31 bits, else 64)."""
+        an aggregate, what its record will say of its device plan
+        (`sparse_dispatch.explain_lines`: `having_where`, and of a sparse
+        plan `sum_word_bits`, `key_words`, `key_bits`, `key_sort_bits`;
+        `key_words` and `having_where` null where lowering finds no
+        device plan for the query) and, over a mesh, the spelling the
+        mesh runs it in (`mesh_program`)."""
+        from tpu_olap.executor import sparse_dispatch
         from tpu_olap.executor.batch import AGG_QUERY_TYPES
         plan = self.planner.plan(query)
         out = plan.explain()
         if plan.rewritten and plan.entry.is_accelerated \
                 and isinstance(plan.query, AGG_QUERY_TYPES):
             try:
-                bits = self.runner.sum_word_bits(plan.query,
-                                                 plan.entry.segments)
-                key = self.runner.key_words(plan.query,
-                                            plan.entry.segments)
+                out.update(sparse_dispatch.explain_lines(
+                    self.runner, plan.query, plan.entry.segments))
             except _UNSUPPORTED:
-                bits = key = None   # no device plan: the fallback answers
+                # no device plan: the fallback answers
                 out["key_words"] = None
-            if bits is not None:
-                out["sum_word_bits"] = bits
-            if key is not None:
-                out["key_words"], out["key_bits"], \
-                    out["key_sort_bits"] = key
-        if plan.rewritten and plan.entry.is_accelerated \
-                and getattr(plan.query, "having", None) is not None:
-            out["having_where"] = self.runner.having_where(
-                plan.query, plan.entry.segments)
-        if plan.rewritten and plan.entry.is_accelerated \
-                and isinstance(plan.query, AGG_QUERY_TYPES) \
-                and self.runner.mesh is not None:
-            out["mesh_program"] = self.runner.mesh_program
+                if getattr(plan.query, "having", None) is not None:
+                    out["having_where"] = None
+            if self.runner.mesh is not None:
+                out["mesh_program"] = self.runner.mesh_program
         return out
 
     # -------------------------------------------------------- passthrough

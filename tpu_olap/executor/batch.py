@@ -501,9 +501,8 @@ def _enqueue_fused_device(runner, table, plans, leg_envs, valid,
            tuple(p.fingerprint() for p in plans),
            win[1] if win else 0,
            _layout_key(layouts))
-    jitted = runner._jit_cache.get(key)
-    hit = jitted is not None
-    if not hit:
+
+    def build():
         mesh_dims = None
         if mesh is not None:
             mesh_dims = (D, win[1] if win is not None else per_chip)
@@ -512,10 +511,10 @@ def _enqueue_fused_device(runner, table, plans, leg_envs, valid,
             fused = _window_fused(fused, win[1], mesh, per_chip)
         if mesh is not None:
             from tpu_olap.executor.sharding import shard_spec
-            jitted = jax.jit(fused, out_shardings=shard_spec(mesh))
-        else:
-            jitted = jax.jit(fused)
-        runner._jit_cache[key] = jitted
+            return jax.jit(fused, out_shardings=shard_spec(mesh))
+        return jax.jit(fused)
+    # the caller counts the compile, once a batch
+    jitted, hit = runner._program(key, build)
     consts_list, seg_args = [], []
     for plan, sm in zip(plans, seg_masks):
         cdev, sarg = runner._args_for(plan, sm, mesh)

@@ -12,7 +12,6 @@ rewritable" -> fallback (SURVEY.md §2 property 2).
 
 from __future__ import annotations
 
-import functools
 import os.path
 from dataclasses import dataclass, field, replace
 
@@ -58,7 +57,7 @@ class PhysicalPlan:
     filter_streams: tuple = ()
     pallas_reason: str | None = "not attempted"  # None = pallas kernel active
     sparse: bool = False       # sort-based path for huge group spaces
-    make_sparse_kernel: object = None   # cap -> kernel fn (sparse only)
+    make_sparse_kernel: object = None   # SparseProgram -> kernel fn
     # the group key's words: a tuple a word of the positions of `sizes`
     # packed into it, ascending, and each position's radix in its word.
     # One word of every position that carries an id, in the mixed radix
@@ -602,43 +601,28 @@ def _lower_agg(query, table, config) -> PhysicalPlan:
         from tpu_olap.kernels.sparse_groupby import compile_having
         having = compile_having(query.having, sparse_agg_plans, pool)
 
-    def make_sparse_kernel(cap, top=None, kept=None, narrow=False,
-                           boundary=None):
-        """The sparse program for a compact table of `cap` slots; with
-        `top` = (metric, threshold, inverted) the table's rows that a TopN
-        keeps, the threshold applied on the device: that program ranks
-        first and reads the tables it does not rank at the kept rows
-        (`sparse_group_reduce`); with `kept` the rows the plan's HAVING
-        lets through, in a bucket of `kept` rows: the same cut by a
-        predicate. `cap` None: the program that counts the groups present
-        and builds no table (`sparse_group_count`). `narrow`: the program
-        whose integer sums of columns stored in 32 bits or fewer ride as
-        one int32 word, and which says in `_narrow_ok` whether every
-        group's sum fits one (the same program where no sum is such).
-        `boundary`: the caller's `sparse_groupby.boundary_read` of the
-        cap and the rows the program will sort ("sorted": the whole [cap]
-        tables read at the runs' boundaries ride `starts`' sort).
-        Where the plan's key is several words (`key_words`) every one of
-        these programs sorts by them all and gives a `_keys` table a
-        word. A word rides in the dtype `sparse_groupby.key_word_dtypes`
-        gives it from `sizes` (int32 where its ids fit 31 bits); the
-        tables are int64 either way."""
+    def make_sparse_kernel(program):
+        """The plan's sparse kernel of `program` (a
+        `sparse_groupby.SparseProgram`): `sparse_group_reduce`'s table
+        program of its `cap` slots, cuts and spellings or, `cap` None,
+        `sparse_group_count`'s. Either sorts by every word of the plan's
+        key (`key_words`; a word int32 where its ids fit 31 bits,
+        `sparse_groupby.key_word_dtypes`). The window's slice is the
+        caller's (`QueryRunner._window_kernel`)."""
         from tpu_olap.kernels.sparse_groupby import (build_group_key64,
                                                      sparse_group_count,
                                                      sparse_group_reduce)
 
-        key_builder = functools.partial(build_group_key64, words=packed)
+        def key_builder(ids, radix, xp):
+            return build_group_key64(ids, radix, words=packed)
 
         def sparse_kernel(env, valid, seg_mask, consts):
-            xp = _jnp()
             fenv, mask, key = _masked_key(env, valid, seg_mask, consts,
                                           key_builder)
-            if cap is None:
-                return sparse_group_count(key, mask, xp)
-            return sparse_group_reduce(
-                key, mask, fenv, sparse_agg_plans, cap, consts, xp, top,
-                None if kept is None else having + (kept,), narrow,
-                boundary)
+            if program.cap is None:
+                return sparse_group_count(key, mask)
+            return sparse_group_reduce(key, mask, fenv, sparse_agg_plans,
+                                       consts, program, having)
         return sparse_kernel
 
     def build(sparse: bool) -> PhysicalPlan:

@@ -38,6 +38,8 @@ from tpu_olap.executor import lowering
 from tpu_olap.executor.lowering import PhysicalPlan, lower
 from tpu_olap.executor.packing import (build_packer, densify, make_layout,
                                        unpack)
+from tpu_olap.executor import sparse_dispatch
+from tpu_olap.executor.sharding import next_pow2 as _next_pow2
 from tpu_olap.executor.results import (agg_specs_by_name, eval_having,
                                        eval_post_aggs, finalize_aggs, iso,
                                        render_value, theta_raw_fields)
@@ -707,6 +709,20 @@ class QueryRunner:
         if metrics is not None:
             metrics["recompiles"] = metrics.get("recompiles", 0) + 1
 
+    def _program(self, key, build, what=None, metrics=None):
+        """(the jitted program remembered under `key`, jit cache hit):
+        built once a key (`build()`), which with `what` is a counted
+        compile (`_note_compile`; None: the caller counts it). Call under
+        the enqueue lock."""
+        fn = self._jit_cache.get(key)
+        if fn is not None:
+            _cache_lru_hit(self._jit_cache, key)
+            return fn, True
+        fn = self._jit_cache[key] = build()
+        if what is not None:
+            self._note_compile(what, metrics)
+        return fn, False
+
     def device_bytes_by_table(self) -> dict:
         """Live device bytes per table: each dataset's resident column/
         null/derived stacks plus this table's cached const/seg-mask
@@ -1020,13 +1036,11 @@ class QueryRunner:
                     f"a group space of {plan.total_groups} has no flat "
                     "int64 group id to key mergeable partials by")
             if plan.sparse:
-                from tpu_olap.kernels.sparse_groupby import SENTINEL
-                out, _ = self._dispatch(
-                    lambda: self._run_sparse(plan, metrics), metrics,
-                    table.name)
-                keys = np.asarray(out["_keys"])
-                pm = keys != SENTINEL
-                present = keys[pm].astype(np.int64)
+                out, _, _ = self._dispatch(
+                    lambda: sparse_dispatch.run_sparse(
+                        self, plan, metrics, cut=False),
+                    metrics, table.name)
+                pm, present = sparse_dispatch.present_groups(out, plan)
                 compact = {k: np.asarray(v)[pm] for k, v in out.items()
                            if not k.startswith("_") or k == "_rows"
                            or k.startswith("_nn_")}
@@ -1658,17 +1672,9 @@ class QueryRunner:
         every [S, ...] input to [W, ...] at `lo` before compute. One
         compile per (template, W); `lo` is traced, so interval changes
         that keep the window size re-use the executable."""
-        import jax
-
         def windowed(env, valid, seg_mask, consts, lo):
-            def sl(a):
-                return jax.lax.dynamic_slice_in_dim(a, lo, W, axis=0)
-            with jax.named_scope("window"):
-                wenv = {
-                    "cols": {c: sl(a) for c, a in env["cols"].items()},
-                    "nulls": {c: sl(a) for c, a in env["nulls"].items()}}
-                valid, seg_mask = sl(valid), sl(seg_mask)
-            return kernel(wenv, valid, seg_mask, consts)
+            return kernel(*_window_slice(env, valid, seg_mask, lo, W),
+                          consts)
         return windowed
 
     @staticmethod
@@ -1704,18 +1710,11 @@ class QueryRunner:
                 n_seg_full = len(seg_mask)
                 key = plan.fingerprint() \
                     + ((win[1],) if win else ())
-                jitted = self._jit_cache.get(key)
-                hit = jitted is not None
-                if hit:
-                    _cache_lru_hit(self._jit_cache, key)
-                else:
-                    if win is not None:
-                        jitted = jax.jit(
-                            self._window_kernel(plan.kernel, win[1]))
-                    else:
-                        jitted = jax.jit(plan.kernel)
-                    self._jit_cache[key] = jitted
-                    self._note_compile("partials", metrics)
+                jitted, hit = self._program(
+                    key, lambda: jax.jit(
+                        self._window_kernel(plan.kernel, win[1])
+                        if win is not None else plan.kernel),
+                    "partials", metrics)
                 t0 = time.perf_counter()
                 with _span("dispatch", jit_cache_hit=hit, num_shards=1,
                            **_form_attr(metrics)):
@@ -1776,18 +1775,11 @@ class QueryRunner:
                         metrics["segments_window_per_chip"] = win[1]
                 key = plan.fingerprint() + ("mesh", D,
                                             win[1] if win else 0)
-                jitted = self._jit_cache.get(key)
-                hit = jitted is not None
-                if hit:
-                    _cache_lru_hit(self._jit_cache, key)
-                else:
-                    if is_agg:
-                        jitted = sh.mesh_agg_kernel(plan, mesh, per_chip,
-                                                    program, win)
-                    else:
-                        jitted = sh.mesh_mask_kernel(plan, mesh)
-                    self._jit_cache[key] = jitted
-                    self._note_compile("mesh", metrics)
+                jitted, hit = self._program(
+                    key, lambda: sh.mesh_agg_kernel(
+                        plan, mesh, per_chip, program, win)
+                    if is_agg else sh.mesh_mask_kernel(plan, mesh),
+                    "mesh", metrics)
                 t0 = time.perf_counter()
                 with _span("dispatch", jit_cache_hit=hit, num_shards=D,
                            mesh_program=program if is_agg else "mask",
@@ -1869,17 +1861,15 @@ class QueryRunner:
                              lowering._default_backend())
         key = plan.fingerprint() + ("packed", layout.cap) \
             + ((win[1],) if win else ())
-        jitted = self._jit_cache.get(key)
-        if jitted is not None:
-            _cache_lru_hit(self._jit_cache, key)
-        if jitted is None:
+
+        def build():
             packed = build_packer(plan.kernel, plan, layout)
             if win is not None:
                 packed = self._window_kernel(packed, win[1])
-            jitted = jax.jit(packed)
-            self._jit_cache[key] = jitted
-            return jitted, layout, False
-        return jitted, layout, True
+            return jax.jit(packed)
+        # the caller counts the compile
+        jitted, hit = self._program(key, build)
+        return jitted, layout, hit
 
     def _run_packed(self, plan: PhysicalPlan, metrics: dict):
         """Single-fetch path: jit(kernel + device finalize/compact/pack),
@@ -1944,507 +1934,6 @@ class QueryRunner:
         metrics["packed"] = True
         return idx, compact, layout
 
-    def _run_sparse(self, plan: PhysicalPlan, metrics: dict, top=None,
-                    having=False):
-        """Sort-based sparse group-by dispatch with adaptive compact-table
-        cap (kernels.sparse_groupby). On a mesh every chip compacts its
-        own rows and the D tables are merged where EngineConfig.mesh_merge
-        says (the chips, or the host broker); EngineConfig.sparse_merge
-        "exchange" lets the merged table hold D × budget groups, "gather"
-        one chip's budget. Returns (partials dict, count): compact
-        tables, SENTINEL-keyed past the present groups.
-        With `top` = (metric, threshold, inverted), one chip's program
-        holds the TopN's threshold and the partials are its [threshold]
-        rows in rank order (`_device_threshold` says when); with `having`
-        it holds the plan's HAVING and the partials are the groups that
-        pass, in a bucket of `_kept` rows (`_device_having`); the record's
-        `cap_tables` says how many [cap] tables each program built."""
-        with _span("dispatch", sparse=True) as sp:
-            out = self._run_sparse_inner(plan, metrics, top, having)
-            sp.set(jit_cache_hit=metrics.get("jit_cache_hit"),
-                   result_groups=metrics.get("result_groups"),
-                   num_shards=metrics.get("num_shards"),
-                   **_form_attr(metrics))
-        return out
-
-    def _run_sparse_inner(self, plan: PhysicalPlan, metrics: dict, top,
-                          having):
-        with self._pipeline_slot():
-            return self._run_sparse_staged(plan, metrics, top, having)
-
-    def _run_sparse_staged(self, plan: PhysicalPlan, metrics: dict,
-                           top=None, having=False):
-        """Adaptive-cap sparse dispatch, two-staged: each attempt's jit
-        build + async dispatch runs under the enqueue lock; the _count
-        probe (a one-element sync) and the final whole-tree fetch run
-        lock-free, so an overflow retry re-enters stage 1. `top` (one
-        chip only) puts the TopN's threshold in the program, which ranks
-        the metric's [cap] table first and reads every other table at the
-        rows it keeps (`sparse_group_reduce`); `_count` stays the table's
-        own, so the probe below reads what it read, and the fetch brings
-        `threshold` rows a table, not `cap`. `having` (one chip only) puts
-        the plan's HAVING there the same way: the program builds the
-        tested aggregates' [cap] tables, compacts the slots that pass
-        into a power-of-two bucket and reads every other table at those;
-        `_kept` says how many passed, a bucket they do not fit is grown
-        and the attempt run again, as a cap is. A group space past the
-        budget whose count no hint tells is counted first, by a program
-        that builds no table, so that the first compact table holds it:
-        each cap is a compile of the sort. One chip's first attempt of a
-        plan with an integer sum of a column stored in 32 bits or fewer
-        runs the narrow program (`sparse_group_reduce`'s `narrow`: such
-        a sum rides as one int32 word); once the count fits the cap its
-        `_narrow_ok` says whether every group's sum fits one, and where
-        it does not the wide program of the same cap runs as one more
-        attempt and the plan is remembered as wide, as a cap is."""
-        from tpu_olap.kernels import sparse_groupby as sg
-        from tpu_olap.kernels.groupby import UnsupportedAggregation
-
-        with self._enqueue_lock(metrics):
-            env, valid, seg_mask = self._prepare(plan, metrics)
-        # the width each stored column is resident at: with the plan's
-        # kinds and the cap, what the kernel picks its reduce from
-        stored = {c: a.dtype for c, a in env["cols"].items()}
-        nullable = frozenset(env["nulls"])
-        win = self._segment_window(plan, len(seg_mask))
-        if win is not None:
-            metrics["segments_window"] = win[1]
-        mesh = self.mesh
-        n_shards = mesh.devices.size if mesh else 1
-        base_key = self._sparse_key(plan, n_shards)
-        use_exchange = mesh is not None and n_shards > 1 and \
-            self.config.sparse_merge == "exchange"
-        budget = self.config.sparse_group_budget
-        # exchange scales global capacity with the mesh; local compaction
-        # and per-owner tables each stay within the per-chip budget
-        cap_limit = min(budget * (n_shards if use_exchange else 1),
-                        plan.total_groups)
-        local_limit = min(budget, plan.total_groups)
-        # a group space that fits the compact table whole starts (and
-        # stays) at a cap that holds it: no attempt can overflow, and the
-        # sort compiles once, not once for the starting cap and again for
-        # the grown one (2-4 minutes a program at 60M rows). Not where a
-        # sketch's [cap, m] state rides: its cap follows the groups present
-        whole_space = plan.total_groups <= budget and not any(
-            p.kind in ("hll", "theta") for p in plan.agg_plans)
-
-        def first_cap(hint):
-            if whole_space:
-                return local_limit
-            return min(local_limit, self.config.sparse_group_cap) \
-                if hint is None else _grown_cap(hint, local_limit)
-
-        def boundary(cap, rows):
-            # how the program of `cap` over `rows` sorted rows reads its
-            # whole [cap] tables: the program's static argument and the
-            # record's word are this one answer
-            return sg.boundary_read(
-                plan.agg_plans, stored, cap, rows, top, nullable,
-                plan.having[1] if having else None)
-
-        hint = self._cap_hints.get(base_key)
-        cap = first_cap(hint)
-        # the HAVING's bucket: the power of two that holds the most
-        # groups any literal of the template has let through
-        kept_key = base_key + ("kept",)
-
-        def kept_bucket(cap):
-            return min(cap, max(HAVING_KEPT_MIN, _next_pow2(
-                self._cap_hints.get(kept_key, 0))))
-
-        # a mesh's programs stay wide: their chips' partial sums are
-        # merged at the accumulator's width
-        wide_key = base_key + ("wide",)
-        n_words = len(plan.key_words)
-        if n_words > 1:
-            self._m_wide_key.inc()
-        if 32 in sg.key_sort_bits(plan.sizes, plan.key_words):
-            self._m_narrow_key.inc()
-        narrow = mesh is None and wide_key not in self._cap_hints \
-            and sg.narrow_sums(plan.agg_plans, stored)
-        t0 = time.perf_counter()
-        hit = False
-        attempts = 0
-        if mesh is None:
-            import jax
-            # pin the enqueued output tree like every other device path
-            # (the caller blocks on the _count probe while the buffers
-            # occupy HBM); a retry/raise unpins the superseded pin
-            pin = None
-
-            def over_budget(count):
-                return UnsupportedAggregation(
-                    f"{count} present groups exceed sparse budget "
-                    f"{cap_limit}")
-
-            def run(cap, kept=None, narrow=False):
-                """Build (once a key, a counted compile) and enqueue the
-                program of `cap` (None: the count alone); call under the
-                enqueue lock. -> (its output tree, jit cache hit, its
-                `boundary_read`)"""
-                consts_dev, seg_arg = self._args_for(plan, seg_mask, None)
-                read = None if cap is None else boundary(
-                    cap, (win[1] if win else valid.shape[0])
-                    * valid.shape[1])
-                key = base_key + (cap,) \
-                    + ((win[1],) if win else ()) \
-                    + (("top",) if top else ()) \
-                    + (("having", kept) if kept else ()) \
-                    + (("narrow",) if narrow else ()) \
-                    + (("sorted",) if read == "sorted" else ())
-                jitted = self._jit_cache.get(key)
-                hit = jitted is not None
-                if hit:
-                    _cache_lru_hit(self._jit_cache, key)
-                else:
-                    kern = plan.make_sparse_kernel(cap, top, kept, narrow,
-                                                   read)
-                    if win is not None:
-                        jitted = jax.jit(
-                            self._window_kernel(kern, win[1]))
-                    else:
-                        jitted = jax.jit(kern)
-                    self._jit_cache[key] = jitted
-                    self._note_compile("sparse", metrics)
-                out = jitted(env, valid, seg_arg, consts_dev,
-                             win[0]) if win is not None else \
-                    jitted(env, valid, seg_arg, consts_dev)
-                return out, hit, read
-
-            try:
-                if hint is None and not whole_space:
-                    # nothing says how many groups are present: count
-                    # them before any table is sized (a run of a sort,
-                    # so an attempt)
-                    attempts += 1
-                    with _span("sparse-count", key_words=n_words) as sp:
-                        with self._enqueue_lock(metrics):
-                            out, hit, _ = run(None)
-                        count = int(out["_count"])
-                        sp.set(present_groups=count, jit_cache_hit=hit)
-                    if count > cap_limit:
-                        raise over_budget(count)
-                    cap = _grown_cap(count, cap_limit)
-                kept = kept_bucket(cap) if having else None
-                while True:
-                    attempts += 1
-                    with _span("sparse-attempt", cap=cap,
-                               key_words=n_words) as sp:
-                        with self._enqueue_lock(metrics):
-                            out, hit, read = run(cap, kept, narrow)
-                            prev, pin = pin, self._pin_inflight(out)
-                        if prev is not None:
-                            self._hbm_ledger.unpin_inflight(prev)
-                        # the one-element sync that waits for the sort
-                        with _span("count-probe"):
-                            count = int(out["_count"])
-                        sp.set(present_groups=count, jit_cache_hit=hit)
-                        # a ready scalar: some group's sum may pass int32
-                        too_wide = narrow and count <= cap \
-                            and not bool(out["_narrow_ok"])
-                        if too_wide:
-                            sp.set(narrow_fallback=True)
-                    if count > cap:
-                        if count > cap_limit:
-                            raise over_budget(count)
-                        cap = _grown_cap(count, cap_limit)
-                        kept = kept_bucket(cap) if having else None
-                        continue
-                    if too_wide:
-                        narrow = False
-                        self._cap_hints[wide_key] = True
-                        self._m_narrow_fallbacks.inc()
-                        metrics["narrow_fallback"] = True
-                        continue
-                    if having:
-                        with _span("having", where="device",
-                                   groups_in=count) as sp:
-                            n_kept = int(out["_kept"])
-                            sp.set(groups_out=n_kept)
-                        self._cap_hints[kept_key] = max(
-                            n_kept, self._cap_hints.get(kept_key, 0))
-                        if n_kept > kept:
-                            kept = kept_bucket(cap)
-                            continue
-                    break
-                out.pop("_narrow_ok", None)   # read above; not a table
-                with _span("host-transfer", cap=cap):
-                    out = self._fetch_tree(out, metrics, pin)
-                pin = None  # consumed (fetch unpins)
-            finally:
-                if pin is not None:
-                    self._hbm_ledger.unpin_inflight(pin)
-            metrics["num_shards"] = 1
-        else:
-            # multi-chip sparse: the one-chip sort/compact kernel mapped
-            # over the mesh + a merge (docs/TPU_NOTES.md "sharded
-            # serving"). Every chip compacts its resident shard in ONE
-            # program a cap (sharding.mesh_sparse_kernel: no collective,
-            # one compile whatever the mesh's size); the chips' present
-            # rows are merged on the device (sharding.mesh_merge_kernel)
-            # or fetched and merged by the broker (merge_sparse).
-            # sparse_merge="exchange" lets the merged table hold
-            # D x sparse_group_budget present groups (capacity scales
-            # with chip count); "gather" keeps the legacy global-budget
-            # contract (every group must fit one chip's table).
-            import jax
-
-            from tpu_olap.executor import sharding as sh
-            if self.mesh_program == "gspmd":
-                # DCN mesh: remote chips' compact tables are not host-
-                # addressable, so neither the fan-out nor the broker
-                # merge can run — hand the WHOLE sparse program to
-                # GSPMD with replicated outputs (global-budget
-                # capacity, like the gather contract)
-                pin = None
-                try:
-                    while True:
-                        attempts += 1
-                        with self._enqueue_lock(metrics):
-                            consts_dev, seg_arg = self._args_for(
-                                plan, seg_mask, mesh)
-                            read = boundary(cap, valid.size)
-                            key = base_key + ("gspmd", cap) \
-                                + (("sorted",) if read == "sorted" else ())
-                            jitted = self._jit_cache.get(key)
-                            hit = jitted is not None
-                            if hit:
-                                _cache_lru_hit(self._jit_cache, key)
-                            else:
-                                jitted = jax.jit(
-                                    plan.make_sparse_kernel(
-                                        cap, boundary=read),
-                                    out_shardings=sh.replicated_spec(
-                                        mesh))
-                                self._jit_cache[key] = jitted
-                                self._note_compile("sparse", metrics)
-                            out = jitted(env, valid, seg_arg,
-                                         consts_dev)
-                            prev, pin = pin, self._pin_inflight(out)
-                        if prev is not None:
-                            self._hbm_ledger.unpin_inflight(prev)
-                        count = int(out["_count"])
-                        if count <= cap:
-                            break
-                        if count > local_limit:
-                            raise UnsupportedAggregation(
-                                f"{count} present groups exceed sparse "
-                                f"budget {local_limit}")
-                        cap = _grown_cap(count, local_limit)
-                    out = self._fetch_tree(out, metrics, pin)
-                    pin = None
-                finally:
-                    if pin is not None:
-                        self._hbm_ledger.unpin_inflight(pin)
-                metrics["num_shards"] = n_shards
-                self._cap_hints[base_key] = count
-                metrics["execute_ms"] = \
-                    (time.perf_counter() - t0) * 1000
-                metrics["jit_cache_hit"] = hit
-                self._note_sparse(metrics, plan, stored, nullable, attempts,
-                                  cap, count, read)
-                return out, count
-            lhint = self._cap_hints.get(base_key + ("local",))
-            if lhint is not None:
-                cap = first_cap(lhint)
-            pin = None
-            try:
-                while True:
-                    attempts += 1
-                    with _span("sparse-attempt", cap=cap,
-                               key_words=n_words) as sp:
-                        with self._enqueue_lock(metrics):
-                            consts_dev, seg_arg = self._args_for(
-                                plan, seg_mask, mesh)
-                            # a chip sorts its own share of the rows
-                            read = boundary(cap, valid.size // n_shards)
-                            key = base_key + ("mesh", cap) \
-                                + (("sorted",) if read == "sorted" else ())
-                            jitted = self._jit_cache.get(key)
-                            hit = jitted is not None
-                            if hit:
-                                _cache_lru_hit(self._jit_cache, key)
-                            else:
-                                jitted = sh.mesh_sparse_kernel(
-                                    plan, mesh, cap, read)
-                                self._jit_cache[key] = jitted
-                                self._note_compile("sparse", metrics)
-                            out = jitted(env, valid, seg_arg, consts_dev)
-                            prev, pin = pin, self._pin_inflight(out)
-                            self._note_chip_dispatch(range(n_shards))
-                        if prev is not None:
-                            self._hbm_ledger.unpin_inflight(prev)
-                        # the D-element sync that waits for the sorts
-                        with _span("count-probe"):
-                            counts = [int(c) for c in
-                                      jax.device_get(out["_count"])]
-                        local_max = max(counts)
-                        sp.set(present_groups=local_max,
-                               jit_cache_hit=hit)
-                    if local_max <= cap:
-                        break
-                    if local_max > local_limit:
-                        raise UnsupportedAggregation(
-                            f"{local_max} per-chip present groups "
-                            f"exceed sparse budget {local_limit}")
-                    cap = _grown_cap(local_max, local_limit)
-                def program(key, build, what):
-                    """A second program of the dispatch, built once a key
-                    (a counted compile); call under the enqueue lock."""
-                    nonlocal hit
-                    fn = self._jit_cache.get(key)
-                    if fn is None:
-                        fn = self._jit_cache[key] = build()
-                        self._note_compile(what, metrics)
-                        hit = False
-                    else:
-                        _cache_lru_hit(self._jit_cache, key)
-                    return fn
-
-                # what leaves the chips is the present groups' size, not
-                # the cap's: tables are cut on the device to a power-of-
-                # two bucket (a program a bucket, no sort in it) before
-                # the one fetch
-                def head(tables, rows, merged):
-                    with self._enqueue_lock(metrics):
-                        return program(
-                            ("sparse-head", n_shards, rows, merged),
-                            lambda: sh.mesh_head_kernel(mesh, rows, merged),
-                            "sparse-head")(tables)
-
-                tables = {k: v for k, v in out.items() if k != "_count"}
-                rows = min(cap, max(64, _next_pow2(local_max)))
-                rows_in = sum(counts)
-                cap_global = min(cap_limit, max(64, _next_pow2(rows_in)))
-                on_device = self.config.mesh_merge == "device" \
-                    and sg.merges_on_device(plan.agg_plans)
-                if on_device:
-                    # every chip gathers the others' first `rows` slots
-                    # and merges them; the host waits for the merged
-                    # count and fetches one chip's copy of the table
-                    with _span("broker-merge", num_shards=n_shards,
-                               where="device") as sp:
-                        with self._enqueue_lock(metrics):
-                            tables = program(
-                                base_key + ("mesh-merge", rows),
-                                lambda: sh.mesh_merge_kernel(plan, mesh,
-                                                             rows),
-                                "sparse-merge")(tables)
-                        count = int(tables.pop("_count"))
-                        sp.set(rows_in=rows_in, groups_out=count)
-                    if count > cap_limit:
-                        raise UnsupportedAggregation(
-                            f"{count} present groups exceed sparse "
-                            f"budget {cap_limit}")
-                    n_from, n_rows = 1, max(64, _next_pow2(count))
-                    if n_rows < n_shards * rows:
-                        tables = head(tables, n_rows, True)
-                    else:
-                        n_rows = n_shards * rows
-                else:
-                    n_from, n_rows = n_shards, n_shards * rows
-                    if rows < cap:
-                        tables = head(tables, rows, False)
-                with _span("sparse-shard-fetch", chips=n_from,
-                           rows=n_rows) as sp:
-                    tables = self._fetch_tree(tables, metrics, pin)
-                    pin = None  # consumed (fetch unpins)
-                    fetched = sum(int(a.nbytes) for a in tables.values())
-                    sp.set(bytes=fetched)
-            finally:
-                if pin is not None:
-                    self._hbm_ledger.unpin_inflight(pin)
-            if on_device:
-                out = dict(tables, _count=np.int32(count))
-            else:
-                # the broker merges the chips' present rows
-                with _span("broker-merge", num_shards=n_shards,
-                           where="broker") as sp:
-                    parts = [dict({k: v[:n] for k, v in t.items()},
-                                  _count=np.int32(n))
-                             for t, n in zip(
-                                 sh.chip_tables(tables, n_shards), counts)]
-                    out = sg.merge_sparse(parts, plan.agg_plans,
-                                          cap_global)
-                    count = int(out["_count"])
-                    sp.set(rows_in=rows_in, groups_out=count)
-                    if count > cap_limit:
-                        raise UnsupportedAggregation(
-                            f"{count} present groups exceed sparse "
-                            f"budget {cap_limit}")
-            metrics["merge"] = "device" if on_device else "broker"
-            metrics["sparse_fetch_bytes"] = fetched
-            metrics["sparse_merge_rows_in"] = rows_in
-            self._cap_hints[base_key + ("local",)] = local_max
-            metrics["num_shards"] = n_shards
-            if use_exchange:
-                metrics["sparse_merge"] = "exchange"
-                metrics["result_cap_owner"] = cap_global
-        self._cap_hints[base_key] = count
-        metrics["execute_ms"] = (time.perf_counter() - t0) * 1000
-        metrics["jit_cache_hit"] = hit
-        self._note_sparse(metrics, plan, stored, nullable, attempts, cap,
-                          count, read, top,
-                          plan.having[1] if having else None, narrow)
-        return out, count
-
-    @staticmethod
-    def _sparse_key(plan, n_shards: int) -> tuple:
-        """What a plan's sparse programs and hints (`_cap_hints`: the
-        groups last seen, the HAVING's bucket, a plan found too wide for
-        the narrow program) are remembered under."""
-        return plan.fingerprint() + ("sparse", n_shards)
-
-    def _note_sparse(self, metrics: dict, plan, stored: dict, nullable,
-                     attempts: int, cap: int, count: int, read,
-                     top=None, having=None, narrow=False):
-        """The sparse dispatch's counters on the record: how many cap
-        attempts ran (1 once the template's hint is warm), the compact
-        table's final cap, and the groups present in it; and which
-        program the reduce of that cap was — whether every [cap] table
-        is read at the sorted runs' boundaries or an aggregate still
-        scatters, the width of the word a min / max is read from, and
-        how many [cap] tables it gathers or segment-reduces (every table,
-        or with `top` the ranked one, with `having` the tested ones, and
-        what still segment-reduces), and the width an integer sum rode
-        the sort, its prefix sum and its boundary gather at (`narrow`:
-        the program that answered was the narrow one): the kernel's own
-        functions of the plan's aggregate kinds and dtypes, the columns'
-        stored dtypes (`nullable`: those with a null mask), the cap and
-        the cut, as the dense `reduce_form` is of num_groups; and
-        `read`, what the program was built with (`boundary_read`: its
-        whole [cap] tables at the runs' boundaries as operands of
-        `starts`' sort, `sorted`, which the registry counts, or `gather`;
-        None, and absent, where it read none there). And the
-        key: how many int64 words it rode the sort as (`key_words`: 1
-        under a group space of 2^62), the bits its dimensions' ids
-        take together (`key_bits`) and the width each word rode the sort
-        at (`key_sort_bits`, a list a word: 32 where the word's ids fit
-        31 bits, `sparse_groupby.key_word_dtypes`)."""
-        from tpu_olap.kernels import sparse_groupby as sg
-        metrics["reduce_form"] = sg.sparse_reduce_form(plan.agg_plans,
-                                                       stored, cap)
-        bits = sg.ext_word_bits(plan.agg_plans, stored, cap)
-        if bits is not None:
-            metrics["ext_word_bits"] = bits
-        bits = sg.sum_word_bits(plan.agg_plans, stored, narrow)
-        if bits is not None:
-            metrics["sum_word_bits"] = bits
-        metrics["cap_tables"] = sg.cap_tables(plan.agg_plans, stored, cap,
-                                              top, nullable, having)
-        if read is not None:
-            metrics["boundary_read"] = read
-        if read == "sorted":
-            self._m_boundary_sorted.inc()
-        metrics["key_words"] = len(plan.key_words)
-        metrics["key_bits"] = sg.key_bits(plan.sizes)
-        metrics["key_sort_bits"] = sg.key_sort_bits(plan.sizes,
-                                                    plan.key_words)
-        metrics["sparse"] = True
-        metrics["sparse_attempts"] = attempts
-        metrics["sparse_cap"] = metrics["result_cap"] = cap
-        metrics["present_groups"] = metrics["result_groups"] = count
-
     # ------------------------------------------------------------ agg paths
 
     def _run_agg(self, query, table) -> QueryResult:
@@ -2480,14 +1969,12 @@ class QueryRunner:
         topn = isinstance(query, TopNQuerySpec)
         if topn:
             metrics["topn_group_space"] = plan.total_groups
-        having = self._device_having(plan)
+        having = sparse_dispatch.device_having(self.mesh, plan)
         if getattr(query, "having", None) is not None:
             metrics["having_where"] = "device" if having else "host"
         if plan.sparse:
-            from tpu_olap.kernels.sparse_groupby import SENTINEL
-            top = self._device_threshold(query, plan) if topn else None
-            out, count = self._dispatch(
-                lambda: self._run_sparse(plan, metrics, top, having),
+            out, count, program = self._dispatch(
+                lambda: sparse_dispatch.run_sparse(self, plan, metrics),
                 metrics, table.name)
             t0 = time.perf_counter()
             with self.stages.stage("finalize", metrics):
@@ -2497,27 +1984,18 @@ class QueryRunner:
                 with _span("post-agg"):
                     eval_post_aggs(arrays, query.post_aggregations)
             names = self._out_names(query)
-            # present groups by sentinel mask: compact tables fill the
-            # tail with SENTINEL; exchange slot tables interleave empties.
-            # A wide key's further words are read where word 0 is present
-            keys = np.asarray(out["_keys"])
-            pm = keys != SENTINEL
-            present = keys[pm].astype(np.int64)
-            if len(plan.key_words) > 1:
-                from tpu_olap.kernels.sparse_groupby import key_names
-                present = (present,) + tuple(
-                    np.asarray(out[n])[pm]
-                    for n in key_names(len(plan.key_words))[1:])
+            pm, present = sparse_dispatch.present_groups(out, plan)
             sub = {n: np.asarray(arrays[n])[pm] for n in names}
             with self.stages.stage("assemble", metrics), \
                     _span("assemble"):
                 if topn:
-                    metrics["topn_rows_fetched"] = len(keys)
-                    res = self._emit_topn(query, plan, present, sub,
-                                          "device" if top else "host")
+                    metrics["topn_rows_fetched"] = len(pm)
+                    res = self._emit_topn(
+                        query, plan, present, sub,
+                        "device" if program.top else "host")
                 else:
                     if "having_where" in metrics:
-                        metrics["having_rows_fetched"] = len(keys)
+                        metrics["having_rows_fetched"] = len(pm)
                     if having:
                         metrics["having_groups_in"] = count
                     res = self._emit_groupby(query, plan, present, sub,
@@ -2690,9 +2168,9 @@ class QueryRunner:
                 * self._segment_scan_bytes(env, valid, table)
             S = len(seg_mask)
             K = plan.total_groups
-            lo, hi = min(compute_ids), max(compute_ids) + 1
+            mesh = self.mesh
             t0 = time.perf_counter()
-            if self.mesh is not None:
+            if mesh is not None:
                 # mesh variant (docs/CACHING.md "cache shards"): the
                 # per-chip LOCAL window slices each chip's placed
                 # segments, the key extends by placed window position,
@@ -2701,79 +2179,49 @@ class QueryRunner:
                 # host and cached per segment; serving folds cached +
                 # fresh entries at the broker via merge_partials
                 from tpu_olap.executor import sharding as sh
-                mesh = self.mesh
                 D = mesh.devices.size
                 per_chip = S // D
-                lo_l = min(i // D for i in compute_ids)
-                hi_l = max(i // D for i in compute_ids) + 1
-                W = min(_next_pow2(hi_l - lo_l), per_chip)
-                lo_l = min(lo_l, per_chip - W)
-                with self._enqueue_lock(metrics):
-                    jkey = plan.fingerprint() + ("segcache-mesh", D, W)
-                    jitted = self._jit_cache.get(jkey)
-                    hit = jitted is not None
-                    if hit:
-                        _cache_lru_hit(self._jit_cache, jkey)
-                    else:
-                        jitted = sh.mesh_seg_partials_kernel(
-                            plan, mesh, per_chip, W, K)
-                        self._jit_cache[jkey] = jitted
-                        self._note_compile("segcache", metrics)
-                    _note_form(metrics, plan, D * W * K)
-                    with _span("dispatch", jit_cache_hit=hit,
-                               segcache=True, num_shards=D,
-                               **_form_attr(metrics)):
-                        consts_dev, seg_arg = self._args_for(
-                            plan, seg_mask, mesh)
-                        out = jitted(env, valid, seg_arg, consts_dev,
-                                     lo_l)
-                    pin = self._pin_inflight(out)
-                    self._note_chip_dispatch(range(D))
-                with _span("host-transfer"):
-                    out = self._fetch_tree(out, metrics, pin)
-                metrics["jit_cache_hit"] = hit
-                metrics["num_shards"] = D
-                metrics["execute_ms"] = (time.perf_counter() - t0) * 1000
-                shaped = {name: np.asarray(a).reshape(
-                    (D, W, K) + np.asarray(a).shape[1:])
-                    for name, a in out.items()}
-                # logical sid -> (chip sid mod D, local sid // D)
-                return {sid: {name: a[sid % D, sid // D - lo_l]
-                              for name, a in shaped.items()}
-                        for sid in compute_ids}
+                lo = min(i // D for i in compute_ids)
+                hi = max(i // D for i in compute_ids) + 1
+                W = min(_next_pow2(hi - lo), per_chip)
+                lo = min(lo, per_chip - W)
+                jkey = plan.fingerprint() + ("segcache-mesh", D, W)
+
+                def build():
+                    return sh.mesh_seg_partials_kernel(plan, mesh,
+                                                       per_chip, W, K)
             else:
                 import jax
+                D = 1
+                lo, hi = min(compute_ids), max(compute_ids) + 1
                 W = min(_next_pow2(hi - lo), S)
                 lo = min(lo, S - W)
-                with self._enqueue_lock(metrics):
-                    jkey = plan.fingerprint() + ("segcache", W)
-                    jitted = self._jit_cache.get(jkey)
-                    hit = jitted is not None
-                    if hit:
-                        _cache_lru_hit(self._jit_cache, jkey)
-                    else:
-                        jitted = jax.jit(
-                            self._seg_partials_kernel(plan, W, K))
-                        self._jit_cache[jkey] = jitted
-                        self._note_compile("segcache", metrics)
-                    _note_form(metrics, plan, W * K)
-                    with _span("dispatch", jit_cache_hit=hit,
-                               segcache=True, num_shards=1,
-                               **_form_attr(metrics)):
-                        consts_dev, seg_arg = self._args_for(
-                            plan, seg_mask, None)
-                        out = jitted(env, valid, seg_arg, consts_dev,
-                                     lo)
-                    pin = self._pin_inflight(out)
-                with _span("host-transfer"):
-                    out = self._fetch_tree(out, metrics, pin)
-                metrics["jit_cache_hit"] = hit
-                metrics["num_shards"] = 1
+                jkey = plan.fingerprint() + ("segcache", W)
+
+                def build():
+                    return jax.jit(self._seg_partials_kernel(plan, W, K))
+            with self._enqueue_lock(metrics):
+                jitted, hit = self._program(jkey, build, "segcache",
+                                            metrics)
+                _note_form(metrics, plan, D * W * K)
+                with _span("dispatch", jit_cache_hit=hit, segcache=True,
+                           num_shards=D, **_form_attr(metrics)):
+                    consts_dev, seg_arg = self._args_for(plan, seg_mask,
+                                                         mesh)
+                    out = jitted(env, valid, seg_arg, consts_dev, lo)
+                pin = self._pin_inflight(out)
+                if mesh is not None:
+                    self._note_chip_dispatch(range(D))
+            with _span("host-transfer"):
+                out = self._fetch_tree(out, metrics, pin)
+            metrics["jit_cache_hit"] = hit
+            metrics["num_shards"] = D
             metrics["execute_ms"] = (time.perf_counter() - t0) * 1000
-        shaped = {name: arr.reshape((W, K) + arr.shape[1:])
-                  for name, arr in out.items()}
-        return {sid: {name: arr[sid - lo]
-                      for name, arr in shaped.items()}
+        shaped = {name: np.asarray(a).reshape(
+            (D, W, K) + np.asarray(a).shape[1:]) for name, a in out.items()}
+        # logical sid -> (chip sid mod D, local sid // D); one chip: (0, sid)
+        return {sid: {name: a[sid % D, sid // D - lo]
+                      for name, a in shaped.items()}
                 for sid in compute_ids}
 
     @staticmethod
@@ -2791,14 +2239,8 @@ class QueryRunner:
         from tpu_olap.kernels.groupby import group_reduce
 
         def fn(env, valid, seg_mask, consts, lo):
-            def sl(a):
-                return jax.lax.dynamic_slice_in_dim(a, lo, W, axis=0)
-            with jax.named_scope("window"):
-                wenv = {
-                    "cols": {c: sl(a) for c, a in env["cols"].items()},
-                    "nulls": {c: sl(a) for c, a in env["nulls"].items()}}
-                valid, seg_mask = sl(valid), sl(seg_mask)
-            fenv, mask, key = plan.key_fn(wenv, valid, seg_mask, consts)
+            fenv, mask, key = plan.key_fn(
+                *_window_slice(env, valid, seg_mask, lo, W), consts)
             with jax.named_scope("key"):
                 r = mask.shape[0] // W
                 seg_local = jnp.repeat(jnp.arange(W, dtype=jnp.int32), r)
@@ -2899,7 +2341,8 @@ class QueryRunner:
         words as a tuple); sub: compact per-group
         final values (present None: the dense [K] tables). Shared tail of
         the dense and sparse paths. `decided`: the groups are those the
-        device's HAVING let through (`_device_having`), so none is tested
+        device's HAVING let through (`sparse_dispatch.device_having`), so
+        none is tested
         here; they are decoded, ordered and limited as any."""
         names = self._out_names(query)
         present, sub, buckets, dim_ids = self._decode_present(
@@ -2946,81 +2389,6 @@ class QueryRunner:
                 druid.append({"version": "v1", "timestamp": ts,
                               "event": ev})
         return QueryResult(query, rows, druid)
-
-    def _device_threshold(self, query, plan):
-        """(metric, threshold, inverted) where one chip's sparse program
-        can apply a TopN's threshold itself, else None (the host ranks the
-        fetched table): one bucket, no mesh (a mesh's broker merges whole
-        tables), and the metric an aggregate whose table column IS its
-        final value, held as an integer (a count or a long sum): a
-        post-aggregation, a sketch's estimate and a min / max's null are
-        made on the host, and a float's NaN ranks differently there."""
-        if self.mesh is not None or plan.sizes[0] != 1:
-            return None
-        for p in plan.agg_plans:
-            if p.name == query.metric and p.kind in ("count", "sum") \
-                    and np.issubdtype(np.dtype(p.acc_dtype), np.integer):
-                return (query.metric, query.threshold, query.inverted)
-        return None
-
-    def _device_having(self, plan) -> bool:
-        """Whether one chip's sparse program decides the GroupBy's HAVING
-        itself (the host then fetches the rows that passed, not the
-        table): the plan took the sparse path, there is no mesh (a chip's
-        partial sum decides nothing, and the broker merges whole tables)
-        and lowering found the predicate decidable from integer tables
-        alone (`sparse_groupby.compile_having`; `_device_threshold`'s
-        rule, with a comparison in the rank's place)."""
-        return self.mesh is None and plan.having is not None
-
-    def having_where(self, query, table) -> str | None:
-        """`device` | `host`: who would decide the GroupBy's HAVING, as
-        its record's `having_where` says after a run (EXPLAIN's line);
-        None where the query has no device plan at all (it fails, or the
-        fallback answers it whole)."""
-        from tpu_olap.kernels.filtereval import UnsupportedFilter
-        from tpu_olap.kernels.groupby import UnsupportedAggregation
-        try:
-            plan = self._lower_cached_inner(query, table)
-        except (UnsupportedAggregation, UnsupportedFilter):
-            return None
-        return "device" if self._device_having(plan) else "host"
-
-    def key_words(self, query, table):
-        """(words, bits, sort bits) of the sparse key the query's plan
-        sorts by, as its record's `key_words`, `key_bits` and
-        `key_sort_bits` say after a run (EXPLAIN's lines): the words the
-        key takes (1 under a group space of 2^62), the bits its
-        dimensions' ids take together and the width each word rides the
-        sort at (32 | 64 a word). None where the plan is not sparse.
-        Raises what lowering raises of a query with no device plan."""
-        from tpu_olap.kernels import sparse_groupby as sg
-        plan = self._lower_cached_inner(query, table)
-        if not plan.sparse:
-            return None
-        return (len(plan.key_words), sg.key_bits(plan.sizes),
-                sg.key_sort_bits(plan.sizes, plan.key_words))
-
-    def sum_word_bits(self, query, table) -> int | None:
-        """32 | 64: the width the integer sums of the query's sparse
-        program would ride the sort at on its next run, as its record's
-        `sum_word_bits` says after one (EXPLAIN's line): 32 where each
-        is a column stored in 32 bits or fewer, there is no mesh and no
-        run of the plan has found a group's sum past int32. None where
-        the plan is not sparse or has no integer sum. Raises what
-        lowering raises of a query with no device plan."""
-        from tpu_olap.executor.dataset import narrow_dtype
-        from tpu_olap.kernels import sparse_groupby as sg
-        plan = self._lower_cached_inner(query, table)
-        if not plan.sparse:
-            return None
-        stored = {p.fields[0]: dt for p in plan.agg_plans
-                  if p.kind == "sum"
-                  and (dt := narrow_dtype(table, p.fields[0])) is not None}
-        return sg.sum_word_bits(
-            plan.agg_plans, stored,
-            self.mesh is None and self._sparse_key(plan, 1) + ("wide",)
-            not in self._cap_hints)
 
     def _assemble_topn(self, query, plan, arrays) -> QueryResult:
         return self._emit_topn(query, plan, None, arrays, "host")
@@ -3300,6 +2668,19 @@ class QueryRunner:
         return QueryResult(query, [record], [record])
 
 
+def _window_slice(env, valid, seg_mask, lo, W: int):
+    """(env, valid, seg_mask) with every [S, ...] input dynamic-sliced to
+    the `W` segments from `lo`, inside a jitted program."""
+    import jax
+
+    def sl(a):
+        return jax.lax.dynamic_slice_in_dim(a, lo, W, axis=0)
+    with jax.named_scope("window"):
+        wenv = {"cols": {c: sl(a) for c, a in env["cols"].items()},
+                "nulls": {c: sl(a) for c, a in env["nulls"].items()}}
+        return wenv, sl(valid), sl(seg_mask)
+
+
 def _note_form(metrics: dict, plan, num_groups: int):
     """`reduce_form` on the record, from the group space the generic
     kernel is built with: the plan's K, or the segment-extended W*K
@@ -3312,35 +2693,14 @@ def _note_form(metrics: dict, plan, num_groups: int):
 def _form_attr(metrics: dict) -> dict:
     """The `dispatch` span's `reduce_form` attribute, where the record of
     the query has one (a generic grouped aggregate on the device), and
-    beside it a sparse min / max's `ext_word_bits`, a sparse integer
-    sum's `sum_word_bits`, the sparse program's `cap_tables` and
-    `boundary_read`, who
-    decides a GroupBy's HAVING (`having_where`) and the sparse key's
-    `key_words`, `key_bits` and `key_sort_bits`."""
+    beside it a sparse program's other words
+    (`sparse_groupby.program_words`) and who decides a GroupBy's HAVING
+    (`having_where`)."""
     return {k: metrics[k]
             for k in ("reduce_form", "ext_word_bits", "sum_word_bits",
                       "cap_tables", "boundary_read", "having_where",
                       "key_words", "key_bits", "key_sort_bits")
             if metrics.get(k) is not None}
-
-
-def _next_pow2(n: int) -> int:
-    return 1 << max(0, int(n) - 1).bit_length() if n > 1 else 1
-
-
-# rows of the smallest bucket a device HAVING compacts the passing groups
-# into (and the host fetches a table): a report's HAVING keeps hundreds of
-# groups out of millions, and each bucket size is a compile of the sort
-HAVING_KEPT_MIN = 1024
-
-
-def _grown_cap(count: int, limit: int) -> int:
-    """The sparse compact table's cap for `count` present groups: what an
-    overflowing attempt grows to AND what the template's next run starts
-    from (its hint), so the program compiled for the retry is the one
-    every later run finds in the jit cache: each size is a compile of
-    the sort."""
-    return min(limit, max(64, _next_pow2(2 * count)))
 
 
 def _limit_order(ls, buckets, dim_ids, labels, sub) -> np.ndarray:

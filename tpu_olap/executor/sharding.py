@@ -151,16 +151,16 @@ def replicate_put(arr, mesh: Mesh):
     return jax.device_put(arr, replicated_spec(mesh))
 
 
-def mesh_sparse_kernel(plan, mesh: Mesh, cap: int, boundary=None):
+def mesh_sparse_kernel(plan, mesh: Mesh, program):
     """Jitted sparse group-by over the mesh: the one-chip program
-    (`plan.make_sparse_kernel(cap, boundary=boundary)`: key, sort, the
-    [cap] tables read at the runs' boundaries, `boundary` said of a chip's
-    share of the rows) `jax.shard_map`ped over the chip axis, as
+    (`plan.make_sparse_kernel(program)`: key, sort, the [cap] tables read
+    at the runs' boundaries, `program.boundary` said of a chip's share of
+    the rows) `jax.shard_map`ped over the chip axis, as
     mesh_agg_kernel maps the dense one. Each chip compacts its own rows;
     out_specs=P(chips) lays the D tables end to end as [D·cap, ...] and
     the D true counts as `_count` [D], a chip each. No collective is in
     the program, and it compiles once a cap whatever the mesh's size."""
-    local = plan.make_sparse_kernel(cap, boundary=boundary)
+    local = plan.make_sparse_kernel(program)
 
     def per_chip(env, valid, seg_mask, consts):
         out = local(env, valid, seg_mask, consts)
@@ -200,16 +200,13 @@ def mesh_merge_kernel(plan, mesh: Mesh, rows: int):
     partial rows), so the merged table stands on every chip and the host
     fetches one copy of it: nothing of a sparse query is sorted or
     reduced on the host."""
-    import jax.numpy as jnp
-
     from tpu_olap.kernels.sparse_groupby import merge_device
 
     def merge_tables(tables):
         with jax.named_scope("merge"):
             whole = {name: jax.lax.all_gather(t[:rows], AXIS, tiled=True)
                      for name, t in tables.items()}
-            return merge_device(whole, plan.agg_plans, mesh.devices.size,
-                                jnp)
+            return merge_device(whole, plan.agg_plans, mesh.devices.size)
 
     return jax.jit(jax.shard_map(merge_tables, mesh=mesh,
                                  in_specs=(P(AXIS),),
@@ -238,14 +235,14 @@ def local_window(pruned_ids, num_shards: int, per_chip: int):
         return None
     lo = min(pruned_ids) // num_shards
     hi = max(pruned_ids) // num_shards + 1
-    W = _next_pow2(hi - lo)
+    W = next_pow2(hi - lo)
     W = min(W, per_chip)
     if 4 * W >= 3 * per_chip:
         return None
     return min(lo, per_chip - W), W
 
 
-def _next_pow2(n: int) -> int:
+def next_pow2(n: int) -> int:
     return 1 << max(0, int(n) - 1).bit_length() if n > 1 else 1
 
 

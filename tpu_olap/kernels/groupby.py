@@ -87,9 +87,9 @@ STAGES = (
 )
 
 
-def stage_scope(name: str, xp):
+def stage_scope(name: str, xp=None):
     """`jax.named_scope(name)` while a device program is being traced
-    (xp is jax.numpy), nothing on the numpy path: every op of the jitted
+    (xp is jax.numpy, or not given), nothing on the numpy path: every op of the jitted
     programs carries its stage, one of `STAGES`, in its op_name, which the
     profiler records beside the compiler's own name for the op (a
     capture's event metadata holds it as the stat `tf_op`). A scope

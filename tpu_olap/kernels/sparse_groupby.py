@@ -25,58 +25,46 @@ XLA's sort is fast on TPU and everything stays static-shaped:
      [0, n_unique); ids clip to a `cap` slot table (+1 overflow slot that
      also swallows the sentinel tail). After the sort a group is a
      contiguous run: `starts[g]`, the run's first row, comes from a
-     second, one-operand sort of the first rows' positions. Where the
-     compact table is a large share of the rows sorted
-     (`boundary_spelling`: up to 8 rows a slot), the prefix sums and
-     running words that step 4 reads a WHOLE [cap] table from ride that
-     sort as further operands, and its first cap + 1 outputs are the
-     read: a sort operand is paid by the row, a gather by the index
-     (`boundary_read` on the record says which);
+     second, one-operand sort of the first rows' positions;
   4. the [cap] tables are READ AT THE RUN BOUNDARIES: a row count is
      starts[g+1] - starts[g], a key is the run's first row's, an integer
      sum or count is the difference of an inclusive prefix sum at the
      run's two ends (wrapping arithmetic: exact so long as the run's own
-     sum fits the prefix's word: the accumulator's 64 bits, or, in the
-     narrow program one chip's runner tries first, ONE int32 word for a
-     sum of a column stored in 32 bits or fewer — one sort operand, an
-     int32 prefix tree and one gather a boundary where an int64 is two
-     u32 of each — with `_narrow_ok` saying from the longest run and the
-     column's largest |value| that every group's sum fits; where it
-     does not the runner runs the wide program and remembers the plan
-     as wide: `sum_word_bits` on the record), an integer min / max of
-     a column stored in 32 bits or fewer is read at the run's last row
-     from a running maximum of the one word (run id << b) | code(value):
-     the run ids do not decrease, so the plain maximum is segmented by
-     construction (`ext_word_dtype`). No row is scattered. What neither
-     gives keeps a segment reduce over the sorted ids — a floating-point
-     sum (the prefix's rounding error is not the group's), a min / max of
-     a column stored in 64 bits or of a double, the sketches' [cap, m]
+     sum fits the prefix's word), an integer min / max of a column stored
+     in 32 bits or fewer is read at the run's last row from a running
+     maximum of the one word (run id << b) | code(value): the run ids do
+     not decrease, so the plain maximum is segmented by construction
+     (`ext_word_dtype`). No row is scattered. What neither gives keeps a
+     segment reduce over the sorted ids — a floating-point sum (the
+     prefix's rounding error is not the group's), a min / max of a
+     column stored in 64 bits or of a double, the sketches' [cap, m]
      state — and `sparse_reduce_form` says "scatter" of such a plan. Slot
      i holds the i-th smallest present group key, so results are already
-     compact AND sorted. Where the program ends in a TopN's threshold
-     (`top`), it RANKS FIRST: only the ranked metric's table is built at
-     [cap] (and `_rows`, a difference of `starts`), `top_k` picks the
-     `threshold` slots it keeps, and every other table is read at those
-     slots alone, from the same sorted operands, prefix sums and running
-     words at the kept runs' two ends (what segment-reduces builds its
-     [cap] table and is indexed). Where a HAVING the device can decide
-     ends the program (`having`), the same cut with a predicate in the
-     rank's place: only the tested aggregates' tables are built at [cap],
-     the passing slots are compacted in slot order into a `kept` bucket
-     and every other table is read there; `cap_tables` counts the [cap]
-     tables each program gathers or segment-reduces;
+     compact AND sorted;
   5. "_count" reports the true unique count — if it exceeds cap the
-     runner re-runs with the next power of two (same adaptive-cap pattern
-     as executor.packing).
+     dispatch re-runs with the next power of two (same adaptive-cap
+     pattern as executor.packing).
 
-Multi-chip merge (P2, SURVEY.md §3.5): each chip's compacted [cap] table
-all-gathers over ICI ([D, cap] is small) and the SAME sort+reduce runs on
-the concatenation — partial sums re-sum, mins re-min, HLL registers
-re-max, theta tables re-merge.
+Which program of a plan that is, is one `SparseProgram` value (its
+fields say what each variant is: a TopN's or a HAVING's cut on the
+device, the narrow sums, how the whole tables are read; chosen by
+`executor.sparse_dispatch.choose_program`), `sparse_group_reduce` says
+what each does to the tables, and `program_words` what the record says
+of it.
+
+Multi-chip merge (P2, SURVEY.md §3.5): the chips' compacted tables are
+merged on the device (`merge_device`) or, with a sketch's state, by the
+host broker (`merge_sparse`).
 """
 
 from __future__ import annotations
 
+import dataclasses
+import functools
+import operator
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from tpu_olap.kernels import hll as hll_mod
@@ -177,7 +165,7 @@ def key_sort_bits(sizes, words) -> list:
     return [8 * dt.itemsize for dt in key_word_dtypes(sizes, words)]
 
 
-def build_group_key64(ids, sizes, xp, words=None):
+def build_group_key64(ids, sizes, words=None):
     """Mixed-radix combine into one key word, int64, or with `words`
     (`pack_key_words` of `sizes`) into each word's `key_word_dtypes`: a
     word whose values fit 31 bits is combined in int32 (no emulated
@@ -185,12 +173,12 @@ def build_group_key64(ids, sizes, xp, words=None):
     several: a tuple, each the combine of its positions' ids under
     `key_radix`. Callers without `words` guard product < 2^62."""
     def combine(ids, radix, dtype):
-        word = getattr(xp, dtype.name)
+        word = getattr(jnp, dtype.name)
         key = None
         for i, s in zip(ids, radix):
             i = i.astype(word)
             key = i if key is None else key * word(s) + i
-        return xp.zeros((), word) if key is None else key
+        return jnp.zeros((), word) if key is None else key
 
     total = 1
     for s in sizes:
@@ -223,8 +211,6 @@ def _sentinel(word):
 def _changes(skeys):
     """[N-1] bool: where a sorted key differs from the row before it, in
     any word (each compared in its own width)."""
-    import functools
-    import operator
     return functools.reduce(operator.or_,
                             [w[1:] != w[:-1] for w in skeys])
 
@@ -238,8 +224,6 @@ def _running(x, kind: str, axis=0):
     caller's `named_scope` (its op_name is `reduce_window_sum` and no
     more): a capture could then not tell the run ids' prefix sum from an
     aggregate's."""
-    import jax
-
     n = x.shape[axis]
     if n == 0:
         return x
@@ -251,46 +235,23 @@ def _running(x, kind: str, axis=0):
                                  [1] * x.ndim, pads)
 
 
-def _sorted_segments(skey, cap, xp):
-    """boundary/gid/count core shared by row reduction and table merge:
-    gid clips into the dropped overflow+sentinel slot `cap`. `skey`: the
-    sorted key, an array or a tuple of its words (a masked row holds the
-    sentinel of word 0's dtype there; what its other words hold starts
-    runs in the tail that no slot keeps)."""
-    skeys = _key_words(skey)
+def _sorted_segments(skeys, cap):
+    """The row reduction's run ids and count: gid clips into the dropped
+    overflow+sentinel slot `cap`. `skeys`: the sorted key's words (a
+    masked row holds the sentinel of word 0's dtype there; what its other
+    words hold starts runs in the tail that no slot keeps)."""
     sentinel = _sentinel(skeys[0])
-    boundary = xp.concatenate([
-        xp.ones((1,), bool),
+    boundary = jnp.concatenate([
+        jnp.ones((1,), bool),
         _changes(skeys),
     ])
-    flags = boundary.astype(xp.int32)
-    gid = (np.cumsum(flags) if xp is np else _running(flags, "add")) - 1
-    count = (boundary & (skeys[0] != sentinel)).sum(dtype=xp.int32)
-    gid = xp.where((gid < cap) & (skeys[0] != sentinel), gid, cap)
+    gid = _running(boundary.astype(jnp.int32), "add") - 1
+    count = (boundary & (skeys[0] != sentinel)).sum(dtype=jnp.int32)
+    gid = jnp.where((gid < cap) & (skeys[0] != sentinel), gid, cap)
     return gid, count
 
 
-def _seg_sum(v, gid, cap, xp):
-    if xp is np:
-        out = np.zeros((cap + 1,) + v.shape[1:], v.dtype)
-        np.add.at(out, gid, v)
-        return out[:cap]
-    import jax
-    return jax.ops.segment_sum(v, gid, num_segments=cap + 1)[:cap]
-
-
-def _seg_ext(v, gid, cap, kind, xp):
-    if xp is np:
-        out = np.full((cap + 1,) + v.shape[1:], _ident(v.dtype, kind),
-                      v.dtype)
-        (np.minimum if kind == "min" else np.maximum).at(out, gid, v)
-        return out[:cap]
-    import jax
-    f = jax.ops.segment_min if kind == "min" else jax.ops.segment_max
-    return f(v, gid, num_segments=cap + 1)[:cap]
-
-
-def _run_starts(skey, cap, xp, riders=()):
+def _run_starts(skey, cap, riders=()):
     """-> (starts, rode). starts: [cap + 1] int32, starts[g] the first row
     of the g-th run of equal
     keys (`skey`: an array, or a tuple of the key's words); for a slot
@@ -314,27 +275,25 @@ def _run_starts(skey, cap, xp, riders=()):
     the same fill, so the order an unstable sort leaves them in cannot
     show. No index is paid for; an operand over the rows sorted is
     (`BOUNDARY_SORT_MAX_ROWS_PER_SLOT`)."""
-    import jax
-
     skeys = _key_words(skey)
     n = skeys[0].shape[0]
     valid = skeys[0] != _sentinel(skeys[0])
-    first = valid & xp.concatenate([xp.ones((1,), bool), _changes(skeys)])
-    tail = valid.sum(dtype=xp.int32)
-    pos = xp.where(first, xp.arange(n, dtype=xp.int32), tail)
+    first = valid & jnp.concatenate([jnp.ones((1,), bool), _changes(skeys)])
+    tail = valid.sum(dtype=jnp.int32)
+    pos = jnp.where(first, jnp.arange(n, dtype=jnp.int32), tail)
     if n < cap + 1:
-        pos = xp.concatenate([pos, xp.broadcast_to(tail, (cap + 1 - n,))])
+        pos = jnp.concatenate([pos, jnp.broadcast_to(tail, (cap + 1 - n,))])
     if not riders:
         return jax.lax.sort(pos, is_stable=False)[:cap + 1], []
 
     def ride(x):
-        fill = xp.where(tail > 0, x[xp.maximum(tail - 1, 0)], 0)
-        before = xp.concatenate([xp.zeros((1,), x.dtype), x[:-1]])
-        x = xp.where(first, before, fill)
+        fill = jnp.where(tail > 0, x[jnp.maximum(tail - 1, 0)], 0)
+        before = jnp.concatenate([jnp.zeros((1,), x.dtype), x[:-1]])
+        x = jnp.where(first, before, fill)
         if n < cap + 1:
-            x = xp.concatenate([x, xp.broadcast_to(fill, (cap + 1 - n,))])
+            x = jnp.concatenate([x, jnp.broadcast_to(fill, (cap + 1 - n,))])
         return x
-    with stage_scope("prefix", xp):
+    with stage_scope("prefix"):
         riders = [ride(x) for x in riders]
     starts, *rode = jax.lax.sort((pos, *riders), num_keys=1,
                                  is_stable=False)
@@ -378,9 +337,6 @@ def _running_max(word):
     sums' `cumsum`; an int64 one is the same along blocks of `_SCAN_BLOCK`
     rows,
     each block raised to the running maximum of the blocks before it."""
-    import jax
-    import jax.numpy as jnp
-
     n = word.shape[0]
     if word.dtype.itemsize <= 4 or n <= _SCAN_BLOCK:
         return _running(word, "max")
@@ -402,8 +358,6 @@ def _ext_running(v, counted, gid, kind, col_dtype, word):
     maximum holds the run's own id above the largest code seen in the
     run; `counted` (None: every row) is False on the rows the aggregator
     leaves out, which code as 0."""
-    import jax.numpy as jnp
-
     lim, b = np.iinfo(col_dtype), np.iinfo(col_dtype).bits + 1
     v = v.astype(word)
     code = v - lim.min + 1 if kind == "max" else lim.max - v + 1
@@ -522,29 +476,49 @@ def sum_word_bits(plans, col_dtypes, narrow: bool):
     return max(bits) if bits else None
 
 
-def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
-                        having=None, narrow=False, boundary=None):
-    """[N] keys + mask -> compacted per-group partials.
+@dataclasses.dataclass(frozen=True)
+class SparseProgram:
+    """What fixes one sparse program of a plan, beside the plan: the one
+    static argument of `sparse_group_reduce` and of lowering's
+    `make_sparse_kernel`, the suffix of the program's jit key (as itself:
+    frozen and hashable), and what `program_words` reads the record's
+    words from. `executor.sparse_dispatch.choose_program` makes it.
+    `cap`: slots of the compact table (None: the program that counts the
+    groups present and builds no table); `top`: (metric, threshold,
+    inverted) of a TopN whose threshold the program applies; `kept`: rows
+    of the bucket the groups a device HAVING lets through are compacted
+    into; `narrow`: an integer sum of a column stored in 32 bits or fewer
+    rides as ONE int32 word; `boundary`: `boundary_read` of the cap and
+    the rows sorted ("sorted" | "gather" | None); `window`: segments of
+    the window the program slices its inputs to, where there is one."""
+    cap: int | None
+    top: tuple | None = None
+    kept: int | None = None
+    narrow: bool = False
+    boundary: str | None = None
+    window: int | None = None
+
+
+def sparse_group_reduce(key, mask, env, plans, consts, program,
+                        having=None):
+    """[N] keys + mask -> compacted per-group partials: the table program
+    of `program` (a `SparseProgram`; its `cap` is the table's slots).
 
     Returns {"_keys": [cap] int64 (SENTINEL marks empty slots),
              "_count": [] int32 true unique count,
              "_rows": [cap], <agg name>: [cap] or [cap, m], ...}.
 
     `key` is one [N] array, or the tuple of a wide key's words
-    (`build_group_key64` with `words`): the words ride the sort as its
-    `num_keys` leading operands, a masked row holds the sentinel in word 0
-    alone, a run ends where any word changes, and the tables gain one a
-    further word (`key_names`: `_keys1`, ...; what they hold in an empty
-    slot is not defined: `_keys` says which slots are present). A word is
-    int64 or int32 (`key_word_dtypes`: the dtype follows the bits the
-    word holds) and rides the sort, the boundary test and the gather of
-    its table in that width; the sentinel is the maximum of word 0's
-    dtype. The tables leave as int64 whatever the words rode as, an empty
-    slot's `_keys` the int64 SENTINEL: they are the int64 program's to
-    the bit.
+    (`build_group_key64` with `words`; the module's step 1): the tables
+    gain one a further word (`key_names`: `_keys1`, ...; what they hold
+    in an empty slot is not defined: `_keys` says which slots are
+    present). A word rides the sort, the boundary test and the gather of
+    its table in its own width (`key_word_dtypes`); the tables leave as
+    int64 whatever the words rode as, an empty slot's `_keys` the int64
+    SENTINEL: they are the int64 program's to the bit.
 
-    With `top` = (metric, threshold, inverted), the rows of that table a
-    TopN by `metric` (a count or a sum of `plans`) keeps, as
+    With `program.top` = (metric, threshold, inverted), the rows of that
+    table a TopN by `metric` (a count or a sum of `plans`) keeps, as
     [min(threshold, cap)] tables in rank order: the program ranks first.
     Of the [cap] tables it builds the metric's alone (and `_rows`, which
     says which slots are present), `top_k` picks the kept slots, and every
@@ -554,35 +528,38 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
     present groups holds the SENTINEL key and the identities of the empty
     slot it points at.
 
-    With `having` = (test, names, kept) from `compile_having`, the groups
-    a HAVING lets through, as [kept] tables in ascending slot (= key)
-    order: the predicate in the rank's place. Of the [cap] tables only
-    those of the aggregates `names` that `test` reads are built (and
-    `_rows`); `_kept` counts the present slots that pass, which are
-    compacted into the first `_kept` of `kept` rows (the runner re-runs
-    with a larger bucket where they do not fit, as it does for `cap`),
+    With `program.kept` and `having` = (test, names), the plan's
+    `compile_having`, the groups a HAVING lets through, as [kept] tables
+    in ascending slot (= key) order: the predicate in the rank's place.
+    Of the [cap] tables only those of the aggregates `names` that `test`
+    reads are built (and `_rows`); `_kept` counts the present slots that
+    pass, which are compacted into the first `_kept` of `kept` rows (the
+    dispatch re-runs with a larger bucket where they do not fit, as it
+    does for `cap`),
     and every other table is read at those slots. A row past `_kept`
     holds the SENTINEL key and no rows.
 
-    With `narrow`, an integer sum of a column stored in 32 bits or fewer
-    (`_narrow_sum`) rides the sort as ONE int32 operand, takes its prefix
-    sum in int32 and is read with one gather a boundary, and the tables
-    hold `_narrow_ok`, a scalar: whether the longest run times the largest
-    |value| of each such column is at most 2^31 - 1. Where it is, no
-    group's sum leaves int32, the wrapped int32 difference IS the sum, and
+    With `program.narrow`, an integer sum of a column stored in 32 bits
+    or fewer (`_narrow_sum`) rides the sort as ONE int32 operand, takes
+    its prefix sum in int32 and is read with one gather a boundary, and
+    the tables hold `_narrow_ok`, a scalar: whether the longest run times
+    the largest |value| of each such column is at most 2^31 - 1. Where it
+    is, no group's sum leaves int32, the wrapped int32 difference IS the
+    sum, and
     the tables (widened to the accumulator a slot) are the wide program's
     to the bit; where it is not (or `_count` passes `cap`: a run past the
     cap is not among `_rows`) the caller runs the wide program. A plan
     with no such sum gives the program it gives without `narrow`, text
     for text.
 
-    `boundary` is the caller's `boundary_read` of this program's plans,
-    cap and rows: with "sorted" the prefix sums and running words a whole
+    `program.boundary` is `boundary_read` of this program's plans, cap
+    and rows: with "sorted" the prefix sums and running words a whole
     [cap] table is read from ride `starts`' sort (`_run_starts`' riders);
     else each is a gather after it. The tables are the same to the bit.
     """
-    import jax
-
+    cap, top, narrow = program.cap, program.top, program.narrow
+    # the plan's predicate cuts only where the program has a bucket for it
+    having = None if program.kept is None else (*having, program.kept)
     # the mask does not ride the sort: a sorted row is masked exactly
     # where its key is the SENTINEL
     slots = {}
@@ -596,13 +573,13 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
             operands.append(arr)
 
     kwords = _key_words(key)
-    with stage_scope("sort", xp):
-        operands = [xp.where(mask, kwords[0], _sentinel(kwords[0])),
+    with stage_scope("sort"):
+        operands = [jnp.where(mask, kwords[0], _sentinel(kwords[0])),
                     *kwords[1:]]
         for p in plans:
             m = mask
             if p.filter_fn is not None:
-                with stage_scope("filter", xp):
+                with stage_scope("filter"):
                     m = mask & p.filter_fn(env, consts)
             if p.kind == "count":
                 if p.filter_fn is not None:
@@ -619,7 +596,7 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
                     if narrow and _narrow_sum(p, x.dtype):
                         dt = np.dtype(np.int32)
                         narrowed.append(p.name)
-                    carry(f"v:{p.name}", xp.where(mm, x, 0).astype(dt))
+                    carry(f"v:{p.name}", jnp.where(mm, x, 0).astype(dt))
                     if prefix_summed(p):
                         prefixed[f"v:{p.name}"] = dt
                 else:
@@ -635,7 +612,7 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
                         carry(operand, x.astype(dt))
                     else:
                         carry(f"v:{p.name}",
-                              xp.where(mm, x.astype(dt),
+                              jnp.where(mm, x.astype(dt),
                                        _ident(dt, p.kind)))
                     if p.filter_fn is not None or nulls is not None:
                         # mm == mask otherwise: the non-null count IS
@@ -644,7 +621,7 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
                         carry(f"nn:{p.name}", mm)
                         prefixed[f"nn:{p.name}"] = np.int32
             elif p.kind in ("hll", "theta"):
-                h, valid = _hash_fields(env, p, m, xp, consts)
+                h, valid = _hash_fields(env, p, m, jnp, consts)
                 carry(f"h:{p.name}", h)
                 carry(f"hv:{p.name}", valid)
             else:
@@ -660,8 +637,8 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
 
     skeys = tuple(sorted_ops[:len(kwords)])
 
-    with stage_scope("runs", xp):
-        gid, count = _sorted_segments(skeys, cap, xp)
+    with stage_scope("runs"):
+        gid, count = _sorted_segments(skeys, cap)
 
     scans = {}   # name -> the [N] prefix sum or running word, built once
 
@@ -671,7 +648,7 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
         (`prefixed`: the accumulator's, or a narrowed sum's and a count's
         int32), or the running word of a min / max (`words`)."""
         if name not in scans:
-            with stage_scope("prefix", xp):
+            with stage_scope("prefix"):
                 if name in prefixed:
                     scans[name] = _running(sorted_ops[slots[name]]
                                            .astype(prefixed[name]), "add")
@@ -699,9 +676,9 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
     # sort where the caller's rule says so
     riders = {name: scan(name)
               for p in _decides(plans, top, having and having[1])
-              for name in reads(p)} if boundary == "sorted" else {}
-    with stage_scope("runs", xp):
-        starts, rode = _run_starts(skeys, cap, xp, list(riders.values()))
+              for name in reads(p)} if program.boundary == "sorted" else {}
+    with stage_scope("runs"):
+        starts, rode = _run_starts(skeys, cap, list(riders.values()))
     rode = dict(zip(riders, rode))
 
     def before(name, row=None):
@@ -711,7 +688,7 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
         if row is None and name in rode:
             return rode[name]
         x, row = scans[name], starts if row is None else row
-        return xp.where(row > 0, x[xp.maximum(row - 1, 0)], 0)
+        return jnp.where(row > 0, x[jnp.maximum(row - 1, 0)], 0)
 
     def run_sum(name, acc_dtype, at=None):
         """Exact integer sum of the sorted operand `name` over the runs
@@ -723,7 +700,7 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
         accumulator's, or a narrowed sum's int32, which `_narrow_ok`
         answers for)."""
         scan(name)
-        with stage_scope("gather", xp):
+        with stage_scope("gather"):
             if at is None:
                 ends = before(name)
                 total = ends[1:] - ends[:-1]
@@ -738,7 +715,7 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
     def segment(f, v):
         # what neither gives: XLA's segment reduce, told that the ids are
         # sorted
-        with stage_scope("segment", xp):
+        with stage_scope("segment"):
             return f(v, gid, num_segments=cap + 1,
                      indices_are_sorted=True)[:cap]
 
@@ -746,7 +723,7 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
         # a [cap] table that is built whole, cut to the slots
         if at is None:
             return t
-        with stage_scope("gather", xp):
+        with stage_scope("gather"):
             return t[at]
 
     def table(p, at=None):
@@ -761,7 +738,7 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
 
     # inside a non-SENTINEL run every row is unmasked: its length is its
     # row count, and a slot is present exactly where its run is not empty
-    with stage_scope("gather", xp):
+    with stage_scope("gather"):
         rows = starts[1:] - starts[:-1]
 
     def extreme(p, rows_at, at=None):
@@ -775,11 +752,11 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
         word = f"w:{p.name}"
         if word in words:
             running = scan(word)
-            with stage_scope("gather", xp):
+            with stage_scope("gather"):
                 ends = starts[1:] if at is None else starts[at + 1]
                 v = _ext_value(
                     rode[word][1:] if at is None and word in rode else
-                    running[xp.maximum(ends - 1, 0)],
+                    running[jnp.maximum(ends - 1, 0)],
                     p.kind, words[word][2])
         else:
             v = kept(segment(
@@ -788,8 +765,8 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
                 at)
         # an empty slot holds the accumulator's identity, whatever
         # width the rows were reduced at
-        with stage_scope("gather", xp):
-            return xp.where(nn > 0, v.astype(p.acc_dtype),
+        with stage_scope("gather"):
+            return jnp.where(nn > 0, v.astype(p.acc_dtype),
                             _ident(p.acc_dtype, p.kind)), nn
 
     # the [cap] tables a cut is decided from, built before it:
@@ -804,7 +781,7 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
             raise UnsupportedAggregation(
                 f"no device threshold by a {ranked.kind!r}")
         whole[metric] = (table(ranked), None)
-        with stage_scope("threshold", xp):
+        with stage_scope("threshold"):
             at, _ = top_k_groups(whole[metric][0], rows > 0, threshold,
                                  inverted)
     elif having is not None:
@@ -813,43 +790,43 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
             if p.name in names:
                 whole[p.name] = (table(p), None) \
                     if p.kind in ("count", "sum") else extreme(p, rows)
-        with stage_scope("having", xp):
+        with stage_scope("having"):
             passing = (rows > 0) & test(whole, consts)
-            n_kept = passing.sum(dtype=xp.int32)
+            n_kept = passing.sum(dtype=jnp.int32)
             # the passing slots first, in slot order: a one-operand sort,
             # as `starts` is (no scatter, and a gather a kept row)
             slot = jax.lax.sort(
-                xp.where(passing, xp.arange(cap, dtype=xp.int32), cap),
+                jnp.where(passing, jnp.arange(cap, dtype=jnp.int32), cap),
                 is_stable=False)[:n_keep]
             live = slot < cap
-            at = xp.minimum(slot, cap - 1)
+            at = jnp.minimum(slot, cap - 1)
 
     # The key of slot g is its first row's; past the present groups that
     # row is in the SENTINEL tail, or out of bounds
-    with stage_scope("gather", xp):
+    with stage_scope("gather"):
         def first_rows():
             # taken anew a table: the text a wide key's program always had
             return starts[:cap] if at is None else starts[at]
         out = {"_count": count, "_rows": kept(rows, at),
-               "_keys": _key_table(skeys[0], first_rows(), 0, xp)}
+               "_keys": _key_table(skeys[0], first_rows(), 0)}
         if live is not None:
             out["_kept"] = n_kept
-            out["_rows"] = xp.where(live, out["_rows"], 0)
-            out["_keys"] = xp.where(live, out["_keys"], SENTINEL)
+            out["_rows"] = jnp.where(live, out["_rows"], 0)
+            out["_keys"] = jnp.where(live, out["_keys"], SENTINEL)
         for w, name in enumerate(key_names(len(kwords))[1:], 1):
-            out[name] = _key_table(skeys[w], first_rows(), w, xp)
+            out[name] = _key_table(skeys[w], first_rows(), w)
     if narrowed:
         # |a run's sum| <= its rows x the column's largest |value|: where
         # that fits int32 for the longest run, every wrapped difference
         # above is the sum itself. A masked row rides as 0, so the plain
         # max / min over the sorted operand is the unmasked rows'
-        with stage_scope("prefix", xp):
-            longest = rows.max(initial=0).astype(xp.int64)
-            ok = xp.ones((), bool)
+        with stage_scope("prefix"):
+            longest = rows.max(initial=0).astype(jnp.int64)
+            ok = jnp.ones((), bool)
             for name in narrowed:
                 v = sorted_ops[slots[f"v:{name}"]]
-                largest = xp.maximum(v.max(initial=0).astype(xp.int64),
-                                     -v.min(initial=0).astype(xp.int64))
+                largest = jnp.maximum(v.max(initial=0).astype(jnp.int64),
+                                     -v.min(initial=0).astype(jnp.int64))
                 ok = ok & (longest * largest <= np.iinfo(np.int32).max)
             out["_narrow_ok"] = ok
 
@@ -872,9 +849,9 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
         if p.kind == "hll":
             h = sorted_ops[slots[f"h:{p.name}"]]
             valid = sorted_ops[slots[f"hv:{p.name}"]]
-            with stage_scope("segment", xp):
+            with stage_scope("segment"):
                 regs = hll_mod.hll_update(h, valid,
-                                          xp.where(valid, gid, 0), cap + 1)
+                                          jnp.where(valid, gid, 0), cap + 1)
             out[p.name] = kept(regs[:cap], at)
             continue
         if p.kind == "theta":
@@ -883,7 +860,7 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
             # theta_update routes invalid rows to the num_groups pad row
             # itself; gid==cap (overflow/sentinel) rows land in the pad
             # row and are sliced off
-            with stage_scope("segment", xp):
+            with stage_scope("segment"):
                 t = theta_mod.theta_update(h, valid, gid, cap + 1,
                                            p.theta_k)
             out[p.name] = kept(t[:cap], at)
@@ -891,7 +868,7 @@ def sparse_group_reduce(key, mask, env, plans, cap, consts, xp, top=None,
     return out
 
 
-def _key_table(word, first_rows, w, xp):
+def _key_table(word, first_rows, w):
     """The table of word `w` of the key: the sorted `word` at the runs'
     `first_rows`, read in the word's own width (an int32 word is ONE u32
     gather where an int64 is two) and widened to the int64 every caller
@@ -903,7 +880,7 @@ def _key_table(word, first_rows, w, xp):
     if table.dtype == np.int64:
         return table
     wide = table.astype(np.int64)
-    return xp.where(table == fill, SENTINEL, wide) if w == 0 else wide
+    return jnp.where(table == fill, SENTINEL, wide) if w == 0 else wide
 
 
 def _gathered(p) -> bool:
@@ -969,25 +946,50 @@ def boundary_read(plans, col_dtypes, cap, n, top=None, nullable=(),
     return boundary_spelling(n, cap) if whole else None
 
 
-def sparse_group_count(key, mask, xp):
+def program_words(program, plans, col_dtypes, nullable, sizes, key_words,
+                  having=None) -> dict:
+    """What the record, the `dispatch` span and EXPLAIN say of `program`,
+    each word the function of that name's (`reduce_form`:
+    `sparse_reduce_form`'s) of static facts alone: the plan's aggregates,
+    the dtype each aggregated column is stored at (`col_dtypes`), the
+    fields that carry a null mask (`nullable`), the key's id domains and
+    their `pack_key_words`, and the names the plan's device HAVING reads
+    (`having`, counted where the program has its bucket). A word that
+    does not apply (no min / max word, no integer sum, no whole table
+    read at the boundaries) is absent."""
+    cap = program.cap
+    words = {
+        "reduce_form": sparse_reduce_form(plans, col_dtypes, cap),
+        "ext_word_bits": ext_word_bits(plans, col_dtypes, cap),
+        "sum_word_bits": sum_word_bits(plans, col_dtypes, program.narrow),
+        "cap_tables": cap_tables(
+            plans, col_dtypes, cap, program.top, nullable,
+            having if program.kept is not None else None),
+        "boundary_read": program.boundary,
+        "key_words": len(key_words),
+        "key_bits": key_bits(sizes),
+        "key_sort_bits": key_sort_bits(sizes, key_words)}
+    return {k: v for k, v in words.items() if v is not None}
+
+
+def sparse_group_count(key, mask):
     """{"_count": [] int32}: the groups present among the unmasked rows,
     and nothing else: a sort of the key alone (one operand, or one a word
-    of a wide key) and its run boundaries, no table. What the runner asks before it sizes the first
-    compact table of a group space past the budget, whose count it has no
-    hint of: a cap attempt that overflows compiles the whole multi-operand
-    sort program only to learn this number."""
-    import jax
-
+    of a wide key) and its run boundaries, no table. What the dispatch
+    asks first of a group space past the budget whose count no hint tells
+    (`sparse_dispatch.attempt_loop`'s `count_first`): a cap attempt that
+    overflows compiles the whole multi-operand sort program only to
+    learn this number."""
     words = _key_words(key)
     sentinel = _sentinel(words[0])
-    with stage_scope("sort", xp):
+    with stage_scope("sort"):
         skeys = jax.lax.sort(
-            (xp.where(mask, words[0], sentinel), *words[1:]),
+            (jnp.where(mask, words[0], sentinel), *words[1:]),
             num_keys=len(words), is_stable=False)
-    with stage_scope("runs", xp):
-        first = xp.concatenate([xp.ones((1,), bool), _changes(skeys)])
+    with stage_scope("runs"):
+        first = jnp.concatenate([jnp.ones((1,), bool), _changes(skeys)])
         return {"_count": (first & (skeys[0] != sentinel))
-                .sum(dtype=xp.int32)}
+                .sum(dtype=jnp.int32)}
 
 
 def compile_having(spec, plans, pool):
@@ -1005,9 +1007,6 @@ def compile_having(spec, plans, pool):
     [cap] mask as the host would: a min / max over no row is null there,
     and a comparison with null is false. The literals ride the ConstPool
     (`pool`), so one program serves every literal."""
-    import functools
-    import operator
-
     from tpu_olap.ir import having as H
 
     by_name = {p.name: p for p in plans}
@@ -1061,7 +1060,7 @@ def merges_on_device(plans) -> bool:
     return all(p.kind in ("count", "sum", "min", "max") for p in plans)
 
 
-def merge_device(tables: dict, plans, parts: int, xp):
+def merge_device(tables: dict, plans, parts: int):
     """Merge `parts` compact tables on the device. `tables` holds them
     laid end to end (an all_gather of the chips' first rows; an empty
     slot carries the SENTINEL key and the reduces' identities). A chip's
@@ -1073,69 +1072,75 @@ def merge_device(tables: dict, plans, parts: int, xp):
     the merged groups first, in key order, as the one-chip program's
     table has them. No row is gathered or scattered. -> tables as long
     as the input, `_count` the merged groups."""
-    import jax
-
     names = [n for n in tables if n != "_keys"]
     sorted_ops = jax.lax.sort(
         (tables["_keys"],) + tuple(tables[n] for n in names),
         num_keys=1, is_stable=False)
     skey = sorted_ops[0]
     kinds = {p.name: p.kind for p in plans if p.kind in ("min", "max")}
-    first = xp.concatenate([xp.ones((1,), bool), skey[1:] != skey[:-1]]) \
+    first = jnp.concatenate([jnp.ones((1,), bool), skey[1:] != skey[:-1]]) \
         & (skey != SENTINEL)
 
     def later(v, k, fill):
         """v as it stands k rows on, `fill` past the end."""
-        return xp.concatenate([v[k:], xp.full((k,), fill, v.dtype)])
+        return jnp.concatenate([v[k:], jnp.full((k,), fill, v.dtype)])
 
     merged = []
     for name, v in zip(names, sorted_ops[1:]):
         kind = kinds.get(name, "sum")
         fill = _ident(v.dtype, kind) if kind != "sum" else 0
-        f = {"sum": xp.add, "min": xp.minimum, "max": xp.maximum}[kind]
+        f = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}[kind]
         acc = v
         for k in range(1, parts):
             same = later(skey, k, SENTINEL) == skey
-            acc = f(acc, xp.where(same, later(v, k, fill), fill))
+            acc = f(acc, jnp.where(same, later(v, k, fill), fill))
         merged.append(acc)
     out = jax.lax.sort(
-        (xp.where(first, skey, SENTINEL),) + tuple(merged),
+        (jnp.where(first, skey, SENTINEL),) + tuple(merged),
         num_keys=1, is_stable=False)
     empty = out[0] == SENTINEL
-    result = {"_count": first.sum(dtype=xp.int32), "_keys": out[0]}
+    result = {"_count": first.sum(dtype=jnp.int32), "_keys": out[0]}
     for name, v in zip(names, out[1:]):
         kind = kinds.get(name, "sum")
-        result[name] = xp.where(
+        result[name] = jnp.where(
             empty, _ident(v.dtype, kind) if kind != "sum" else 0, v)
     return result
 
 
 def merge_sparse(parts: list, plans, cap):
     """The host broker's merge of compacted tables (the chips' present
-    rows, fetched): concatenate and re-reduce by key into [cap] tables.
-    Values are already partial aggregates, so the merge semantics differ
-    from row reduction — sums and counts re-sum, min/max re-extremize,
-    HLL registers re-max, theta re-merges pairwise."""
-    xp = np
+    rows, fetched), in numpy: concatenate and re-reduce by key into [cap]
+    tables. Values are already partial aggregates, so the merge semantics
+    differ from row reduction — sums and counts re-sum, min/max
+    re-extremize, HLL registers re-max, theta re-merges pairwise."""
     keys = np.concatenate([p["_keys"] for p in parts])
     order = np.argsort(keys, kind="stable")
     skey = keys[order]
-    gid, count = _sorted_segments(skey, cap, xp)
+    # run ids; gid clips into the dropped overflow+sentinel slot `cap`
+    boundary = np.concatenate([np.ones((1,), bool), skey[1:] != skey[:-1]])
+    gid = np.cumsum(boundary.astype(np.int32)) - 1
+    count = (boundary & (skey != SENTINEL)).sum(dtype=np.int32)
+    gid = np.where((gid < cap) & (skey != SENTINEL), gid, cap)
     # a chip whose LOCAL table overflowed already dropped groups; the
     # merged distinct count alone cannot see them, so take the max with
     # every per-part count — the runner then retries with a larger cap
     for p in parts:
         if "_count" in p:
-            count = xp.maximum(count, p["_count"].astype(xp.int32))
+            count = np.maximum(count, p["_count"].astype(np.int32))
 
     def gathered(name):
-        return xp.concatenate([p[name] for p in parts])[order]
+        return np.concatenate([p[name] for p in parts])[order]
 
     def seg_sum(v):
-        return _seg_sum(v, gid, cap, xp)
+        out = np.zeros((cap + 1,) + v.shape[1:], v.dtype)
+        np.add.at(out, gid, v)
+        return out[:cap]
 
     def seg_ext(v, kind):
-        return _seg_ext(v, gid, cap, kind, xp)
+        out = np.full((cap + 1,) + v.shape[1:], _ident(v.dtype, kind),
+                      v.dtype)
+        (np.minimum if kind == "min" else np.maximum).at(out, gid, v)
+        return out[:cap]
 
     out = {"_count": count, "_rows": seg_sum(gathered("_rows"))}
     out["_keys"] = seg_ext(skey, "min")
@@ -1149,42 +1154,31 @@ def merge_sparse(parts: list, plans, cap):
             out[p.name] = seg_ext(gathered(p.name), "max")
         elif p.kind == "theta":
             out[p.name] = _seg_theta_union(gathered(p.name), gid, cap,
-                                           len(parts), xp)
+                                           len(parts))
         else:
             raise UnsupportedAggregation(p.kind)
     return out
 
 
-def _seg_theta_union(rows, gid, cap, n_parts, xp):
-    """Segmented theta union: [n, k] row-sorted tables with group ids
-    `gid` (sorted; cap = dropped pad slot) -> [cap, k] merged tables of
-    the k smallest distinct per group. Each part contributes at most one
-    row per key, so within-group rank < n_parts; rows rank-scatter into
-    a [cap, n_parts*k] wide buffer which sorts, dedupes, and truncates.
-    Transient memory is cap * n_parts * k * 8B — sparse_theta_k_cap
-    keeps that modest."""
-    import jax
-
+def _seg_theta_union(rows, gid, cap, n_parts):
+    """Segmented theta union, in numpy: [n, k] row-sorted tables with
+    group ids `gid` (sorted; cap = dropped pad slot) -> [cap, k] merged
+    tables of the k smallest distinct per group. Each part contributes at
+    most one row per key, so within-group rank < n_parts; rows
+    rank-scatter into a [cap, n_parts*k] wide buffer which sorts,
+    dedupes, and truncates. Transient memory is cap * n_parts * k * 8B —
+    sparse_theta_k_cap keeps that modest."""
     n, k = rows.shape
-    idx = xp.arange(n, dtype=xp.int32)
-    boundary = xp.concatenate([xp.ones((1,), bool), gid[1:] != gid[:-1]])
-    starts = xp.where(boundary, idx, 0)
-    if xp is np:
-        seg_start = np.maximum.accumulate(starts)
-    else:
-        seg_start = jax.lax.cummax(starts)
-    rank = xp.minimum(idx - seg_start, n_parts - 1)
-    slot = gid.astype(xp.int64) * n_parts + rank
-    shape = ((cap + 1) * n_parts, k)
-    if xp is np:
-        buf = np.full(shape, theta_mod.EMPTY, rows.dtype)
-        buf[slot] = rows
-    else:
-        buf = xp.full(shape, theta_mod.EMPTY, rows.dtype) \
-            .at[slot].set(rows, mode="drop")
+    idx = np.arange(n, dtype=np.int32)
+    boundary = np.concatenate([np.ones((1,), bool), gid[1:] != gid[:-1]])
+    seg_start = np.maximum.accumulate(np.where(boundary, idx, 0))
+    rank = np.minimum(idx - seg_start, n_parts - 1)
+    slot = gid.astype(np.int64) * n_parts + rank
+    buf = np.full(((cap + 1) * n_parts, k), theta_mod.EMPTY, rows.dtype)
+    buf[slot] = rows
     wide = buf[:cap * n_parts].reshape(cap, n_parts * k)
-    wide = xp.sort(wide, axis=-1)
-    dup = xp.concatenate(
-        [xp.zeros((cap, 1), bool), wide[:, 1:] == wide[:, :-1]], axis=-1)
-    wide = xp.sort(xp.where(dup, theta_mod.EMPTY, wide), axis=-1)
+    wide = np.sort(wide, axis=-1)
+    dup = np.concatenate(
+        [np.zeros((cap, 1), bool), wide[:, 1:] == wide[:, :-1]], axis=-1)
+    wide = np.sort(np.where(dup, theta_mod.EMPTY, wide), axis=-1)
     return wide[:, :k]
